@@ -17,6 +17,7 @@ elasticsearch_tpu/native for the search kernels).
 from __future__ import annotations
 
 import json
+import logging
 import math
 import os
 import queue
@@ -30,24 +31,19 @@ _NATIVE_DIR = os.path.join(
     "native")
 _BIN_PATH = os.path.join(_NATIVE_DIR, "ml_autodetect")
 
-_build_lock = threading.Lock()
-
+logger = logging.getLogger("elasticsearch_tpu.ml")
 
 def autodetect_binary() -> Optional[str]:
-    """Locate (building on demand) the ml_autodetect binary, or None."""
-    src = os.path.join(_NATIVE_DIR, "ml_autodetect.cc")
-    if not os.path.exists(src):
-        return _BIN_PATH if os.path.exists(_BIN_PATH) else None
-    with _build_lock:
-        if (os.path.exists(_BIN_PATH)
-                and os.path.getmtime(_BIN_PATH) >= os.path.getmtime(src)):
-            return _BIN_PATH
-        try:
-            subprocess.run(["make", "-C", _NATIVE_DIR, "ml_autodetect"],
-                           check=True, capture_output=True, timeout=180)
-        except Exception:
-            return None
-    return _BIN_PATH if os.path.exists(_BIN_PATH) else None
+    """Locate (building on demand) the ml_autodetect binary, or None —
+    the in-process twin below serves a host without a compiler."""
+    from elasticsearch_tpu.native import build_target
+    try:
+        build_target("ml_autodetect", "ml_autodetect.cc")
+    except RuntimeError as exc:
+        logger.warning("ml_autodetect binary unavailable, in-process "
+                       "twin serves: %s", exc)
+        return None
+    return _BIN_PATH
 
 
 class AutodetectProcess:

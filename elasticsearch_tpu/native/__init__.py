@@ -15,11 +15,15 @@ implementation ran; `AVAILABLE` reports it for stats/tests.
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import logging
 import os
 import subprocess
 from typing import Optional, Tuple
 
 import numpy as np
+
+logger = logging.getLogger("elasticsearch_tpu.native")
 
 _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
@@ -27,37 +31,64 @@ _NATIVE_DIR = os.path.join(
 _SO_PATH = os.path.join(_NATIVE_DIR, "libes_native.so")
 
 _lib: Optional[ctypes.CDLL] = None
-_load_attempted = False
+_load_error: Optional[str] = None
 AVAILABLE = False
 
 
-def _try_build() -> bool:
-    src = os.path.join(_NATIVE_DIR, "es_native.cc")
-    if not os.path.exists(src):
-        return False
-    if (os.path.exists(_SO_PATH)
-            and os.path.getmtime(_SO_PATH) >= os.path.getmtime(src)):
-        return True
-    try:
-        subprocess.run(["make", "-C", _NATIVE_DIR, "libes_native.so"], check=True,
-                       capture_output=True, timeout=120)
-        return os.path.exists(_SO_PATH)
-    except Exception:
-        return False
+def _up_to_date(target: str, src: str) -> bool:
+    return (os.path.exists(target)
+            and os.path.getmtime(target) >= os.path.getmtime(src))
+
+
+def build_target(target: str, src: str, timeout: int = 180) -> None:
+    """`make` one native target, safe under concurrent first use.
+
+    A fresh checkout has no build outputs (they are git-ignored), so every
+    process that starts together — pytest workers, a server beside its
+    client — reaches this at once. The Makefile links to a temporary name
+    and renames it into place, so a target that exists is always whole;
+    the file lock only keeps the others from compiling the same thing
+    again. Raises RuntimeError carrying the compiler's output."""
+    path = os.path.join(_NATIVE_DIR, target)
+    src_path = os.path.join(_NATIVE_DIR, src)
+    if not os.path.exists(src_path):
+        if os.path.exists(path):
+            return
+        raise RuntimeError(f"native source {src_path} is missing")
+    if _up_to_date(path, src_path):
+        return
+    with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _up_to_date(path, src_path):
+            return
+        try:
+            proc = subprocess.run(["make", "-C", _NATIVE_DIR, target],
+                                  capture_output=True, text=True,
+                                  timeout=timeout)
+        except (OSError, subprocess.TimeoutExpired) as exc:
+            raise RuntimeError(f"make {target} did not run: {exc}") from exc
+        if proc.returncode != 0 or not os.path.exists(path):
+            raise RuntimeError(
+                f"make {target} failed (rc {proc.returncode}):\n"
+                f"{proc.stdout}{proc.stderr}")
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, AVAILABLE, _load_attempted
+    """The library, or None when it could not be built or loaded. The
+    reason is kept in `_load_error` (and logged once): bindings with a
+    numpy fallback go on without it, `require()` raises it."""
+    global _lib, AVAILABLE, _load_error
     if _lib is not None:
         return _lib
-    if _load_attempted:
-        return None  # build/load failed once; don't retry per call
-    _load_attempted = True
-    if not _try_build():
-        return None
+    if _load_error is not None:
+        return None  # build/load failed once; don't rebuild per call
     try:
+        build_target("libes_native.so", "es_native.cc")
         lib = ctypes.CDLL(_SO_PATH)
-    except OSError:
+    except (RuntimeError, OSError) as exc:
+        _load_error = str(exc)
+        logger.warning("native library unavailable, numpy fallbacks "
+                       "serve: %s", _load_error)
         return None
     i64p = ctypes.POINTER(ctypes.c_int64)
     i32p = ctypes.POINTER(ctypes.c_int32)
@@ -77,6 +108,15 @@ def _load() -> Optional[ctypes.CDLL]:
     lib.es_topk_f32.restype = ctypes.c_int64
     _lib = lib
     AVAILABLE = True
+    return lib
+
+
+def require() -> ctypes.CDLL:
+    """The library, for callers with no fallback: raises with the build
+    or load error (the compiler's output) instead of returning None."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError(f"native kernels unavailable: {_load_error}")
     return lib
 
 
@@ -185,9 +225,7 @@ def knn_i8p_topk(queries: np.ndarray, packed: np.ndarray, n: int, d4: int,
     or [B, ng*16] per-query u8. Returns (scores [B, k], rows [B, k]) with
     -inf/-1 padding. Requires the native library (no numpy fallback — the
     caller routes to the device path when unavailable)."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native kernels unavailable")
+    lib = require()
     queries = np.ascontiguousarray(queries, dtype=np.float32)
     b, d = queries.shape
     out_s = np.empty((b, k), dtype=np.float32)

@@ -232,7 +232,7 @@ class _Kernel:
         self.donate_argnums = tuple(donate_argnums)
         self.grid_check = grid_check
         self.jitted = None  # built lazily (jax import cost)
-        # x64 kernels trace AND execute under jax.experimental.enable_x64:
+        # x64 kernels trace AND execute under jax.enable_x64:
         # the process default stays 32-bit (the serving kernels are f32 by
         # design), but 64-bit accumulator kernels (aggs.*: int64 counts,
         # f64 sums — date millis don't fit int32/f32) need the scoped flag
@@ -246,8 +246,8 @@ def _x64_scope(enabled: bool):
     if not enabled:
         import contextlib
         return contextlib.nullcontext()
-    from jax.experimental import enable_x64
-    return enable_x64()
+    import jax
+    return jax.enable_x64(True)
 
 
 class _Entry:

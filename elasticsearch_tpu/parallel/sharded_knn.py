@@ -40,11 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pre-0.6 jax keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from elasticsearch_tpu.ops import dispatch
 from elasticsearch_tpu.ops import knn as knn_ops
 from elasticsearch_tpu.ops import similarity as sim
@@ -54,14 +49,10 @@ from elasticsearch_tpu.parallel import mesh as mesh_lib
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking off (the
-    knob was renamed check_rep → check_vma across jax releases)."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    """`jax.shard_map` with replication checking off — the one place the
+    package builds sharded programs from (tpulint TPU001)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class ShardedCorpus(NamedTuple):

@@ -351,8 +351,11 @@ def int4_planes_jnp(packed, dtype=None):
 
 def split_query_planes_jnp(queries):
     """Match a query batch [Q, D] to the int4 plane layout:
-    (even dims [Q, D/2], odd dims [Q, D/2])."""
-    return queries[:, 0::2], queries[:, 1::2]
+    (even dims [Q, D/2], odd dims [Q, D/2]). A reshape, not a strided
+    index: `queries[:, 0::2]` lowers to a gather that hands the matmul a
+    transposed bf16 operand, which XLA-CPU's dot cannot run."""
+    pairs = queries.reshape(queries.shape[0], -1, 2)
+    return pairs[:, :, 0], pairs[:, :, 1]
 
 
 def pack_sign_bits_jnp(queries):

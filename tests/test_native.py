@@ -8,8 +8,63 @@ from elasticsearch_tpu import native
 
 @pytest.fixture(scope="module", autouse=True)
 def built():
-    assert native._load() is not None, "native library failed to build"
+    native.require()  # raises with the compiler's output
     assert native.AVAILABLE
+
+
+def test_concurrent_first_use_builds_once_and_all_load(tmp_path):
+    """A fresh checkout has no `native/*.so`; every process that starts
+    together builds on first import. Four importers against an empty
+    build directory must all load the library (the old in-place `make`
+    let a second process CDLL a half-written file and latch the failure
+    for its lifetime)."""
+    import os
+    import shutil
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    # a scratch tree shaped like the checkout: the loader finds native/
+    # relative to its own file, so no option of the program is needed
+    pkg = tmp_path / "elasticsearch_tpu" / "native"
+    pkg.mkdir(parents=True)
+    (tmp_path / "elasticsearch_tpu" / "__init__.py").write_text("")
+    shutil.copy(os.path.join(repo, "elasticsearch_tpu", "native",
+                             "__init__.py"), pkg / "__init__.py")
+    build = tmp_path / "native"
+    build.mkdir()
+    for name in ("Makefile", "es_native.cc"):
+        shutil.copy(os.path.join(repo, "native", name), build / name)
+    code = ("import elasticsearch_tpu.native as n; "
+            "assert n.AVAILABLE, n._load_error; "
+            "assert n.__file__.startswith(%r); "
+            "print(n.topk(__import__('numpy').arange(5.0), 2).tolist())"
+            % str(tmp_path))
+    env = dict(os.environ, PYTHONPATH=str(tmp_path))
+    procs = [subprocess.Popen([sys.executable, "-c", code], env=env,
+                              cwd=str(tmp_path), stdout=subprocess.PIPE,
+                              stderr=subprocess.PIPE, text=True)
+             for _ in range(4)]
+    outs = [p.communicate(timeout=170) for p in procs]
+    for p, (out, err) in zip(procs, outs):
+        assert p.returncode == 0, err
+        assert out.strip() == "[4, 3]"
+    left = sorted(os.listdir(build))
+    assert "libes_native.so" in left
+    assert not [f for f in left if f.endswith(".tmp")], left
+
+
+def test_require_raises_with_build_output(monkeypatch):
+    """A caller with no fallback gets the build error, not a bare None."""
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_load_error",
+                        "make libes_native.so failed (rc 2):\ng++: boom")
+    with pytest.raises(RuntimeError, match="g\\+\\+: boom"):
+        native.require()
+    with pytest.raises(RuntimeError, match="native kernels unavailable"):
+        native.knn_i8p_topk(np.zeros((1, 4), np.float32),
+                            np.zeros(64, np.uint8), 1, 1,
+                            np.ones(16, np.float32), None, 1.0, None, 1)
 
 
 def ref_bm25(freqs, lengths, idf, avg_len, k1, b, boost):

@@ -8,5 +8,10 @@ def sum64(values):
         return values.sum()
 
 
+def sum64_installed_spelling(values):
+    with jax.enable_x64(True):  # [expect] x64 reference
+        return values.sum()
+
+
 def flip_global():
     jax.config.update("jax_enable_x64", True)  # [expect] global flip

@@ -100,10 +100,10 @@ class RawJitRule(Rule):
     owns `jax.jit(...).lower(...).compile()`, a closed bucket grid, and
     strict-mode enforcement. A raw `jax.jit` anywhere else is a second,
     unbucketed compile path the strict gate cannot see. Raw-JAX
-    `shard_map` imports are confined to the version-portable wrapper in
-    `parallel/sharded_knn.py` for the same reason (plus the 0.4.37 import
-    split the seed tripped over); building programs THROUGH that wrapper
-    and registering them is the sanctioned pattern.
+    `shard_map` references (`jax.shard_map`, or the deprecated
+    `jax.experimental.shard_map` spelling) are confined to the wrapper in
+    `parallel/sharded_knn.py` for the same reason; building programs
+    THROUGH that wrapper and registering them is the sanctioned pattern.
     """
 
     rule_id = "TPU001"
@@ -170,7 +170,7 @@ class RawJitRule(Rule):
                     findings.append(ctx.finding(
                         self.rule_id, node,
                         "raw JAX shard_map import — build sharded "
-                        "programs through the version-portable wrapper "
+                        "programs through the wrapper "
                         "(parallel/sharded_knn.shard_map) and register "
                         "them with the dispatcher"))
         return findings
