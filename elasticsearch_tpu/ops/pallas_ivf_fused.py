@@ -46,16 +46,6 @@ from elasticsearch_tpu.quant import codec as quant_codec
 _NEG = float(NEG_INF)
 
 
-def default_interpret() -> bool:
-    """Mosaic compiles only on TPU-class backends (same probe as the
-    binned kNN kernel)."""
-    return not dispatch.is_accelerator_backend()
-
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    return default_interpret() if interpret is None else bool(interpret)
-
-
 def fused_eligible(parts_dtype, metric: str, precision: str = "bf16") -> bool:
     """Can the fused kernel serve this layout? (dtype on the fused
     ladder, dot-like metric, bf16 serving precision). Callers separately
@@ -88,67 +78,74 @@ def _dense_kernel(ids_ref, q_ref, parts_ref, scales_ref, out_ref):
     row (1/0) otherwise — zero on padding either way, so the same mask
     pins padding slots to NEG_INF before the board leaves the kernel."""
     dots = jax.lax.dot_general(
-        q_ref[:].astype(jnp.bfloat16), parts_ref[0].astype(jnp.bfloat16),
+        q_ref[:].astype(jnp.bfloat16), parts_ref[:].astype(jnp.bfloat16),
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     s = dots * scales_ref[:]
-    out_ref[0] = jnp.where(scales_ref[:] > 0, s, _NEG)
+    out_ref[:] = jnp.where(scales_ref[:] > 0, s, _NEG)
 
 
 def _int4_kernel(ids_ref, qe_ref, qo_ref, parts_ref, scales_ref, out_ref):
     """int4 packed-nibble tiles: unpack the (even, odd) level planes
     in-register and run two half-width passes against the matching
     query planes (the codec's one bit layout)."""
-    tile = parts_ref[0]
-    lo = ((tile & jnp.uint8(0x0F)).astype(jnp.int32) - 8).astype(jnp.bfloat16)
-    hi = ((tile >> 4).astype(jnp.int32) - 8).astype(jnp.bfloat16)
+    # widen before the nibble split: Mosaic has no 8-bit vector shift
+    # ("failed to legalize operation 'arith.shrui'" on i8 lanes)
+    tile = parts_ref[:].astype(jnp.int32)
+    lo = ((tile & 0x0F) - 8).astype(jnp.bfloat16)
+    hi = ((tile >> 4) - 8).astype(jnp.bfloat16)
     dn = (((1,), (1,)), ((), ()))
     dots = (jax.lax.dot_general(qe_ref[:].astype(jnp.bfloat16), lo, dn,
                                 preferred_element_type=jnp.float32)
             + jax.lax.dot_general(qo_ref[:].astype(jnp.bfloat16), hi, dn,
                                   preferred_element_type=jnp.float32))
     s = dots * scales_ref[:]
-    out_ref[0] = jnp.where(scales_ref[:] > 0, s, _NEG)
+    out_ref[:] = jnp.where(scales_ref[:] > 0, s, _NEG)
 
 
 def _fused_probe_board(queries, ivf: IVFPartitions, probe_ids,
                        interpret: bool):
     """[Q, nprobe, cap] masked score board, tiles gathered via the
-    scalar-prefetched probe ids (one partition tile per grid step)."""
+    scalar-prefetched probe ids (one partition tile per grid step).
+
+    Every one-row operand carries a singleton axis ahead of its lanes
+    ([nq, 1, d], [nlist, 1, cap], [nq, nprobe, 1, cap]) so the last two
+    block dimensions equal the array's — Mosaic refuses a (1, d) block of
+    an [nq, d] array (sublane dimension neither 8-divisible nor whole).
+    The leading block dimensions are squeezed: the kernel bodies see
+    [1, d] / [cap, w] / [1, cap] tiles."""
     nq = queries.shape[0]
     nprobe = probe_ids.shape[1]
     nlist, cap, w = ivf.parts.shape
-    out_shape = jax.ShapeDtypeStruct((nq, nprobe, cap), jnp.float32)
-    out_spec = pl.BlockSpec((1, 1, cap), lambda q, j, ids: (q, j, 0))
-    part_spec = pl.BlockSpec((1, cap, w), lambda q, j, ids: (ids[q, j], 0, 0))
-    scale_spec = pl.BlockSpec((1, cap), lambda q, j, ids: (ids[q, j], 0))
+    out_shape = jax.ShapeDtypeStruct((nq, nprobe, 1, cap), jnp.float32)
+    out_spec = pl.BlockSpec((None, None, 1, cap),
+                            lambda q, j, ids: (q, j, 0, 0))
+    part_spec = pl.BlockSpec((None, cap, w),
+                             lambda q, j, ids: (ids[q, j], 0, 0))
+    scale_spec = pl.BlockSpec((None, 1, cap),
+                              lambda q, j, ids: (ids[q, j], 0, 0))
+    scales = ivf.part_scales.reshape(nlist, 1, cap)
+
+    def q_spec(width):
+        return pl.BlockSpec((None, 1, width), lambda q, j, ids: (q, 0, 0))
+
     if ivf.parts.dtype == jnp.uint8:
         qe, qo = quant_codec.split_query_planes_jnp(
             queries.astype(jnp.float32))
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(nq, nprobe),
-            in_specs=[
-                pl.BlockSpec((1, w), lambda q, j, ids: (q, 0)),
-                pl.BlockSpec((1, w), lambda q, j, ids: (q, 0)),
-                part_spec, scale_spec,
-            ],
-            out_specs=out_spec)
-        return pl.pallas_call(
-            _int4_kernel, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret,
-        )(probe_ids, qe, qo, ivf.parts, ivf.part_scales)
-    d = w
+        kernel = _int4_kernel
+        q_ops = (qe.reshape(nq, 1, w), qo.reshape(nq, 1, w))
+    else:
+        kernel = _dense_kernel
+        q_ops = (queries.astype(jnp.float32).reshape(nq, 1, w),)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(nq, nprobe),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda q, j, ids: (q, 0)),
-            part_spec, scale_spec,
-        ],
+        in_specs=[q_spec(w)] * len(q_ops) + [part_spec, scale_spec],
         out_specs=out_spec)
-    return pl.pallas_call(
-        _dense_kernel, grid_spec=grid_spec, out_shape=out_shape,
+    board = pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
         interpret=interpret,
-    )(probe_ids, queries.astype(jnp.float32), ivf.parts, ivf.part_scales)
+    )(probe_ids, *q_ops, ivf.parts, scales)
+    return board.reshape(nq, nprobe, cap)
 
 
 def _fused_probe_impl(queries, ivf: IVFPartitions, probe_ids, k: int,
@@ -184,7 +181,7 @@ def fused_probe_scores(queries, ivf: IVFPartitions, probe_ids, k: int,
     """
     return dispatch.call("ivf.fused_probe", queries, ivf, probe_ids,
                          k=k, metric=metric,
-                         interpret=_resolve_interpret(interpret))
+                         interpret=dispatch.pallas_interpret(interpret))
 
 
 def warmup_entries(ivf: IVFPartitions, nprobe: int, dims: int, k_buckets,
@@ -198,7 +195,7 @@ def warmup_entries(ivf: IVFPartitions, nprobe: int, dims: int, k_buckets,
     parts_spec = dispatch.specs_like(ivf)
     entries = []
     cap = ivf.parts.shape[1]
-    interp = _resolve_interpret(interpret)
+    interp = dispatch.pallas_interpret(interpret)
     for q in query_buckets:
         qspec = dispatch.query_spec(q, dims)
         pspec = jax.ShapeDtypeStruct((q, nprobe), jnp.int32)

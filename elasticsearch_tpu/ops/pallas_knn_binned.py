@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from elasticsearch_tpu.ops import dispatch
 from elasticsearch_tpu.ops import similarity as sim
@@ -47,20 +48,6 @@ MASK = ~((1 << IDX_BITS) - 1)
 # cosine scores live in [-1, 1]; dot products are clamped into this window
 SHIFT = 4.0
 CLAMP = 3.0
-
-
-def default_interpret() -> bool:
-    """Mosaic compiles only on TPU-class backends; everywhere else the
-    kernel must run in interpret mode or `pallas_call` raises "Only
-    interpret mode is supported on CPU backend" (the r06
-    run_north_star_10m_int8 CPU-capture failure). Every public entry
-    resolves `interpret=None` through this probe."""
-    from elasticsearch_tpu.ops import dispatch
-    return not dispatch.is_accelerator_backend()
-
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    return default_interpret() if interpret is None else bool(interpret)
 
 
 def _reduce_packed(p, out_ref):
@@ -237,7 +224,7 @@ def binned_knn_search(
     interpret=None auto-detects (interpret mode off TPU backends).
     """
     return dispatch.call("knn.binned", queries, corpus, k=k, metric=metric,
-                         interpret=_resolve_interpret(interpret))
+                         interpret=dispatch.pallas_interpret(interpret))
 
 
 def _rescored_impl(queries, corpus, k: int, metric: str,
@@ -297,7 +284,7 @@ def binned_knn_search_rescored(
     the recall gate is tight, a real tax on small corpora."""
     return dispatch.call("knn.binned_rescored", queries, corpus, k=k,
                          metric=metric, rescore_bins=rescore_bins,
-                         interpret=_resolve_interpret(interpret))
+                         interpret=dispatch.pallas_interpret(interpret))
 
 
 def _rescored_packed_impl(queries, corpus, k: int, metric: str,
@@ -348,7 +335,7 @@ def binned_knn_search_rescored_packed(
     return dispatch.call("knn.binned_rescored_packed", queries, corpus,
                          k=k, metric=metric,
                          rescore_candidates=rescore_candidates,
-                         interpret=_resolve_interpret(interpret))
+                         interpret=dispatch.pallas_interpret(interpret))
 
 
 def _rescored_hybrid_impl(queries, corpus, k: int, metric: str,
@@ -423,7 +410,43 @@ def binned_knn_search_rescored_hybrid(
     return dispatch.call("knn.binned_rescored_hybrid", queries, corpus,
                          k=k, metric=metric, rescore_bins=rescore_bins,
                          rescore_candidates=rescore_candidates,
-                         interpret=_resolve_interpret(interpret))
+                         interpret=dispatch.pallas_interpret(interpret))
+
+
+# v5e has 128 MiB of VMEM per core; the compiler's default scoped limit is
+# 16 MiB. The kernel asks for what its blocks need (below) and never more
+# than this, which leaves the compiler room for its own scratch.
+VMEM_LIMIT_CAP = 100 << 20
+# Query rows per grid step. Buckets above it tile the query axis (the
+# corpus tile stays resident across the inner query steps), so the
+# [QUERY_TILE, BLOCK_N] f32/int32 temporaries are bounded whatever the
+# bucket.
+QUERY_TILE = 256
+
+
+def vmem_bytes(nq: int, d: int, itemsize: int) -> int:
+    """Scoped VMEM one grid step needs: the double-buffered corpus and
+    query tiles (lanes pad to 128), the [tq, BLOCK_N] score and packed
+    temporaries the 64-deep reduction spills, and the small row/output
+    blocks. An estimate from above, held to the chip's compiler in
+    tests/test_chip_compile.py: every width `kernel_holds` admits must
+    compile under this limit."""
+    tq = min(nq, QUERY_TILE)
+    d_pad = -(-d // 128) * 128
+    tiles = 2 * (BLOCK_N + max(tq, 32)) * d_pad * itemsize
+    temps = 3 * max(tq, 8) * BLOCK_N * 4
+    rows = 2 * 3 * 8 * BLOCK_N * 4 + 2 * max(tq, 8) * BINS_PER_TILE * 4
+    return tiles + temps + rows + (2 << 20)
+
+
+def kernel_holds(d: int, matrix_dtype) -> bool:
+    """Whether the binned kernel can hold a corpus of this row width at
+    EVERY query bucket — the routing fact `knn_search_auto`,
+    `build_corpus`'s padding and the store's warmup grid all read, so a
+    shape the chip's compiler would refuse is never sent (it takes the
+    exact path by this rule, not by a caught compile error)."""
+    itemsize = 1 if jnp.dtype(matrix_dtype) == jnp.int8 else 2
+    return vmem_bytes(QUERY_TILE, d, itemsize) <= VMEM_LIMIT_CAP
 
 
 def _binned_packed(queries, corpus, metric, interpret):
@@ -432,10 +455,32 @@ def _binned_packed(queries, corpus, metric, interpret):
         raise ValueError(f"corpus rows {n_pad} not divisible by {BLOCK_N}")
     q = _prep_queries(queries, metric)
     nq = q.shape[0]
+    tq = min(nq, QUERY_TILE)
+    if nq % tq != 0:
+        raise ValueError(f"query rows {nq} not a multiple of {tq}")
     n_tiles = n_pad // BLOCK_N
     valid, tpat = _tile_patterns(n_pad, corpus.num_valid)
+    int8 = corpus.matrix.dtype == jnp.int8
+    # grid: corpus tiles outer, query tiles inner — the corpus block index
+    # does not change across the inner steps, so each tile is fetched from
+    # HBM once however many query tiles score against it
+    grid = (n_tiles, nq // tq)
+    q_spec = pl.BlockSpec((tq, d), lambda i, j: (j, 0))
+    c_spec = pl.BlockSpec((BLOCK_N, d), lambda i, j: (i, 0))
+    row_spec = pl.BlockSpec((1, BLOCK_N), lambda i, j: (0, i))
+    tpat_spec = pl.BlockSpec((1, BLOCK_N), lambda i, j: (0, 0))
+    call = dict(
+        grid=grid,
+        out_specs=pl.BlockSpec((tq, BINS_PER_TILE), lambda i, j: (j, i)),
+        out_shape=jax.ShapeDtypeStruct((nq, n_tiles * BINS_PER_TILE),
+                                       jnp.int32),
+        interpret=interpret,
+    )
+    if not interpret:
+        call["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes(nq, d, 1 if int8 else 2))
 
-    if corpus.matrix.dtype == jnp.int8:
+    if int8:
         # symmetric per-query quantization (the codec registry's one
         # int8 recipe, in-trace twin); dequant inside the kernel
         from elasticsearch_tpu.quant import codec as quant_codec
@@ -443,18 +488,10 @@ def _binned_packed(queries, corpus, metric, interpret):
         row_scale_valid = (corpus.scales.reshape(1, n_pad) * valid)
         packed = pl.pallas_call(
             _int8_kernel,
-            grid=(n_tiles,),
-            in_specs=[
-                pl.BlockSpec((nq, d), lambda i: (0, 0)),
-                pl.BlockSpec((BLOCK_N, d), lambda i: (i, 0)),
-                pl.BlockSpec((nq, 1), lambda i: (0, 0)),
-                pl.BlockSpec((1, BLOCK_N), lambda i: (0, i)),
-                pl.BlockSpec((1, BLOCK_N), lambda i: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((nq, BINS_PER_TILE), lambda i: (0, i)),
-            out_shape=jax.ShapeDtypeStruct(
-                (nq, n_tiles * BINS_PER_TILE), jnp.int32),
-            interpret=interpret,
+            in_specs=[q_spec, c_spec,
+                      pl.BlockSpec((tq, 1), lambda i, j: (j, 0)),
+                      row_spec, tpat_spec],
+            **call,
         )(q8, corpus.matrix, qscale.astype(jnp.float32),
           row_scale_valid, tpat)
         return packed, q
@@ -464,15 +501,7 @@ def _binned_packed(queries, corpus, metric, interpret):
     kernel = _KERNEL_COSINE if metric == sim.COSINE else _KERNEL_CLAMPED
     packed = pl.pallas_call(
         kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((nq, d), lambda i: (0, 0)),
-            pl.BlockSpec((BLOCK_N, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, BLOCK_N), lambda i: (0, i)),
-            pl.BlockSpec((1, BLOCK_N), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((nq, BINS_PER_TILE), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((nq, n_tiles * BINS_PER_TILE), jnp.int32),
-        interpret=interpret,
+        in_specs=[q_spec, c_spec, row_spec, tpat_spec],
+        **call,
     )(qb, mb, valid, tpat)
     return packed, q

@@ -51,19 +51,18 @@ _NEG = float(NEG_INF)
 LANE = 128
 
 
-def default_interpret() -> bool:
-    """Mosaic compiles only on TPU-class backends (same probe as the
-    fused IVF kernel)."""
-    return not dispatch.is_accelerator_backend()
-
-
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    return default_interpret() if interpret is None else bool(interpret)
-
-
 # ---------------------------------------------------------------------------
 # kernel bodies — one (query, candidate doc) token tile per grid step
 # ---------------------------------------------------------------------------
+
+def _max_then_sum(masked):
+    """MaxSim reduce of a [Tq, cap] board to [1, 1], rank-2 all the way:
+    Mosaic cannot relayout the rank-1 [Tq] vector a plain `max(axis=1)`
+    leaves ("Invalid relayout: Non-singleton logical dimension is
+    replicated in destination but not in source")."""
+    best = jnp.max(masked, axis=1, keepdims=True)       # [Tq, 1]
+    return jnp.sum(best, axis=0, keepdims=True)         # [1, 1]
+
 
 def _dense_kernel(ids_ref, q_ref, toks_ref, scales_ref, out_ref):
     """f32/bf16/int8 token tiles: [Tq, D] x [cap, D]^T with f32
@@ -72,12 +71,12 @@ def _dense_kernel(ids_ref, q_ref, toks_ref, scales_ref, out_ref):
     padding slots, then the MaxSim reduce: max over doc tokens, sum
     over query tokens."""
     dots = jax.lax.dot_general(
-        q_ref[0].astype(jnp.bfloat16), toks_ref[0].astype(jnp.bfloat16),
+        q_ref[:].astype(jnp.bfloat16), toks_ref[:].astype(jnp.bfloat16),
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)            # [Tq, cap]
     s = scales_ref[:]                                   # [1, cap]
     masked = jnp.where(s > 0, dots * s, _NEG)
-    out_ref[...] = jnp.sum(jnp.max(masked, axis=1)).reshape(1, 1)
+    out_ref[:] = _max_then_sum(masked)
 
 
 def _int4_kernel(ids_ref, qe_ref, qo_ref, toks_ref, scales_ref, out_ref):
@@ -85,17 +84,18 @@ def _int4_kernel(ids_ref, qe_ref, qo_ref, toks_ref, scales_ref, out_ref):
     planes in-register and run two half-width passes against the
     matching query planes (the codec's one bit layout), then the same
     masked MaxSim reduce."""
-    tile = toks_ref[0]
-    lo = ((tile & jnp.uint8(0x0F)).astype(jnp.int32) - 8).astype(jnp.bfloat16)
-    hi = ((tile >> 4).astype(jnp.int32) - 8).astype(jnp.bfloat16)
+    # widen before the nibble split: Mosaic has no 8-bit vector shift
+    tile = toks_ref[:].astype(jnp.int32)
+    lo = ((tile & 0x0F) - 8).astype(jnp.bfloat16)
+    hi = ((tile >> 4) - 8).astype(jnp.bfloat16)
     dn = (((1,), (1,)), ((), ()))
-    dots = (jax.lax.dot_general(qe_ref[0].astype(jnp.bfloat16), lo, dn,
+    dots = (jax.lax.dot_general(qe_ref[:].astype(jnp.bfloat16), lo, dn,
                                 preferred_element_type=jnp.float32)
-            + jax.lax.dot_general(qo_ref[0].astype(jnp.bfloat16), hi, dn,
+            + jax.lax.dot_general(qo_ref[:].astype(jnp.bfloat16), hi, dn,
                                   preferred_element_type=jnp.float32))
     s = scales_ref[:]
     masked = jnp.where(s > 0, dots * s, _NEG)
-    out_ref[...] = jnp.sum(jnp.max(masked, axis=1)).reshape(1, 1)
+    out_ref[:] = _max_then_sum(masked)
 
 
 def _maxsim_impl(ids, q, qe, qo, toks, scales, interpret: bool):
@@ -105,32 +105,35 @@ def _maxsim_impl(ids, q, qe, qo, toks, scales, interpret: bool):
     path passes the (even, odd) query planes [Q, Tq, W] with q None."""
     nq, wc = ids.shape
     _n_pad, cap, wd = toks.shape
-    out_shape = jax.ShapeDtypeStruct((nq, wc), jnp.float32)
-    out_spec = pl.BlockSpec((1, 1), lambda qi, j, ids_: (qi, j))
-    tok_spec = pl.BlockSpec((1, cap, wd),
+    # one-row / one-cell operands carry singleton axes (scales arrive
+    # resident as [n_pad, 1, cap]; the board leaves as [nq, wc, 1, 1])
+    # so the last two block dimensions equal the array's — Mosaic
+    # refuses a (1, cap) block of [n_pad, cap] and a (1, 1) block of
+    # [nq, wc]. Leading block dimensions are squeezed.
+    out_shape = jax.ShapeDtypeStruct((nq, wc, 1, 1), jnp.float32)
+    out_spec = pl.BlockSpec((None, None, 1, 1),
+                            lambda qi, j, ids_: (qi, j, 0, 0))
+    tok_spec = pl.BlockSpec((None, cap, wd),
                             lambda qi, j, ids_: (ids_[qi, j], 0, 0))
-    scale_spec = pl.BlockSpec((1, cap), lambda qi, j, ids_: (ids_[qi, j], 0))
+    scale_spec = pl.BlockSpec((None, 1, cap),
+                              lambda qi, j, ids_: (ids_[qi, j], 0, 0))
     if toks.dtype == jnp.uint8:
-        tq = qe.shape[1]
-        qspec = pl.BlockSpec((1, tq, wd), lambda qi, j, ids_: (qi, 0, 0))
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(nq, wc),
-            in_specs=[qspec, qspec, tok_spec, scale_spec],
-            out_specs=out_spec)
-        return pl.pallas_call(
-            _int4_kernel, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret,
-        )(ids, qe.astype(jnp.float32), qo.astype(jnp.float32), toks, scales)
-    tq = q.shape[1]
-    qspec = pl.BlockSpec((1, tq, wd), lambda qi, j, ids_: (qi, 0, 0))
+        kernel = _int4_kernel
+        q_ops = (qe.astype(jnp.float32), qo.astype(jnp.float32))
+    else:
+        kernel = _dense_kernel
+        q_ops = (q.astype(jnp.float32),)
+    tq = q_ops[0].shape[1]
+    qspec = pl.BlockSpec((None, tq, wd), lambda qi, j, ids_: (qi, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(nq, wc),
-        in_specs=[qspec, tok_spec, scale_spec],
+        in_specs=[qspec] * len(q_ops) + [tok_spec, scale_spec],
         out_specs=out_spec)
-    return pl.pallas_call(
-        _dense_kernel, grid_spec=grid_spec, out_shape=out_shape,
+    board = pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
         interpret=interpret,
-    )(ids, q.astype(jnp.float32), toks, scales)
+    )(ids, *q_ops, toks, scales)
+    return board.reshape(nq, wc)
 
 
 def _grid_maxsim(statics, sigs) -> bool:
@@ -156,8 +159,10 @@ dispatch.DISPATCH.register(
 
 def _split_token_planes(q):
     """(even, odd) dim planes of a [Q, Tq, D] token batch — the 3-D
-    twin of `quant_codec.split_query_planes_jnp` (same bit layout)."""
-    return q[:, :, 0::2], q[:, :, 1::2]
+    twin of `quant_codec.split_query_planes_jnp` (same bit layout, and
+    a reshape rather than a strided index for the same reason)."""
+    pairs = q.reshape(q.shape[0], q.shape[1], -1, 2)
+    return pairs[..., 0], pairs[..., 1]
 
 
 def maxsim_rescore(ids, q_tokens, toks, scales,
@@ -167,16 +172,18 @@ def maxsim_rescore(ids, q_tokens, toks, scales,
 
     q_tokens [Q, Tq, D] f32 must be metric-prepped and zero-padded to
     the tile's lane width and a pow-2 Tq; toks/scales are the field's
-    [N_pad, cap, W] device tile + [N_pad, cap] per-token scales.
+    [N_pad, cap, W] device tile + [N_pad, 1, cap] per-token scales (the
+    singleton axis is the resident layout, see `_maxsim_impl` — a
+    per-call reshape would copy the whole array).
     Invalid candidate slots must point at an all-padding doc row (the
     field layout reserves one), which scores NEG_INF. Returns the
     [Q, W] f32 board."""
     if toks.dtype == jnp.uint8:
         qe, qo = _split_token_planes(q_tokens)
         return dispatch.call("maxsim.rescore", ids, None, qe, qo, toks,
-                             scales, interpret=_resolve_interpret(interpret))
+                             scales, interpret=dispatch.pallas_interpret(interpret))
     return dispatch.call("maxsim.rescore", ids, q_tokens, None, None, toks,
-                         scales, interpret=_resolve_interpret(interpret))
+                         scales, interpret=dispatch.pallas_interpret(interpret))
 
 
 def maxsim_reference(ids, q_tokens, toks, scales):
@@ -209,7 +216,7 @@ def maxsim_reference(ids, q_tokens, toks, scales):
             qb = qtok.astype(jnp.bfloat16)
         for j in range(wc):
             tile = toks[ids[qi, j]]
-            s = scales[ids[qi, j]][None, :]
+            s = scales[ids[qi, j]]                      # [1, cap]
             if int4:
                 lo = ((tile & jnp.uint8(0x0F)).astype(jnp.int32)
                       - 8).astype(jnp.bfloat16)
@@ -236,9 +243,9 @@ def warmup_entries(n_pad: int, cap: int, packed_w: int, tok_dtype,
     same resolution serving uses, so the warmed programs ARE the ones
     `maxsim_rescore` dispatches."""
     entries = []
-    interp = _resolve_interpret(interpret)
+    interp = dispatch.pallas_interpret(interpret)
     toks_spec = jax.ShapeDtypeStruct((n_pad, cap, packed_w), tok_dtype)
-    scales_spec = jax.ShapeDtypeStruct((n_pad, cap), jnp.float32)
+    scales_spec = jax.ShapeDtypeStruct((n_pad, 1, cap), jnp.float32)
     int4 = tok_dtype == jnp.uint8
     for q in query_buckets:
         for tq in tq_rungs:

@@ -148,8 +148,10 @@ class LateInteractionField:
     def _device_arrays(self):
         if self._device is not None and self._device_version == self.version:
             return self._device
+        # scales ride as [n_pad, 1, cap]: the kernel's one-row block
+        # needs the singleton axis resident (ops/pallas_maxsim.py)
         self._device = (jnp.asarray(self.tile),
-                        jnp.asarray(self.tile_scales))
+                        jnp.asarray(self.tile_scales[:, None, :]))
         self._device_version = self.version
         return self._device
 

@@ -721,15 +721,11 @@ class VectorStoreShard:
         serving path executes."""
         if fc.corpus is None or not self.warmup_enabled():
             return
-        from elasticsearch_tpu.ops import pallas_knn_binned as binned
         corpus_spec = dispatch.specs_like(fc.corpus)
         n_pad = fc.corpus.matrix.shape[0]
         packed = str(fc.corpus.matrix.dtype) in ("uint8", "uint32")
-        binned_ok = (fc.metric in (sim.COSINE, sim.DOT_PRODUCT,
-                                   sim.MAX_INNER_PRODUCT)
-                     and not packed
-                     and n_pad % binned.BLOCK_N == 0
-                     and not binned.default_interpret())
+        binned_ok = knn_ops.binned_route(
+            n_pad, fc.dims, fc.corpus.matrix.dtype, fc.metric)
         entries = []
         for q in dispatch.WARMUP_QUERY_BUCKETS:
             qspec = dispatch.query_spec(q, fc.dims)

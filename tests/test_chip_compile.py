@@ -1,0 +1,263 @@
+"""Ask the chip's compiler before the chip.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached (`v5e:2x2`). These tests compile — from
+`ShapeDtypeStruct`s placed on one described device — the jitted
+implementations the serving path dispatches, at published widths and on
+the dispatcher's own bucket ladder. Nothing runs: a compile that passes
+is not a chip run, and says nothing about results or times. What it does
+catch is everything interpret mode cannot: block shapes Mosaic refuses,
+kernels that need more VMEM than their limit, ops with no TPU lowering.
+
+This is the ONLY file that describes a topology, and it does so inside a
+module-scoped fixture (never at import, in a `skipif`, in `parametrize`
+arguments or in `conftest.py`): only one process may load the TPU's
+library, and every xdist worker imports every test file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from elasticsearch_tpu.ops import aggs as agg_ops
+from elasticsearch_tpu.ops import knn as knn_ops
+from elasticsearch_tpu.ops import pallas_ivf_fused as ivf_fused
+from elasticsearch_tpu.ops import pallas_knn_binned as binned
+from elasticsearch_tpu.ops import pallas_maxsim as maxsim
+from elasticsearch_tpu.ops import similarity as sim
+from elasticsearch_tpu.ops.knn_ivf import IVFPartitions
+from elasticsearch_tpu.parallel import layout
+from elasticsearch_tpu.parallel import mesh as mesh_lib
+from elasticsearch_tpu.parallel import sharded_knn
+
+N_ROWS = 1 << 20            # BASELINE config 1's corpus: 1,048,576 rows
+# the largest bucket the store sends the binned kernel: the per-(field, k)
+# CombiningBatcher's batch ceiling (serving/batcher.py max_batch=256)
+STORE_MAX_BUCKET = 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip; keep these out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _corpus_spec(sh, n, d, dtype, residual=False):
+    return knn_ops.Corpus(
+        matrix=_sds(sh, (n, d), dtype),
+        sq_norms=_sds(sh, (n,), jnp.float32),
+        scales=_sds(sh, (n,), jnp.float32),
+        num_valid=_sds(sh, (), jnp.int32),
+        residual=_sds(sh, (n, d), jnp.int8) if residual else None,
+        residual_scales=_sds(sh, (n,), jnp.float32) if residual else None)
+
+
+def _compile(fn, static_argnames, *args, **statics):
+    return jax.jit(fn, static_argnames=static_argnames).lower(
+        *args, **statics).compile()
+
+
+def _has_mosaic_call(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# knn.binned — the Pallas kernel unfiltered cosine/dot kNN rides
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("nq", [1, 64, STORE_MAX_BUCKET])
+@pytest.mark.parametrize("d", [128, 768])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_binned_compiles_at_published_widths(one_chip, dtype, d, nq):
+    assert binned.kernel_holds(d, dtype)
+    compiled = _compile(
+        binned._binned_impl, ("k", "metric", "interpret"),
+        _sds(one_chip, (nq, d), jnp.float32),
+        _corpus_spec(one_chip, N_ROWS, d, dtype),
+        k=10, metric=sim.COSINE, interpret=False)
+    assert _has_mosaic_call(compiled)
+
+
+def test_binned_rescored_packed_int8_residual_compiles(one_chip):
+    compiled = _compile(
+        binned._rescored_packed_impl,
+        ("k", "metric", "rescore_candidates", "interpret"),
+        _sds(one_chip, (64, 768), jnp.float32),
+        _corpus_spec(one_chip, N_ROWS, 768, jnp.int8, residual=True),
+        k=10, metric=sim.COSINE, rescore_candidates=128, interpret=False)
+    assert _has_mosaic_call(compiled)
+
+
+def test_binned_query_axis_tiles_past_the_store_ceiling(one_chip):
+    """Buckets above QUERY_TILE tile the query axis instead of growing
+    the [nq, BLOCK_N] temporaries: the dispatcher's top rung compiles
+    under the same VMEM limit as the 256 bucket."""
+    nq = 2048
+    assert nq > binned.QUERY_TILE
+    assert (binned.vmem_bytes(nq, 768, 2)
+            == binned.vmem_bytes(binned.QUERY_TILE, 768, 2))
+    _compile(binned._binned_impl, ("k", "metric", "interpret"),
+             _sds(one_chip, (nq, 768), jnp.float32),
+             _corpus_spec(one_chip, N_ROWS, 768, jnp.bfloat16),
+             k=10, metric=sim.COSINE, interpret=False)
+
+
+@pytest.mark.parametrize("dtype,itemsize", [(jnp.bfloat16, 2),
+                                            (jnp.int8, 1)],
+                         ids=["bf16", "int8"])
+def test_widest_row_the_rule_admits_compiles(one_chip, dtype, itemsize):
+    """`kernel_holds` is an estimate; the compiler is the judge. The
+    widest lane-multiple row width the rule admits (up to the mapping's
+    4096-dim ceiling) must compile under `vmem_bytes`' limit, and the
+    next one up must be refused by the RULE — so the store never sends
+    a shape only the compiler would catch."""
+    widths = [d for d in range(128, 4096 + 1, 128)
+              if binned.kernel_holds(d, dtype)]
+    widest = widths[-1]
+    assert binned.vmem_bytes(STORE_MAX_BUCKET, widest,
+                             itemsize) <= binned.VMEM_LIMIT_CAP
+    if widest < 4096:
+        assert not binned.kernel_holds(widest + 128, dtype)
+        assert not knn_ops.binned_route(N_ROWS, widest + 128, dtype,
+                                        sim.COSINE)
+    _compile(binned._binned_impl, ("k", "metric", "interpret"),
+             _sds(one_chip, (STORE_MAX_BUCKET, widest), jnp.float32),
+             _corpus_spec(one_chip, 1 << 17, widest, dtype),
+             k=10, metric=sim.COSINE, interpret=False)
+
+
+# ---------------------------------------------------------------------------
+# knn.exact — the filtered / l2 route
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("d,metric", [(128, sim.COSINE), (960, sim.L2_NORM)])
+def test_exact_filtered_route_compiles(one_chip, d, metric):
+    nq = 64
+    n = N_ROWS if d == 128 else 1 << 18     # GIST-1M-shaped: 262,144 x 960
+    _compile(knn_ops._knn_search_impl,
+             ("k", "metric", "precision", "block_size"),
+             _sds(one_chip, (nq, d), jnp.float32),
+             _corpus_spec(one_chip, n, d, jnp.bfloat16),
+             _sds(one_chip, (nq, n), jnp.bool_),
+             k=10, metric=metric, precision="bf16", block_size=None)
+
+
+# ---------------------------------------------------------------------------
+# scalar-prefetch kernels: fused IVF probe, MaxSim rescore
+# ---------------------------------------------------------------------------
+
+def _ivf_spec(sh, nlist, cap, d, dtype, w):
+    return IVFPartitions(
+        centroids=_sds(sh, (nlist, d), jnp.float32),
+        centroid_sq=_sds(sh, (nlist,), jnp.float32),
+        parts=_sds(sh, (nlist, cap, w), dtype),
+        part_scales=_sds(sh, (nlist, cap), jnp.float32),
+        part_sq=_sds(sh, (nlist, cap), jnp.float32),
+        part_rows=_sds(sh, (nlist, cap), jnp.int32))
+
+
+@pytest.mark.parametrize("dtype,w", [(jnp.bfloat16, 128), (jnp.uint8, 64)],
+                         ids=["dense", "int4"])
+def test_fused_ivf_probe_compiles(one_chip, dtype, w):
+    """One warmed grid point of a 1M-row ivf index: pick_nlist -> 1024
+    lists, cap = ceil(1024 * 1.5) = 1536, query bucket 8, nprobe 16."""
+    nq, nprobe, nlist, cap, d = 8, 16, 1024, 1536, 128
+    compiled = _compile(
+        ivf_fused._fused_probe_impl, ("k", "metric", "interpret"),
+        _sds(one_chip, (nq, d), jnp.float32),
+        _ivf_spec(one_chip, nlist, cap, d, dtype, w),
+        _sds(one_chip, (nq, nprobe), jnp.int32),
+        k=10, metric=sim.COSINE, interpret=False)
+    assert _has_mosaic_call(compiled)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.uint8],
+                         ids=["dense", "int4"])
+def test_maxsim_rescore_compiles(one_chip, dtype):
+    """One warmed grid point of a rank_vectors field: query bucket 8,
+    window 64, 32 query tokens, 65,536 docs x 128-token cap x 128 lanes
+    (64 packed bytes for int4)."""
+    nq, wc, tq, n_pad, cap = 8, 64, 32, 1 << 16, 128
+    wd = 64 if dtype == jnp.uint8 else 128
+    q = _sds(one_chip, (nq, tq, wd), jnp.float32)
+    args = ((None, q, q) if dtype == jnp.uint8 else (q, None, None))
+    compiled = _compile(
+        maxsim._maxsim_impl, ("interpret",),
+        _sds(one_chip, (nq, wc), jnp.int32), *args,
+        _sds(one_chip, (n_pad, cap, wd), dtype),
+        _sds(one_chip, (n_pad, 1, cap), jnp.float32),
+        interpret=False)
+    assert _has_mosaic_call(compiled)
+
+
+# ---------------------------------------------------------------------------
+# an x64 agg kernel, and the 4-shard mesh program
+# ---------------------------------------------------------------------------
+
+def test_x64_agg_kernel_compiles(one_chip):
+    """`aggs.hist_metric` (date_histogram + stats sub-agg) over 131,072
+    rows, traced under the dispatcher's scoped x64 flag: f64 keys and
+    sums, int64 counts — types the chip emulates."""
+    r, b = 1 << 17, 64
+    with jax.enable_x64(True):
+        f64 = lambda *shape: _sds(one_chip, shape, jnp.float64)  # noqa: E731
+        flag = lambda: _sds(one_chip, (r,), jnp.bool_)           # noqa: E731
+        compiled = _compile(
+            agg_ops._agg_hist_metric, ("n_buckets",),
+            f64(r), flag(), flag(), f64(6), f64(2), f64(r), flag(),
+            n_buckets=b)
+    cnt, total = compiled.out_info[:2]
+    assert cnt.dtype == jnp.int64 and cnt.shape == (b + 1,)
+    assert total.dtype == jnp.float64
+
+
+def test_four_shard_mesh_knn_compiles(topo):
+    """`mesh.knn` on a Mesh of the four described devices: int8 768-d
+    rows sharded over the `shard` axis, shard-local exact kNN, the
+    candidate merge an all-gather over ICI."""
+    mesh = mesh_lib.make_mesh(num_shards=4, dp=1, devices=topo.devices)
+    n, d, nq, k = 1 << 22, 768, 64, 10
+    from jax.sharding import NamedSharding
+    corpus = layout.shape_specs(sharded_knn.ShardedCorpus(
+        jax.ShapeDtypeStruct((n, d), jnp.int8),
+        jax.ShapeDtypeStruct((n,), jnp.float32),
+        jax.ShapeDtypeStruct((n,), jnp.float32),
+        jax.ShapeDtypeStruct((4,), jnp.int32)), mesh)
+    queries = jax.ShapeDtypeStruct(
+        (nq, d), jnp.float32,
+        sharding=NamedSharding(mesh, layout.query_spec(2)))
+    compiled = _compile(
+        sharded_knn._distributed_knn_impl,
+        ("k", "mesh", "metric", "precision", "block_size"),
+        queries, corpus, None, k=k, mesh=mesh, metric=sim.COSINE,
+        precision="bf16", block_size=None)
+    assert "all-gather" in compiled.as_text()
+    # every device holds a quarter of the matrix, not the whole
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert per_device < (n * d) // 2

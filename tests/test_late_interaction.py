@@ -73,7 +73,8 @@ class TestKernelParity:
             q[qi, :nt, :dims] = quant_tokens.prep_tokens(
                 rng.standard_normal((nt, dims)).astype(np.float32),
                 "cosine")
-        return ids, q, toks, scales
+        # the kernel's resident scales layout: [n_pad, 1, cap]
+        return ids, q, toks, scales[:, None, :]
 
     def test_f32_matches_reference_tightly(self):
         rng = np.random.default_rng(3)

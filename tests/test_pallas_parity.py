@@ -86,6 +86,86 @@ def test_interpret_binned_validity_mask_excludes_padding(data):
     assert (ids < 100).all()
 
 
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+def test_interpret_binned_query_tiles_match_one_tile(data, dtype, monkeypatch):
+    """Buckets above QUERY_TILE walk a (corpus tile, query tile) grid.
+    Every query row must come back exactly as it does from a one-tile
+    call: the tiling changes which grid step scores a row, never its
+    score (two corpus tiles, four query tiles of 8)."""
+    vecs, qs, _, _ = data
+    rng = np.random.default_rng(5)
+    more = np.concatenate([vecs, rng.standard_normal(
+        (4000, D)).astype(np.float32)])
+    queries = np.concatenate([qs] * 4)            # 32 rows
+    corpus = knn_ops.build_corpus(more, metric=sim.COSINE, dtype=dtype,
+                                  pad_to=2 * binned.BLOCK_N)
+    s1, i1 = binned.binned_knn_search(queries, corpus, k=K,
+                                      metric=sim.COSINE, interpret=True)
+    s1, i1 = np.asarray(s1), np.asarray(i1)
+    monkeypatch.setattr(binned, "QUERY_TILE", 8)
+    dispatch.DISPATCH.clear()   # the tile size is baked into the program
+    try:
+        s4, i4 = binned.binned_knn_search(queries, corpus, k=K,
+                                          metric=sim.COSINE,
+                                          interpret=True)
+        np.testing.assert_array_equal(np.asarray(i4), i1)
+        np.testing.assert_array_equal(np.asarray(s4), s1)
+    finally:
+        dispatch.DISPATCH.clear()
+
+
+def test_binned_route_is_a_rule_of_shapes_and_backend(monkeypatch):
+    """The route to the Pallas kernel is decided from the backend, the
+    metric and the corpus shape — and a probe that raises propagates
+    instead of reading as "not an accelerator"."""
+    import jax.numpy as jnp
+    monkeypatch.setattr(dispatch, "backend_platform", lambda: "tpu")
+    ok = dict(n_pad=1 << 20, d=768, matrix_dtype=jnp.bfloat16,
+              metric=sim.COSINE)
+    assert knn_ops.binned_route(**ok)
+    assert not knn_ops.binned_route(**{**ok, "metric": sim.L2_NORM})
+    assert not knn_ops.binned_route(**{**ok, "n_pad": (1 << 20) + 128})
+    assert not knn_ops.binned_route(**{**ok, "matrix_dtype": jnp.uint8})
+    assert not knn_ops.binned_route(**{**ok, "d": 4096})      # VMEM rule
+    assert knn_ops.binned_route(**{**ok, "d": 4096,
+                                   "matrix_dtype": jnp.int8})
+    # padding follows the same rule
+    assert knn_ops.preferred_pad_multiple(1 << 20, 768, "bf16") \
+        == binned.BLOCK_N
+    assert knn_ops.preferred_pad_multiple(1 << 20, 4096, "bf16") \
+        == knn_ops.LANE
+    assert knn_ops.preferred_pad_multiple(1 << 20, 768, "int4") \
+        == knn_ops.LANE
+    monkeypatch.setattr(dispatch, "backend_platform", lambda: "cpu")
+    assert not knn_ops.binned_route(**ok)
+    assert dispatch.pallas_interpret(None) is True
+
+    def boom():
+        raise RuntimeError("no backend")
+    monkeypatch.setattr(dispatch, "backend_platform", boom)
+    with pytest.raises(RuntimeError, match="no backend"):
+        knn_ops.binned_route(**ok)
+    with pytest.raises(RuntimeError, match="no backend"):
+        dispatch.pallas_interpret(None)
+    assert dispatch.pallas_interpret(False) is False
+
+
+def test_kernel_error_propagates_from_knn_search_auto(data, monkeypatch):
+    """A kernel that fails must fail the search, not be swapped for the
+    exact path behind the caller's back."""
+    vecs, qs, _, _ = data
+    corpus = knn_ops.build_corpus(vecs, metric=sim.COSINE, dtype="bf16",
+                                  pad_to=binned.BLOCK_N)
+    monkeypatch.setattr(dispatch, "is_accelerator_backend", lambda: True)
+
+    def boom(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+    monkeypatch.setattr(binned, "binned_knn_search", boom)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        knn_ops.knn_search_auto(np.asarray(qs), corpus, k=K,
+                                metric=sim.COSINE)
+
+
 # ---------------------------------------------------------------------------
 # fused IVF gather+score kernel (ops/pallas_ivf_fused.py): the scalar-
 # prefetch gather must reproduce the scan-based probe scorer exactly
