@@ -189,3 +189,9 @@ class SearchPhaseExecutionError(SearchEngineError):
         super().__init__(message, phase=phase)
         self.phase = phase
         self.shard_failures = list(shard_failures)
+
+    def to_dict(self) -> dict:
+        d = super().to_dict()
+        if self.shard_failures:
+            d["failed_shards"] = [dict(f) for f in self.shard_failures]
+        return d

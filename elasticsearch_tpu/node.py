@@ -10,6 +10,7 @@ TransportActions call into the node's services.
 from __future__ import annotations
 
 import copy
+import logging
 import threading as _threading
 import time
 import uuid as _uuid
@@ -32,6 +33,8 @@ from elasticsearch_tpu.common.settings import parse_time_value
 from elasticsearch_tpu.telemetry import metrics as _telemetrics
 from elasticsearch_tpu.telemetry import trace as _teletrace
 from elasticsearch_tpu.version import __version__
+
+logger = logging.getLogger("elasticsearch_tpu.node")
 
 MAX_RESULT_WINDOW_SCROLL = 10_000
 
@@ -500,20 +503,12 @@ class Node:
         self.plugins.load_all()
         self.plugins.apply_extensions()
         self.plugins.start_node(self)
-        # shape-bucketed kernel dispatch (ops/dispatch.py): wire JAX's
-        # persistent compilation cache so a node restart re-loads compiled
-        # executables from disk instead of re-paying XLA compiles
-        # (settings: search.dispatch.persistent_cache_dir, default
-        # <data>/_state/xla_cache when search.dispatch.persistent_cache
-        # is truthy; search.dispatch.warmup overrides the warmup policy)
+        # shape-bucketed kernel dispatch (ops/dispatch.py):
+        # search.dispatch.warmup overrides the warmup policy. The
+        # persistent compilation cache is the server entry point's to
+        # configure (dispatch.configure_compile_cache), not a node setting
         from elasticsearch_tpu.common.settings import setting_bool
         from elasticsearch_tpu.ops import dispatch as _dispatch
-        cache_dir = self.settings.get("search.dispatch.persistent_cache_dir")
-        if not cache_dir and setting_bool(
-                self.settings.get("search.dispatch.persistent_cache")):
-            cache_dir = _os.path.join(data_path, "_state", "xla_cache")
-        if cache_dir:
-            _dispatch.configure_persistent_cache(str(cache_dir))
         warm = self.settings.get("search.dispatch.warmup")
         self._dispatch_warmup = setting_bool(warm) if warm is not None \
             else None
@@ -1668,6 +1663,24 @@ class Node:
                             _ShardScopedStore(store, all_ids - failed),
                             body, use_partial_aggs, frozen)
                         cache_key = None  # partial result: never cache
+                    except SearchEngineError:
+                        raise  # the request's own fault, with its status
+                    except Exception as e:
+                        # anything else the query phase raises — a kernel
+                        # the device refused, a dispatch that died — fails
+                        # THIS index's shards in the response (the
+                        # reference's per-shard failure), it is never
+                        # answered from another route behind the caller
+                        logger.exception(
+                            "query phase failed on index [%s]", svc.name)
+                        for s in svc.shards:
+                            shard_failures.append({
+                                "shard": s.shard_id, "index": svc.name,
+                                "node": self.node_id,
+                                "reason": {
+                                    "type": "shard_execution_exception",
+                                    "reason": f"{type(e).__name__}: {e}"}})
+                        continue
                     if cache_key is not None:
                         cache_used.put(cache_key, result)
                 q_nanos = time.perf_counter_ns() - q_start
@@ -2625,10 +2638,37 @@ class Node:
                 "fs": fs_probe(self.indices.data_path),
                 "process": process_probe(),
                 "indices": indices_section,
+                "device": self._device_stats_section(),
                 "discovery": discovery_section,
                 "breakers": self.breakers.stats(),
                 "thread_pool": self.thread_pool.stats(),
                 "telemetry": self._telemetry_stats_section()}
+
+    @staticmethod
+    def _device_stats_section() -> dict:
+        """What the kernels ran on, as JAX reports it: platform, device
+        kind and count, each device's `memory_stats()` (where the
+        backend keeps them), and the two inputs of the host-vs-device
+        cost model (`serving/batcher.py`) — the measured dispatch
+        overhead (null until a search has needed it) and the peak the
+        table gives this device kind."""
+        import jax
+
+        from elasticsearch_tpu.serving import batcher
+        devices = jax.devices()
+        memory = []
+        for d in devices:
+            ms = d.memory_stats() or {}
+            memory.append({k: int(ms[k]) for k in
+                           ("bytes_in_use", "peak_bytes_in_use",
+                            "bytes_limit") if k in ms})
+        return {"platform": devices[0].platform,
+                "device_kind": devices[0].device_kind,
+                "count": len(devices),
+                "memory": memory,
+                "cost_model": {
+                    "device_overhead_ms": batcher._overhead_ms,
+                    "device_peak_ops": batcher.device_peak_ops()}}
 
     def _recovery_section(self) -> dict:
         """`indices.recovery` for a single node: block-level restore
@@ -2731,7 +2771,8 @@ class Node:
         continuous-batching scheduler counters (queue wait / topups /
         overlap — the 1cl/4cl closed-loop tail attribution)."""
         out = {"searches": 0, "ivf_searches": 0, "fallback_searches": 0,
-               "mesh_searches": 0, "fused_probe_searches": 0,
+               "mesh_searches": 0, "host_mirror_searches": 0,
+               "fused_probe_searches": 0,
                "rescore_searches": 0, "rescore_window_rows": 0,
                "rescore_promoted": 0, "rescore_nanos": 0,
                "route_nanos": 0, "score_nanos": 0, "merge_nanos": 0,

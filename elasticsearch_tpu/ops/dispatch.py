@@ -18,8 +18,8 @@ through one dispatcher that owns:
 * a keyed executable cache over `jax.jit(...).lower(...).compile()` AOT
   artifacts, with `donate_argnums` on score-board/accumulator buffers
   (the caller allocates them fresh per call; XLA reuses their HBM for
-  the outputs) and optional wiring to JAX's persistent compilation
-  cache directory so node restarts don't re-pay compiles;
+  the outputs); `configure_compile_cache` owns JAX's persistent
+  compilation cache so node restarts don't re-pay compiles;
 * warmup — `warmup()` pre-compiles a declared bucket grid on a
   background thread when an index opens / a batcher starts, so the
   first real query of any bucket finds its program ready;
@@ -193,36 +193,36 @@ def in_k_grid(k: int, limit: Optional[int] = None) -> bool:
 # Persistent compilation cache
 # ---------------------------------------------------------------------------
 
-_persistent_cache_dir: Optional[str] = None
+# `<checkout>/.jax_cache` (git-ignored): a fixed path, because the path is
+# part of what a restart must find again — never under a data directory
+# (tests and benches run nodes on mkdtemp paths), a pid or a time.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+_compile_cache_dir: Optional[str] = None
 
 
-def configure_persistent_cache(cache_dir: Optional[str]) -> bool:
-    """Point JAX's persistent compilation cache at `cache_dir` so node
-    restarts re-load compiled executables from disk instead of re-paying
-    XLA compiles (setting: `search.dispatch.persistent_cache_dir`).
-    Returns True when the cache was wired."""
-    global _persistent_cache_dir
+def configure_compile_cache() -> str:
+    """The one owner of JAX's persistent compilation cache, so a node
+    restart re-loads compiled executables from disk instead of re-paying
+    XLA compiles. Where `JAX_COMPILATION_CACHE_DIR` is set JAX reads it
+    itself and nothing is set in code; otherwise the cache lives at
+    `DEFAULT_COMPILE_CACHE_DIR`. Either way every executable is kept
+    (serving kernels are small and quick to compile; the defaults would
+    skip them). The server calls this before the first backend touch.
+    Returns the directory."""
+    global _compile_cache_dir
+    import jax
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
-        return False
-    try:
-        import jax
+        cache_dir = DEFAULT_COMPILE_CACHE_DIR
         os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        # serving kernels are small; cache everything, not just slow builds
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:
-            pass  # knob renamed across jax versions; best-effort
-        _persistent_cache_dir = str(cache_dir)
-        return True
-    except Exception as exc:  # pragma: no cover - depends on jax build
-        logger.warning("persistent compilation cache not wired: %s", exc)
-        return False
-
-
-def persistent_cache_dir() -> Optional[str]:
-    return _persistent_cache_dir
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    _compile_cache_dir = cache_dir
+    return cache_dir
 
 
 # ---------------------------------------------------------------------------
@@ -586,7 +586,7 @@ class Dispatcher:
         with self._lock:
             out = dict(self._counters)
             out["cached_executables"] = len(self._cache)
-            out["persistent_cache_dir"] = _persistent_cache_dir
+            out["compile_cache_dir"] = _compile_cache_dir
             if per_bucket:
                 out["buckets"] = {k: dict(v)
                                   for k, v in sorted(self._bucket.items())}

@@ -171,6 +171,20 @@ def min_rows() -> int:
     return _cfg["min_rows"]
 
 
+def _mesh_build_failed(what: str, exc: Exception) -> None:
+    """A mesh that cannot be built: with `search.mesh.enabled: true` the
+    operator asked for it, so serving on one device instead would hide
+    the fault — raise. In auto mode one chip is a normal deployment: log
+    why and stay single-device (the caller latches that until restart or
+    reconfigure; stats alone show `available: false`, not why)."""
+    if _cfg["enabled"] is True:
+        raise RuntimeError(
+            f"search.mesh.enabled is set but {what} could not be "
+            f"built: {exc}") from exc
+    logger.warning("mesh serving off: %s could not be built", what,
+                   exc_info=exc)
+
+
 def serving_mesh():
     """The process-wide (dp=R, shard=S) serving mesh, or None when mesh
     execution is off (disabled, or fewer than 2 usable devices). R comes
@@ -195,15 +209,11 @@ def serving_mesh():
             # just the single device and stays off
             if dp * n >= 2:
                 mesh = mesh_lib.make_mesh(num_shards=n, dp=dp)
-        except Exception:
-            # the latch below caches this None for the process lifetime:
-            # without a log line a multi-chip node would silently serve
-            # single-device until restart (stats only show available:
-            # false, not why)
-            logger.warning("mesh serving disabled: serving-mesh build "
-                           "failed (latched off until restart or "
-                           "reconfigure)", exc_info=True)
-            mesh = None
+            elif _cfg["enabled"] is True:
+                raise RuntimeError(
+                    f"{n_dev} device(s) cannot form a mesh of 2 or more")
+        except Exception as exc:
+            _mesh_build_failed("the serving mesh", exc)
     with _lock:
         if _mesh_built:
             # another thread won the build race: keep ITS object — the
@@ -278,10 +288,8 @@ def mesh_for_shards(n_shards: int):
         if n_shards >= 1 and n_shards <= n_dev:
             dp = min(_effective_dp(n_dev), _pow2_floor(n_dev // n_shards))
             built = mesh_lib.make_mesh(num_shards=n_shards, dp=max(dp, 1))
-    except Exception:
-        logger.warning("mesh_for_shards(%d) build failed", n_shards,
-                       exc_info=True)
-        built = None
+    except Exception as exc:
+        _mesh_build_failed(f"a {n_shards}-shard mesh", exc)
     with _lock:
         return _shard_meshes.setdefault(n_shards, built)
 

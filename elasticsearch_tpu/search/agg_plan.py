@@ -699,12 +699,14 @@ class AggEngine:
                                      observed=fb.observed)
                     except SearchEngineError:
                         raise  # parity errors (max_buckets, bad params)
-                    except Exception as exc:  # pragma: no cover - safety
-                        reason = "device_error"
+                    except Exception:
+                        # an unexpected device error is NOT a reasoned
+                        # fallback: count it and let it fail the shard
+                        # (node.search reports it in `_shards.failures`)
+                        # — the host walker answering instead hid a
+                        # broken dispatcher for a whole release
                         self._reason("device_error", docs=len(rows))
-                        logger.warning(
-                            "device agg [%s] failed; serving from host: %s",
-                            name, exc)
+                        raise
             if res is None:
                 if node is not None and node.mode == "host" \
                         and node.host_reason:

@@ -274,7 +274,8 @@ class VectorStoreShard:
         self.knn_stats: Dict[str, int] = {
             "searches": 0, "ivf_searches": 0, "fallback_searches": 0,
             "ivf_trains": 0, "ivf_restores": 0,
-            "mesh_searches": 0, "fused_probe_searches": 0,
+            "mesh_searches": 0, "host_mirror_searches": 0,
+            "fused_probe_searches": 0,
             "rescore_searches": 0, "rescore_window_rows": 0,
             "rescore_promoted": 0, "rescore_nanos": 0,
             "route_nanos": 0, "score_nanos": 0, "merge_nanos": 0,
@@ -1199,6 +1200,7 @@ class VectorStoreShard:
                     and CostModel.prefer_host(len(requests), fc.host.n,
                                               fc.host.dims))
         if use_host:
+            self.knn_stats["host_mirror_searches"] += 1
             mask = None
             if any_filter:
                 mask = np.ones((len(requests), n_valid), dtype=bool)

@@ -372,14 +372,48 @@ class TestDispatcherMechanics:
         # recording off: drain yields nothing
         assert dispatch.DISPATCH.drain_events() == []
 
-    def test_persistent_cache_configure(self, tmp_path):
+    @pytest.mark.parametrize("env_dir", [None, "env_cache"])
+    def test_compile_cache_has_one_owner(self, tmp_path, monkeypatch,
+                                         env_dir):
+        """JAX_COMPILATION_CACHE_DIR set: JAX reads it, the code sets no
+        directory of its own. Unset: `<checkout>/.jax_cache`, a fixed
+        path with no mkdtemp, pid or time in it."""
         import jax
-        old = jax.config.jax_compilation_cache_dir
+        updates = []
+        real_update = jax.config.update
+
+        def spy(name, value):
+            updates.append(name)
+            if name != "jax_compilation_cache_dir":
+                real_update(name, value)
+        old = (jax.config.jax_persistent_cache_min_compile_time_secs,
+               jax.config.jax_persistent_cache_min_entry_size_bytes)
+        monkeypatch.setattr(jax.config, "update", spy)
+        monkeypatch.setattr(dispatch, "DEFAULT_COMPILE_CACHE_DIR",
+                            str(tmp_path / ".jax_cache"))
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                               str(tmp_path / env_dir))
         try:
-            assert dispatch.configure_persistent_cache(
-                str(tmp_path / "xla_cache"))
-            assert dispatch.persistent_cache_dir() == \
-                str(tmp_path / "xla_cache")
-            assert (tmp_path / "xla_cache").is_dir()
+            got = dispatch.configure_compile_cache()
+            if env_dir is None:
+                assert got == str(tmp_path / ".jax_cache")
+                assert (tmp_path / ".jax_cache").is_dir()
+                assert "jax_compilation_cache_dir" in updates
+            else:
+                assert got == str(tmp_path / env_dir)
+                assert "jax_compilation_cache_dir" not in updates
+            assert dispatch.stats()["compile_cache_dir"] == got
+            assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
         finally:
-            jax.config.update("jax_compilation_cache_dir", old)
+            real_update("jax_persistent_cache_min_compile_time_secs", old[0])
+            real_update("jax_persistent_cache_min_entry_size_bytes", old[1])
+            monkeypatch.setattr(dispatch, "_compile_cache_dir", None)
+
+    def test_default_compile_cache_dir_is_fixed_under_the_checkout(self):
+        import os
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert dispatch.DEFAULT_COMPILE_CACHE_DIR == os.path.join(
+            repo, ".jax_cache")
