@@ -680,6 +680,7 @@ class AggEngine:
                     if route == "probe":
                         self._count("router_probes")
                     try:
+                        compiles0 = dispatch.DISPATCH.compile_count()
                         t0 = time.perf_counter_ns()
                         boards, mesh_used = self._run_device_node(
                             ctx, node, spec, rows, mask_box, partial)
@@ -691,7 +692,13 @@ class AggEngine:
                         assemble_nanos += t2 - t1
                         engine = "device_mesh" if mesh_used else "device"
                         self._count("device_nodes")
-                        if self.cost_router is not None:
+                        # a call that compiled is not a sample of what
+                        # the device costs: booked as one, the compile
+                        # seconds sent the family's next REPROBE requests
+                        # to the host walker
+                        if self.cost_router is not None and \
+                                dispatch.DISPATCH.compile_count() \
+                                == compiles0:
                             self.cost_router.observe_device(fam, t2 - t0)
                     except _Fallback as fb:
                         reason = fb.reason

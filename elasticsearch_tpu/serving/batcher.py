@@ -91,29 +91,24 @@ DEVICE_PEAKS = {
     "TPU v5 lite": (197.0e12, 393.0e12, 819.0e9),
     "TPU v5e": (197.0e12, 393.0e12, 819.0e9),
 }
-# The CPU backend (the tests) has no published peak; the cost model only
-# needs device-vs-host ORDER there, and this keeps the order the tests
-# were written against.
-_CPU_BACKEND_OPS = 100.0e12
-
 _device_ops: Optional[float] = None
 
 
-def device_peak_ops() -> float:
-    """bf16 matmul peak of the live backend, from `DEVICE_PEAKS`."""
+def device_peak_ops() -> Optional[float]:
+    """bf16 matmul peak of the live backend, from `DEVICE_PEAKS`; None on
+    the CPU backend, which has no device to price (see `prefer_host`)."""
     global _device_ops
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
     if _device_ops is None:
-        import jax
-        dev = jax.devices()[0]
-        if dev.platform == "cpu":
-            _device_ops = _CPU_BACKEND_OPS
-        elif dev.device_kind in DEVICE_PEAKS:
-            _device_ops = DEVICE_PEAKS[dev.device_kind][0]
-        else:
+        if dev.device_kind not in DEVICE_PEAKS:
             raise RuntimeError(
                 f"no published peak for device kind [{dev.device_kind}] "
                 f"(platform {dev.platform}); add it to "
                 f"serving/batcher.DEVICE_PEAKS with its source")
+        _device_ops = DEVICE_PEAKS[dev.device_kind][0]
     return _device_ops
 
 
@@ -177,6 +172,14 @@ class CostModel:
 
     @classmethod
     def prefer_host(cls, batch: int, n_rows: int, dims: int) -> bool:
+        """Host mirror or device? Where JAX's backend IS the CPU there is
+        no device round trip for the mirror to save and no published
+        peak to price the other side with: the answer is the device
+        route, by that fact — so a CPU run (the tests, a rehearsal of
+        `chip_smoke.py`) takes the routes the chip will take, and the
+        route cannot flip with the machine's load."""
+        if device_peak_ops() is None:
+            return False
         return (cls.host_ms(batch, n_rows, dims)
                 < cls.device_ms(batch, n_rows, dims))
 

@@ -42,10 +42,6 @@ def _break_device_knn(monkeypatch):
     def boom(*a, **kw):
         raise RuntimeError("Mosaic failed to compile TPU kernel (injected)")
     monkeypatch.setattr(knn_ops, "knn_search_auto", boom)
-    # a 40-row corpus would ride the host mirror; the route under test
-    # is the device one
-    monkeypatch.setattr(batcher.CostModel, "prefer_host",
-                        classmethod(lambda cls, *a: False))
 
 
 def test_knn_kernel_error_is_a_failed_shard_not_a_slower_answer(
@@ -127,6 +123,14 @@ def test_peak_table_knows_v5e_and_refuses_an_unknown_tpu(monkeypatch):
     monkeypatch.setattr(batcher, "_device_ops", None)
 
 
+def test_cpu_backend_never_prefers_the_host_mirror():
+    """With the CPU as JAX's backend there is no device to price: the
+    route is the device one by that fact, at any size and batch."""
+    assert batcher.device_peak_ops() is None
+    for batch, rows in ((1, 128), (1, 4096), (64, 1 << 20)):
+        assert batcher.CostModel.prefer_host(batch, rows, 128) is False
+
+
 def test_overhead_probe_failure_raises(monkeypatch):
     from elasticsearch_tpu.ops import dispatch
     monkeypatch.setattr(batcher, "_overhead_ms", None)
@@ -168,6 +172,6 @@ def test_nodes_stats_reports_what_ran(node):
     assert dev["platform"] == "cpu" and dev["count"] >= 1
     assert isinstance(dev["device_kind"], str) and dev["device_kind"]
     assert len(dev["memory"]) == dev["count"]
-    assert dev["cost_model"]["device_peak_ops"] > 0
+    assert dev["cost_model"]["device_peak_ops"] is None   # CPU backend
     knn = n.local_node_stats()["indices"]["knn"]
     assert "host_mirror_searches" in knn
