@@ -343,16 +343,17 @@ def recall(got: List[List[int]], want: np.ndarray) -> float:
 
 
 def search_twice(srv: Server, index: str, body: dict):
-    """Send one request twice: the first call may compile, the second is
-    a dispatch-cache hit — so every kernel the phase rides shows a HIT in
+    """Send one request twice (the request cache is off for every search
+    this script sends): the first call may compile, the second is a
+    dispatch-cache hit — so every kernel the phase rides shows a HIT in
     `indices.dispatch` (a warmup compile alone leaves only a miss) — and
     the same request must answer the same. Returns (response, first-call
     seconds, second-call ms)."""
     t = time.monotonic()
-    first = srv.ok("POST", f"/{index}/_search", body)
+    first = srv.ok("POST", f"/{index}/_search?request_cache=false", body)
     first_s = time.monotonic() - t
     t = time.monotonic()
-    again = srv.ok("POST", f"/{index}/_search", body)
+    again = srv.ok("POST", f"/{index}/_search?request_cache=false", body)
     again_ms = (time.monotonic() - t) * 1000.0
     for r in (first, again):
         r.pop("took", None)
@@ -386,7 +387,7 @@ def knn_burst(srv: Server, data: Data, index: str,
     one after another (every request its own batch of 1)."""
     def one(i):
         t1 = time.monotonic()
-        ids = hit_ids(srv.ok("POST", f"/{index}/_search",
+        ids = hit_ids(srv.ok("POST", f"/{index}/_search?request_cache=false",
                              knn_body(data.queries[1 + i])))
         return ids, (time.monotonic() - t1) * 1000.0
     t = time.monotonic()
@@ -404,10 +405,11 @@ def check_recall(name: str, value: float, floor: float) -> None:
 
 
 def agg_phase(srv: Server, data: Data, index: str) -> float:
-    body = {"size": 0, "request_cache": False, "aggs": {
-        "cats": {"terms": {"field": "cat", "size": N_CATS}},
-        "days": {"date_histogram": {"field": "ts",
-                                    "fixed_interval": "1d"}}}}
+    body = {"size": 0, "track_total_hits": True,
+            "aggs": {
+                "cats": {"terms": {"field": "cat", "size": N_CATS}},
+                "days": {"date_histogram": {"field": "ts",
+                                            "fixed_interval": "1d"}}}}
     resp, _first_s, ms = search_twice(srv, index, body)
     if resp["_shards"].get("failed"):
         raise SmokeFailure(f"agg search reported failed shards: "
@@ -430,19 +432,21 @@ def agg_phase(srv: Server, data: Data, index: str) -> float:
     return ms
 
 
-def wait_compiles_settle(srv: Server, quiet_s: float = 4.0,
-                         limit_s: float = 240.0) -> int:
-    """Block until the dispatcher's compile count has not moved for
-    `quiet_s` (the background warmup grid is done): a server stopped
-    mid-warmup would leave the next start entries to add."""
+def wait_compiles_settle(srv: Server, quiet_s: float = 3.0,
+                         limit_s: float = 300.0) -> int:
+    """Block until no compile is in flight and the dispatcher's compile
+    count has not moved for `quiet_s`: the background warmup grid is
+    done. A server stopped mid-warmup would leave the next start entries
+    to add, and a search that overtakes the warmup thread compiles its
+    kernel from another call path."""
     deadline = time.monotonic() + limit_s
     last, since = -1, time.monotonic()
     while time.monotonic() < deadline:
-        n = srv.node_stats()["indices"]["dispatch"]["compiles"]
-        if n != last:
-            last, since = n, time.monotonic()
+        d = srv.node_stats()["indices"]["dispatch"]
+        if d["compiles"] != last or d["compiling"]:
+            last, since = d["compiles"], time.monotonic()
         elif time.monotonic() - since >= quiet_s:
-            return n
+            return last
         time.sleep(0.5)
     raise SmokeFailure(f"compiles still arriving after {limit_s:.0f}s")
 
@@ -516,12 +520,12 @@ def compile_cache_dir() -> str:
             or os.path.join(HERE, ".jax_cache"))
 
 
-def cache_entries(cache_dir: str) -> int:
+def cache_entries(cache_dir: str) -> set:
     try:
-        return sum(1 for n in os.listdir(cache_dir)
-                   if not n.endswith("-atime"))
+        return {n for n in os.listdir(cache_dir)
+                if not n.endswith("-atime")}
     except OSError:
-        return 0
+        return set()
 
 
 def check_platform(srv: Server, rehearse: bool) -> None:
@@ -561,7 +565,7 @@ def run_one_chip(args, out_dir: str) -> dict:
         f"cosine bf16 single shard, seed={args.seed}")
     cache_dir = compile_cache_dir()
     say(f"compile_cache dir={cache_dir} "
-        f"entries_at_start={cache_entries(cache_dir)}")
+        f"entries_at_start={len(cache_entries(cache_dir))}")
 
     srv = Server(out_dir, data_dir, "cold")
     try:
@@ -577,6 +581,7 @@ def run_one_chip(args, out_dir: str) -> dict:
         want_f = data.oracle_topk(data.queries[BURST + 1:], allowed)
         say(f"filter selectivity={allowed.mean():.3f}")
 
+        wait_compiles_settle(srv)       # the warmup grid, if the store warms
         seq = knn_sequential(srv, data, index)
         # start -> first answer, less the time spent loading documents
         cold_s = time.monotonic() - srv.started - bulk_s
@@ -595,6 +600,9 @@ def run_one_chip(args, out_dir: str) -> dict:
         agg_ms = agg_phase(srv, data, index)
         say(f"aggs terms+date_histogram over {data.rows} docs equal the "
             f"plain count; ms={agg_ms:.1f}")
+        # flush now (a flush refreshes, and a refresh may start background
+        # warmups), so that nothing is in flight when the server is stopped
+        srv.ok("POST", f"/{index}/_flush")
         wait_compiles_settle(srv)
         node = srv.node_stats()
         stats_checks(node, args.rehearse, "knn.binned")
@@ -606,13 +614,12 @@ def run_one_chip(args, out_dir: str) -> dict:
             raise SmokeFailure("the burst never formed a batch: "
                                f"{json.dumps(sched)}")
         device = device_of(node)
-        srv.ok("POST", f"/{index}/_flush")
     finally:
         srv.stop()
     entries_cold = cache_entries(cache_dir)
     say(f"server_cold exit_code={srv.proc.returncode} "
-        f"cache_entries={entries_cold}")
-    if entries_cold == 0:
+        f"cache_entries={len(entries_cold)}")
+    if not entries_cold:
         raise SmokeFailure(f"the compile cache at {cache_dir} is empty")
 
     # second start: same data directory, same cache directory; the same
@@ -628,6 +635,7 @@ def run_one_chip(args, out_dir: str) -> dict:
                                f"not {data.rows}")
         say(f"server_warm pid={srv2.proc.pid} up_s={up_warm:.1f} "
             f"recovery_s={recovery_s:.1f} docs={count}")
+        wait_compiles_settle(srv2)
         seq2 = knn_sequential(srv2, data, index)
         warm_s = time.monotonic() - srv2.started
         one_by_one, _ = knn_burst(srv2, data, index, at_once=False)
@@ -647,14 +655,17 @@ def run_one_chip(args, out_dir: str) -> dict:
         srv2.stop()
     entries_warm = cache_entries(cache_dir)
     say(f"time_to_first_answer cold_s={cold_s:.1f} warm_s={warm_s:.1f} "
-        f"(server start to the first kNN answer; cold leaves out the "
-        f"{bulk_s:.0f}s spent in _bulk)")
+        f"(server start, warmup grid compiled, first kNN answer; cold "
+        f"leaves out the {bulk_s:.0f}s spent in _bulk)")
     say(f"first_search cold_s={seq['first_search_s']:.2f} "
         f"warm_s={seq2['first_search_s']:.2f}")
-    say(f"compile_cache entries cold={entries_cold} warm={entries_warm}")
+    say(f"compile_cache entries cold={len(entries_cold)} "
+        f"warm={len(entries_warm)}")
     if entries_warm != entries_cold:
+        added = sorted(n[:60] for n in entries_warm - entries_cold)
         raise SmokeFailure(f"the second start changed the compile cache: "
-                           f"{entries_cold} -> {entries_warm} entries")
+                           f"{len(entries_cold)} -> {len(entries_warm)} "
+                           f"entries; added {added}")
     return device
 
 
@@ -672,7 +683,7 @@ def run_four_chips(args, out_dir: str) -> dict:
     index = "smoke4"
     data_dir = os.path.join(out_dir, "data")
     data = Data(args.seed, args.rows or (1 << 22), dims=768)
-    n_dev = 4 if not args.rehearse else args.rehearse_devices
+    n_dev = 4
     say(f"deployment BASELINE.json config 4 shape: rows={data.rows} "
         f"dims=768 cosine int8_flat, mesh of {n_dev}, seed={args.seed}")
 
@@ -785,9 +796,6 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU rehearsal: relaxes the platform check and "
                          "the Pallas-kernel-dispatched check, nothing else")
-    ap.add_argument("--rehearse-devices", type=int, default=4,
-                    help="virtual CPU devices the --chips 4 rehearsal's "
-                         "server was given (XLA_FLAGS)")
     ap.add_argument("--ingest-budget", type=float, default=400.0,
                     help="seconds REST ingest may take before rows are cut")
     ap.add_argument("--out", default=os.path.join(HERE, "chiprun_out",
@@ -807,7 +815,12 @@ def main(argv=None) -> int:
     t0 = time.monotonic()
     try:
         run = run_four_chips if args.chips == 4 else run_one_chip
-        device = run(args, out_dir)
+        try:
+            device = run(args, out_dir)
+        finally:
+            # the logs stay; the index does not (it would not fit what
+            # the chip tool brings back)
+            shutil.rmtree(os.path.join(out_dir, "data"), ignore_errors=True)
     except SmokeFailure as e:
         say(f"FAILED: {e}")
         for tag in ("cold", "warm", "mesh", "single"):

@@ -1,6 +1,6 @@
 """Shape-bucketed kernel dispatch: every device program is pre-compiled.
 
-BENCH_MATRIX_r06 showed the serving path dominated by XLA recompilation,
+A round-6 CPU capture showed the serving path dominated by XLA recompilation,
 not arithmetic: batch=4 ran at 149 ms p50 while batch=16 ran at 31.6 ms,
 and both closed-loop rows blew the p99 <= 3x p50 gate — every distinct
 (batch, k, corpus) shape hit `jax.jit`'s tracing path in the serving hot
@@ -200,9 +200,6 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
 
-_compile_cache_dir: Optional[str] = None
-
-
 def configure_compile_cache() -> str:
     """The one owner of JAX's persistent compilation cache, so a node
     restart re-loads compiled executables from disk instead of re-paying
@@ -212,7 +209,6 @@ def configure_compile_cache() -> str:
     (serving kernels are small and quick to compile; the defaults would
     skip them). The server calls this before the first backend touch.
     Returns the directory."""
-    global _compile_cache_dir
     import jax
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
@@ -221,7 +217,6 @@ def configure_compile_cache() -> str:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _compile_cache_dir = cache_dir
     return cache_dir
 
 
@@ -337,6 +332,10 @@ class Dispatcher:
                           "compile_nanos": 0, "out_of_grid_compiles": 0,
                           "warmup_compiles": 0, "inline_calls": 0,
                           "async_calls": 0}
+        # compiles in flight right now (a gauge, not reset with the
+        # counters): with `compiles` it tells a caller when the warmup
+        # grid has really finished — a long compile moves no counter
+        self._compiling = 0
         self._bucket: Dict[str, Dict[str, int]] = {}
         self._trace = threading.local()
 
@@ -528,9 +527,16 @@ class Dispatcher:
         # thread compiling different buckets).
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
+        with self._lock:
+            self._compiling += 1
         t0 = time.perf_counter_ns()
-        with _x64_scope(kernel.x64):
-            compiled = kernel.jitted.lower(*args, **static_kwargs).compile()
+        try:
+            with _x64_scope(kernel.x64):
+                compiled = kernel.jitted.lower(
+                    *args, **static_kwargs).compile()
+        finally:
+            with self._lock:
+                self._compiling -= 1
         nanos = time.perf_counter_ns() - t0
         # telemetry-registry mirror of the compile counters: a live
         # p99 over compile cost (and a compile-rate counter) sits next
@@ -583,10 +589,12 @@ class Dispatcher:
 
     # --------------------------------------------------------------- stats
     def stats(self, per_bucket: bool = True) -> dict:
+        import jax
         with self._lock:
             out = dict(self._counters)
+            out["compiling"] = self._compiling
             out["cached_executables"] = len(self._cache)
-            out["compile_cache_dir"] = _compile_cache_dir
+            out["compile_cache_dir"] = jax.config.jax_compilation_cache_dir
             if per_bucket:
                 out["buckets"] = {k: dict(v)
                                   for k, v in sorted(self._bucket.items())}

@@ -64,35 +64,40 @@ def pad_rows(n: int, multiple: int = LANE) -> int:
     return ((n + multiple - 1) // multiple) * multiple
 
 
-def binned_route(n_pad: int, d: int, matrix_dtype, metric: str) -> bool:
-    """Does the binned Pallas kernel serve unfiltered bf16-precision
-    searches of a corpus of this shape? One rule for `knn_search_auto`,
-    `build_corpus`'s padding and the store's warmup grid: a TPU backend
-    (Mosaic compiles nowhere else), a dot-like metric, unpacked storage,
-    rows tiled to the kernel's block, and a row width the kernel's VMEM
-    budget holds. Everything else takes the exact path by this rule —
-    never because a kernel call failed."""
+def binned_serves(d: int, matrix_dtype, metric: str) -> bool:
+    """Can the binned Pallas kernel serve corpora of this row shape here?
+    A TPU backend (Mosaic compiles nowhere else), a dot-like metric,
+    unpacked storage, and a row width the kernel's VMEM budget holds."""
     from elasticsearch_tpu.ops import pallas_knn_binned as binned
     return (metric in (sim.COSINE, sim.DOT_PRODUCT, sim.MAX_INNER_PRODUCT)
             and jnp.dtype(matrix_dtype) not in (jnp.uint8, jnp.uint32)
-            and n_pad % binned.BLOCK_N == 0
             and binned.kernel_holds(d, matrix_dtype)
             and dispatch.is_accelerator_backend())
+
+
+def binned_route(n_pad: int, d: int, matrix_dtype, metric: str) -> bool:
+    """Does the binned kernel serve unfiltered bf16-precision searches of
+    THIS corpus: `binned_serves`, and rows tiled to the kernel's block.
+    One rule for `knn_search_auto` and the store's warmup grid (and, via
+    `binned_serves`, `build_corpus`'s padding). Everything else takes the
+    exact path by this rule — never because a kernel call failed."""
+    from elasticsearch_tpu.ops import pallas_knn_binned as binned
+    return (n_pad % binned.BLOCK_N == 0
+            and binned_serves(d, matrix_dtype, metric))
 
 
 def preferred_pad_multiple(n: int, d: int, dtype: str,
                            metric: str = sim.COSINE) -> int:
     """Pad large corpora to the binned kernel's tile size wherever that
-    kernel will serve them (`binned_route`); everywhere it can't (CPU,
+    kernel will serve them (`binned_serves`); everywhere it can't (CPU,
     l2, packed encodings, rows too wide for its VMEM budget), keep
     minimal lane padding — no wasted HBM/FLOPs."""
     from elasticsearch_tpu.ops import pallas_knn_binned as binned
     if n < binned.BLOCK_N or dtype in quant_codec.PACKED_ENCODINGS:
         return LANE
     matrix_dtype = jnp.int8 if dtype == "int8" else jnp.bfloat16
-    if binned_route(binned.BLOCK_N, d, matrix_dtype, metric):
-        return binned.BLOCK_N
-    return LANE
+    return (binned.BLOCK_N if binned_serves(d, matrix_dtype, metric)
+            else LANE)
 
 
 def build_corpus(

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import os
 import signal
 import sys
 
@@ -294,4 +295,12 @@ def _run_clustered(args, settings, seed_hosts, initial_masters, bootstrap) -> in
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    code = main()
+    # The node is closed and everything durable is on disk, but daemon
+    # threads (warmup compiles, the agg column resync a refresh starts)
+    # may still be inside XLA. Finalizing the interpreter under them
+    # aborts the process (SIGABRT, "FATAL: exception not rethrown" — seen
+    # on the chip when SIGTERM followed a flush): leave without it.
+    sys.stdout.flush()
+    sys.stderr.flush()
+    os._exit(code)

@@ -88,28 +88,23 @@ HOST_MEM_BPS = 10.0e9
 # is an error, not a default: a made-up peak silently mis-routes between
 # the chip and the host mirror.
 DEVICE_PEAKS = {
-    "TPU v5 lite": (197.0e12, 393.0e12, 819.0e9),
-    "TPU v5e": (197.0e12, 393.0e12, 819.0e9),
+    "TPU v5 lite": (197.0e12, 393.0e12, 819.0e9),   # what a v5e reports
 }
-_device_ops: Optional[float] = None
 
 
 def device_peak_ops() -> Optional[float]:
     """bf16 matmul peak of the live backend, from `DEVICE_PEAKS`; None on
     the CPU backend, which has no device to price (see `prefer_host`)."""
-    global _device_ops
     import jax
     dev = jax.devices()[0]
     if dev.platform == "cpu":
         return None
-    if _device_ops is None:
-        if dev.device_kind not in DEVICE_PEAKS:
-            raise RuntimeError(
-                f"no published peak for device kind [{dev.device_kind}] "
-                f"(platform {dev.platform}); add it to "
-                f"serving/batcher.DEVICE_PEAKS with its source")
-        _device_ops = DEVICE_PEAKS[dev.device_kind][0]
-    return _device_ops
+    if dev.device_kind not in DEVICE_PEAKS:
+        raise RuntimeError(
+            f"no published peak for device kind [{dev.device_kind}] "
+            f"(platform {dev.platform}); add it to "
+            f"serving/batcher.DEVICE_PEAKS with its source")
+    return DEVICE_PEAKS[dev.device_kind][0]
 
 
 def device_overhead_ms() -> float:

@@ -1,10 +1,12 @@
 """CPU-resident int8 mirror of a vector field for latency serving.
 
-A TPU dispatch costs a fixed host↔device round trip (~100 µs direct-attached,
-far more through a tunnel); for small/medium corpora one VNNI pass on the
-host CPU beats that overhead, so the serving layer (serving/batcher.py)
-routes latency-sensitive searches here and keeps the device path for
-throughput batches and large corpora. The reference has no such split —
+A TPU dispatch costs a fixed host↔device round trip (measured once per
+process, `serving/batcher.device_overhead_ms`); where one VNNI pass over a
+small corpus on the host CPU beats that overhead, the serving layer
+(serving/batcher.py `CostModel`) routes the search here, and keeps the
+device path for throughput batches and large corpora. Every search served
+here is counted (`_nodes/stats indices.knn.host_mirror_searches`); ROADMAP
+D4 decides whether the path stays. The reference has no such split —
 Lucene scores every vector per-doc in Java (`ScoreScriptUtils.java:86-171`);
 this mirror is the host-side analog of the device `Corpus`, sharing its
 metric conventions (ops/similarity.py raw scores) so results are

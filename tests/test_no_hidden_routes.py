@@ -111,7 +111,6 @@ def test_peak_table_knows_v5e_and_refuses_an_unknown_tpu(monkeypatch):
             self.platform, self.device_kind = platform, kind
 
     def peak_for(dev):
-        monkeypatch.setattr(batcher, "_device_ops", None)
         monkeypatch.setattr(jax, "devices", lambda *a: [dev])
         return batcher.device_peak_ops()
 
@@ -120,7 +119,6 @@ def test_peak_table_knows_v5e_and_refuses_an_unknown_tpu(monkeypatch):
                                                    819.0e9)
     with pytest.raises(RuntimeError, match="no published peak"):
         peak_for(Dev("tpu", "TPU v9 imaginary"))
-    monkeypatch.setattr(batcher, "_device_ops", None)
 
 
 def test_cpu_backend_never_prefers_the_host_mirror():

@@ -93,7 +93,7 @@ class RawJitRule(Rule):
     """TPU001: no raw `jax.jit` / `pjit` / raw-JAX `shard_map` outside
     `ops/dispatch.py` registrations.
 
-    Historical bug (BENCH_MATRIX_r06 → PR 4): every distinct (batch, k,
+    Historical bug (a round-6 CPU capture → PR 4): every distinct (batch, k,
     corpus) shape hit `jax.jit`'s tracing path in the serving hot loop —
     batch=4 ran at 149 ms p50 vs batch=16 at 31.6 ms, all of it XLA
     recompilation. The fix was the shape-bucketed dispatcher: ONE module
