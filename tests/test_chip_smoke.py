@@ -1,4 +1,4 @@
-"""`chip_smoke.py`'s CPU rehearsal, as subprocesses at 4,096 rows.
+"""`chip_smoke.py`'s CPU rehearsal, as subprocesses (4,096 rows healthy).
 
 The script is the quickest proof that the system still starts on the
 chip, so it must not be able to exit 0 past a failed phase: a healthy
@@ -27,7 +27,7 @@ LIMIT_S = 120
 
 class _Run:
     def __init__(self, root, name, *flags, kill_server=False,
-                 cpu_devices=None):
+                 cpu_devices=None, rows=4096):
         self.lines = []
         self.killed_pid = None
         self._kill_server = kill_server
@@ -39,7 +39,7 @@ class _Run:
                                 f"{cpu_devices}")
         self.proc = subprocess.Popen(
             [sys.executable, os.path.join(REPO, "chip_smoke.py"),
-             "--rows", "4096", "--out", str(root / name), *flags],
+             "--rows", str(rows), "--out", str(root / name), *flags],
             cwd=REPO, env=env, stdout=subprocess.PIPE,
             stderr=subprocess.STDOUT, text=True)
         self._reader = threading.Thread(target=self._read, daemon=True)
@@ -77,11 +77,13 @@ def runs(tmp_path_factory):
     started = {
         "healthy": _Run(root, "healthy", "--rehearse"),
         "killed": _Run(root, "killed", "--rehearse", kill_server=True),
+        # the fault-injection runs load one _bulk block, not two: five
+        # servers fsync-ing at once is load the rest of the suite feels
         "bad_recall": _Run(root, "bad_recall", "--rehearse",
-                           "--break-recall"),
+                           "--break-recall", rows=2048),
         "no_chip": _Run(root, "no_chip"),
         "four": _Run(root, "four", "--rehearse", "--chips", "4",
-                     cpu_devices=4),
+                     cpu_devices=4, rows=2048),
     }
     yield started
     for run in started.values():
