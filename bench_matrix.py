@@ -222,15 +222,19 @@ def knn_scheduler_stats(node) -> dict:
     """Continuous-batching scheduler fields of the closed-loop (1cl/4cl)
     rows: the per-(field, k) kNN batchers' counters summed over shards
     (`_nodes/stats indices.knn.scheduler`)."""
+    from elasticsearch_tpu.telemetry import REGISTRY
     sched = node._knn_stats_section().get("scheduler", {})
     return {
         "sched": {key: sched.get(key, 0)
                   for key in ("batches", "pipelined_batches", "topups",
                               "deadline_sheds", "overlap_hits")},
+        # the scheduler's times are the telemetry stages (process-wide,
+        # cumulative): the batchers keep no second copy
         "tail_ms": {
-            "queue_wait": round(sched.get("queue_wait_nanos", 0) / 1e6, 1),
-            "dispatch": round(sched.get("dispatch_nanos", 0) / 1e6, 1),
-            "finalize": round(sched.get("finalize_nanos", 0) / 1e6, 1)}}
+            key: round(REGISTRY.histogram(name).sum_ns / 1e6, 1)
+            for key, name in (("queue_wait", "serving.queue_wait"),
+                              ("dispatch", "serving.device_dispatch"),
+                              ("finalize", "serving.device_sync"))}}
 
 
 def _emit(name, qps, marginal, p50, p99, recall, n, d, dtype, extra=None,

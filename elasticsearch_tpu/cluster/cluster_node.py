@@ -52,6 +52,7 @@ from elasticsearch_tpu.common.threadpool import EsRejectedExecutionError
 from elasticsearch_tpu.index.engine import Engine
 from elasticsearch_tpu.serving import fanout as fanout_lib
 from elasticsearch_tpu.serving.fanout import ScatterGather
+from elasticsearch_tpu import telemetry
 from elasticsearch_tpu.telemetry import trace as telemetry_trace
 from elasticsearch_tpu.index.mapping import MapperService
 from elasticsearch_tpu.index.seqno import ReplicationTracker
@@ -1531,20 +1532,19 @@ class ClusterNode:
                 fold_aggs()
 
         fan_trace = fan.get("trace")
-        qspan = None
-        if fan_trace is not None:
-            qspan = fan_trace.begin_span("phase.query",
-                                         parent_id=fan.get("trace_parent"),
-                                         targets=len(targets))
-            # per-leg spans parent under the phase span; ended by
-            # query_done below on EVERY completion path (ScatterGather's
-            # on_done is structural — the sweep timer guarantees it)
+        # stage `phase.query`: filed by query_done below on EVERY
+        # completion path (ScatterGather's on_done is structural — the
+        # sweep timer guarantees it); per-leg spans parent under its
+        # span, whose id therefore exists from the start
+        q_start = time.monotonic_ns()
+        q_id = telemetry.new_span_id() if fan_trace is not None else None
 
         def query_done(summary):
-            if qspan is not None:
-                fan_trace.end_span(
-                    qspan, status="timeout" if summary["any_timed_out"]
-                    else "ok")
+            telemetry.stage_done(
+                "phase.query", q_start, time.monotonic_ns(),
+                (fan_trace, fan.get("trace_parent"), None),
+                status="timeout" if summary["any_timed_out"] else "ok",
+                span_id=q_id, targets=len(targets))
             fold_aggs(force=True)
             fan["phases"]["query"] = summary
             if not fan["partial"] and (summary["any_timed_out"]
@@ -1568,8 +1568,7 @@ class ClusterNode:
             budget_ms=self._phase_budget(fan, budgets["query_budget_ms"]),
             stats=self.fanout_stats, observe=self._ars_observe,
             on_done=query_done,
-            trace=fan_trace,
-            trace_parent=qspan.span_id if qspan is not None else None)
+            trace=fan_trace, trace_parent=q_id)
         deadline_ms = self._phase_deadline_ms(fan,
                                               budgets["query_budget_ms"])
 
@@ -1652,18 +1651,17 @@ class ClusterNode:
         hits: List[Optional[dict]] = [None] * len(window_entries)
 
         fan_trace = fan.get("trace")
-        fspan = None
-        if fan_trace is not None:
-            fspan = fan_trace.begin_span("phase.fetch",
-                                         parent_id=fan.get("trace_parent"),
-                                         targets=len(by_shard))
-            # ended by fetch_done on every completion path below
+        # stage `phase.fetch`: filed by fetch_done on every completion
+        # path below
+        f_start = time.monotonic_ns()
+        f_id = telemetry.new_span_id() if fan_trace is not None else None
 
         def fetch_done(summary):
-            if fspan is not None:
-                fan_trace.end_span(
-                    fspan, status="timeout" if summary["any_timed_out"]
-                    else "ok")
+            telemetry.stage_done(
+                "phase.fetch", f_start, time.monotonic_ns(),
+                (fan_trace, fan.get("trace_parent"), None),
+                status="timeout" if summary["any_timed_out"] else "ok",
+                span_id=f_id, targets=len(by_shard))
             fan["phases"]["fetch"] = summary
             out["hits"]["hits"] = [h for h in hits if h is not None]
             finish_response()
@@ -1679,8 +1677,7 @@ class ClusterNode:
             budget_ms=budgets["fetch_budget_ms"],
             stats=self.fanout_stats, observe=self._ars_observe,
             on_done=fetch_done,
-            trace=fan_trace,
-            trace_parent=fspan.span_id if fspan is not None else None)
+            trace=fan_trace, trace_parent=f_id)
         deadline_ms = self.scheduler.now_ms + budgets["fetch_budget_ms"]
 
         def fold(outcome, resp, _err, positions):
@@ -1811,8 +1808,8 @@ class ClusterNode:
                     partial_aggs=True,
                     query_cache=self.caches.query,
                     deadline_at=deadline_at)
-                telemetry_trace.record_span(
-                    "shard.query_phase", time.perf_counter_ns() - t0)
+                telemetry.stage_done("shard.query_phase", t0,
+                                     time.perf_counter_ns())
         except EsRejectedExecutionError:
             # the continuous batcher's EDF queue shed the device leg on
             # the propagated deadline — exactly the remote-admission shed
@@ -2329,10 +2326,10 @@ class ClusterNode:
             if rtrace is not None:
                 telemetry_trace.TRACER.finish(rtrace, status="error")
             raise
-        if rtrace is not None:
-            rtrace.record_span("hydrate", time.perf_counter_ns() - t0,
-                               parent_id=rtrace.root.span_id,
-                               hits=len(hits))
+        telemetry.stage_done(
+            "shard.hydrate", t0, time.perf_counter_ns(),
+            (rtrace, rtrace.root.span_id if rtrace is not None else None,
+             None), hits=len(hits))
         answer({"hits": hits})
 
     def client_get(self, index: str, doc_id: str,

@@ -34,7 +34,7 @@ from elasticsearch_tpu.common.errors import (
     IllegalArgumentError, IndexNotFoundError, SearchEngineError,
 )
 from elasticsearch_tpu.node import Node
-from elasticsearch_tpu.telemetry import metrics as _telemetrics
+from elasticsearch_tpu import telemetry as _telemetry
 from elasticsearch_tpu.telemetry import trace as _teletrace
 
 
@@ -1016,7 +1016,8 @@ class ClusterAwareNode(Node):
                           telemetry_ctx=_teletrace.capture())
         self.counters["search"] += 1
         took_s = _time.perf_counter() - t0
-        _telemetrics.record("search.took", int(took_s * 1e9))
+        _telemetry.stage_done("search.took", t0 * 1e9,
+                              (t0 + took_s) * 1e9)
         # the coordinator ships the phase summary on a private key so
         # the slow log gets it on UNPROFILED requests too; pop it before
         # the response reaches the client

@@ -10,10 +10,12 @@ attributable at a glance: the node thread pools prefix `es[<pool>]`
 (common/threadpool.py), background workers name themselves at spawn
 (`segments-merge`, `dispatch-warmup`, `batcher-warmup`,
 `agg-column-resync`), and the combining batcher — which runs on BORROWED
-submitter threads — tags the current thread for the duration of a drain
-or finalize section (`telemetry.thread_section`: `»batcher-drain`,
-`»batcher-finalize`). The report maps each thread to its subsystem from
-that name.
+submitter threads — tags the current thread for the duration of its
+dispatch and finalize stages (the `section` of the same `telemetry.stage`
+call that times `serving.device_dispatch` / `serving.device_sync`:
+`»batcher-drain`, `»batcher-finalize`), so hot threads and spans cannot
+name one stretch two ways. The report maps each thread to its subsystem
+from that name.
 """
 
 from __future__ import annotations

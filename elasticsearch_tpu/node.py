@@ -30,7 +30,7 @@ from elasticsearch_tpu.search.service import (
     execute_fetch_phase, execute_query_phase,
 )
 from elasticsearch_tpu.common.settings import parse_time_value
-from elasticsearch_tpu.telemetry import metrics as _telemetrics
+from elasticsearch_tpu import telemetry as _telemetry
 from elasticsearch_tpu.telemetry import trace as _teletrace
 from elasticsearch_tpu.version import __version__
 
@@ -548,8 +548,12 @@ class Node:
         # sampling + trace-ring sizing. Process-wide like the dispatcher
         # — only an explicit setting reconfigures (same clobber rule as
         # warmup above).
-        from elasticsearch_tpu import telemetry as _telemetry
         _telemetry.configure_from_settings(self.settings)
+        # the collector's pauses and the device-starved counters exist
+        # from the node's start, so that one that never moved reads 0
+        _telemetry.time_gc()
+        from elasticsearch_tpu.serving.batcher import IDLE
+        IDLE.ensure_counters()
         # set by the server bootstrap after native hardening runs; embedded
         # nodes have no hardening (reference: JNANatives.LOCAL_MLOCKALL)
         self.natives = None
@@ -1130,7 +1134,8 @@ class Node:
                 # response reaches the client.
                 phases = resp.pop("_took_phases", None)
                 took_s = time.perf_counter() - start
-                _telemetrics.record("search.took", int(took_s * 1e9))
+                _telemetry.stage_done("search.took", start * 1e9,
+                                      (start + took_s) * 1e9)
                 _task = _teletrace.current_task()
                 self.search_slow_log.maybe_log(
                     svc.settings, svc.name, took_s,
@@ -1557,6 +1562,9 @@ class Node:
         max_score = None
         merged_aggs = None
         phase_nanos = {"query_nanos": 0, "fetch_nanos": 0, "merge_nanos": 0}
+        # the phases' stages, filed together with `search.took` at the
+        # end (the filing code then runs once a request, back to back)
+        phase_stages: list = []
         shard_failures: List[dict] = []
         pre_filter = body.pop("__pre_filter_shard_size__", None)
         skipped_shards = 0
@@ -1685,8 +1693,8 @@ class Node:
                         cache_used.put(cache_key, result)
                 q_nanos = time.perf_counter_ns() - q_start
                 phase_nanos["query_nanos"] += q_nanos
-                _teletrace.record_span(f"query[{svc.name}]", q_nanos,
-                                       index=svc.name)
+                phase_stages.append(("search.query", q_start,
+                                     q_start + q_nanos, svc.name))
                 for f in getattr(result, "failures", None) or []:
                     f = dict(f)
                     f["index"] = svc.name
@@ -1706,8 +1714,8 @@ class Node:
                     index_settings=svc.settings.as_flat_dict())
                 f_nanos = time.perf_counter_ns() - f_start
                 phase_nanos["fetch_nanos"] += f_nanos
-                _teletrace.record_span(f"fetch[{svc.name}]", f_nanos,
-                                       index=svc.name)
+                phase_stages.append(("search.fetch", f_start,
+                                     f_start + f_nanos, svc.name))
                 for h, score, sv in zip(hits, result.scores,
                                         result.sort_values or [None] * len(hits)):
                     if factor != 1.0 and h.get("_score") is not None:
@@ -1770,8 +1778,8 @@ class Node:
         else:
             all_hits.sort(key=lambda t: -t[1])
         phase_nanos["merge_nanos"] = time.perf_counter_ns() - m_start
-        _teletrace.record_span("merge", phase_nanos["merge_nanos"],
-                               hits=len(all_hits))
+        phase_stages.append(("search.merge", m_start,
+                             m_start + phase_nanos["merge_nanos"], None))
         collapse_spec = body.get("collapse")
         if collapse_spec and len(readers) > 1:
             # cross-index collapse: per-index phases deduped their own
@@ -1843,7 +1851,10 @@ class Node:
         # breaches carry the phase breakdown, the caller's X-Opaque-ID,
         # and this request's trace (id + top spans) when sampled
         took_s = time.perf_counter() - start
-        _telemetrics.record("search.took", int(took_s * 1e9))
+        for name, begun, ended, index in phase_stages:
+            _telemetry.stage_done(name, begun, ended, index=index)
+        _telemetry.stage_done("search.took", start * 1e9,
+                              (start + took_s) * 1e9)
         _task = _teletrace.current_task()
         for svc, _, _ in readers:
             self.search_slow_log.maybe_log(
@@ -2762,14 +2773,16 @@ class Node:
         return policy.stats()
 
     def _knn_stats_section(self) -> dict:
-        """Vector-search engine counters summed over local shards: total
-        searches, how many took the pruned tpu_ivf path vs fell back to
+        """Vector-search engine counters summed over local shards:
+        `searches` (DISPATCHES of the exhaustive routes: one a coalesced
+        batch, not the searches in it — those are `scheduler.requests`),
+        how many took the pruned tpu_ivf path vs fell back to
         exhaustive (or rode the SPMD mesh), fused-probe dispatches and
         two-phase rescore window stats (the quant subsystem's serving
         counters), cumulative per-phase device time, the per-field
         encoding/bytes-per-doc ladder breakdown, and the per-(field, k)
-        continuous-batching scheduler counters (queue wait / topups /
-        overlap — the 1cl/4cl closed-loop tail attribution)."""
+        continuous-batching scheduler counters (batches / requests /
+        topups / overlap; their times are the telemetry stages)."""
         out = {"searches": 0, "ivf_searches": 0, "fallback_searches": 0,
                "mesh_searches": 0, "host_mirror_searches": 0,
                "fused_probe_searches": 0,
@@ -2816,8 +2829,11 @@ class Node:
         latency, queue wait, device dispatch/sync, fan-out leg latency —
         p50/p90/p99/p999 each, no bench harness required) plus the
         tracer's sampling/ring counters. Process-wide like the dispatch
-        section."""
+        section. The device-starved counters are booked up to this
+        read first, so that two reads bracket exactly their window."""
+        from elasticsearch_tpu.serving.batcher import IDLE
         from elasticsearch_tpu.telemetry import REGISTRY, TRACER
+        IDLE.flush()
         return {**REGISTRY.snapshot(), "tracing": TRACER.snapshot()}
 
     def local_traces_section(self, limit: int = 50) -> dict:

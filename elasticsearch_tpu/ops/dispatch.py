@@ -45,7 +45,6 @@ from __future__ import annotations
 import logging
 import os
 import threading
-import time
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -248,6 +247,25 @@ class _Kernel:
         # at call time (the AOT executable's arg-aval check canonicalizes
         # host numpy inputs against the active config).
         self.x64 = bool(x64)
+
+
+def _named(kernel: "_Kernel"):
+    """The kernel's function under `jax.named_scope("es.<kernel name>")`:
+    every device operation of the program then carries its dispatch
+    kernel's name in the profiler trace's operation metadata, not only
+    its HLO line."""
+    import functools
+
+    import jax
+
+    fn, scope = kernel.fn, "es." + kernel.name
+
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        with jax.named_scope(scope):
+            return fn(*args, **kwargs)
+
+    return named
 
 
 def _x64_scope(enabled: bool):
@@ -514,7 +532,7 @@ class Dispatcher:
 
         if kernel.jitted is None:
             kernel.jitted = jax.jit(
-                kernel.fn, static_argnames=kernel.static_argnames,
+                _named(kernel), static_argnames=kernel.static_argnames,
                 donate_argnums=kernel.donate_argnums)
         # CPU backends can't honor donation; the fallback is silent
         # copy-free-anyway execution, not an error worth a log line. The
@@ -527,25 +545,24 @@ class Dispatcher:
         # thread compiling different buckets).
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
+        # stage `dispatch.compile`: a live p99 over compile cost (and,
+        # as its count, a compile rate) sits next to the serving
+        # latency histograms in `_nodes/stats telemetry` — a nonzero
+        # steady-state rate there is the recompile-regression signal
+        # without waiting for the strict-mode gate; a sampled request
+        # that had to compile shows it as a span
+        from elasticsearch_tpu.telemetry import stage as _stage
         with self._lock:
             self._compiling += 1
-        t0 = time.perf_counter_ns()
         try:
-            with _x64_scope(kernel.x64):
+            with _stage("dispatch.compile", kernel=key_str) as st, \
+                    _x64_scope(kernel.x64):
                 compiled = kernel.jitted.lower(
                     *args, **static_kwargs).compile()
         finally:
             with self._lock:
                 self._compiling -= 1
-        nanos = time.perf_counter_ns() - t0
-        # telemetry-registry mirror of the compile counters: a live
-        # p99 over compile cost (and a compile-rate counter) sits next
-        # to the serving latency histograms in `_nodes/stats telemetry`
-        # — a nonzero steady-state rate there is the recompile-
-        # regression signal without waiting for the strict-mode gate
-        from elasticsearch_tpu.telemetry import metrics as _metrics
-        _metrics.counter("dispatch.compiles").inc()
-        _metrics.record("dispatch.compile", nanos)
+        nanos = st.nanos
         entry = _Entry(compiled, key_str, nanos)
         with self._lock:
             self._cache[key] = entry

@@ -143,7 +143,7 @@ def _fused_probe_board(queries, ivf: IVFPartitions, probe_ids,
         out_specs=out_spec)
     board = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret, name="es_ivf_fused_probe",
     )(probe_ids, *q_ops, ivf.parts, scales)
     return board.reshape(nq, nprobe, cap)
 

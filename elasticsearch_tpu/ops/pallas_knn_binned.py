@@ -475,6 +475,9 @@ def _binned_packed(queries, corpus, metric, interpret):
         out_shape=jax.ShapeDtypeStruct((nq, n_tiles * BINS_PER_TILE),
                                        jnp.int32),
         interpret=interpret,
+        # the kernel's name in the device trace (the HLO line alone is
+        # `_binned_impl…` with its shapes)
+        name="es_knn_binned_int8" if int8 else "es_knn_binned",
     )
     if not interpret:
         call["compiler_params"] = pltpu.CompilerParams(

@@ -131,7 +131,7 @@ def _maxsim_impl(ids, q, qe, qo, toks, scales, interpret: bool):
         out_specs=out_spec)
     board = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret, name="es_maxsim",
     )(ids, *q_ops, toks, scales)
     return board.reshape(nq, wc)
 

@@ -24,7 +24,7 @@ from elasticsearch_tpu.version import __version__
 
 
 def _rest_telemetry(req, node, action: str, force_trace: bool = False,
-                    description: str = "", parse_nanos: int = 0):
+                    description: str = "", parsed=None):
     """Per-request telemetry binding for an instrumented handler: live
     task (tasks API + cancellation token), trace when sampled or forced,
     X-Opaque-ID captured once from the header and threaded through
@@ -32,8 +32,7 @@ def _rest_telemetry(req, node, action: str, force_trace: bool = False,
     return telemetry.rest_request(
         node, action,
         opaque_id=(req.headers or {}).get("x-opaque-id"),
-        force_trace=force_trace, description=description,
-        parse_nanos=parse_nanos)
+        force_trace=force_trace, description=description, parsed=parsed)
 
 
 def _cat_table(req, headers, rows) -> Tuple[int, Any]:
@@ -333,21 +332,18 @@ def register_all(rc: RestController, node: Node) -> None:
                         resp["suggest"].pop(name)
 
     def bulk(req):
-        t_parse = time.perf_counter_ns()
+        t_parse = time.monotonic_ns()
         ops = req.ndjson()
-        parse_nanos = time.perf_counter_ns() - t_parse
+        parsed = (t_parse, time.monotonic_ns())
         with _rest_telemetry(req, node, "indices:data/write/bulk",
                              force_trace=req.bool_param("trace"),
                              description=f"requests[{len(ops)}]",
-                             parse_nanos=parse_nanos):
-            t0 = time.perf_counter_ns()
-            resp = node.bulk(ops,
-                             default_index=req.params.get("index"),
-                             refresh=req.param("refresh"),
-                             source_filter=_get_source_filter(req))
-            telemetry.record_span("bulk.execute",
-                                  time.perf_counter_ns() - t0,
-                                  ops=len(ops))
+                             parsed=parsed):
+            with telemetry.stage("bulk.execute", ops=len(ops)):
+                resp = node.bulk(ops,
+                                 default_index=req.params.get("index"),
+                                 refresh=req.param("refresh"),
+                                 source_filter=_get_source_filter(req))
             return 200, resp
 
     rc.register("POST", "/_bulk", bulk)
@@ -370,9 +366,9 @@ def register_all(rc: RestController, node: Node) -> None:
 
     # ---------------------------------------------------------------- search
     def search(req):
-        t_parse = time.perf_counter_ns()
+        t_parse = time.monotonic_ns()
         body = req.json() or {}
-        parse_nanos = time.perf_counter_ns() - t_parse
+        parsed = (t_parse, time.monotonic_ns())
         # every search runs as a live task under telemetry: sampled by
         # telemetry.tracing.sample_rate, forced by ?trace=true or a
         # profile body; X-Opaque-ID rides the task, the trace, and any
@@ -382,7 +378,7 @@ def register_all(rc: RestController, node: Node) -> None:
                 force_trace=(req.bool_param("trace")
                              or bool(body.get("profile"))),
                 description=f"indices[{req.params.get('index') or '_all'}]",
-                parse_nanos=parse_nanos) as tr:
+                parsed=parsed) as tr:
             status, resp = _search_inner(req, body)
             if tr is not None and isinstance(resp, dict) \
                     and body.get("profile"):

@@ -11,9 +11,11 @@ from __future__ import annotations
 
 import asyncio
 import json
+import time
 import urllib.parse
 from typing import Optional, Tuple
 
+from elasticsearch_tpu import telemetry
 from elasticsearch_tpu.common import xcontent
 from elasticsearch_tpu.rest.controller import RestController
 
@@ -72,27 +74,40 @@ class HttpServer:
 
     async def _handle(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter) -> None:
+        idle_ns = time.monotonic_ns()     # accepted; later: last response out
         try:
             while True:
-                request = await self._read_request(reader)
+                request = await self._read_request(reader, idle_ns)
                 if request is None:
                     break
-                method, path, query, headers, body = request
+                front, method, path, query, headers, body = request
                 from elasticsearch_tpu.common.threadpool import (
                     EsRejectedExecutionError, pool_for_route,
                 )
                 try:
+                    front.submit_ns = time.monotonic_ns()
                     future = self.thread_pool.submit(
                         pool_for_route(method, path),
-                        self.controller.dispatch, method, path, query,
+                        self._run_handler, front, method, path, query,
                         body, headers.get("content-type"), headers)
                     status, payload = await asyncio.wrap_future(future)
+                    # the handler's return on the worker -> this
+                    # coroutine running again: the loop's lag
+                    front.wake_ns = time.monotonic_ns()
                 except EsRejectedExecutionError as e:
                     status, payload = 429, {"error": e.to_dict(),
                                             "status": 429}
                 keep_alive = headers.get("connection", "keep-alive").lower() != "close"
-                await self._write_response(writer, status, payload, keep_alive,
-                                           accept=headers.get("accept"))
+                try:
+                    with telemetry.annotation("http.respond"):
+                        await self._write_response(
+                            writer, status, payload, keep_alive,
+                            accept=headers.get("accept"))
+                finally:
+                    # the request's stages are filed here, together; a
+                    # sampled trace ends socket to socket
+                    idle_ns = time.monotonic_ns()
+                    front.finish(idle_ns)
                 if not keep_alive:
                     break
         except (ConnectionResetError, asyncio.IncompleteReadError):
@@ -104,13 +119,30 @@ class HttpServer:
             except Exception:
                 pass
 
-    async def _read_request(self, reader: asyncio.StreamReader):
+    def _run_handler(self, front, *request):
+        """On the pool's worker: the front marks the handler's start and
+        return and rides the thread, so that a handler which samples the
+        request (`telemetry.rest_request`) finds it."""
+        with front:
+            return self.controller.dispatch(*request)
+
+    async def _read_request(self, reader: asyncio.StreamReader,
+                            idle_ns: int):
         try:
             request_line = await reader.readline()
         except (asyncio.LimitOverrunError, ValueError):
             return None
         if not request_line:
             return None
+        # the request's life in the server starts here: its line in hand
+        front = telemetry.Front(idle_ns, time.monotonic_ns())
+        with telemetry.annotation("http.read"):
+            request = await self._read_rest(reader, request_line)
+        front.read_ns = time.monotonic_ns()
+        return None if request is None else (front,) + request
+
+    async def _read_rest(self, reader: asyncio.StreamReader,
+                         request_line: bytes):
         try:
             method, target, _version = request_line.decode("latin-1").split(" ", 2)
         except ValueError:

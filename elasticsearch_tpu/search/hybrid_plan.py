@@ -51,6 +51,7 @@ from elasticsearch_tpu.search.service import (
     ShardSearchResult, execute_fetch_phase, execute_query_phase,
 )
 from elasticsearch_tpu.serving.batcher import BoundedBatcher
+from elasticsearch_tpu.telemetry import stage_done as _stage_done
 
 DEFAULT_RANK_CONSTANT = 60
 DEFAULT_WINDOW = 100
@@ -813,6 +814,12 @@ class HybridExecutor:
             cache_state.append(hit)
         plan_nanos = time.perf_counter_ns() - t0
         self.stats["plan_nanos"] += plan_nanos
+        # fine-grained stages of the batch: this thread runs inside the
+        # batcher's `serving.device_dispatch` / `serving.device_sync`
+        # stage, whose context is the batch LEADER's trace, so these hang
+        # under it (followers link to the batcher's span). Every reading
+        # was taken anyway: zero added host syncs
+        _stage_done("hybrid.plan", t0, t0 + plan_nanos)
 
         breaker_bytes = reader.num_docs * 16 * max(len(bodies), 1)
         self.node.breakers.add_estimate("request", breaker_bytes,
@@ -836,6 +843,8 @@ class HybridExecutor:
                 reader, store, ctx, plans, bound)
             dispatch_nanos = time.perf_counter_ns() - t0
             self.stats["dispatch_nanos"] += dispatch_nanos
+            _stage_done("hybrid.device_dispatch", t0, t0 + dispatch_nanos,
+                        coalesced=len(bodies))
         except BaseException:
             if trace:
                 _dispatch.DISPATCH.drain_events()
@@ -881,6 +890,7 @@ class HybridExecutor:
                 sync_nanos = time.perf_counter_ns() - t0
                 with self._stats_lock:
                     self.stats["sync_nanos"] += sync_nanos
+                _stage_done("hybrid.device_sync", t0, t0 + sync_nanos)
             finally:
                 if trace:
                     dispatch_events = _dispatch.DISPATCH.drain_events()
@@ -914,6 +924,7 @@ class HybridExecutor:
             fuse_nanos = time.perf_counter_ns() - t0
             with self._stats_lock:
                 self.stats["fuse_nanos"] += fuse_nanos
+            _stage_done("hybrid.fuse", t0, t0 + fuse_nanos)
 
             t0 = time.perf_counter_ns()
             out = []
@@ -957,6 +968,7 @@ class HybridExecutor:
             hydrate_nanos = time.perf_counter_ns() - t0
             with self._stats_lock:
                 self.stats["hydrate_nanos"] += hydrate_nanos
+            _stage_done("hybrid.hydrate", t0, t0 + hydrate_nanos)
             # private key (popped by _search_rrf): the slow log needs
             # the phase breakdown on EVERY breach, not just profiled
             # requests — batch-scoped figures, same semantics as the
@@ -976,24 +988,6 @@ class HybridExecutor:
                 if prof is not None:
                     prof["hybrid"]["breakdown"]["hydrate_nanos"] = \
                         hydrate_nanos
-            tr = handle["sched_meta"].get("trace")
-            if tr is not None:
-                # fine-grained stage attribution on the batch LEADER's
-                # trace (the batcher already recorded the coarse
-                # batch.dispatch/batch.finalize pair and linked
-                # followers): every duration below was measured at an
-                # existing sync point — retroactive spans, zero added
-                # host syncs
-                parent = handle["sched_meta"].get("trace_parent")
-                tr.record_span("hybrid.plan", plan_nanos, parent_id=parent)
-                tr.record_span("hybrid.device_dispatch",
-                               handle["dispatch_nanos"], parent_id=parent,
-                               coalesced=len(bodies))
-                tr.record_span("hybrid.device_sync", sync_nanos,
-                               parent_id=parent)
-                tr.record_span("hybrid.fuse", fuse_nanos, parent_id=parent)
-                tr.record_span("hybrid.hydrate", hydrate_nanos,
-                               parent_id=parent)
             return out
         finally:
             self.node.breakers.release("request",

@@ -51,8 +51,10 @@ from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 from elasticsearch_tpu.common.errors import TaskCancelledError
 from elasticsearch_tpu.common.threadpool import EsRejectedExecutionError
+from elasticsearch_tpu.telemetry import UNSAMPLED as _UNSAMPLED
 from elasticsearch_tpu.telemetry import metrics as _metrics
-from elasticsearch_tpu.telemetry import thread_section as _thread_section
+from elasticsearch_tpu.telemetry import stage as _stage
+from elasticsearch_tpu.telemetry import stage_done as _stage_done
 from elasticsearch_tpu.telemetry import trace as _tt
 
 _overhead_lock = threading.Lock()
@@ -179,11 +181,99 @@ class CostModel:
                 < cls.device_ms(batch, n_rows, dims))
 
 
+IDLE_NO_REQUEST = "serving.idle_no_request_nanos"
+IDLE_PICKUP = "serving.idle_pickup_nanos"
+
+
+class IdleClock:
+    """Device-starved time by cause, counted where the batches are.
+
+    The vector stores count their batches in flight (dispatched, not yet
+    finalized). From the instant the last one lands (1 -> 0) to the next
+    dispatch (0 -> 1) the device has no kNN batch to work on; that
+    interval goes to one of two counters:
+
+    * `serving.idle_no_request_nanos` — until the first request was
+      enqueued in a store's batcher: nobody asked;
+    * `serving.idle_pickup_nanos` — from that enqueue to the dispatch: a
+      request was waiting and no runner had launched it yet (run lock,
+      GIL, batch forming, the host half of the dispatch before launch).
+
+    A request already waiting when the last batch landed makes the whole
+    interval pickup. Cost: two clock reads a BATCH, on the edges only;
+    `waiting()` reads the clock once an idle stretch (the first request
+    after a dispatch began notes its time; the check is unlocked, and a
+    lost race files one interval under the neighbouring cause). Process-
+    wide like the registry it feeds: one device, one clock. The time
+    left over is a batch in flight: host work (`dispatch.prepare`,
+    `.h2d`, `.launch`, `.d2h`, `.land`) or the wait for the device
+    (`dispatch.sync_wait`)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._active = 0
+        self._idle_since = 0        # 0: never yet busy (start-up is no gap)
+        self._first_waiting = 0     # 0: nobody since the last dispatch began
+
+    def waiting(self, now_ns: Optional[int] = None) -> None:
+        """A request is about to queue for a dispatch."""
+        if not self._first_waiting:
+            self._first_waiting = now_ns or time.monotonic_ns()
+
+    def begin(self, now_ns: Optional[int] = None) -> None:
+        """A batch goes in flight."""
+        with self._lock:
+            self._active += 1
+            first, self._first_waiting = self._first_waiting, 0
+            if self._active != 1 or not self._idle_since:
+                return
+            since, self._idle_since = self._idle_since, 0
+            now = now_ns or time.monotonic_ns()
+        self._book(since, first, now)
+
+    def end(self, now_ns: Optional[int] = None) -> None:
+        """A batch has landed (or its handle was dropped)."""
+        with self._lock:
+            self._active = max(0, self._active - 1)
+            if self._active == 0:
+                self._idle_since = now_ns or time.monotonic_ns()
+
+    def flush(self, now_ns: Optional[int] = None) -> None:
+        """Book the idle stretch that is still open, up to now: a reader
+        of the counters (`_nodes/stats`) then sees them as of its read,
+        and a stretch that straddles two reads is split between them."""
+        with self._lock:
+            if self._active or not self._idle_since:
+                return
+            now = now_ns or time.monotonic_ns()
+            since, self._idle_since = self._idle_since, now
+            first = self._first_waiting
+        self._book(since, first, now)
+
+    @staticmethod
+    def _book(since: int, first: int, now: int) -> None:
+        asked = min(max(first, since), now) if first else now
+        # resolved per call: a test-time `REGISTRY.reset()` must not
+        # detach the clock from the registry
+        _metrics.counter(IDLE_NO_REQUEST).inc(asked - since)
+        _metrics.counter(IDLE_PICKUP).inc(now - asked)
+
+    def ensure_counters(self) -> None:
+        """Create both counters, so that one that never moved reads 0 in
+        `_nodes/stats telemetry` and not nothing (called when a node
+        starts)."""
+        _metrics.counter(IDLE_NO_REQUEST)
+        _metrics.counter(IDLE_PICKUP)
+
+
+IDLE = IdleClock()
+
+
 class _QueueEntry:
     """One queued request: payload, future, and its schedule metadata."""
 
     __slots__ = ("request", "fut", "enqueued", "deadline", "seq", "claimed",
-                 "trace", "span_parent", "token")
+                 "ctx", "trace", "span_parent", "token")
 
     def __init__(self, request, fut: Future, enqueued: float,
                  deadline: Optional[float], seq: int):
@@ -199,7 +289,8 @@ class _QueueEntry:
         # cannot follow the request — the entry carries its own trace
         # (None = unsampled), parent span id, and cancellation token
         # (the live task; a truthy `.cancelled` sheds at EDF admission)
-        self.trace, self.span_parent, self.token = _tt.capture()
+        self.ctx = _tt.capture()
+        self.trace, self.span_parent, self.token = self.ctx
 
     def sort_key(self) -> Tuple[float, int]:
         return (self.deadline if self.deadline is not None else float("inf"),
@@ -209,9 +300,7 @@ class _QueueEntry:
 def _fresh_sched_stats() -> dict:
     return {"batches": 0, "pipelined_batches": 0, "requests": 0,
             "topups": 0, "deadline_sheds": 0, "cancelled_sheds": 0,
-            "overlap_hits": 0,
-            "queue_wait_nanos": 0, "dispatch_nanos": 0,
-            "finalize_nanos": 0}
+            "overlap_hits": 0}
 
 
 class CombiningBatcher:
@@ -239,8 +328,10 @@ class CombiningBatcher:
       retry path (synthesized from the pair when not given).
 
     `sched` counts the scheduler's work: batches, top-ups, schedule-time
-    deadline sheds, dispatch/finalize overlap hits, and cumulative
-    queue-wait/dispatch/finalize time.
+    deadline sheds, dispatch/finalize overlap hits. Its TIMES are the
+    telemetry stages `serving.queue_wait` (a request), `serving.batch_form`,
+    `serving.device_dispatch` and `serving.device_sync` (a batch): one
+    `telemetry.stage` call each, and kept nowhere else.
     """
 
     def __init__(self, execute: Optional[Callable[[Sequence], List]],
@@ -290,17 +381,16 @@ class CombiningBatcher:
         """Live scheduler snapshot for load-aware routing — what the
         mesh policy's dp-vs-shard router reads (via the store's
         `_queued_requests`): queued entries, in-flight batches, and the
-        cumulative pressure counters (`topups`, `overlap_hits`,
-        `queue_wait_nanos`) that say whether this batcher has been
-        running hot. Note the router's queue-depth signal uses
+        cumulative pressure counters (`topups`, `overlap_hits`) that say
+        whether this batcher has been running hot. Note the router's
+        queue-depth signal uses
         `pending` only — in-flight batches are already counted by the
         store's dispatch gauge."""
         with self._q_lock:
             return {"pending": len(self._queue),
                     "inflight": self._inflight,
                     "topups": self.sched["topups"],
-                    "overlap_hits": self.sched["overlap_hits"],
-                    "queue_wait_nanos": self.sched["queue_wait_nanos"]}
+                    "overlap_hits": self.sched["overlap_hits"]}
 
     def _deadline_for(self, now: float) -> Optional[float]:
         """Absolute deadline for a request enqueued at `now`; None means
@@ -350,10 +440,8 @@ class CombiningBatcher:
         queue exactly like an expired deadline, before any device time
         is spent on an answer nobody will read."""
         self.sched["cancelled_sheds"] += 1
-        if entry.trace is not None:
-            entry.trace.record_span(
-                "queue.wait", int((now - entry.enqueued) * 1e9),
-                parent_id=entry.span_parent, status="cancelled")
+        _stage_done("serving.queue_wait", entry.enqueued * 1e9, now * 1e9,
+                    entry.ctx, status="cancelled")
         if not entry.fut.done():
             entry.fut.set_exception(TaskCancelledError(
                 "task cancelled while queued (shed at EDF admission)"))
@@ -383,15 +471,11 @@ class CombiningBatcher:
                 continue
             if len(claimed) < want:
                 entry.claimed = True
-                wait_ns = int((now - entry.enqueued) * 1e9)
-                self.sched["queue_wait_nanos"] += wait_ns
-                # live-tail surface + per-request attribution: both are
-                # plain host writes (no syncs, no allocation beyond the
-                # span) — safe under _q_lock
-                _metrics.record("serving.queue_wait", wait_ns)
-                if entry.trace is not None:
-                    entry.trace.record_span("queue.wait", wait_ns,
-                                            parent_id=entry.span_parent)
+                # enqueue (the submitter's thread) -> claim (the
+                # runner's): plain host writes (no syncs, no allocation
+                # beyond the span) — safe under _q_lock
+                _stage_done("serving.queue_wait", entry.enqueued * 1e9,
+                            now * 1e9, entry.ctx)
                 claimed.append(entry)
             else:
                 keep.append(entry)
@@ -480,21 +564,29 @@ class CombiningBatcher:
                 return entry
         return None
 
-    def _trace_batch(self, batch: List[_QueueEntry], name: str,
-                     dur_ns: int, status: str = "ok") -> None:
-        """Record one batch-stage span on the leader's trace and link
-        every traced follower to it. Retroactive spans only — the
-        duration was already measured at an existing sync point, so this
-        adds zero host syncs."""
+    def _batch_stage(self, batch: List[_QueueEntry], name: str,
+                     section: str) -> _stage:
+        """One batch-level stage: its span lands on the trace LEADER's
+        trace. The `with` block must close, and `_link_followers` run,
+        BEFORE any future of the batch resolves — a submitter thread
+        woken by set_result may immediately finish its request and ship
+        the trace, and the span must already be in it."""
+        # no member sampled: the histogram and no span (never the runner
+        # thread's own trace)
         leader = self._trace_leader(batch)
-        if leader is None:
+        return _stage(name, ctx=leader.ctx if leader is not None
+                      else _UNSAMPLED, section=section,
+                      coalesced=len(batch))
+
+    def _link_followers(self, batch: List[_QueueEntry], st: _stage) -> None:
+        """Every traced follower links to the leader's batch span
+        instead of double-counting device time the whole batch shared."""
+        if st.span_id is None:
             return
-        span_id = leader.trace.record_span(
-            name, dur_ns, parent_id=leader.span_parent, status=status,
-            coalesced=len(batch))
+        leader = self._trace_leader(batch)
         for entry in batch:
             if entry.trace is not None and entry is not leader:
-                entry.trace.add_link(leader.trace.trace_id, span_id,
+                entry.trace.add_link(leader.trace.trace_id, st.span_id,
                                      "coalesced_follower")
 
     def _trace_since(self, batch: List[_QueueEntry]) -> Optional[int]:
@@ -523,42 +615,29 @@ class CombiningBatcher:
 
     def _run_sync(self, batch: List[_QueueEntry]) -> None:
         """Classic synchronous serving of one batch (under the run
-        lock)."""
+        lock). Dispatch + device sync run back to back, so the whole
+        stage is one figure: `serving.device_dispatch`."""
         trace_since = self._trace_since(batch)
-        t0 = time.perf_counter_ns()
-        err: Optional[BaseException] = None
+        err: Optional[Exception] = None
         results = None
-
-        def land_stage() -> None:
-            # stats + stage span land BEFORE any future resolves: a
-            # submitter thread woken by set_result may immediately
-            # finish its request and ship the trace — the span must
-            # already be in it. Sync path: dispatch + device sync ran
-            # back to back, so the whole stage is one figure.
-            dt = time.perf_counter_ns() - t0
-            self.sched["dispatch_nanos"] += dt
-            _metrics.record("serving.device_dispatch", dt)
-            self._trace_batch(batch, "batch.execute", dt,
-                              status="ok" if err is None else "error")
-
         try:
+            st = self._batch_stage(batch, "serving.device_dispatch",
+                                   "batcher-drain")
             try:
-                results = self._execute([e.request for e in batch])
-            except Exception as exc:
-                err = exc
+                with st:
+                    try:
+                        results = self._execute(
+                            [e.request for e in batch])
+                        self._check_results(batch, results)
+                    except Exception as exc:
+                        err = exc
+                        st.status = "error"
             except BaseException as exc:  # KeyboardInterrupt/SystemExit:
-                err = exc
-                land_stage()
-                for entry in batch:      # fail fast, no serial retries
+                for entry in batch:       # fail fast, no serial retries
                     if not entry.fut.done():
                         entry.fut.set_exception(exc)
                 raise
-            if err is None:
-                try:
-                    self._check_results(batch, results)
-                except Exception as exc:
-                    err = exc
-            land_stage()
+            self._link_followers(batch, st)
             if err is None:
                 self._set_results(batch, results)
             else:
@@ -568,7 +647,8 @@ class CombiningBatcher:
 
     def _begin_pipelined(self, batch: List[_QueueEntry]):
         """Dispatch stage (under the run lock): launch the batch's device
-        work WITHOUT syncing. Returns the finalize context."""
+        work WITHOUT syncing (`serving.device_dispatch`). Returns the
+        finalize context."""
         trace_since = self._trace_since(batch)
         self._depth_sem.acquire()   # bounds in-flight un-finalized batches
         with self._q_lock:
@@ -578,28 +658,25 @@ class CombiningBatcher:
                 # exists to create
                 self.sched["overlap_hits"] += 1
             self._inflight += 1
-        t0 = time.perf_counter_ns()
         handle: Any = None
-        err: Optional[BaseException] = None
+        err: Optional[Exception] = None
+        st = self._batch_stage(batch, "serving.device_dispatch",
+                               "batcher-drain")
         try:
-            handle = self._dispatch_fn([e.request for e in batch])
-        except Exception as exc:
-            err = exc
+            with st:
+                try:
+                    handle = self._dispatch_fn([e.request for e in batch])
+                except Exception as exc:
+                    err = exc
+                    st.status = "error"
         except BaseException as exc:
-            err = exc
             for entry in batch:
                 if not entry.fut.done():
                     entry.fut.set_exception(exc)
             self._end_pipelined()
             self._annotate(trace_since, len(batch))
             raise
-        finally:
-            dt = time.perf_counter_ns() - t0
-            self.sched["dispatch_nanos"] += dt
-            # pipelined launch: un-synced device work under the lock
-            _metrics.record("serving.device_dispatch", dt)
-            self._trace_batch(batch, "batch.dispatch", dt,
-                              status="ok" if err is None else "error")
+        self._link_followers(batch, st)
         return batch, handle, err, trace_since
 
     def _end_pipelined(self) -> None:
@@ -611,41 +688,28 @@ class CombiningBatcher:
                           err: Optional[Exception],
                           trace_since: Optional[int]) -> None:
         """Finalize stage (OUTSIDE the run lock): device sync + host
-        post-processing. Runs concurrently with the next batch's
-        dispatch stage."""
+        post-processing (`serving.device_sync`). Runs concurrently with
+        the next batch's dispatch stage."""
         released = False
-        t0 = time.perf_counter_ns()
         results = None
-
-        def land_stage() -> None:
-            # the deferred device-sync + host post-processing stage:
-            # histogram for the live tail, leader span + follower links
-            # for per-request attribution. Lands BEFORE any future
-            # resolves — a submitter thread woken by set_result may
-            # immediately finish its request and ship the trace, and the
-            # span must already be in it.
-            dt = time.perf_counter_ns() - t0
-            with self._q_lock:   # concurrent finalizes both land here
-                self.sched["finalize_nanos"] += dt
-            _metrics.record("serving.device_sync", dt)
-            self._trace_batch(batch, "batch.finalize", dt,
-                              status="ok" if err is None else "error")
-
         try:
             if err is None:
+                st = self._batch_stage(batch, "serving.device_sync",
+                                       "batcher-finalize")
                 try:
-                    results = self._finalize_fn(handle)
-                    self._check_results(batch, results)
-                except Exception as exc:
-                    err = exc
+                    with st:
+                        try:
+                            results = self._finalize_fn(handle)
+                            self._check_results(batch, results)
+                        except Exception as exc:
+                            err = exc
+                            st.status = "error"
                 except BaseException as exc:
-                    err = exc
-                    land_stage()
                     for entry in batch:
                         if not entry.fut.done():
                             entry.fut.set_exception(exc)
                     raise
-            land_stage()
+                self._link_followers(batch, st)
             if err is None:
                 self._set_results(batch, results)
             else:
@@ -686,38 +750,33 @@ class CombiningBatcher:
         with self._run_lock:
             if entry is not None and (entry.fut.done() or entry.claimed):
                 return
-            batch = self._drain()
-            if batch:
-                batch = self._topup(batch)
+            # run lock taken -> the batch is fixed (the top-up's bounded
+            # wait for late arrivals included); one a scheduler turn
+            with _stage("serving.batch_form"):
+                batch = self._drain()
+                if batch:
+                    batch = self._topup(batch)
             if not batch:
                 return
             self.sched["batches"] += 1
             self.sched["requests"] += len(batch)
             now = time.monotonic()
-            leader = self._trace_leader(batch)
             self._tls.meta = {
                 "coalesced": len(batch),
                 "queue_wait_max_nanos": int(max(
-                    (now - e.enqueued) for e in batch) * 1e9),
-                # leader trace handoff: the executor's finalize stage
-                # (possibly another thread) attaches its fine-grained
-                # spans (plan/fuse/hydrate) to the batch leader's trace
-                "trace": leader.trace if leader is not None else None,
-                "trace_parent": leader.span_parent
-                if leader is not None else None}
-            # name the drain/finalize sections on the borrowed runner
-            # thread so `_nodes/hot_threads` attributes a busy stack to
-            # the batcher instead of to whichever client thread happened
-            # to become the runner
-            with _thread_section("batcher-drain"):
-                if self._dispatch_fn is not None:
-                    self.sched["pipelined_batches"] += 1
-                    pending = self._begin_pipelined(batch)
-                else:
-                    self._run_sync(batch)
+                    (now - e.enqueued) for e in batch) * 1e9)}
+            # the dispatch and finalize stages name their sections
+            # (`»batcher-drain`, `»batcher-finalize`) on the borrowed
+            # runner thread, so `_nodes/hot_threads` attributes a busy
+            # stack to the batcher instead of to whichever client thread
+            # happened to become the runner
+            if self._dispatch_fn is not None:
+                self.sched["pipelined_batches"] += 1
+                pending = self._begin_pipelined(batch)
+            else:
+                self._run_sync(batch)
         if pending is not None:
-            with _thread_section("batcher-finalize"):
-                self._finish_pipelined(*pending)
+            self._finish_pipelined(*pending)
 
     def submit(self, request, deadline_at: Optional[float] = None):
         fut: Future = Future()

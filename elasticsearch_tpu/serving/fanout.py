@@ -58,9 +58,11 @@ Settings (cluster-level, dynamic via `PUT /_cluster/settings`):
 
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, Dict, Optional
 
-from elasticsearch_tpu.telemetry import metrics as _metrics
+from elasticsearch_tpu.telemetry import new_span_id as _new_span_id
+from elasticsearch_tpu.telemetry import stage_done as _stage_done
 
 # key under which a sub-request carries its deadline envelope; "_"-prefixed
 # so it can never collide with a user-visible request field
@@ -232,9 +234,9 @@ class ScatterGather:
         self.stats = stats if stats is not None else FanoutStats()
         self._on_done = on_done
         # request trace (telemetry.trace.Trace) of the search this phase
-        # serves: each launch opens a per-leg span ended at resolution —
-        # resolution is structural (response/failure/sweep timer), so a
-        # dead node produces an ERROR span, never a leaked one
+        # serves: each leg is one stage `fanout.leg`, filed at its
+        # resolution — resolution is structural (response/failure/sweep
+        # timer), so a dead node produces an ERROR span, never a leak
         self._trace = trace
         self._trace_parent = trace_parent
         # latency observer (ARS EWMA feed): called with (node_id, took_ms)
@@ -262,15 +264,14 @@ class ScatterGather:
         self._launched += 1
         self._pending[key] = node_id
         sent_ms = self._scheduler.now_ms
-        leg_span = None
-        if self._trace is not None:
-            leg_span = self._trace.begin_span(
-                f"{self.phase}[{node_id}]", parent_id=self._trace_parent,
-                node=node_id, shard=str(key))
-            if request is not None:
-                # the remote's segment parents under THIS leg span, so
-                # the merged tree shows coordinator leg → remote work
-                attach_trace(request, self._trace, leg_span.span_id)
+        sent_ns = time.monotonic_ns()
+        leg_id = None
+        if self._trace is not None and request is not None:
+            # the remote's segment parents under THIS leg's span, so
+            # the merged tree shows coordinator leg → remote work: the
+            # span's id goes out with the request, before the leg ends
+            leg_id = _new_span_id()
+            attach_trace(request, self._trace, leg_id)
 
         def resolve(outcome: str, payload=None, err=None) -> None:
             if self._pending.pop(key, None) is None:
@@ -278,11 +279,13 @@ class ScatterGather:
             self._timeout_resolvers.pop(key, None)
             self._counts[outcome] += 1
             pc[outcome] += 1
-            if leg_span is not None:
-                # one end per leg, on every outcome: a dead node's leg is
-                # an ERROR span in the trace, not a leak
-                self._trace.end_span(
-                    leg_span, status="ok" if outcome == OK else outcome)
+            # one stage per leg, on every outcome (launch -> response,
+            # failure or sweep timer): a dead node's leg is an ERROR
+            # span in the trace, not a leak
+            _stage_done("fanout.leg", sent_ns, time.monotonic_ns(),
+                        (self._trace, self._trace_parent, None),
+                        status=outcome, span_id=leg_id, phase=self.phase,
+                        node=node_id, shard=str(key))
             try:
                 if on_item is not None:
                     on_item(outcome, payload, err)
@@ -292,9 +295,6 @@ class ScatterGather:
 
         def on_response(resp) -> None:
             took = max(self._scheduler.now_ms - sent_ms, 0)
-            # live fan-out leg tail (`_nodes/stats telemetry`): scheduler-
-            # clock ms (virtual under the simulator) as nanos
-            _metrics.record("fanout.leg", int(took * 1e6))
             if key not in self._pending:
                 # late: the timer already resolved this shard. Observe the
                 # true latency (the ARS signal that makes the next request

@@ -1,7 +1,13 @@
 """End-to-end request telemetry: traces, metrics, live tasks.
 
-Three coupled pieces (ISSUE 14), one always-on low-overhead layer:
+Three coupled pieces (ISSUE 14), one always-on low-overhead layer, and
+ONE call form that feeds them all (`telemetry.stage`, ISSUE 26):
 
+* `telemetry.stage` — `with stage(name):` / `stage_done(name, start_ns,
+  end_ns, ctx)` time one boundary: the histogram `name`, a span of that
+  name where the request is sampled, and (same-thread form) an event
+  `es.<name>` in a `jax.profiler` trace, on the device's clock. No site
+  writes a histogram or a span by hand.
 * `telemetry.trace` — distributed tracing. Every search/write request
   gets a trace (sampled by `telemetry.tracing.sample_rate`, forced by
   `?trace=true` or a `profile` body) whose spans cover REST parse,
@@ -36,11 +42,20 @@ never clobbers an earlier node's choice):
 from __future__ import annotations
 
 from contextlib import contextmanager
-from typing import Optional
+from typing import Optional, Tuple
 
 from elasticsearch_tpu.telemetry import metrics
 from elasticsearch_tpu.telemetry import trace as trace_mod
 from elasticsearch_tpu.telemetry.metrics import REGISTRY
+from elasticsearch_tpu.telemetry.stages import (
+    UNSAMPLED,
+    Front,
+    annotation,
+    new_span_id,
+    stage,
+    stage_done,
+    time_gc,
+)
 from elasticsearch_tpu.telemetry.trace import (
     TRACER,
     Trace,
@@ -48,16 +63,15 @@ from elasticsearch_tpu.telemetry.trace import (
     current_span_id,
     current_task,
     current_trace,
-    record_span,
-    span,
     use,
 )
 
 __all__ = [
-    "metrics", "trace_mod", "REGISTRY", "TRACER", "Trace",
-    "capture", "current_span_id", "current_task", "current_trace",
-    "record_span", "span", "use", "rest_request",
-    "configure_from_settings", "thread_section",
+    "metrics", "trace_mod", "REGISTRY", "TRACER", "Trace", "Front",
+    "UNSAMPLED", "annotation", "capture", "current_span_id",
+    "current_task", "current_trace",
+    "new_span_id", "stage", "stage_done", "time_gc", "use",
+    "rest_request", "configure_from_settings",
 ]
 
 
@@ -77,51 +91,51 @@ def configure_from_settings(settings: Optional[dict]) -> None:
 @contextmanager
 def rest_request(node, action: str, *, opaque_id: Optional[str] = None,
                  force_trace: bool = False, description: str = "",
-                 parse_nanos: int = 0):
+                 parsed: Optional[Tuple[int, int]] = None):
     """Instrument one REST request end to end: register a live task
     (visible in `GET _tasks`, cancellable into the batcher queue), open
     a trace when sampled/forced, and install both on the thread so every
     layer below (batcher entries, fan-out envelopes, slow logs) can see
-    them. Yields the Trace (or None when unsampled)."""
+    them. Yields the Trace (or None when unsampled). `parsed` is the
+    (start_ns, end_ns) of the body's parse, which has to come before the
+    sampling decision (a `profile` body forces a trace): stage
+    `rest.parse`.
+
+    Under the HTTP server the thread carries the request's `Front`: a
+    sampled trace then starts where the front did (request line in
+    hand), hangs the handler's spans under the `rest.handle` span the
+    front will file, and is finished by the server after
+    `http.respond`, not here."""
     tracer = TRACER
+    front = trace_mod._CTX.front
+    if front is not None and front.trace is not None:
+        front = None      # a nested instrumented call: the outer one owns it
     tr = tracer.start(action, node_id=getattr(node, "node_id", "?"),
-                      forced=force_trace, opaque_id=opaque_id)
-    if tr is not None and parse_nanos:
-        tr.record_span("rest.parse", parse_nanos,
-                       parent_id=tr.root.span_id)
+                      forced=force_trace, opaque_id=opaque_id,
+                      started_ns=front.start_ns if front is not None
+                      else None)
+    parent = None
+    if tr is not None and front is not None:
+        parent = front.adopt(tr)
     tasks = getattr(node, "tasks", None)
     task = None
     if tasks is not None:
         task = tasks.register(action, description=description,
                               opaque_id=opaque_id, trace=tr)
+    status = None
     try:
-        with use(trace=tr, task=task):
+        with use(trace=tr, span_id=parent, task=task):
+            if parsed is not None:
+                stage_done("rest.parse", parsed[0], parsed[1])
             yield tr
     except BaseException:
-        if tr is not None:
-            tracer.finish(tr, status="error")
-            tr = None
+        status = "error"
         raise
     finally:
         if task is not None:
             tasks.unregister(task)
         if tr is not None:
-            tracer.finish(tr)
-
-
-@contextmanager
-def thread_section(section: str):
-    """Temporarily tag the current thread's name with the serving section
-    it is executing (`»batcher-drain`, `»batcher-finalize`, ...), so a
-    hot-threads report attributes a busy stack to its subsystem even
-    when the work runs on a borrowed submitter thread (the combining
-    batcher has no threads of its own — the first submitter in becomes
-    the runner). One string assignment each way; nanoseconds."""
-    import threading
-    t = threading.current_thread()
-    prev = t.name
-    t.name = f"{prev}»{section}"
-    try:
-        yield
-    finally:
-        t.name = prev
+            if front is not None:
+                front.status = status
+            else:
+                tracer.finish(tr, status=status)

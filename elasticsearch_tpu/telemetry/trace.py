@@ -6,12 +6,12 @@ wait, the shape-bucketed device dispatch, the deferred device sync at
 finalize, hydrate and merge — and lands, completed, in a bounded per-node
 ring served by `GET _nodes/traces`. Design constraints, in order:
 
-* zero host syncs — spans NEVER force a device read. Live spans read
-  `time.monotonic_ns()` around host work; device-time attribution reuses
-  durations the serving code already measures at its existing sync
-  points (`record_span(name, dur_ns)` is retroactive). tpulint
-  TPU002/TPU009 stay clean by construction because tracing adds no
-  blocking calls.
+* zero host syncs — spans NEVER force a device read. A span is the
+  `time.monotonic_ns()` reading before and after a stretch of host work
+  (`telemetry.stage`), or two readings the serving code took at its
+  existing sync points (`telemetry.stage_done`): both carry their REAL
+  start. tpulint TPU002/TPU009 stay clean by construction because
+  tracing adds no blocking calls.
 * survives the async pipelined batcher — a request's dispatch and
   finalize run on different threads, so context travels on the queue
   entry (captured at enqueue from the submitting thread's context), not
@@ -30,9 +30,10 @@ Sampling: `telemetry.tracing.sample_rate` picks every round(1/rate)-th
 request deterministically (a counter, not an RNG — reproducible in
 tests); `?trace=true` or a `profile` body forces a trace regardless.
 
-Spans opened live (`begin_span`) MUST be closed on every path — use the
-`span()` context manager or `end_span` in a `finally:`; tpulint TPU012
-flags the leaked-span shape statically.
+Call sites use `telemetry.stage` / `telemetry.stage_done` and nothing of
+this module's span plumbing: `begin_span` / `end_span` serve the root
+span and `stage` itself, and a span opened with them MUST be closed on
+every path (tpulint TPU012 flags the leaked-span shape statically).
 """
 
 from __future__ import annotations
@@ -57,8 +58,9 @@ class Span:
                  "status", "attrs")
 
     def __init__(self, name: str, parent_id: Optional[str],
-                 start_ns: int, attrs: Optional[dict] = None):
-        self.span_id = _new_id()
+                 start_ns: int, attrs: Optional[dict] = None,
+                 span_id: Optional[str] = None):
+        self.span_id = span_id or _new_id()
         self.parent_id = parent_id
         self.name = name
         self.start_ns = start_ns
@@ -88,7 +90,8 @@ class Trace:
     def __init__(self, action: str, node_id: str,
                  opaque_id: Optional[str] = None, forced: bool = False,
                  trace_id: Optional[str] = None,
-                 parent_span_id: Optional[str] = None):
+                 parent_span_id: Optional[str] = None,
+                 started_ns: Optional[int] = None):
         self.trace_id = trace_id or _new_id()
         self.node_id = node_id
         self.action = action
@@ -96,42 +99,52 @@ class Trace:
         self.forced = forced
         self.spans: List[Span] = []
         self.links: List[dict] = []
-        self.started_ns = time.monotonic_ns()
+        # `started_ns`: when the request's life began, where that was
+        # before the sampling decision (the HTTP front has the request
+        # line in hand before any handler can ask for a trace)
+        self.started_ns = started_ns or time.monotonic_ns()
         self.took_ns: Optional[int] = None
         self._open: Dict[str, str] = {}   # span_id -> name (insertion order)
         self._lock = threading.Lock()
-        self.root = self.begin_span(action, parent_id=parent_span_id)
+        self.root = self.begin_span(action, parent_id=parent_span_id,
+                                    start_ns=self.started_ns)
 
     # ----------------------------------------------------------- live spans
     def begin_span(self, name: str, parent_id: Optional[str] = None,
-                   **attrs) -> Span:
-        """Open a live span NOW. Every begin_span must reach `end_span`
-        on all paths (context manager or try/finally — tpulint TPU012)."""
-        sp = Span(name, parent_id, time.monotonic_ns(), attrs or None)
+                   start_ns: Optional[int] = None, **attrs) -> Span:
+        """Open a live span (NOW, or at the `start_ns` the caller read).
+        Every begin_span must reach `end_span` on all paths (context
+        manager or try/finally — tpulint TPU012)."""
+        sp = Span(name, parent_id, start_ns or time.monotonic_ns(),
+                  attrs or None)
         with self._lock:
             self.spans.append(sp)
             self._open[sp.span_id] = name
         return sp
 
-    def end_span(self, sp: Span, status: Optional[str] = None) -> None:
+    def end_span(self, sp: Span, status: Optional[str] = None,
+                 end_ns: Optional[int] = None) -> None:
         if sp.dur_ns is None:
-            sp.dur_ns = time.monotonic_ns() - sp.start_ns
+            sp.dur_ns = (end_ns or time.monotonic_ns()) - sp.start_ns
         if status is not None:
             sp.status = status
         with self._lock:
             self._open.pop(sp.span_id, None)
 
-    # ---------------------------------------------------- retroactive spans
-    def record_span(self, name: str, dur_ns: int,
-                    parent_id: Optional[str] = None,
-                    status: str = "ok", **attrs) -> str:
-        """Attach an already-measured duration as a closed span — the
-        zero-host-sync path for device-adjacent attribution: the serving
-        code measured `dur_ns` at a sync point that already exists, and
-        the span is born finished (it can never leak)."""
-        sp = Span(name, parent_id, time.monotonic_ns() - max(int(dur_ns), 0),
-                  attrs or None)
-        sp.dur_ns = max(int(dur_ns), 0)
+    # -------------------------------------------------------- closed spans
+    def add_span(self, name: str, start_ns: int, end_ns: int,
+                 parent_id: Optional[str] = None, status: str = "ok",
+                 span_id: Optional[str] = None, **attrs) -> str:
+        """Attach a stretch both of whose ends were already read as a
+        closed span — the zero-host-sync path for device-adjacent
+        attribution and for waits that end on another thread. The span
+        is born finished (it can never leak) and keeps its real start.
+        `span_id` is one handed out beforehand (`new_span_id`) to a
+        remote segment or a follower that had to name this span before
+        it ended."""
+        sp = Span(name, parent_id, int(start_ns), attrs or None,
+                  span_id=span_id)
+        sp.dur_ns = max(int(end_ns) - int(start_ns), 0)
         sp.status = status
         with self._lock:
             self.spans.append(sp)
@@ -237,14 +250,16 @@ class Tracer:
 
     # ------------------------------------------------------------ lifecycle
     def start(self, action: str, node_id: str, forced: bool = False,
-              opaque_id: Optional[str] = None) -> Optional[Trace]:
+              opaque_id: Optional[str] = None,
+              started_ns: Optional[int] = None) -> Optional[Trace]:
         """Root-trace entry (the REST layer). None = not sampled."""
         if not forced and not self.should_sample():
             return None
         with self._lock:
             self.stats["started"] += 1
             self.stats["forced" if forced else "sampled"] += 1
-        return Trace(action, node_id, opaque_id=opaque_id, forced=forced)
+        return Trace(action, node_id, opaque_id=opaque_id, forced=forced,
+                     started_ns=started_ns)
 
     def start_remote(self, action: str, node_id: str, trace_id: str,
                      parent_span_id: Optional[str],
@@ -257,8 +272,9 @@ class Tracer:
         return Trace(action, node_id, opaque_id=opaque_id, forced=True,
                      trace_id=trace_id, parent_span_id=parent_span_id)
 
-    def finish(self, trace: Trace, status: Optional[str] = None) -> None:
-        trace.end_span(trace.root, status=status)
+    def finish(self, trace: Trace, status: Optional[str] = None,
+               end_ns: Optional[int] = None) -> None:
+        trace.end_span(trace.root, status=status, end_ns=end_ns)
         trace.took_ns = trace.root.dur_ns
         with self._lock:
             self.stats["completed"] += 1
@@ -306,6 +322,7 @@ class _Ctx(threading.local):
     trace: Optional[Trace] = None
     span_id: Optional[str] = None
     task: Optional[Any] = None
+    front: Optional[Any] = None   # telemetry.stages.Front of an HTTP request
 
 
 _CTX = _Ctx()
@@ -346,35 +363,3 @@ def use(trace: Optional[Trace] = None, span_id: Optional[str] = None,
         yield
     finally:
         _CTX.trace, _CTX.span_id, _CTX.task = prev
-
-
-@contextmanager
-def span(name: str, **attrs):
-    """Live child span under the current context; no-op (yields None)
-    when this request isn't traced. The with-shape is the API on purpose
-    — it cannot leak (tpulint TPU012)."""
-    tr = _CTX.trace
-    if tr is None:
-        yield None
-        return
-    sp = tr.begin_span(name, parent_id=_CTX.span_id, **attrs)
-    prev = _CTX.span_id
-    _CTX.span_id = sp.span_id
-    try:
-        yield sp
-    except BaseException:
-        tr.end_span(sp, status="error")
-        raise
-    finally:
-        tr.end_span(sp)
-        _CTX.span_id = prev
-
-
-def record_span(name: str, dur_ns: int, status: str = "ok",
-                **attrs) -> Optional[str]:
-    """Retroactive span on the current trace (None when untraced)."""
-    tr = _CTX.trace
-    if tr is None:
-        return None
-    return tr.record_span(name, dur_ns, parent_id=_CTX.span_id,
-                          status=status, **attrs)

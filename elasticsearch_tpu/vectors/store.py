@@ -19,8 +19,10 @@ and logged — `_nodes/stats indices.segments`).
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -34,8 +36,10 @@ from elasticsearch_tpu.ops import dispatch
 from elasticsearch_tpu.ops import knn as knn_ops
 from elasticsearch_tpu.ops import similarity as sim
 from elasticsearch_tpu.quant import rescore as quant_rescore
-from elasticsearch_tpu.serving.batcher import CombiningBatcher, CostModel
+from elasticsearch_tpu.serving.batcher import (
+    IDLE, CombiningBatcher, CostModel)
 from elasticsearch_tpu.telemetry import metrics as _telemetry_metrics
+from elasticsearch_tpu.telemetry import stage as _stage
 from elasticsearch_tpu.vectors.host_corpus import HostFieldCorpus, packed_nbytes
 
 # host int8 mirrors are built for corpora whose packed+rescore footprint is
@@ -266,11 +270,14 @@ class VectorStoreShard:
         # stale (field, k) variants; their history must not vanish from
         # _nodes/stats)
         self._sched_retired: Dict[str, int] = {}
-        # per-phase serving telemetry (profile "knn" section, _nodes/stats)
         # restored IVF layouts (recovery/seed.py): consumed by the next
         # sync's IVF build so a restored/relocated shard re-places rows
         # into the snapshotted centroids instead of re-training k-means
         self._restored_ivf: Dict[str, dict] = {}
+        # per-phase serving telemetry (profile "knn" section, _nodes/stats
+        # indices.knn). `searches` counts DISPATCHES of the exhaustive
+        # routes, one a coalesced batch, not the searches in them: the
+        # requests are `scheduler.requests` (the batchers' count)
         self.knn_stats: Dict[str, int] = {
             "searches": 0, "ivf_searches": 0, "fallback_searches": 0,
             "ivf_trains": 0, "ivf_restores": 0,
@@ -816,8 +823,10 @@ class VectorStoreShard:
         signal). Mirrored onto the telemetry registry so `_nodes/stats
         telemetry` shows the live in-flight gauge next to the latency
         histograms (resolved per call — a cached Gauge handle would
-        detach from the registry across a test-time `reset()`)."""
+        detach from the registry across a test-time `reset()`). The
+        0 -> 1 edge closes a device-starved interval (`IdleClock`)."""
         _telemetry_metrics.gauge("serving.inflight_dispatches").inc()
+        IDLE.begin()
         with self._active_lock:
             n = self._active_dispatches
             self._active_dispatches += 1
@@ -825,6 +834,7 @@ class VectorStoreShard:
 
     def _end_dispatch(self) -> None:
         _telemetry_metrics.gauge("serving.inflight_dispatches").dec()
+        IDLE.end()
         with self._active_lock:
             self._active_dispatches = max(0, self._active_dispatches - 1)
 
@@ -846,10 +856,10 @@ class VectorStoreShard:
 
     def scheduler_stats(self) -> Dict[str, int]:
         """Continuous-batching scheduler counters summed over this
-        shard's kNN batchers (live + retired): batches, top-ups,
-        schedule-time deadline sheds, dispatch/finalize overlap hits, and
-        cumulative queue-wait / dispatch / finalize time — the closed-
-        loop tail attribution the 1cl/4cl bench rows record."""
+        shard's kNN batchers (live + retired): batches, requests,
+        top-ups, schedule-time deadline sheds, dispatch/finalize overlap
+        hits. Their times are the telemetry stages `serving.queue_wait`,
+        `serving.device_dispatch` and `serving.device_sync`."""
         out = dict(self._sched_retired)
         with self._batchers_lock:
             batchers = list(self._batchers.values())
@@ -942,6 +952,7 @@ class VectorStoreShard:
         # the EDF queue sheds this entry at schedule time if it expires
         # before a runner claims it (EsRejectedExecutionError to the
         # caller, counted in sched["deadline_sheds"])
+        IDLE.waiting()
         return batcher.submit(
             (np.asarray(query_vector, dtype=np.float32), filter_rows),
             deadline_at=deadline_at)
@@ -1007,16 +1018,30 @@ class VectorStoreShard:
             if kind == "mesh":
                 return self._finalize_mesh(payload)
             fc, s, i, k_eff, n_valid, n_real, rescore_ctx = payload
-            scores = np.asarray(s)[:, :k_eff]
-            ids = np.asarray(i)[:, :k_eff]
-            if rescore_ctx is not None:
-                # phase two: exact f32 re-rank of the coarse window (the
-                # blocking gather+score lives HERE, at response-assembly
-                # time, with the device sync — never in dispatch)
-                scores, ids = self._apply_rescore(rescore_ctx, scores,
-                                                  ids, n_real)
-            return self._land_results(fc, scores, ids, -1e37, n_valid,
-                                      n_real)
+            # the two reads below are the ones this path always made, in
+            # their order, each now under its own name. An explicit
+            # `block_until_ready` before them would split waiting from
+            # copying cleanly, and cost a third round trip to the device
+            # and a third hand-over of the interpreter lock a batch
+            # (measured on the chip, PERF.md PR 26): so
+            # `dispatch.sync_wait` is the first read, which waits for what
+            # is left of the device's work and copies the score board,
+            # and `dispatch.d2h` the second, which copies the id board (as
+            # large): the wait alone is their difference
+            with _stage("dispatch.sync_wait"):
+                scores = np.asarray(s)[:, :k_eff]
+            with _stage("dispatch.d2h"):
+                ids = np.asarray(i)[:, :k_eff]
+            with _stage("dispatch.land"):
+                if rescore_ctx is not None:
+                    # phase two: exact f32 re-rank of the coarse window
+                    # (the blocking gather+score lives HERE, at response-
+                    # assembly time, with the device sync — never in
+                    # dispatch)
+                    scores, ids = self._apply_rescore(rescore_ctx, scores,
+                                                      ids, n_real)
+                return self._land_results(fc, scores, ids, -1e37, n_valid,
+                                          n_real)
         finally:
             # every pending handle was counted in flight at dispatch;
             # its slot releases the gauge exactly once
@@ -1100,14 +1125,22 @@ class VectorStoreShard:
         un-synced arrays in the handle; host/IVF routes complete here
         (they are host-side or sync internally). Tracks the in-flight
         gauge the dp router reads."""
-        others = self._begin_dispatch()
-        slot = _InflightSlot(self)
+        # `serving.device_dispatch` in three: `dispatch.prepare` (the
+        # host's work on the batch before a byte moves: the in-flight
+        # books, stack, route, pad, mask), then what the chosen route
+        # does — on the exhaustive device routes `dispatch.h2d` and
+        # `dispatch.launch`
+        slot = None
         try:
-            handle = self._dispatch_many_routed(
-                fc, k, precision, requests, others,
-                num_candidates=num_candidates)
+            with _stage("dispatch.prepare"):
+                others = self._begin_dispatch()
+                slot = _InflightSlot(self)
+                launch = self._route_prepared(fc, k, precision, requests,
+                                              others, num_candidates)
+            handle = launch()
         except BaseException:
-            slot.release()
+            if slot is not None:
+                slot.release()
             raise
         if handle[0] == "done":
             slot.release()
@@ -1116,11 +1149,11 @@ class VectorStoreShard:
         # abandoned handle) releases the gauge
         return handle + (slot,)
 
-    def _dispatch_many_routed(self, fc: FieldCorpus, k: int,
-                              precision: str, requests, others: int,
-                              num_candidates: Optional[int] = None):
-        import jax.numpy as jnp
-
+    def _route_prepared(self, fc: FieldCorpus, k: int, precision: str,
+                        requests, others: int,
+                        num_candidates: Optional[int]):
+        """Pick the batch's route and do its host-side preparation.
+        Returns the rest of the dispatch as a call with no arguments."""
         if fc.gens is not None:
             # generational field: serve from the CURRENT copy-on-write
             # snapshot (a background merge may have installed since this
@@ -1129,8 +1162,9 @@ class VectorStoreShard:
             # to the pre-generational store; anything else fans out.
             snap = fc.gens.snapshot()
             if not snap.simple:
-                return self._dispatch_generational(
-                    snap, fc, k, precision, requests, num_candidates)
+                return functools.partial(
+                    self._dispatch_generational, snap, fc, k, precision,
+                    requests, num_candidates)
             base = snap.generations[0]
             if base.corpus is not fc.corpus or fc.source is None:
                 fc = FieldCorpus(base.corpus, base.row_map, fc.metric,
@@ -1165,10 +1199,9 @@ class VectorStoreShard:
         if fc.router is not None:
             reason = fc.router.should_fallback(k_eff, any_filter, precision)
             if reason is None:
-                return ("done",
-                        self._execute_ivf(fc, k_eff, n_valid, queries,
-                                          len(requests), num_candidates,
-                                          rescore_ctx=rescore_ctx))
+                return lambda: ("done", self._execute_ivf(
+                    fc, k_eff, n_valid, queries, len(requests),
+                    num_candidates, rescore_ctx=rescore_ctx))
             self.knn_stats["fallback_searches"] += 1
             self.last_knn_phases = {"engine": "tpu_exhaustive",
                                     "fallback_reason": reason}
@@ -1189,7 +1222,7 @@ class VectorStoreShard:
             queue_depth=others + self._queued_requests())
         if mesh is not None:
             if k_eff <= fc.mesh_state.layout.rows_per_shard:
-                return self._execute_mesh(fc, k_eff, n_valid, queries,
+                return self._prepare_mesh(fc, k_eff, n_valid, queries,
                                           requests, any_filter,
                                           precision, mesh,
                                           rescore_ctx=rescore_ctx)
@@ -1207,15 +1240,18 @@ class VectorStoreShard:
                 for i, (_, fr) in enumerate(requests):
                     if fr is not None:
                         mask[i] = np.isin(fc.row_map, fr)
-            scores, ids = fc.host.search(queries, k_eff, mask=mask)
-            return ("done",
-                    self._land_results(fc, np.asarray(scores),
-                                       np.asarray(ids), -np.inf, n_valid,
-                                       len(requests)))
+
+            def host_route():
+                scores, ids = fc.host.search(queries, k_eff, mask=mask)
+                return ("done",
+                        self._land_results(fc, np.asarray(scores),
+                                           np.asarray(ids), -np.inf,
+                                           n_valid, len(requests)))
+            return host_route
 
         queries = _pad_batch(queries, len(requests))
         b_pad = len(queries)
-        mask = None
+        m = None
         if any_filter:
             n_pad = fc.corpus.matrix.shape[0]
             m = np.zeros((b_pad, n_pad), dtype=bool)
@@ -1224,22 +1260,36 @@ class VectorStoreShard:
                     m[i, :n_valid] = True
                 else:
                     m[i, :n_valid] = np.isin(fc.row_map, fr)
-            mask = jnp.asarray(m)
+        return functools.partial(self._launch_single, fc, queries, m, k_eff,
+                                 n_valid, len(requests), precision,
+                                 rescore_ctx)
+
+    def _launch_single(self, fc: FieldCorpus, queries: np.ndarray,
+                       m: Optional[np.ndarray], k_eff: int, n_valid: int,
+                       n_real: int, precision: str, rescore_ctx):
+        """The single-device exhaustive route after its preparation:
+        upload, then launch WITHOUT syncing."""
+        import jax.numpy as jnp
+
+        with _stage("dispatch.h2d"):
+            mask = None if m is None else jnp.asarray(m)
+            q = jnp.asarray(queries)
         # k rounds up the dispatch bucket ladder so a workload that
         # sweeps k (10, 12, 13, ...) reuses one compiled program per
         # rung; the extra columns slice away at finalize (top-k prefixes
         # are exact)
         k_b = dispatch.bucket_k(k_eff,
                                 limit=fc.corpus.matrix.shape[0])
-        s, i = knn_ops.knn_search_auto(
-            jnp.asarray(queries), fc.corpus, k=k_b, metric=fc.metric,
-            filter_mask=mask, precision=precision,
-            rescore_candidates=fc.rescore_candidates)
-        # un-synced: s/i are device futures until finalize_many reads
-        # them — count the deferred sync so `_nodes/stats
-        # indices.dispatch` shows how much serving load pipelines
-        dispatch.DISPATCH.note_async()
-        return ("pending", (fc, s, i, k_eff, n_valid, len(requests),
+        with _stage("dispatch.launch"):
+            s, i = knn_ops.knn_search_auto(
+                q, fc.corpus, k=k_b, metric=fc.metric,
+                filter_mask=mask, precision=precision,
+                rescore_candidates=fc.rescore_candidates)
+            # un-synced: s/i are device futures until finalize_many
+            # reads them — count the deferred sync so `_nodes/stats
+            # indices.dispatch` shows how much serving load pipelines
+            dispatch.DISPATCH.note_async()
+        return ("pending", (fc, s, i, k_eff, n_valid, n_real,
                             rescore_ctx))
 
     def _dispatch_generational(self, snap, fc: FieldCorpus, k: int,
@@ -1335,30 +1385,24 @@ class VectorStoreShard:
             out.append((fc.row_map[rid], sc.astype(np.float32)))
         return out
 
-    def _execute_mesh(self, fc: FieldCorpus, k_eff: int, n_valid: int,
+    def _prepare_mesh(self, fc: FieldCorpus, k_eff: int, n_valid: int,
                       queries: np.ndarray, requests, any_filter: bool,
                       precision: str, mesh, rescore_ctx=None):
-        """Launch one coalesced exact-kNN batch as ONE SPMD program over
+        """One coalesced exact-kNN batch as ONE SPMD program over
         the mesh-resident sharded corpus (`parallel/sharded_knn.py`):
         shard-local matmul + top-k, all-gather candidate merge, k-ladder
         slice-back at finalize. `mesh` is whatever the dp-vs-shard
         router picked — the full serving mesh or one dp-group submesh
         (the corpus view for a group is a free re-layout of the
-        dp-replicated arrays). Returns an UN-SYNCED handle: the device
+        dp-replicated arrays). Pads and masks on the host (inside the
+        caller's `dispatch.prepare`) and returns the launch, which
+        returns an UN-SYNCED handle: the device
         sync lands in `_finalize_mesh` at response-assembly time, so
         batch N's merge overlaps batch N+1's dispatch — with dp > 1 the
         overlapping dispatch runs on a DIFFERENT device group, which is
         the replicated mesh's whole throughput story. Result-identical
         to the single-device path (the tier-1 mesh suite pins byte
         parity)."""
-        import time as _time
-
-        import jax
-        import jax.numpy as jnp
-
-        from elasticsearch_tpu.parallel.sharded_knn import (
-            distributed_knn_search)
-
         from elasticsearch_tpu.parallel import mesh as mesh_lib
 
         ms = fc.mesh_state
@@ -1372,8 +1416,8 @@ class VectorStoreShard:
         b_pad = len(queries)
         per = ms.layout.rows_per_shard
         k_b = dispatch.bucket_k(k_eff, limit=per)
-        t0 = _time.perf_counter_ns()
-        mask = None
+        t0 = time.monotonic_ns()
+        m = None
         if any_filter:
             m = np.zeros((b_pad, len(ms.slot_map)), dtype=bool)
             valid_slots = ms.slot_map >= 0  # == filter_mask(all-ones)
@@ -1382,47 +1426,63 @@ class VectorStoreShard:
                     m[i] = valid_slots
                 else:
                     m[i] = ms.filter_mask(np.isin(fc.row_map, fr))
-            mask = jax.device_put(jnp.asarray(m),
-                                  ms.mask_sharding(2, mesh))
-        q = jax.device_put(jnp.asarray(queries), ms.query_sharding(mesh))
-        scores, gids = distributed_knn_search(
-            q, ms.corpus_for(mesh), k_b, mesh, metric=fc.metric,
-            filter_mask=mask, precision=precision)
+        return functools.partial(
+            self._launch_mesh, fc, ms, mesh, queries, m, k_eff, k_b, b_pad,
+            n_valid, len(requests), t0, precision, rescore_ctx)
+
+    def _launch_mesh(self, fc: FieldCorpus, ms, mesh, queries: np.ndarray,
+                     m: Optional[np.ndarray], k_eff: int, k_b: int,
+                     b_pad: int, n_valid: int, n_real: int, t0: int,
+                     precision: str, rescore_ctx):
+        import jax
+        import jax.numpy as jnp
+
+        from elasticsearch_tpu.parallel.sharded_knn import (
+            distributed_knn_search)
+
+        with _stage("dispatch.h2d"):
+            mask = None if m is None else jax.device_put(
+                jnp.asarray(m), ms.mask_sharding(2, mesh))
+            q = jax.device_put(jnp.asarray(queries),
+                               ms.query_sharding(mesh))
+        with _stage("dispatch.launch"):
+            scores, gids = distributed_knn_search(
+                q, ms.corpus_for(mesh), k_b, mesh, metric=fc.metric,
+                filter_mask=mask, precision=precision)
         # un-synced boards: the device sync is deferred to finalize
         dispatch.DISPATCH.note_async()
         return ("mesh", (fc, ms, mesh, scores, gids, k_eff, k_b, b_pad,
-                         n_valid, len(requests), t0, rescore_ctx))
+                         n_valid, n_real, t0, rescore_ctx))
 
     def _finalize_mesh(self, payload) -> list:
         """Land one mesh dispatch: device sync, k slice-back, slot-map
         join, and the router/leg accounting."""
-        import time as _time
-
         from elasticsearch_tpu.parallel import mesh as mesh_lib
         from elasticsearch_tpu.parallel import policy as mesh_policy
 
         (fc, ms, mesh, scores, gids, k_eff, k_b, b_pad, n_valid, n_real,
          t0, rescore_ctx) = payload
-        gids.block_until_ready()
-        t1 = _time.perf_counter_ns()
-        scores = np.asarray(scores)[:, :k_eff]
-        gids = np.asarray(gids)[:, :k_eff]
-        flat = ms.map_ids(gids)
+        with _stage("dispatch.sync_wait") as wait:
+            gids.block_until_ready()
+        with _stage("dispatch.d2h"):
+            scores = np.asarray(scores)[:, :k_eff]
+            gids = np.asarray(gids)[:, :k_eff]
         rescore_info = None
-        if rescore_ctx is not None:
-            # exact phase over flat corpus rows (the slot-map join
-            # already happened, so the window gathers through the same
-            # RowSource as the single-device path)
-            scores, flat = self._apply_rescore(rescore_ctx, scores,
-                                               flat, n_real)
-            rescore_info = (self.last_knn_phases or {}).get("rescore")
-        out = []
-        for qi in range(n_real):
-            sc, rid = scores[qi], flat[qi]
-            valid = (sc > -1e37) & (rid >= 0) & (rid < n_valid)
-            sc, rid = sc[valid], rid[valid]
-            out.append((fc.row_map[rid], sc.astype(np.float32)))
-        t2 = _time.perf_counter_ns()
+        with _stage("dispatch.land") as land:
+            flat = ms.map_ids(gids)
+            if rescore_ctx is not None:
+                # exact phase over flat corpus rows (the slot-map join
+                # already happened, so the window gathers through the
+                # same RowSource as the single-device path)
+                scores, flat = self._apply_rescore(rescore_ctx, scores,
+                                                   flat, n_real)
+                rescore_info = (self.last_knn_phases or {}).get("rescore")
+            out = self._land_results(fc, scores, flat, -1e37, n_valid,
+                                     n_real)
+        # the router's leg accounting, from the stages' own clock
+        # readings: dispatch start -> boards ready, then d2h + merge
+        t1 = wait.start_ns + wait.nanos
+        t2 = land.start_ns + land.nanos
         n_shards = mesh_lib.shard_size(mesh)
         gather = mesh_policy.gather_bytes(n_shards, b_pad, k_b)
         mesh_policy.record_leg("knn", t1 - t0, t2 - t1, gather)

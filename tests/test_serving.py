@@ -537,13 +537,29 @@ class TestContinuousScheduler:
     def test_queue_wait_and_scheduler_counters_accumulate(self):
         from elasticsearch_tpu.serving.batcher import CombiningBatcher
 
+        from elasticsearch_tpu.telemetry import REGISTRY
+
+        def hist(name):
+            h = REGISTRY.histogram(name)
+            return h.count, h.sum_ns
+
+        before = {n: hist(n) for n in ("serving.queue_wait",
+                                       "serving.device_dispatch",
+                                       "serving.batch_form")}
         b = CombiningBatcher(lambda reqs: list(reqs))
         for i in range(4):
             assert b.submit(i) == i
         assert b.sched["batches"] == 4
         assert b.sched["requests"] == 4
-        assert b.sched["queue_wait_nanos"] >= 0
-        assert b.sched["dispatch_nanos"] > 0
+        # the scheduler's times live in the telemetry stages, once a
+        # request / once a batch, and nowhere else
+        assert not [k for k in b.sched if k.endswith("_nanos")]
+        for name, (count, total) in before.items():
+            after_count, after_total = hist(name)
+            assert after_count == count + 4, name
+            assert after_total >= total, name
+        assert hist("serving.device_dispatch")[1] > \
+            before["serving.device_dispatch"][1]
 
     def test_store_scheduler_stats_survive_batcher_retirement(self):
         """Refresh drops stale (field, k) batchers; their scheduler

@@ -14,7 +14,7 @@ def deadline_math(budget_s):
 
 
 def context_manager_span(telemetry, work):
-    with telemetry.span("score"):
+    with telemetry.stage("score"):
         return work()
 
 
@@ -35,6 +35,6 @@ def cross_closure_close(trace, launch):
     launch(resolve)
 
 
-def retroactive_span(trace, dur_ns):
-    # born closed — record_span cannot leak
-    trace.record_span("device.sync", dur_ns)
+def closed_span(telemetry, start_ns, end_ns):
+    # born closed — stage_done cannot leak
+    telemetry.stage_done("device.sync", start_ns, end_ns)

@@ -1214,10 +1214,11 @@ class TelemetryDisciplineRule(Rule):
     `x.end()`/`x.finish()`, not a `with` item. A leaked span stays open
     forever: the tasks API reports it as the request's `current_span`
     after the request finished, and the trace ring shows a span with
-    `dur_ns: null` that sums into nothing. The fix is the `span()`
-    context manager, `end_span` in a `finally:`, or — for durations
-    measured at existing sync points — the retroactive
-    `record_span(name, dur_ns)`, which is born closed and cannot leak.
+    `dur_ns: null` that sums into nothing. The fix is the
+    `telemetry.stage()` context manager, `end_span` in a `finally:`, or
+    — for a stretch both of whose ends were read at existing sync
+    points — `telemetry.stage_done(name, start_ns, end_ns)`, whose span
+    is born closed and cannot leak.
     Spans stored onto objects (attributes, dict slots) are cross-thread
     handoffs the analysis cannot follow and stay out of scope, like
     TPU004's aliasing rules.
@@ -1316,8 +1317,9 @@ class TelemetryDisciplineRule(Rule):
                 f"{call.func.attr}() but never closed in this function "
                 "(leaked-span class): the tasks API keeps reporting it "
                 "as current_span and the trace ring shows dur_ns: null "
-                "— use the span() context manager, end_span in a "
-                "finally:, or the retroactive record_span(name, dur_ns)"))
+                "— use the telemetry.stage() context manager, end_span "
+                "in a finally:, or telemetry.stage_done(name, start_ns, "
+                "end_ns), born closed"))
 
 
 # ---------------------------------------------------------------------------
