@@ -28,9 +28,9 @@ The HTTP server's five stages of a request (`http.read`,
 read as clock marks on the request's `Front` and filed by `stage_done`
 in two goes: on the hot path a mark is one attribute, and the loop's one
 thread, which under load is the scarcest thing the server has, files only
-what no worker can. A sampled request's trace starts with its
-request line in hand and is finished after the response: socket to
-socket.
+what no worker can (the last two of a response it had to write itself).
+A sampled request's trace starts with its request line in hand and is
+finished after the response: socket to socket.
 """
 
 from __future__ import annotations
@@ -92,20 +92,26 @@ class Front:
     """One HTTP request as the server's front sees it: the clock marks
     of its way through the loop and the pool, filed as five stages: those
     known when the handler returns on the worker, there; the last two
-    when the response is written (`finish`). The server sets the marks
-    as plain attributes; `with front:` on the pool's worker marks the
-    handler's start and return and puts the front on the thread, where
-    the handler's `rest_request` finds it when it samples the request
-    (`adopt`).
+    where the response ends (`finish`): on that worker, which sends the
+    answer itself, or on the loop where the loop had to write it (TLS, a
+    short write, a 429). The server sets the marks as plain attributes;
+    `with front:` on the pool's worker marks the handler's start and
+    return and puts the front on the thread, where the handler's
+    `rest_request` finds it when it samples the request (`adopt`).
 
-        idle_ns    the connection's last response written (or accept)
+        idle_ns    the connection's last response out (or accept)
         start_ns   request line in hand           -> http.read
-        read_ns    body complete, query parsed
+        read_ns    body complete, query parsed, and the connection's
+                   previous response out (a client that pipelines waits
+                   here)
         submit_ns  thread_pool.submit             -> http.pool_wait
         handle_ns  the handler's first instruction -> rest.handle
         return_ns  (status, payload) returned     -> http.loop_wake
-        wake_ns    the coroutine runs again
-        (finish)   response written and drained   <- http.respond
+        wake_ns    the first instruction of responding, on whichever
+                   thread responds: microseconds later on the worker,
+                   the loop's lag where the loop has to -> http.respond
+        (finish)   the bytes handed to the socket whole (worker), or
+                   written and drained (loop)
     """
 
     __slots__ = ("idle_ns", "start_ns", "read_ns", "submit_ns", "handle_ns",
@@ -160,8 +166,8 @@ class Front:
         return self.handle_id
 
     def finish(self, end_ns: int) -> None:
-        """On the loop, the response written: file what the worker could
-        not, finish the request's trace (if any)."""
+        """Where the response ended, on whichever thread: file the last
+        two stages, finish the request's trace (if any)."""
         ctx = self._ctx()
         if self.handle_ns:
             stage_done("http.loop_wake", self.return_ns, self.wake_ns, ctx)

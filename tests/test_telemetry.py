@@ -560,6 +560,12 @@ def _hist(name):
     return h.count, h.sum_ns
 
 
+def _responses():
+    """(written by a pool worker, written by the loop)."""
+    c = metrics.REGISTRY.snapshot()["counters"]
+    return c["http.responses.worker"], c["http.responses.loop"]
+
+
 def test_stage_feeds_histogram_and_sampled_span_with_real_start():
     tr = TRACER.start("act", node_id="n", forced=True)
     before = {n: _hist(n) for n in ("t26.outer", "t26.inner")}
@@ -772,6 +778,7 @@ def test_http_server_traces_a_request_socket_to_socket(rest, node):
     names = ("http.read", "http.pool_wait", "rest.handle",
              "http.loop_wake", "http.respond")
     before = {n: _hist(n)[0] for n in names + ("search.took",)}
+    responses = _responses()
     conn = http.client.HTTPConnection("127.0.0.1", server.port, timeout=30)
     try:
         body = json.dumps({"query": {"match": {"a": "hello"}}})
@@ -785,11 +792,14 @@ def test_http_server_traces_a_request_socket_to_socket(rest, node):
         conn.close()
         loop.call_soon_threadsafe(loop.stop)
     deadline = time.monotonic() + 10
-    while not TRACER.traces(node_id=node.node_id) \
+    while (not TRACER.traces(node_id=node.node_id)
+           or _hist("http.respond")[0] < before["http.respond"] + 2) \
             and time.monotonic() < deadline:
-        time.sleep(0.01)       # finished on the loop, after the response
+        time.sleep(0.01)       # finished on the worker, after the response
     for n in names + ("search.took",):
         assert _hist(n)[0] == before[n] + 2, n
+    # plain TCP, answers under a kilobyte: both written by their workers
+    assert _responses() == (responses[0] + 2, responses[1])
     ring = TRACER.traces(node_id=node.node_id)
     assert len(ring) == 1, "only the forced request is traced"
     spans = {sp["name"]: sp for sp in ring[0]["spans"]}
@@ -806,6 +816,15 @@ def test_http_server_traces_a_request_socket_to_socket(rest, node):
     assert order == sorted(order) and order[0] >= root["start_ns"]
     assert ring[0]["took_ns"] >= sum(
         spans[n]["dur_ns"] for n in names) * 0.999
+    # the last two stages, filed once a request by the thread that
+    # responded: each starts where the one before it ended, and the trace
+    # ends with the bytes handed to the socket
+    wake, respond = spans["http.loop_wake"], spans["http.respond"]
+    assert wake["dur_ns"] >= 0 and respond["dur_ns"] > 0
+    assert wake["start_ns"] == handle["start_ns"] + handle["dur_ns"]
+    assert respond["start_ns"] == wake["start_ns"] + wake["dur_ns"]
+    assert root["start_ns"] + root["dur_ns"] == \
+        respond["start_ns"] + respond["dur_ns"], "socket to socket"
 
 
 def test_idle_clock_books_each_gap_to_its_cause_exactly():
