@@ -231,7 +231,7 @@ class IVFRouter:
         t2 = time.perf_counter_ns()
         n_shards = int(mesh.shape[mesh_lib.SHARD_AXIS])
         gather = policy.gather_bytes(n_shards, len(queries), k_dev)
-        policy.record_leg("ivf", t1 - t0, t2 - t1, gather)
+        policy.record_leg("ivf", gather)
         phases = {"engine": "tpu_ivf_mesh", "nprobe": nprobe,
                   "nlist": idx.nlist, "mesh_shards": n_shards,
                   "scored_rows": nprobe * idx.cap,

@@ -368,8 +368,6 @@ class LexicalField:
         ranges). Returns None when the sharded program can't hold the
         contract (ranked window deeper than a shard's slot range) — the
         caller then runs the single-device board."""
-        import time as _time
-
         from elasticsearch_tpu.ops import dispatch
         from elasticsearch_tpu.parallel import mesh as mesh_lib
         from elasticsearch_tpu.parallel import policy
@@ -384,7 +382,6 @@ class LexicalField:
         tile_ids, boosts, required, n_pad = _pad_query_bucket(
             tile_ids, boosts, required)
         slots_d, impacts_d, scales_d = self._device_arrays_mesh(mesh)
-        t0 = _time.perf_counter_ns()
         # launch-guarded enqueue: collective programs sharing devices
         # must enqueue in one order (parallel/mesh.launch_guard)
         with mesh_lib.launch_guard(mesh):
@@ -395,15 +392,13 @@ class LexicalField:
                 impacts_d, scales_d, k=k_b, width=width, mesh=mesh)
         vals = np.asarray(vals)[:, :k_req]
         gslots = np.asarray(gslots)[:, :k_req]
-        t1 = _time.perf_counter_ns()
         out = []
         for qi in range(n_real):
             v, si = vals[qi], gslots[qi]
             keep = (v > -np.inf) & (si >= 0) & (si < self.n_slots)
             v, si = v[keep], si[keep]
             out.append((self.row_map[si], v.astype(np.float32)))
-        t2 = _time.perf_counter_ns()
-        policy.record_leg(self.FAMILY, t1 - t0, t2 - t1,
+        policy.record_leg(self.FAMILY,
                           policy.gather_bytes(n_shards, n_pad, k_b))
         return out
 

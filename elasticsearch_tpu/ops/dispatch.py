@@ -682,6 +682,17 @@ WARMUP_QUERY_BUCKETS = (1, 8, 16, 64)
 WARMUP_K_BUCKETS = (10, 100)
 
 
+def query_buckets_upto(n: int) -> Tuple[int, ...]:
+    """Every rung of the query ladder up to the one that holds n queries
+    (1, 8, 16, 32, ...): a grid that leaves no rung out under its top."""
+    top = bucket_queries(n)
+    rungs, b = [1], 8
+    while b <= top:
+        rungs.append(b)
+        b *= 2
+    return tuple(rungs)
+
+
 _default_warmup: Optional[bool] = None
 
 

@@ -21,7 +21,8 @@ reference's encode-at-parse-time design (`DenseVectorFieldMapper.parse`).
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+import threading
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +59,55 @@ class Corpus(NamedTuple):
     num_valid: jax.Array
     residual: Optional[jax.Array] = None
     residual_scales: Optional[jax.Array] = None
+
+
+class DeferredCorpus:
+    """A `Corpus` that is uploaded on its first use.
+
+    Where every search of a field is answered by the mesh's sharded copy
+    (`vectors/store.py` under `search.mesh.enabled: true`), a whole copy
+    on one device would only double that device's share. Until
+    `resident()` is first called this object reads as the corpus's
+    shape: each array field is a `jax.ShapeDtypeStruct` (`.shape` and
+    `.dtype` are what the routing and the k clamp read). `resident()`
+    builds the arrays once, under a lock; afterwards the fields read as
+    the arrays themselves. Hand `resident(corpus)` to a device program,
+    never the object."""
+
+    __slots__ = ("_spec", "_build", "_real", "_lock")
+
+    def __init__(self, spec: Corpus, build: Callable[[], Corpus]):
+        self._spec = spec
+        self._build = build
+        self._real: Optional[Corpus] = None
+        self._lock = threading.Lock()
+
+    @property
+    def built(self) -> bool:
+        return self._real is not None
+
+    def resident(self) -> Corpus:
+        if self._real is None:
+            with self._lock:
+                if self._real is None:
+                    self._real = self._build()
+                    self._build = None
+        return self._real
+
+    def __getattr__(self, name):
+        # only the Corpus fields reach here (the slots resolve first)
+        return getattr(self._real if self._real is not None
+                       else self._spec, name)
+
+
+def resident(corpus):
+    """The device arrays of `corpus`, built now if they were deferred."""
+    return corpus.resident() if isinstance(corpus, DeferredCorpus) \
+        else corpus
+
+
+def is_resident(corpus) -> bool:
+    return not isinstance(corpus, DeferredCorpus) or corpus.built
 
 
 def pad_rows(n: int, multiple: int = LANE) -> int:
@@ -98,6 +148,26 @@ def preferred_pad_multiple(n: int, d: int, dtype: str,
     matrix_dtype = jnp.int8 if dtype == "int8" else jnp.bfloat16
     return (binned.BLOCK_N if binned_serves(d, matrix_dtype, metric)
             else LANE)
+
+
+def corpus_spec(n: int, d: int, metric: str, dtype: str,
+                residual: bool) -> Corpus:
+    """The shape `build_corpus` gives n rows of d dims (unpacked
+    encodings only), as `jax.ShapeDtypeStruct`s: what a `DeferredCorpus`
+    reads as before it is built."""
+    n_pad = pad_rows(max(n, 1), preferred_pad_multiple(n, d, dtype, metric))
+
+    def arr(shape, kind):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(kind))
+
+    res = dtype == "int8" and residual
+    return Corpus(
+        matrix=arr((n_pad, d), quant_codec.MATRIX_DTYPES[dtype]),
+        sq_norms=arr((n_pad,), jnp.float32),
+        scales=arr((n_pad,), jnp.float32),
+        num_valid=arr((), jnp.int32),
+        residual=arr((n_pad, d), jnp.int8) if res else None,
+        residual_scales=arr((n_pad,), jnp.float32) if res else None)
 
 
 def build_corpus(

@@ -16,11 +16,14 @@ of coordinator-side RPC reduces (`SearchPhaseController.mergeTopDocs:221`).
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict, Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from elasticsearch_tpu import telemetry
 
 DP_AXIS = "dp"
 SHARD_AXIS = "shard"
@@ -47,8 +50,12 @@ class _MultiLock:
         self._locks = locks
 
     def __enter__(self):
+        # `mesh.guard_wait`: from asking for the guard to holding it, the
+        # time a launch stands behind other launches on its devices
+        asked = time.monotonic_ns()
         for lock in self._locks:
             lock.acquire()
+        telemetry.stage_done("mesh.guard_wait", asked, time.monotonic_ns())
         return self
 
     def __exit__(self, *exc):

@@ -96,10 +96,10 @@ _counters = {
     "hbm_rejections": 0,
     "hbm_last_rejected_bytes": 0,
     "hbm_accepted_bytes": 0,    # high-water accepted dp× footprint
-    # per-leg timing: local = the SPMD program (shard-local score + ICI
-    # merge, one compiled unit), merge = host-side result shaping
-    "legs": {},               # leg -> {local_nanos, merge_nanos,
-                              #         collective_bytes, dispatches}
+    # per-leg counts of sharded dispatches and their analytic all-gather
+    # payload (a profile reads one batch's share as a difference). Their
+    # TIMES are telemetry's: the stages `dispatch.*` and `mesh.guard_wait`
+    "legs": {},               # leg -> {collective_bytes, dispatches}
 }
 
 
@@ -169,6 +169,13 @@ def _effective_dp(n_devices: int) -> int:
 
 def min_rows() -> int:
     return _cfg["min_rows"]
+
+
+def explicitly_enabled() -> bool:
+    """`search.mesh.enabled: true`, as against unset (auto): the operator
+    sized the deployment for the mesh, so a field it answers keeps no
+    whole copy on one device (`vectors/store.py` `sync`)."""
+    return _cfg["enabled"] is True
 
 
 def _mesh_build_failed(what: str, exc: Exception) -> None:
@@ -422,18 +429,17 @@ def reclassify_single(reason: str) -> None:
             _counters["reasons"].get(reason, 0) + 1
 
 
-def record_leg(leg: str, local_nanos: int, merge_nanos: int,
-               collective_bytes: int) -> None:
-    """Accumulate one sharded dispatch's timings: `local` is the SPMD
-    program (shard-local work + the in-program ICI merge), `merge` the
-    host-side result shaping, `collective_bytes` the analytic all-gather
-    payload (S * Q * k * (score + id bytes))."""
+def record_leg(leg: str, collective_bytes: int) -> None:
+    """Count one sharded dispatch and its analytic all-gather payload
+    (S * Q * k * (score + id bytes)): per leg here, where a profile
+    takes one batch's share, and in all under the telemetry counters
+    `mesh.dispatches` and `mesh.collective_bytes`."""
+    from elasticsearch_tpu.telemetry import metrics
+    metrics.counter("mesh.dispatches").inc()
+    metrics.counter("mesh.collective_bytes").inc(int(collective_bytes))
     with _lock:
         entry = _counters["legs"].setdefault(
-            leg, {"local_nanos": 0, "merge_nanos": 0,
-                  "collective_bytes": 0, "dispatches": 0})
-        entry["local_nanos"] += int(local_nanos)
-        entry["merge_nanos"] += int(merge_nanos)
+            leg, {"collective_bytes": 0, "dispatches": 0})
         entry["collective_bytes"] += int(collective_bytes)
         entry["dispatches"] += 1
 
@@ -456,6 +462,11 @@ def stats() -> dict:
     with _lock:
         return {
             "available": mesh is not None,
+            # where a mesh-served field keeps its whole single-device
+            # copy: built on first use under `enabled: true`, resident
+            # beside the shards otherwise (`vectors/store.py` `sync`)
+            "single_device_copy": ("on_first_use" if explicitly_enabled()
+                                   else "resident"),
             "num_shards": n_shards,
             "dp": dp,
             "devices": {"total": n_shards * dp, "shard_axis": n_shards,

@@ -168,21 +168,28 @@ def _knn_step(q, mat, sqn, scl, nvalid, fmask, *, k, metric, precision,
     aliased padding ids into the merge), then the ICI candidate merge."""
     from elasticsearch_tpu.ops.topk import merge_top_k
 
-    local = knn_ops.Corpus(mat, sqn, scl, nvalid[0])
-    rows_per_shard = mat.shape[0]
-    s, i = knn_ops.knn_search(q, local, k, metric=metric,
-                              filter_mask=fmask, precision=precision,
-                              block_size=block_size)
-    shard_id = jax.lax.axis_index(mesh_lib.SHARD_AXIS)
-    # the local top-k returns NEG_INF for padding/filtered slots but an
-    # ARBITRARY row index beside it; pin both so no consumer can alias
-    valid = s > NEG_INF
-    s = jnp.where(valid, s, -jnp.inf)
-    gids = jnp.where(valid, i + shard_id * rows_per_shard,
-                     jnp.int32(-1))
-    all_s = jax.lax.all_gather(s, mesh_lib.SHARD_AXIS)   # [S, Qdp, k] over ICI
-    all_i = jax.lax.all_gather(gids, mesh_lib.SHARD_AXIS)
-    return merge_top_k(all_s, all_i, k)
+    # the three scopes name the program's parts in a device trace: the
+    # shard's own scoring and top-k, the candidates' way over ICI, and
+    # the merge every device makes of them
+    with jax.named_scope("es.mesh.score"):
+        local = knn_ops.Corpus(mat, sqn, scl, nvalid[0])
+        rows_per_shard = mat.shape[0]
+        s, i = knn_ops.knn_search(q, local, k, metric=metric,
+                                  filter_mask=fmask, precision=precision,
+                                  block_size=block_size)
+        shard_id = jax.lax.axis_index(mesh_lib.SHARD_AXIS)
+        # the local top-k returns NEG_INF for padding/filtered slots but
+        # an ARBITRARY row index beside it; pin both so no consumer can
+        # alias
+        valid = s > NEG_INF
+        s = jnp.where(valid, s, -jnp.inf)
+        gids = jnp.where(valid, i + shard_id * rows_per_shard,
+                         jnp.int32(-1))
+    with jax.named_scope("es.mesh.gather"):
+        all_s = jax.lax.all_gather(s, mesh_lib.SHARD_AXIS)  # [S, Qdp, k], ICI
+        all_i = jax.lax.all_gather(gids, mesh_lib.SHARD_AXIS)
+    with jax.named_scope("es.mesh.merge"):
+        return merge_top_k(all_s, all_i, k)
 
 
 def _distributed_knn_impl(queries, corpus, filter_mask, k, mesh,
@@ -509,10 +516,14 @@ class ShardedFieldState:
         mesh = mesh if mesh is not None else self.mesh
         return NamedSharding(mesh, layout.mask_spec(ndim))
 
-    def warmup_entries(self, dims: int):
+    def warmup_entries(self, dims: int, precision: str = "bf16"):
         """(kernel, arg specs, statics) entries pre-compiling the sharded
         serving grid — mirrors `vectors/store._schedule_warmup` but with
-        mesh-sharded input layouts baked into the AOT specs. With dp > 1
+        mesh-sharded input layouts baked into the AOT specs. Every rung
+        of the query ladder up to the grid's top is there (a burst forms
+        the rungs between as well, and a compile of this program on the
+        serving path is seconds), with the precision the caller serves
+        in. With dp > 1
         the grid covers BOTH routes the dp-vs-shard router can pick: the
         full-mesh program (query buckets the dp axis divides) and every
         dp-group submesh (all interactive buckets), so strict mode stays
@@ -527,7 +538,8 @@ class ShardedFieldState:
         for mesh in meshes:
             corpus_spec = layout.shape_specs(self.corpus, mesh)
             mesh_dp = mesh_lib.dp_size(mesh)
-            for q in dispatch.WARMUP_QUERY_BUCKETS:
+            for q in dispatch.query_buckets_upto(
+                    max(dispatch.WARMUP_QUERY_BUCKETS)):
                 if q % mesh_dp:
                     continue   # the router never full-meshes this bucket
                 qspec = jax.ShapeDtypeStruct(
@@ -538,7 +550,7 @@ class ShardedFieldState:
                     entries.append((
                         "mesh.knn", (qspec, corpus_spec, None),
                         {"k": k_b, "mesh": mesh, "metric": self.metric,
-                         "precision": "bf16", "block_size": None}))
+                         "precision": precision, "block_size": None}))
         return entries
 
 

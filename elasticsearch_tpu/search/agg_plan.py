@@ -1005,7 +1005,7 @@ class AggEngine:
             n_boards = 1 + 4 * len(node.subs)
             b_len = len(boards.get("counts",
                                    boards.get("metric", (np.zeros(1),))[0]))
-            policy.record_leg("aggs", 0, 0,
+            policy.record_leg("aggs",
                               policy.gather_bytes(s, n_boards, b_len))
             self._count("mesh_dispatches")
         return boards, mesh_used
@@ -1195,7 +1195,7 @@ class AggEngine:
             from elasticsearch_tpu.parallel import mesh as mesh_lib
             from elasticsearch_tpu.parallel import policy
             s = int(mesh.shape[mesh_lib.SHARD_AXIS])
-            policy.record_leg("aggs", 0, 0,
+            policy.record_leg("aggs",
                               policy.gather_bytes(s, 1, lanes_out[0]))
             self._count("mesh_dispatches")
         return boards, mesh_used

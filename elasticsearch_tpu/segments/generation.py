@@ -129,7 +129,10 @@ class Generation:
 
     @property
     def nbytes(self) -> int:
-        """Resident device bytes (matrix + norms + scales + residual)."""
+        """Resident device bytes (matrix + norms + scales + residual);
+        none while the copy is deferred to its first use."""
+        if not knn_ops.is_resident(self.corpus):
+            return 0
         total = 0
         for arr in (self.corpus.matrix, self.corpus.sq_norms,
                     self.corpus.scales, self.corpus.residual,

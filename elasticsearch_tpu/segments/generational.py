@@ -211,15 +211,18 @@ class GenerationSet:
                 m = np.zeros(n_pad, dtype=bool)
                 m[:gen.n_rows] = live
             mask = jnp.asarray(m)
+        # a base the mesh answers defers its single-device copy
+        # (`knn_ops.DeferredCorpus`): this leg is its first use
+        corpus = knn_ops.resident(gen.corpus)
         if gen.kernel == "knn.exact" and mask is None:
             # the initial base rides the monolithic auto-router (binned
             # Pallas fast path on TPU, warmed grid) — byte-identical to
             # the pre-generational serving path by construction
-            s, ids = knn_ops.knn_search_auto(qj, gen.corpus, k=k_g,
+            s, ids = knn_ops.knn_search_auto(qj, corpus, k=k_g,
                                              metric=metric,
                                              precision=precision)
         else:
-            s, ids = dispatch.call(gen.kernel, qj, gen.corpus, mask,
+            s, ids = dispatch.call(gen.kernel, qj, corpus, mask,
                                    k=k_g, metric=metric,
                                    precision=precision, block_size=None)
         ids = ids + np.int32(off)
@@ -282,7 +285,6 @@ class GenerationSet:
             mesh = ms.mesh
         per = ms.layout.rows_per_shard
         k_b = dispatch.bucket_k(min(k_t, per), limit=per)
-        t0 = time.perf_counter_ns()
         mask = None
         if any_filter or gen.has_tombstones:
             live = gen.live_mask()
@@ -303,7 +305,6 @@ class GenerationSet:
             q, ms.corpus_for(mesh), k_b, mesh, metric=metric,
             filter_mask=mask, precision=precision)
         gids.block_until_ready()
-        t1 = time.perf_counter_ns()
         scores = np.asarray(scores, dtype=np.float32)
         local = ms.map_ids(np.asarray(gids))   # flat rows of this gen
         ids = np.where(local >= 0, local + off, -1).astype(np.int32)
@@ -313,8 +314,7 @@ class GenerationSet:
             ids = np.pad(ids, pad, constant_values=-1)
         gather = mesh_policy.gather_bytes(mesh_lib.shard_size(mesh),
                                           b_pad, k_b)
-        mesh_policy.record_leg("knn", t1 - t0,
-                               time.perf_counter_ns() - t1, gather)
+        mesh_policy.record_leg("knn", gather)
         if knn_stats is not None:
             knn_stats["mesh_searches"] += 1
         return scores, ids, "mesh"

@@ -50,6 +50,10 @@ HOST_MIRROR_MAX_BYTES = 512_000_000
 # tpu_ivf fields smaller than this quietly serve exhaustive
 IVF_MIN_ROWS = 512
 
+# the precision a search runs in where its caller names none (REST never
+# does): what the serving route passes, so what the warm-up grids compile
+SERVING_PRECISION = "bf16"
+
 _METRIC_MAP = {
     "cosine": sim.COSINE,
     "dot_product": sim.DOT_PRODUCT,
@@ -444,6 +448,11 @@ class VectorStoreShard:
             # the whole matrix (block concatenation — extraction itself
             # was still delta-cached above)
             full = view.matrix()
+            from elasticsearch_tpu.parallel import policy as mesh_policy
+            mesh_eligible = mesh_policy.eligible(
+                len(row_map),
+                device_bytes=device_corpus_nbytes(
+                    len(row_map), mapper.dims, dtype))
             # `"rescore": true` on the int8 rung additionally keeps the
             # residual rescore level — the analog of Lucene retaining raw
             # f32 vectors beside the quantized copy (reference
@@ -460,6 +469,15 @@ class VectorStoreShard:
                                                 mapper.similarity)
                 corpus = knn_ops.corpus_from_encoded(
                     data, enc_scales, full, metric=metric, dtype=dtype)
+            elif mesh_eligible and mesh_policy.explicitly_enabled():
+                # the operator asked for the mesh and the sharded copy
+                # below answers this field: a whole copy on one device
+                # beside it would make that device hold twice its share
+                # (at a corpus that needs four chips, more than it has).
+                # What still needs it builds it on first use
+                corpus = self._deferred_corpus(
+                    view.as_source(), len(row_map), mapper.dims, metric,
+                    dtype, residual)
             else:
                 corpus = knn_ops.build_corpus(
                     full, metric=metric, dtype=dtype, residual=residual)
@@ -528,11 +546,7 @@ class VectorStoreShard:
                         ivf, nprobe=nprobe,
                         recall_target=self.knn_recall_target)
             mesh_state = None
-            from elasticsearch_tpu.parallel import policy as mesh_policy
-            if mesh_policy.eligible(
-                    len(row_map),
-                    device_bytes=device_corpus_nbytes(
-                        len(row_map), mapper.dims, dtype)):
+            if mesh_eligible:
                 from elasticsearch_tpu.parallel.sharded_knn import (
                     extend_or_build)
                 mesh = mesh_policy.serving_mesh()
@@ -599,6 +613,30 @@ class VectorStoreShard:
                 for key in [k for k in self._batchers if k[0] == field]:
                     self._retire_sched(self._batchers.pop(key))
             self._schedule_warmup(self._fields[field])
+
+    @staticmethod
+    def _deferred_corpus(source, n_rows: int, dims: int, metric: str,
+                         dtype: str, residual: bool):
+        """The single-device corpus of a field the mesh answers, built on
+        first use from the columnar rows (nothing corpus-sized is pinned
+        meanwhile). A use is a route that leaves the mesh: k deeper than
+        a shard, or the mesh switched off by a later `configure`; each
+        build counts as `mesh.single_device_fallbacks`."""
+        spec = knn_ops.corpus_spec(n_rows, dims, metric, dtype, residual)
+        # registered at 0 here, so that a reader finds "none" and not
+        # "no such counter" (resolved by name at each use: a handle would
+        # outlive a test's `REGISTRY.reset()`)
+        _telemetry_metrics.counter("mesh.single_device_fallbacks")
+
+        def build():
+            _telemetry_metrics.counter("mesh.single_device_fallbacks").inc()
+            logger.info("building the single-device copy of a mesh-served "
+                        "field on first use: rows=%d dims=%d dtype=%s",
+                        n_rows, dims, dtype)
+            return knn_ops.build_corpus(
+                source.gather(), metric=metric, dtype=dtype,
+                pad_to=spec.matrix.shape[0], residual=residual)
+        return knn_ops.DeferredCorpus(spec, build)
 
     @staticmethod
     def _reader_prefix_ok(old_version: tuple, new_version: tuple) -> bool:
@@ -729,13 +767,17 @@ class VectorStoreShard:
         serving path executes."""
         if fc.corpus is None or not self.warmup_enabled():
             return
-        corpus_spec = dispatch.specs_like(fc.corpus)
         n_pad = fc.corpus.matrix.shape[0]
         packed = str(fc.corpus.matrix.dtype) in ("uint8", "uint32")
         binned_ok = knn_ops.binned_route(
             n_pad, fc.dims, fc.corpus.matrix.dtype, fc.metric)
         entries = []
-        for q in dispatch.WARMUP_QUERY_BUCKETS:
+        # a deferred single-device copy (the mesh answers this field) has
+        # no grid to warm: its programs compile if it is ever built
+        single = knn_ops.is_resident(fc.corpus)
+        corpus_spec = dispatch.specs_like(fc.corpus) if single else None
+        query_buckets = dispatch.WARMUP_QUERY_BUCKETS if single else ()
+        for q in query_buckets:
             qspec = dispatch.query_spec(q, fc.dims)
             for k in dispatch.WARMUP_K_BUCKETS:
                 if packed and fc.rescore:
@@ -766,7 +808,8 @@ class VectorStoreShard:
             # the sharded serving grid pre-compiles alongside the
             # single-device one, so the first mesh-routed query of any
             # interactive bucket finds its SPMD program ready
-            entries.extend(fc.mesh_state.warmup_entries(fc.dims))
+            entries.extend(fc.mesh_state.warmup_entries(
+                fc.dims, precision=SERVING_PRECISION))
         if fc.router is not None:
             from elasticsearch_tpu.parallel import policy as mesh_policy
             from elasticsearch_tpu.parallel import sharded_ivf
@@ -899,7 +942,7 @@ class VectorStoreShard:
 
     def search(self, field: str, query_vector: np.ndarray, k: int,
                filter_rows: Optional[np.ndarray] = None,
-               precision: str = "bf16",
+               precision: str = SERVING_PRECISION,
                num_candidates: Optional[int] = None,
                deadline_at: Optional[float] = None
                ) -> Tuple[np.ndarray, np.ndarray]:
@@ -958,7 +1001,7 @@ class VectorStoreShard:
             deadline_at=deadline_at)
 
     def search_many(self, field: str, requests, k: int,
-                    precision: str = "bf16",
+                    precision: str = SERVING_PRECISION,
                     num_candidates: Optional[int] = None) -> list:
         """Score a whole batch of (query_vector, filter_rows) requests in
         ONE dispatch — the hybrid plan's kNN leg. Where `search` relies on
@@ -970,7 +1013,7 @@ class VectorStoreShard:
                                    num_candidates=num_candidates))
 
     def search_many_async(self, field: str, requests, k: int,
-                          precision: str = "bf16",
+                          precision: str = SERVING_PRECISION,
                           num_candidates: Optional[int] = None):
         """Launch a whole batch's kNN WITHOUT syncing: route + dispatch
         the device program and return an opaque handle whose un-synced
@@ -1282,7 +1325,7 @@ class VectorStoreShard:
                                 limit=fc.corpus.matrix.shape[0])
         with _stage("dispatch.launch"):
             s, i = knn_ops.knn_search_auto(
-                q, fc.corpus, k=k_b, metric=fc.metric,
+                q, knn_ops.resident(fc.corpus), k=k_b, metric=fc.metric,
                 filter_mask=mask, precision=precision,
                 rescore_candidates=fc.rescore_candidates)
             # un-synced: s/i are device futures until finalize_many
@@ -1479,13 +1522,13 @@ class VectorStoreShard:
                 rescore_info = (self.last_knn_phases or {}).get("rescore")
             out = self._land_results(fc, scores, flat, -1e37, n_valid,
                                      n_real)
-        # the router's leg accounting, from the stages' own clock
+        # the profile's phase split, from the stages' own clock
         # readings: dispatch start -> boards ready, then d2h + merge
         t1 = wait.start_ns + wait.nanos
         t2 = land.start_ns + land.nanos
         n_shards = mesh_lib.shard_size(mesh)
         gather = mesh_policy.gather_bytes(n_shards, b_pad, k_b)
-        mesh_policy.record_leg("knn", t1 - t0, t2 - t1, gather)
+        mesh_policy.record_leg("knn", gather)
         self.knn_stats["mesh_searches"] += 1
         self.knn_stats["score_nanos"] += t1 - t0
         self.knn_stats["merge_nanos"] += t2 - t1
