@@ -13,14 +13,25 @@ from __future__ import annotations
 import struct
 from typing import Any, Callable, Dict, List, Optional
 
+import numpy as np
+
 from elasticsearch_tpu.common.errors import SearchEngineError
 from elasticsearch_tpu.version import WIRE_VERSION
+
+# A generic list of doubles is n nine-byte (tag 3, big-endian double) pairs on
+# the wire: one record of this dtype an element. From PACK_MIN elements on,
+# one array write (or read) of them beats the element-wise walk, which stays
+# for every other list; the bytes are the same either way.
+_TAGGED_DOUBLE = np.dtype([("tag", "u1"), ("value", ">f8")])
+_FLOATS_ONLY = {float}
+PACK_MIN = 8
 
 
 class StreamOutput:
     def __init__(self, version: int = WIRE_VERSION):
         self.version = version
         self._buf = bytearray()
+        self.packed_lists = 0    # lists written as one array, for a caller's counter
 
     def bytes(self) -> bytes:
         return bytes(self._buf)
@@ -100,9 +111,19 @@ class StreamOutput:
         elif isinstance(v, bytes):
             self.write_byte(5); self.write_byte_array(v)
         elif isinstance(v, (list, tuple)):
-            self.write_byte(6); self.write_vint(len(v))
-            for item in v:
-                self.write_generic(item)
+            n = len(v)
+            self.write_byte(6); self.write_vint(n)
+            # exactly `float`: a bool or an int has another tag, and a float
+            # subclass (np.float64) keeps the walk it had
+            if n >= PACK_MIN and set(map(type, v)) == _FLOATS_ONLY:
+                pairs = np.empty(n, _TAGGED_DOUBLE)
+                pairs["tag"] = 3
+                pairs["value"] = v
+                self._buf += pairs.tobytes()
+                self.packed_lists += 1
+            else:
+                for item in v:
+                    self.write_generic(item)
         elif isinstance(v, dict):
             self.write_byte(7); self.write_vint(len(v))
             for k, item in v.items():
@@ -207,7 +228,14 @@ class StreamInput:
         if tag == 5:
             return self.read_byte_array()
         if tag == 6:
-            return [self.read_generic() for _ in range(self.read_vint())]
+            n = self.read_vint()
+            size = n * _TAGGED_DOUBLE.itemsize
+            if n >= PACK_MIN and self.remaining() >= size and self._data[self._pos] == 3:
+                pairs = np.frombuffer(self._data, _TAGGED_DOUBLE, n, self._pos)
+                if (pairs["tag"] == 3).all():
+                    self._pos += size
+                    return pairs["value"].tolist()
+            return [self.read_generic() for _ in range(n)]
         if tag == 7:
             return {self.read_string(): self.read_generic() for _ in range(self.read_vint())}
         raise SearchEngineError(f"unknown generic tag [{tag}]")

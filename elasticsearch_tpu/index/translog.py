@@ -23,8 +23,14 @@ from typing import Any, Dict, Iterator, List, Optional
 
 from elasticsearch_tpu.common.errors import SearchEngineError
 from elasticsearch_tpu.common.serialization import StreamInput, StreamOutput
+from elasticsearch_tpu.telemetry import metrics
 
 CHECKPOINT_FILE = "translog.ckp"
+
+# counters: records appended, and lists in them that `write_generic` wrote as
+# one array (a vector a document: the two read the same after a vector load)
+COUNT_OPS = "translog.ops"
+COUNT_PACKED_LISTS = "translog.packed_lists"
 
 OP_INDEX = "index"
 OP_DELETE = "delete"
@@ -50,6 +56,8 @@ class Translog:
         # persisted) only when a trim actually discards history
         self.min_retained_seq_no = ckp.get("min_retained_seq_no", 0)
         self._file = open(self._gen_path(self.generation), "ab")
+        metrics.counter(COUNT_OPS)              # read 0, not absent, before
+        metrics.counter(COUNT_PACKED_LISTS)     # the first record
 
     # -- paths / checkpoint ---------------------------------------------------
     def _gen_path(self, gen: int) -> str:
@@ -88,6 +96,9 @@ class Translog:
         rec.write_bytes(payload)
         rec.write_bytes(struct.pack(">I", zlib.crc32(payload) & 0xFFFFFFFF))
         self._file.write(rec.bytes())
+        metrics.counter(COUNT_OPS).inc()
+        if out.packed_lists:
+            metrics.counter(COUNT_PACKED_LISTS).inc(out.packed_lists)
         self.max_seq_no = max(self.max_seq_no, op.get("seq_no", -1))
         if self.sync_policy == "request":
             self.sync()

@@ -1,16 +1,20 @@
 """Tests for settings, serialization, and x-content."""
 
+import struct
+
+import numpy as np
 import pytest
 
-from elasticsearch_tpu.common.errors import IllegalArgumentError
+from elasticsearch_tpu.common.errors import IllegalArgumentError, SearchEngineError
 from elasticsearch_tpu.common.serialization import (
-    NamedWriteable, NamedWriteableRegistry, StreamInput, StreamOutput,
+    PACK_MIN, NamedWriteable, NamedWriteableRegistry, StreamInput, StreamOutput,
 )
 from elasticsearch_tpu.common.settings import (
     Property, ScopedSettings, Setting, Settings, parse_byte_size, parse_time_value,
 )
 from elasticsearch_tpu.common import xcontent
 from elasticsearch_tpu.common.xcontent import ObjectParser, XContentType
+from tests import wire_reference
 
 
 def test_settings_flatten_and_nest():
@@ -66,6 +70,70 @@ def test_stream_roundtrip():
     assert inp.read_optional_string() is None
     assert inp.read_generic() == {"a": [1, 2.5, True, None], "b": "x"}
     assert inp.remaining() == 0
+
+
+def _floats(n, seed=0):
+    return np.random.default_rng(seed).standard_normal(n).tolist()
+
+
+_PAYLOAD_NAN = struct.unpack(">d", bytes.fromhex("7ff8000000abcdef"))[0]
+_SIGNALLING_NAN = struct.unpack(">d", bytes.fromhex("7ff0000000000001"))[0]
+_SPECIALS = [float("nan"), float("inf"), float("-inf"), -0.0, 0.0, 5e-324,
+             -2.2250738585072009e-308, 1.7976931348623157e308, _PAYLOAD_NAN,
+             _SIGNALLING_NAN, 1.0, 1 / 3]
+
+# (case, value, lists in it that take the array path)
+_GENERIC_CASES = [
+    ("floats_0", [], 0),
+    ("floats_1", _floats(1), 0),
+    ("floats_below_threshold", _floats(PACK_MIN - 1), 0),
+    ("floats_at_threshold", _floats(PACK_MIN), 1),
+    ("floats_256", _floats(256), 1),
+    ("floats_768", _floats(768), 1),
+    ("specials", list(_SPECIALS), 1),
+    ("tuple", tuple(_floats(16)), 1),
+    ("tuple_short", tuple(_floats(3)), 0),
+    ("one_int", _floats(9) + [7] + _floats(9), 0),
+    ("one_bool", _floats(9) + [True] + _floats(9), 0),
+    ("one_none", [None] + _floats(12), 0),
+    ("one_np_float64", _floats(12) + [np.float64(0.25)], 0),
+    ("one_nested_list", _floats(9) + [_floats(3)] + _floats(9), 0),
+    ("nested_packed_list", _floats(9) + [_floats(16)] + ["x"], 1),
+    ("ints", list(range(-5, 20)), 0),
+    ("strings", [f"s{i}" for i in range(12)], 0),
+    ("document", {"op": "index", "id": "doc-17", "seq_no": 16, "primary_term": 1,
+                  "version": 1, "source": {
+                      "emb": _floats(256, 1), "title": "héllo", "views": 3,
+                      "tags": ["a", "b"], "geo": {"lat": 1.5, "lon": -2.5},
+                      "ok": True, "none": None, "short": [0.5, 1.5],
+                      "more": [_floats(8, 2), _floats(768, 3)]}}, 3),
+]
+
+
+@pytest.mark.parametrize("case,value,packed", _GENERIC_CASES,
+                         ids=[c[0] for c in _GENERIC_CASES])
+def test_generic_bytes_are_the_element_walks(case, value, packed):
+    """Whatever loop writes a list, the bytes are the plain walk's, and
+    `read_generic` gives the walk's values back from them."""
+    expected = wire_reference.generic(value)
+    out = StreamOutput()
+    out.write_generic(value)
+    assert out.bytes() == expected
+    assert out.packed_lists == packed
+    inp = StreamInput(expected)
+    back = inp.read_generic()
+    assert inp.remaining() == 0
+    # compared as bytes: NaN is not equal to itself, and -0.0 equals 0.0
+    assert wire_reference.generic(back) == expected
+    if isinstance(value, (list, tuple)) and set(map(type, value)) <= {float}:
+        assert type(back) is list and all(type(x) is float for x in back)
+
+
+@pytest.mark.parametrize("cut", [1, 9, 9 * PACK_MIN, 9 * 255])
+def test_generic_float_list_cut_short_is_refused(cut):
+    data = wire_reference.generic(_floats(256))
+    with pytest.raises(SearchEngineError, match="truncated"):
+        StreamInput(data[:-cut]).read_generic()
 
 
 class _Probe(NamedWriteable):

@@ -1,5 +1,7 @@
 """Engine tests: CRUD, versioning, refresh/NRT, translog recovery, merge."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -9,6 +11,8 @@ from elasticsearch_tpu.common.errors import (
 from elasticsearch_tpu.index.engine import Engine
 from elasticsearch_tpu.index.mapping import MapperService
 from elasticsearch_tpu.index.translog import Translog, TranslogCorruptedError
+from elasticsearch_tpu.telemetry import metrics
+from tests import wire_reference
 
 MAPPING = {
     "properties": {
@@ -180,6 +184,90 @@ def test_translog_corruption_detected(tmp_path):
     with pytest.raises(TranslogCorruptedError):
         t2.read_ops(0)
     t2.close()
+
+
+def _vector_ops(dims, n=5):
+    rng = np.random.default_rng(dims)
+    ops = [{"op": "index", "id": f"d{i}", "seq_no": i, "primary_term": 1, "version": 1,
+            "source": {"emb": rng.standard_normal(dims).tolist(), "title": f"t{i}"}}
+           for i in range(n)]
+    ops[1]["routing"] = "r1"
+    ops.append({"op": "delete", "id": "d0", "seq_no": n, "primary_term": 1, "version": 2})
+    ops.append({"op": "noop", "seq_no": n + 1, "reason": "gap", "primary_term": 1})
+    return ops
+
+
+@pytest.mark.parametrize("dims", [4, 256, 768])
+def test_translog_records_are_the_element_walks(tmp_path, dims):
+    """A generation file written one element at a time (the format before
+    vectors were packed) replays to the same operations, and `add` writes
+    that very file."""
+    ops = _vector_ops(dims)
+    old = tmp_path / "old"
+    old.mkdir()
+    log = b"".join(wire_reference.translog_record(op) for op in ops)
+    (old / "translog-1.tlog").write_bytes(log)
+    t = Translog(str(old), sync_policy="async")
+    assert t.read_ops(0) == ops
+    t.close()
+    t = Translog(str(tmp_path / "new"), sync_policy="async")
+    for op in ops:
+        t.add(op)
+    t.close()
+    assert (tmp_path / "new" / "translog-1.tlog").read_bytes() == log
+
+
+def test_vector_bulk_survives_a_dropped_engine(tmp_path):
+    """2,048 vector documents under `async`, synced, the engine dropped with
+    no flush: every `_source` comes back as JSON gave it (doubles, not the
+    float32 the mapper coerces to), and the counters say each vector took the
+    array path."""
+    dims, n = 256, 2048
+    mapping = {"properties": {"emb": {"type": "dense_vector", "dims": dims},
+                              "title": {"type": "keyword"}}}
+    rng = np.random.default_rng(29)
+    sources = [{"emb": v.tolist(), "title": f"t{i}"}
+               for i, v in enumerate(rng.standard_normal((n, dims)))]
+    path = str(tmp_path / "shard")
+    ops0 = metrics.counter("translog.ops").value
+    packed0 = metrics.counter("translog.packed_lists").value
+    e = Engine(path, MapperService(mapping), translog_sync="async")
+    for i, src in enumerate(sources):
+        e.index(str(i), src)
+    assert metrics.counter("translog.ops").value - ops0 == n
+    assert metrics.counter("translog.packed_lists").value - packed0 == n
+    e.translog.sync()
+    del e    # no flush, no close: what a killed process leaves behind
+    e2 = Engine(path, MapperService(mapping), translog_sync="async")
+    assert e2.doc_count() == n
+    for i, src in enumerate(sources):
+        assert e2.get(str(i))["_source"] == src
+    x = e2.get("0")["_source"]["emb"][0]
+    assert type(x) is float and float(np.float32(x)) != x
+    e2.close()
+
+
+@pytest.mark.parametrize("policy,fsyncs", [("request", 2), ("async", 0)])
+def test_translog_add_fsyncs_as_before(tmp_path, monkeypatch, policy, fsyncs):
+    """`request` durability: the generation file and the checkpoint, each
+    once an operation; `async`: none until `sync()`."""
+    t = Translog(str(tmp_path / "tl"), sync_policy=policy)
+    calls = []
+    real = os.fsync
+
+    def counted(fd):
+        calls.append(fd)
+        return real(fd)
+
+    monkeypatch.setattr(os, "fsync", counted)
+    for op in _vector_ops(256):
+        before = len(calls)
+        t.add(op)
+        assert len(calls) - before == fsyncs
+    before = len(calls)
+    t.sync()
+    assert len(calls) - before == 2
+    t.close()
 
 
 def test_mapping_dynamic_and_multifield(tmp_path):
