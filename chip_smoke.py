@@ -500,7 +500,7 @@ def stats_checks(node: dict, rehearse: bool, knn_key: str,
     if knn["host_mirror_searches"]:
         raise SmokeFailure(
             f"{knn['host_mirror_searches']} searches went to the HOST "
-            f"mirror; cost model inputs: {json.dumps(dev['cost_model'])}")
+            f"mirror")
     if not expect_aggs:
         return
     aggs = node["indices"]["aggs"]

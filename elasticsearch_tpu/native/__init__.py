@@ -215,61 +215,6 @@ def topk(scores: np.ndarray, k: int) -> np.ndarray:
     return out[:n]
 
 
-def knn_i8p_topk(queries: np.ndarray, packed: np.ndarray, n: int, d4: int,
-                 row_scales: np.ndarray, row_bias: Optional[np.ndarray],
-                 dot_mul: float, mask: Optional[np.ndarray], k: int
-                 ) -> Tuple[np.ndarray, np.ndarray]:
-    """Batched int8 kNN over a 16-row-interleaved packed corpus (the
-    `es_knn_i8p_topk` kernel; see vectors/host_corpus.py for the layout
-    builder). queries [B, D] f32 metric-prepped; mask None, [ng*16] shared
-    or [B, ng*16] per-query u8. Returns (scores [B, k], rows [B, k]) with
-    -inf/-1 padding. Requires the native library (no numpy fallback — the
-    caller routes to the device path when unavailable)."""
-    lib = require()
-    queries = np.ascontiguousarray(queries, dtype=np.float32)
-    b, d = queries.shape
-    out_s = np.empty((b, k), dtype=np.float32)
-    out_r = np.empty((b, k), dtype=np.int32)
-    mask_ptr, mask_stride = None, 0
-    if mask is not None:
-        mask = np.ascontiguousarray(mask, dtype=np.uint8)
-        if mask.ndim == 2:
-            mask_stride = mask.shape[1]
-        mask_ptr = _ptr(mask, ctypes.c_uint8)
-    lib.es_knn_i8p_topk(
-        _ptr(queries, ctypes.c_float), b, d,
-        _ptr(packed, ctypes.c_uint8), n, d4,
-        _ptr(row_scales, ctypes.c_float),
-        _ptr(row_bias, ctypes.c_float) if row_bias is not None else None,
-        dot_mul, mask_ptr, mask_stride, k,
-        _ptr(out_s, ctypes.c_float), _ptr(out_r, ctypes.c_int32))
-    return out_s, out_r
-
-
-def knn_has_vnni() -> bool:
-    """True when the native int8 kNN kernel runs its AVX512-VNNI path on
-    this host (the scalar fallback is ~100x slower; the serving cost model
-    prices the scan accordingly)."""
-    lib = _load()
-    return bool(lib is not None and lib.es_knn_i8p_has_vnni())
-
-
-def _bind_knn(lib: ctypes.CDLL) -> None:
-    i32p = ctypes.POINTER(ctypes.c_int32)
-    f32p = ctypes.POINTER(ctypes.c_float)
-    u8p = ctypes.POINTER(ctypes.c_uint8)
-    lib.es_knn_i8p_topk.argtypes = [
-        f32p, ctypes.c_int64, ctypes.c_int64,
-        u8p, ctypes.c_int64, ctypes.c_int64,
-        f32p, f32p, ctypes.c_float,
-        u8p, ctypes.c_int64, ctypes.c_int64, f32p, i32p]
-    lib.es_knn_i8p_topk.restype = None
-    lib.es_knn_i8p_has_vnni.argtypes = []
-    lib.es_knn_i8p_has_vnni.restype = ctypes.c_int32
-
-
 # Build/load at import so the first search request never pays the compile
 # (a stat-only no-op once libes_native.so is newer than the source).
 _load()
-if _lib is not None:
-    _bind_knn(_lib)

@@ -237,28 +237,6 @@ class _MultiShardVectorStore:
                 keep[i] = False
         return out_rows[keep], scores[keep]
 
-    def _prefer_host(self, field: str) -> bool:
-        """True when every shard has a host VNNI mirror and the cost model
-        says a host pass beats a device round-trip for this corpus size
-        (serving/batcher.py) — then the per-shard path (whose shard stores
-        route host-side) wins over the fused mesh program."""
-        from elasticsearch_tpu.serving.batcher import CostModel
-
-        total, dims, pending = 0, 0, 0
-        for shard in self.svc.shards:
-            store = shard.vector_store
-            fc = store.field(field) if hasattr(store, "field") else None
-            if fc is None or fc.host is None:
-                return False
-            total += len(fc.row_map)
-            dims = fc.dims
-            if hasattr(store, "pending_requests"):
-                pending += store.pending_requests(field)
-        # this request plus whatever is already queued behind the shard
-        # batchers: under concurrent load the coalesced batch amortizes the
-        # device dispatch, so the fused mesh program wins earlier
-        return total > 0 and CostModel.prefer_host(1 + pending, total, dims)
-
     def search(self, field: str, query_vector, k: int, filter_rows=None,
                precision: str = "bf16", num_candidates=None,
                deadline_at=None):
@@ -266,8 +244,7 @@ class _MultiShardVectorStore:
         self._phases = {}
         # k beyond the per-shard padded row count cannot merge losslessly
         # in the fused program; such deep k falls back to the host merge
-        if state is not None and k <= state["per"] \
-                and not self._prefer_host(field):
+        if state is not None and k <= state["per"]:
             # the fused mesh program has no per-phase split to report
             return self._mesh_search(state, query_vector, k, filter_rows,
                                      precision)
@@ -2659,13 +2636,12 @@ class Node:
     def _device_stats_section() -> dict:
         """What the kernels ran on, as JAX reports it: platform, device
         kind and count, each device's `memory_stats()` (where the
-        backend keeps them), and the two inputs of the host-vs-device
-        cost model (`serving/batcher.py`) — the measured dispatch
-        overhead (null until a search has needed it) and the peak the
-        table gives this device kind."""
+        backend keeps them), and the measured dispatch overhead
+        (`ops/dispatch.device_overhead_ms`; null until a BM25 search
+        has needed it)."""
         import jax
 
-        from elasticsearch_tpu.serving import batcher
+        from elasticsearch_tpu.ops import dispatch
         devices = jax.devices()
         memory = []
         for d in devices:
@@ -2678,8 +2654,7 @@ class Node:
                 "count": len(devices),
                 "memory": memory,
                 "cost_model": {
-                    "device_overhead_ms": batcher._overhead_ms,
-                    "device_peak_ops": batcher.device_peak_ops()}}
+                    "device_overhead_ms": dispatch._overhead_ms}}
 
     def _recovery_section(self) -> dict:
         """`indices.recovery` for a single node: block-level restore
@@ -2784,7 +2759,11 @@ class Node:
         continuous-batching scheduler counters (batches / requests /
         topups / overlap; their times are the telemetry stages)."""
         out = {"searches": 0, "ivf_searches": 0, "fallback_searches": 0,
-               "mesh_searches": 0, "host_mirror_searches": 0,
+               "mesh_searches": 0,
+               # no store counts this any more: a constant 0 kept for
+               # benchmark/kinds/knn.py, knn_int8.py and chip_smoke.py,
+               # which read the key and compare it with 0 (ROADMAP D13)
+               "host_mirror_searches": 0,
                "fused_probe_searches": 0,
                "rescore_searches": 0, "rescore_window_rows": 0,
                "rescore_promoted": 0, "rescore_nanos": 0,

@@ -33,9 +33,8 @@ fields the same treatment `vectors/store.py` gives `dense_vector`:
   is called once per distinct df, not once per term).
 
 A numpy host twin (`_score_host`) runs the identical math for corpora
-below the device-dispatch break-even (the `serving/batcher.py` CostModel
-call), so routing is invisible to callers — the same contract the vector
-store's host VNNI mirror keeps.
+below the device-dispatch break-even (`_prefer_device`), so routing is
+invisible to callers.
 """
 
 from __future__ import annotations
@@ -478,11 +477,11 @@ class LexicalField:
 
     def _prefer_device(self, batch: int) -> bool:
         """Device dispatch pays the fixed round-trip; the host twin pays a
-        scan over ~nnz + n_slots per query. Same break-even logic as the
-        vector CostModel, priced for the scatter-bound lexical shape."""
-        from elasticsearch_tpu.serving.batcher import device_overhead_ms
+        scan over ~nnz + n_slots per query, priced for the scatter-bound
+        lexical shape."""
+        from elasticsearch_tpu.ops import dispatch
         host_ms = batch * (self.nnz + self.n_slots) / 2.0e8 * 1000.0
-        return host_ms > device_overhead_ms()
+        return host_ms > dispatch.device_overhead_ms()
 
 
 def _bm25_topk(scores0, counts0, tile_ids, boosts, required, tile_slots,

@@ -106,11 +106,8 @@ def _bf16x2(x):
     return hi, lo
 
 
-def _rescore_scores(q, corpus: Corpus, gather):
-    """Near-exact candidate scores [Q, C] for a gathered candidate set.
-
-    `gather(arr)` maps a corpus-aligned array ([N_pad, D] or [N_pad]) to
-    its candidate gather ([Q, C, D] / [Q, C]).
+def _rescore_scores(q, corpus: Corpus, rows):
+    """Near-exact scores [Q, C] of the candidate row ids `rows` [Q, C].
 
     Precision story — this is what makes the "rescoring may only help"
     invariant hold (base picks ⊆ candidate set, and a near-exact
@@ -125,6 +122,11 @@ def _rescore_scores(q, corpus: Corpus, gather):
     reconstruction.
     """
     q_hi, q_lo = _bf16x2(q)
+
+    def gather(arr):
+        # a corpus-aligned array ([N_pad, D] or [N_pad]) to its
+        # candidates ([Q, C, D] / [Q, C])
+        return arr[rows]
 
     def dot(c):
         kw = dict(preferred_element_type=jnp.float32)
@@ -143,26 +145,6 @@ def _rescore_scores(q, corpus: Corpus, gather):
         return dot(cand)
     c_hi, c_lo = _bf16x2(cand)
     return dot(c_hi) + dot(c_lo)
-
-
-def _row_gather(rows):
-    """gather() over explicit row ids [Q, C]."""
-    return lambda arr: arr[rows]
-
-
-def _bin_gather(tile_idx, lane_idx, nq, b, d):
-    """gather() over whole [BIN_SIZE]-row bins (coarse block transfers,
-    far cheaper on HBM than row-level gathers). tile_idx/lane_idx: [Q, B]
-    bin coordinates; gathered shapes flatten to [Q, B*BIN_SIZE(, D)]."""
-    def g(arr):
-        n_pad = arr.shape[0]
-        n_tiles = n_pad // BLOCK_N
-        if arr.ndim == 2:
-            r = arr.reshape(n_tiles, BIN_SIZE, BINS_PER_TILE, d)
-            return r[tile_idx, :, lane_idx, :].reshape(nq, b * BIN_SIZE, d)
-        r = arr.reshape(n_tiles, BIN_SIZE, BINS_PER_TILE)
-        return r[tile_idx, :, lane_idx].reshape(nq, b * BIN_SIZE)
-    return g
 
 
 def _decode(packed, k):
@@ -227,66 +209,6 @@ def binned_knn_search(
                          interpret=dispatch.pallas_interpret(interpret))
 
 
-def _rescored_impl(queries, corpus, k: int, metric: str,
-                   rescore_bins: int, interpret: bool):
-    packed, q = _binned_packed(queries, corpus, metric, interpret)
-    nq, ncols = packed.shape
-    cols = jnp.arange(ncols, dtype=jnp.int32)[None, :]
-    bin_base = (cols // BINS_PER_TILE) * BLOCK_N + cols % BINS_PER_TILE
-    cand_s = jax.lax.bitcast_convert_type(
-        packed & jnp.int32(MASK), jnp.float32) - SHIFT
-    r = min(rescore_bins, ncols)
-    _, bin_pos = jax.lax.top_k(cand_s, r)                       # [Q, R]
-    base = jnp.take_along_axis(
-        jnp.broadcast_to(bin_base, (nq, ncols)), bin_pos, axis=1)
-    # a bin's rows stride by BINS_PER_TILE within its tile
-    d = corpus.matrix.shape[1]
-    tile_idx = base // BLOCK_N                                  # [Q, R]
-    lane_idx = base % BLOCK_N                                   # bin lane
-    row_ids = base[:, :, None] + (
-        jnp.arange(BIN_SIZE, dtype=jnp.int32)
-        * BINS_PER_TILE)[None, None, :]
-    flat_ids = row_ids.reshape(nq, r * BIN_SIZE)                # [Q, C]
-    # the query stays UNQUANTIZED here (the kernel's main pass quantizes
-    # it to int8): removing the query-side quantization error is where
-    # the recall headroom comes from (see _rescore_scores)
-    scores = _rescore_scores(
-        q, corpus, _bin_gather(tile_idx, lane_idx, nq, r, d))
-    valid = flat_ids < corpus.num_valid
-    scores = jnp.where(valid, scores, -jnp.inf)
-    vals, pos = jax.lax.top_k(scores, k)
-    return vals, jnp.take_along_axis(flat_ids, pos, axis=1)
-
-
-dispatch.DISPATCH.register(
-    "knn.binned_rescored", _rescored_impl,
-    static_argnames=("k", "metric", "rescore_bins", "interpret"),
-    grid_check=_grid_binned)
-
-
-def binned_knn_search_rescored(
-    queries: jax.Array,
-    corpus: Corpus,
-    k: int,
-    metric: str = sim.COSINE,
-    rescore_bins: int = 16,
-    interpret: Optional[bool] = None,
-):
-    """Binned pass + re-scoring of the top bins' member rows with the
-    UNQUANTIZED query.
-
-    The binned kernel keeps one candidate per 64-row bin and (for int8
-    corpora) quantizes the query; both cost recall. The top
-    `rescore_bins` bins per query re-score all their member rows with
-    the full-precision query (bin gather + bf16 einsum). Measured on
-    v5e: +0.007 recall@10 on clustered 1M x 768 int8 at ~6 ms/batch-256
-    (corpus-size independent, gather-bound) — worthwhile headroom when
-    the recall gate is tight, a real tax on small corpora."""
-    return dispatch.call("knn.binned_rescored", queries, corpus, k=k,
-                         metric=metric, rescore_bins=rescore_bins,
-                         interpret=dispatch.pallas_interpret(interpret))
-
-
 def _rescored_packed_impl(queries, corpus, k: int, metric: str,
                           rescore_candidates: int, interpret: bool):
     packed, q = _binned_packed(queries, corpus, metric, interpret)
@@ -300,7 +222,7 @@ def _rescored_packed_impl(queries, corpus, k: int, metric: str,
     lane = pos % BINS_PER_TILE
     t = sel & ((1 << IDX_BITS) - 1)
     rows = tile_base + t * BINS_PER_TILE + lane              # [Q, C]
-    scores = _rescore_scores(q, corpus, _row_gather(rows))
+    scores = _rescore_scores(q, corpus, rows)
     valid = rows < corpus.num_valid
     scores = jnp.where(valid, scores, -jnp.inf)
     vals, p2 = jax.lax.top_k(scores, k)
@@ -324,91 +246,15 @@ def binned_knn_search_rescored_packed(
     """Binned pass + re-scoring of the top PACKED candidates with the
     unquantized query.
 
-    Unlike `binned_knn_search_rescored` (which re-reads whole 64-row bins,
-    ~200 MB/batch of gathers), this reuses the exact winner row each packed
-    column already identifies: the top `rescore_candidates` columns decode
-    to row ids, and only those rows ([Q, C, D], ~25 MB/batch at C=128) are
-    re-scored in bf16. Removes the query-side int8 quantization error at a
-    few percent of the bin-rescore's bandwidth; bin-collision loss (second
-    winner inside one bin) stays, so the ceiling is between the base and
-    bin-rescored variants."""
+    The binned kernel keeps one candidate per 64-row bin and (for int8
+    corpora) quantizes the query. This reuses the exact winner row each
+    packed column already identifies: the top `rescore_candidates`
+    columns decode to row ids, and only those rows ([Q, C, D], ~25
+    MB/batch at C=128) are re-scored in bf16. Removes the query-side int8
+    quantization error; bin-collision loss (second winner inside one
+    bin) stays."""
     return dispatch.call("knn.binned_rescored_packed", queries, corpus,
                          k=k, metric=metric,
-                         rescore_candidates=rescore_candidates,
-                         interpret=dispatch.pallas_interpret(interpret))
-
-
-def _rescored_hybrid_impl(queries, corpus, k: int, metric: str,
-                          rescore_bins: int, rescore_candidates: int,
-                          interpret: bool):
-    packed, q = _binned_packed(queries, corpus, metric, interpret)
-    nq, ncols = packed.shape
-    cand_s = jax.lax.bitcast_convert_type(
-        packed & jnp.int32(MASK), jnp.float32) - SHIFT
-
-    d = corpus.matrix.shape[1]
-    cols_all = jnp.arange(ncols, dtype=jnp.int32)[None, :]
-    bin_base_all = (cols_all // BINS_PER_TILE) * BLOCK_N \
-        + cols_all % BINS_PER_TILE
-
-    # whole-bin members for the top rescore_bins bins
-    b = min(rescore_bins, ncols)
-    _, bin_pos = jax.lax.top_k(cand_s, b)
-    base = jnp.take_along_axis(
-        jnp.broadcast_to(bin_base_all, (nq, ncols)), bin_pos, axis=1)
-    tile_idx = base // BLOCK_N
-    lane_idx = base % BLOCK_N
-    bin_rows = (base[:, :, None]
-                + (jnp.arange(BIN_SIZE, dtype=jnp.int32)
-                   * BINS_PER_TILE)[None, None, :]).reshape(nq, b * BIN_SIZE)
-    bin_scores = _rescore_scores(
-        q, corpus, _bin_gather(tile_idx, lane_idx, nq, b, d))
-
-    # packed winner rows beyond those bins
-    c = min(rescore_candidates, ncols)
-    _, pos = jax.lax.top_k(cand_s, c)
-    sel = jnp.take_along_axis(packed, pos, axis=1)
-    tb = (pos // BINS_PER_TILE) * BLOCK_N
-    lane = pos % BINS_PER_TILE
-    t = sel & ((1 << IDX_BITS) - 1)
-    pk_rows = tb + t * BINS_PER_TILE + lane
-    pk_scores = _rescore_scores(q, corpus, _row_gather(pk_rows))
-
-    rows = jnp.concatenate([bin_rows, pk_rows], axis=1)
-    scores = jnp.concatenate([bin_scores, pk_scores], axis=1)
-    valid = rows < corpus.num_valid
-    # duplicate rows (a packed winner inside a rescored bin) must not fill
-    # two top-k slots: keep the FIRST occurrence
-    order_cols = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :]
-    first = rows[:, :, None] == rows[:, None, :]
-    dup = (first & (order_cols[:, None, :] < order_cols[:, :, None])).any(2)
-    scores = jnp.where(valid & ~dup, scores, -jnp.inf)
-    vals, p2 = jax.lax.top_k(scores, k)
-    return vals, jnp.take_along_axis(rows, p2, axis=1)
-
-
-dispatch.DISPATCH.register(
-    "knn.binned_rescored_hybrid", _rescored_hybrid_impl,
-    static_argnames=("k", "metric", "rescore_bins", "rescore_candidates",
-                     "interpret"),
-    grid_check=_grid_binned)
-
-
-def binned_knn_search_rescored_hybrid(
-    queries: jax.Array,
-    corpus: Corpus,
-    k: int,
-    metric: str = sim.COSINE,
-    rescore_bins: int = 4,
-    rescore_candidates: int = 128,
-    interpret: Optional[bool] = None,
-):
-    """Binned pass + hybrid re-score: the top few WHOLE bins (recovers
-    same-bin collision losses where true neighbors concentrate) plus the
-    top packed candidate rows (removes query-quantization error broadly).
-    ~1/4 of the 16-bin rescore's gather traffic for most of its recall."""
-    return dispatch.call("knn.binned_rescored_hybrid", queries, corpus,
-                         k=k, metric=metric, rescore_bins=rescore_bins,
                          rescore_candidates=rescore_candidates,
                          interpret=dispatch.pallas_interpret(interpret))
 

@@ -7,10 +7,9 @@ tokens.py` is the token-block variant for late-interaction
 (multi-vector) fields — metric prep, lane padding, per-token codec
 rows, pooled coarse centroids. `quant/rescore.py` is the exact-rescore
 half of two-phase serving. Everything that quantizes
-(`ops/quantization`, `ops/pallas_knn_binned`'s query path,
-`vectors/host_corpus`, the IVF partition upload, the sharded mesh
-build, the token-block extraction) routes through here; tpulint TPU013
-keeps it that way.
+(`ops/quantization`, `ops/pallas_knn_binned`'s query path, the IVF
+partition upload, the sharded mesh build, the token-block extraction)
+routes through here; tpulint TPU013 keeps it that way.
 """
 
 from elasticsearch_tpu.quant import codec, rescore, tokens

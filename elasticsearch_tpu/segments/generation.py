@@ -63,12 +63,12 @@ class Generation:
     """Immutable device generation + host bookkeeping."""
 
     __slots__ = ("gen_id", "corpus", "row_map", "source",
-                 "tombstones", "kernel", "host", "router", "mesh_state",
+                 "tombstones", "kernel", "router", "mesh_state",
                  "_live_cache")
 
     def __init__(self, gen_id: int, corpus, row_map: np.ndarray,
                  source, tombstones: Optional[np.ndarray] = None,
-                 kernel: str = "segments.knn", host=None, router=None,
+                 kernel: str = "segments.knn", router=None,
                  mesh_state=None):
         self.gen_id = gen_id
         self.corpus = corpus              # knn_ops.Corpus (device pytree)
@@ -84,7 +84,6 @@ class Generation:
         # build (reuses the store's warmed monolithic grid), "segments.knn"
         # for bucket-padded sealed/merged generations
         self.kernel = kernel
-        self.host = host                  # HostFieldCorpus mirror (base only)
         self.router = router              # ann.IVFRouter (graduated base)
         self.mesh_state = mesh_state      # parallel ShardedFieldState
         self._live_cache = None
@@ -149,7 +148,7 @@ class Generation:
         at compaction); the mesh state stays (searches mask it)."""
         return Generation(self.gen_id, self.corpus, self.row_map,
                           self.source, tombstones=tombstones,
-                          kernel=self.kernel, host=None, router=None,
+                          kernel=self.kernel, router=None,
                           mesh_state=self.mesh_state)
 
     def live_mask(self) -> np.ndarray:

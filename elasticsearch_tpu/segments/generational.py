@@ -365,7 +365,7 @@ class GenerationalCorpus:
     @classmethod
     def from_monolithic(cls, corpus, row_map: np.ndarray, source,
                         metric: str, dtype: str,
-                        rescore: bool, dims: int, host=None, router=None,
+                        rescore: bool, dims: int, router=None,
                         mesh_state=None, **kwargs) -> "GenerationalCorpus":
         """Wrap a legacy full build as generation 0 (kernel `knn.exact`
         — the monolithic grid the store already warms). `source` is the
@@ -378,8 +378,8 @@ class GenerationalCorpus:
         gc = cls(metric, dtype, rescore, dims, **kwargs)
         gen = Generation(gc._next_gen_id, corpus,
                          np.asarray(row_map, dtype=np.int64),
-                         source, kernel="knn.exact", host=host,
-                         router=router, mesh_state=mesh_state)
+                         source, kernel="knn.exact", router=router,
+                         mesh_state=mesh_state)
         gc._next_gen_id += 1
         gc._set = GenerationSet((gen,))
         return gc
@@ -682,7 +682,6 @@ class GenerationalCorpus:
             merged.router = self._graduate_ivf(victims[0], merged, vecs)
             merged.mesh_state = self._graduate_mesh(victims[0], merged,
                                                     vecs)
-            merged.host = self._graduate_host(merged, vecs)
         if self.warmup_cb is not None:
             self.warmup_cb(merged.warmup_entries(self.dims, self.metric))
         return merged
@@ -728,22 +727,6 @@ class GenerationalCorpus:
         return IVFRouter(ivf, nprobe=params.get("nprobe", "auto"),
                          recall_target=float(
                              params.get("recall_target", 0.95)))
-
-    def _graduate_host(self, merged: Generation, vecs: np.ndarray):
-        """Rebuild the host VNNI latency mirror for the new base — same
-        eligibility policy as the monolithic sync path, built HERE so a
-        consolidated corpus keeps the low-latency host route instead of
-        silently regressing to device-only after its first merge."""
-        from elasticsearch_tpu import native
-        from elasticsearch_tpu.vectors.host_corpus import (
-            HostFieldCorpus, packed_nbytes)
-        max_bytes = int(self.knn_params.get("host_mirror_max_bytes", 0))
-        if (not native.AVAILABLE
-                or self.dtype in ("int8", "int4", "binary")
-                or merged.n_rows == 0
-                or packed_nbytes(merged.n_rows, self.dims) > max_bytes):
-            return None
-        return HostFieldCorpus(vecs, self.metric)
 
     def _graduate_mesh(self, old_base: Generation, merged: Generation,
                        vecs: np.ndarray):

@@ -1,7 +1,7 @@
-"""Query serving layer: micro-batching dispatch + host/device cost routing."""
+"""Query serving layer: micro-batching dispatch."""
 
 from elasticsearch_tpu.serving.batcher import (
-    BoundedBatcher, CombiningBatcher, CostModel,
+    BoundedBatcher, CombiningBatcher,
 )
 
-__all__ = ["BoundedBatcher", "CombiningBatcher", "CostModel"]
+__all__ = ["BoundedBatcher", "CombiningBatcher"]

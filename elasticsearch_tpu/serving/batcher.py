@@ -33,13 +33,6 @@ adapted to the shape-bucketed dispatcher):
     rescore/hydrate — OUTSIDE the lock, so the next runner's dispatch
     overlaps with this batch's host work. `async_depth` bounds how many
     batches may be in flight un-finalized.
-
-* `CostModel` — per-dispatch host-vs-device routing. A device dispatch pays
-  a fixed round-trip (measured once, lazily, against the live backend); a
-  host VNNI pass pays corpus-scan time. Small corpus + small batch → host
-  kernel (native/es_native.cc es_knn_i8p_topk); large corpus or deep batch →
-  device matmul+top-k. Both return identical raw-score conventions, so the
-  router is invisible to callers.
 """
 
 from __future__ import annotations
@@ -56,130 +49,6 @@ from elasticsearch_tpu.telemetry import metrics as _metrics
 from elasticsearch_tpu.telemetry import stage as _stage
 from elasticsearch_tpu.telemetry import stage_done as _stage_done
 from elasticsearch_tpu.telemetry import trace as _tt
-
-_overhead_lock = threading.Lock()
-_overhead_ms: Optional[float] = None
-
-
-def _probe_kernel(x):
-    """Tiny round-trip kernel for `device_overhead_ms` (registered
-    lazily — jax import cost stays off module import)."""
-    return x + 1.0
-
-
-def _host_gops() -> float:
-    """Measured ~200 GOPS peak with AVX512-VNNI; priced at 150 GOPS — a
-    25% derate for sustained serving (frequency throttle + co-running
-    work), so the router only sends the host scans it can actually absorb.
-    The scalar fallback the kernel dispatches to on older hosts is ~100x
-    slower — price it honestly so the router doesn't send scans to a path
-    that can't serve them."""
-    from elasticsearch_tpu import native
-    if native.knn_has_vnni():
-        return 150.0e9
-    return 2.0e9
-
-
-HOST_GOPS = None  # resolved lazily via _host_gops (native lib load order)
-HOST_MEM_BPS = 10.0e9
-
-# Published per-chip peaks, keyed by `jax.devices()[0].device_kind`:
-# (bf16 FLOP/s, int8 OP/s, HBM bytes/s). Source: Google Cloud
-# documentation, "TPU v5e" system architecture page — 197 TFLOP/s bf16,
-# 393 TOP/s int8, 819 GB/s HBM per chip. A TPU that is not in the table
-# is an error, not a default: a made-up peak silently mis-routes between
-# the chip and the host mirror.
-DEVICE_PEAKS = {
-    "TPU v5 lite": (197.0e12, 393.0e12, 819.0e9),   # what a v5e reports
-}
-
-
-def device_peak_ops() -> Optional[float]:
-    """bf16 matmul peak of the live backend, from `DEVICE_PEAKS`; None on
-    the CPU backend, which has no device to price (see `prefer_host`)."""
-    import jax
-    dev = jax.devices()[0]
-    if dev.platform == "cpu":
-        return None
-    if dev.device_kind not in DEVICE_PEAKS:
-        raise RuntimeError(
-            f"no published peak for device kind [{dev.device_kind}] "
-            f"(platform {dev.platform}); add it to "
-            f"serving/batcher.DEVICE_PEAKS with its source")
-    return DEVICE_PEAKS[dev.device_kind][0]
-
-
-def device_overhead_ms() -> float:
-    """One-time measurement of a tiny dispatch round-trip against the
-    live backend — the fixed cost a device dispatch must amortize. A
-    probe that fails raises: a guessed overhead would route searches to
-    the host mirror (or away from it) on no evidence."""
-    global _overhead_ms
-    if _overhead_ms is not None:
-        return _overhead_ms
-    with _overhead_lock:
-        if _overhead_ms is not None:
-            return _overhead_ms
-        import jax.numpy as jnp
-
-        import numpy as _np
-
-        from elasticsearch_tpu.ops import dispatch
-
-        # the probe rides the same dispatcher every serving kernel
-        # uses (a raw jax.jit here was a second compile path outside
-        # the AOT cache — tpulint TPU001), so the measured round trip
-        # includes the dispatch layer a real serving call pays
-        dispatch.DISPATCH.register("serving.overhead_probe",
-                                   _probe_kernel)
-        x = _np.zeros((8,), _np.float32)
-        # tpulint: disable=TPU009(one-time-per-process probe under the measurement latch, not a serving queue lock — nothing queues on it)
-        _np.asarray(dispatch.call("serving.overhead_probe",
-                                  jnp.asarray(x)))
-        samples = []
-        for _ in range(3):
-            # a serving dispatch pays h2d (queries/mask), execute, AND
-            # d2h (results) — measure the full round trip
-            t0 = time.perf_counter()
-            # tpulint: disable=TPU002(the probe MEASURES the per-dispatch d2h round trip on purpose; 3 iterations, once per process, not a serving loop),TPU009(same: the measurement latch is not a serving queue lock)
-            _np.asarray(dispatch.call("serving.overhead_probe",
-                                      jnp.asarray(x)))
-            samples.append((time.perf_counter() - t0) * 1000.0)
-        _overhead_ms = max(0.05, min(samples))
-    return _overhead_ms
-
-
-class CostModel:
-    """Estimate dispatch latency for a (batch, corpus) shape on each path."""
-
-    @staticmethod
-    def host_ms(batch: int, n_rows: int, dims: int) -> float:
-        global HOST_GOPS
-        if HOST_GOPS is None:
-            HOST_GOPS = _host_gops()
-        groups = (batch + 15) // 16  # kernel computes 16 query lanes a pass
-        compute = 2.0 * groups * 16 * n_rows * dims / HOST_GOPS * 1000.0
-        mem = groups * n_rows * dims / HOST_MEM_BPS * 1000.0
-        return max(compute, mem) + 0.05
-
-    @staticmethod
-    def device_ms(batch: int, n_rows: int, dims: int) -> float:
-        compute = 2.0 * batch * n_rows * dims / device_peak_ops() * 1000.0
-        return device_overhead_ms() + compute
-
-    @classmethod
-    def prefer_host(cls, batch: int, n_rows: int, dims: int) -> bool:
-        """Host mirror or device? Where JAX's backend IS the CPU there is
-        no device round trip for the mirror to save and no published
-        peak to price the other side with: the answer is the device
-        route, by that fact — so a CPU run (the tests, a rehearsal of
-        `chip_smoke.py`) takes the routes the chip will take, and the
-        route cannot flip with the machine's load."""
-        if device_peak_ops() is None:
-            return False
-        return (cls.host_ms(batch, n_rows, dims)
-                < cls.device_ms(batch, n_rows, dims))
-
 
 IDLE_NO_REQUEST = "serving.idle_no_request_nanos"
 IDLE_PICKUP = "serving.idle_pickup_nanos"
@@ -371,9 +240,7 @@ class CombiningBatcher:
 
     # ------------------------------------------------------------ queue
     def pending(self) -> int:
-        """Requests queued but not yet claimed by a runner — the
-        coalescing signal cost routers use to estimate the NEXT batch's
-        size."""
+        """Requests queued but not yet claimed by a runner."""
         with self._q_lock:
             return len(self._queue)
 

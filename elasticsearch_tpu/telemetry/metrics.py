@@ -3,10 +3,10 @@
 Before this module the repo's latency numbers lived in two places that
 could not answer "what is p99 RIGHT NOW": cumulative nanos totals in
 per-subsystem stats dicts (`_nodes/stats` could report a mean but never a
-tail) and closed-loop percentiles computed inside `bench_matrix.py` (a
-harness, not a serving surface). This registry is the one in-tree home
-for live distributions: subsystems record durations as they already
-measure them (no new clock reads, no device syncs), and
+tail) and closed-loop percentiles computed by a harness outside the
+program. This registry is the one in-tree home for live distributions:
+subsystems record durations as they already measure them (no new clock
+reads, no device syncs), and
 `_nodes/stats telemetry` renders p50/p90/p99/p999 from the histograms on
 demand.
 
@@ -16,9 +16,7 @@ is no configuration and no rescaling; recording appends to a pending
 list that is folded into the buckets every `FOLD_AT` values and before
 every read. Percentiles interpolate
 linearly inside the winning bucket, which bounds the error to one bucket
-width — the bench cross-check (`gate` in bench_matrix) asserts the
-histogram-derived p99 agrees with a closed-loop measured p99 within one
-bucket.
+width.
 
 Process-wide like the kernel dispatcher (`ops/dispatch.DISPATCH`): one
 registry serves every node in the process, and the stats section is

@@ -29,22 +29,15 @@ import numpy as np
 
 logger = logging.getLogger("elasticsearch_tpu.vectors")
 
-from elasticsearch_tpu import native
 from elasticsearch_tpu.index.mapping import DenseVectorFieldMapper
 from elasticsearch_tpu.index.segment import ShardReader
 from elasticsearch_tpu.ops import dispatch
 from elasticsearch_tpu.ops import knn as knn_ops
 from elasticsearch_tpu.ops import similarity as sim
 from elasticsearch_tpu.quant import rescore as quant_rescore
-from elasticsearch_tpu.serving.batcher import (
-    IDLE, CombiningBatcher, CostModel)
+from elasticsearch_tpu.serving.batcher import IDLE, CombiningBatcher
 from elasticsearch_tpu.telemetry import metrics as _telemetry_metrics
 from elasticsearch_tpu.telemetry import stage as _stage
-from elasticsearch_tpu.vectors.host_corpus import HostFieldCorpus, packed_nbytes
-
-# host int8 mirrors are built for corpora whose packed+rescore footprint is
-# below this (3 bytes/element); larger corpora serve from the device only
-HOST_MIRROR_MAX_BYTES = 512_000_000
 
 # below this many rows the exhaustive matmul beats IVF routing overhead;
 # tpu_ivf fields smaller than this quietly serve exhaustive
@@ -91,12 +84,12 @@ class _InflightSlot:
 class FieldCorpus:
     """Device corpus for one vector field + host-side row maps."""
 
-    __slots__ = ("corpus", "row_map", "metric", "dims", "version", "host",
+    __slots__ = ("corpus", "row_map", "metric", "dims", "version",
                  "router", "mesh_state", "gens", "encoding", "rescore",
                  "rescore_oversample", "rescore_candidates", "source")
 
     def __init__(self, corpus, row_map: np.ndarray, metric: str, dims: int,
-                 version: tuple, host=None, router=None, mesh_state=None,
+                 version: tuple, router=None, mesh_state=None,
                  gens=None, encoding: str = "bf16", rescore: bool = False,
                  rescore_oversample: int = 4,
                  rescore_candidates: int = 128, source=None):
@@ -105,7 +98,6 @@ class FieldCorpus:
         self.metric = metric
         self.dims = dims
         self.version = version        # cache key: segment/tombstone fingerprint
-        self.host = host              # HostFieldCorpus latency mirror (or None)
         self.router = router          # ann.IVFRouter (tpu_ivf engine) or None
         # parallel.sharded_knn.ShardedFieldState: the mesh-resident
         # row-sharded copy + slot maps (None when the mesh router would
@@ -186,7 +178,6 @@ def device_corpus_nbytes(n_rows: int, dims: int, dtype: str) -> int:
 
 class VectorStoreShard:
     def __init__(self, dtype: str = "bf16",
-                 host_mirror_max_bytes: int = HOST_MIRROR_MAX_BYTES,
                  knn_engine: str = "tpu", knn_nlist=None,
                  knn_nprobe="auto", knn_recall_target: float = 0.95,
                  warmup: Optional[bool] = None, topup: bool = True,
@@ -201,7 +192,6 @@ class VectorStoreShard:
                  semantic_cache_size: int = 128,
                  semantic_cache_threshold: float = 0.995):
         self.dtype = dtype
-        self.host_mirror_max_bytes = host_mirror_max_bytes
         self.knn_engine = knn_engine        # "tpu" (exhaustive) | "tpu_ivf"
         self.knn_nlist = knn_nlist          # None = pick_nlist(n)
         self.knn_nprobe = knn_nprobe        # "auto" | int
@@ -285,8 +275,7 @@ class VectorStoreShard:
         self.knn_stats: Dict[str, int] = {
             "searches": 0, "ivf_searches": 0, "fallback_searches": 0,
             "ivf_trains": 0, "ivf_restores": 0,
-            "mesh_searches": 0, "host_mirror_searches": 0,
-            "fused_probe_searches": 0,
+            "mesh_searches": 0, "fused_probe_searches": 0,
             "rescore_searches": 0, "rescore_window_rows": 0,
             "rescore_promoted": 0, "rescore_nanos": 0,
             "route_nanos": 0, "score_nanos": 0, "merge_nanos": 0,
@@ -481,15 +470,6 @@ class VectorStoreShard:
             else:
                 corpus = knn_ops.build_corpus(
                     full, metric=metric, dtype=dtype, residual=residual)
-            host = None
-            # quantized fields score their packed encoding on the device;
-            # a bf16-rescored host mirror would make result quality depend
-            # on routing — skip it so the route stays invisible to callers
-            if (native.AVAILABLE
-                    and dtype not in ("int8", "int4", "binary")
-                    and packed_nbytes(len(row_map), mapper.dims)
-                    <= self.host_mirror_max_bytes):
-                host = HostFieldCorpus(full, metric)
             router = None
             if (self._field_engine(mapper) == "tpu_ivf"
                     and len(row_map) >= IVF_MIN_ROWS):
@@ -582,7 +562,7 @@ class VectorStoreShard:
                     GenerationalCorpus, TieredMergePolicy)
                 gens = GenerationalCorpus.from_monolithic(
                     corpus, row_map, view.as_source(), metric, dtype,
-                    residual, mapper.dims, host=host, router=router,
+                    residual, mapper.dims, router=router,
                     mesh_state=mesh_state,
                     policy=TieredMergePolicy(self.segments_tier_size,
                                              self.segments_max_l0),
@@ -596,16 +576,14 @@ class VectorStoreShard:
                         "nlist": opts.get("nlist", self.knn_nlist),
                         "nprobe": opts.get("nprobe", self.knn_nprobe),
                         "recall_target": self.knn_recall_target,
-                        "min_rows": IVF_MIN_ROWS,
-                        "host_mirror_max_bytes":
-                            self.host_mirror_max_bytes})
+                        "min_rows": IVF_MIN_ROWS})
             with self._views_lock:
                 if gens is not None:
                     self._gens[field] = gens
                 self._fields[field] = FieldCorpus(
                     corpus, row_map, metric, mapper.dims, version,
-                    host=host, router=router, mesh_state=mesh_state,
-                    gens=gens, encoding=dtype, rescore=plan["rescore"],
+                    router=router, mesh_state=mesh_state, gens=gens,
+                    encoding=dtype, rescore=plan["rescore"],
                     rescore_oversample=plan["rescore_oversample"],
                     rescore_candidates=plan["rescore_candidates"],
                     source=view.as_source())
@@ -696,7 +674,6 @@ class VectorStoreShard:
         enc = plan.get("encoding", gc.dtype)
         return FieldCorpus(
             base.corpus, snap.row_map, metric, dims, version,
-            host=base.host if snap.simple else None,
             router=base.router, mesh_state=base.mesh_state, gens=gc,
             encoding=enc,
             rescore=plan.get("rescore", enc in ("int4", "binary")),
@@ -852,14 +829,6 @@ class VectorStoreShard:
     def field(self, name: str) -> Optional[FieldCorpus]:
         return self._fields.get(name)
 
-    def pending_requests(self, field: str) -> int:
-        """Queued-but-unexecuted searches across this field's batchers —
-        the coalescing signal the mesh-vs-host cost router folds into its
-        batch-size estimate."""
-        with self._batchers_lock:
-            return sum(b.pending() for key, b in self._batchers.items()
-                       if key[0] == field)
-
     def _begin_dispatch(self) -> int:
         """Count this dispatch in flight; returns how many OTHERS were
         already in flight (the dp router's concurrency half of the load
@@ -953,9 +922,8 @@ class VectorStoreShard:
         bitset from a boolean query; host → device additive mask).
 
         Concurrent callers coalesce through a per-(field, k) combining
-        batcher into ONE dispatch, which a cost model routes to either the
-        host VNNI mirror or the device matmul program (serving/batcher.py) —
-        the round-3 path paid a full device round-trip per query.
+        batcher into ONE device dispatch (serving/batcher.py) — the
+        round-3 path paid a full device round-trip per query.
         """
         fc = self._fields.get(field)
         if fc is None or fc.corpus is None or len(fc.row_map) == 0:
@@ -1007,7 +975,7 @@ class VectorStoreShard:
         ONE dispatch — the hybrid plan's kNN leg. Where `search` relies on
         concurrent callers colliding in the combining batcher, this entry
         is for a caller that already holds a batch (the hybrid executor's
-        runner thread) and wants exactly one device/host round-trip."""
+        runner thread) and wants exactly one device round-trip."""
         return self.finalize_many(
             self.search_many_async(field, requests, k, precision=precision,
                                    num_candidates=num_candidates))
@@ -1019,9 +987,9 @@ class VectorStoreShard:
         the device program and return an opaque handle whose un-synced
         arrays `finalize_many` lands later — the hybrid executor's
         pipelined score stage (host RRF/hydrate of batch N overlaps the
-        device dispatch of batch N+1). Routes that are host-side or that
-        sync internally (host mirror, IVF, mesh) complete here and the
-        handle is already final; results are byte-identical either way."""
+        device dispatch of batch N+1). A route that syncs internally
+        (IVF) completes here and the handle is already final; results
+        are byte-identical either way."""
         fc = self._fields.get(field)
         if fc is None or fc.corpus is None or len(fc.row_map) == 0:
             return ("done", [(np.zeros(0, dtype=np.int64),
@@ -1165,9 +1133,9 @@ class VectorStoreShard:
                              num_candidates: Optional[int] = None):
         """Route, build masks, and LAUNCH the device program. The
         exhaustive device paths (single-device AND mesh) return
-        un-synced arrays in the handle; host/IVF routes complete here
-        (they are host-side or sync internally). Tracks the in-flight
-        gauge the dp router reads."""
+        un-synced arrays in the handle; the IVF route syncs internally
+        and completes here. Tracks the in-flight gauge the dp router
+        reads."""
         # `serving.device_dispatch` in three: `dispatch.prepare` (the
         # host's work on the batch before a byte moves: the in-flight
         # books, stack, route, pad, mask), then what the chosen route
@@ -1211,8 +1179,7 @@ class VectorStoreShard:
             base = snap.generations[0]
             if base.corpus is not fc.corpus or fc.source is None:
                 fc = FieldCorpus(base.corpus, base.row_map, fc.metric,
-                                 fc.dims, fc.version, host=base.host,
-                                 router=base.router,
+                                 fc.dims, fc.version, router=base.router,
                                  mesh_state=base.mesh_state,
                                  gens=fc.gens, encoding=fc.encoding,
                                  rescore=fc.rescore,
@@ -1252,7 +1219,7 @@ class VectorStoreShard:
         # mesh router: a corpus past the policy's row floor with a
         # sharded resident copy serves as ONE SPMD program (shard-local
         # matmul + ICI all-gather merge); everything else takes the
-        # single-device / host paths below. With dp > 1 the policy also
+        # single-device path below. With dp > 1 the policy also
         # picks the dp-vs-shard split from this batch's bucket and the
         # live load (queued requests + other in-flight dispatches) — a
         # loaded queue routes to one dp group so concurrent batches
@@ -1270,27 +1237,6 @@ class VectorStoreShard:
                                           precision, mesh,
                                           rescore_ctx=rescore_ctx)
             mesh_policy.reclassify_single("knn_k_deeper_than_shard")
-
-        use_host = (fc.host is not None and precision != "f32"
-                    and rescore_ctx is None
-                    and CostModel.prefer_host(len(requests), fc.host.n,
-                                              fc.host.dims))
-        if use_host:
-            self.knn_stats["host_mirror_searches"] += 1
-            mask = None
-            if any_filter:
-                mask = np.ones((len(requests), n_valid), dtype=bool)
-                for i, (_, fr) in enumerate(requests):
-                    if fr is not None:
-                        mask[i] = np.isin(fc.row_map, fr)
-
-            def host_route():
-                scores, ids = fc.host.search(queries, k_eff, mask=mask)
-                return ("done",
-                        self._land_results(fc, np.asarray(scores),
-                                           np.asarray(ids), -np.inf,
-                                           n_valid, len(requests)))
-            return host_route
 
         queries = _pad_batch(queries, len(requests))
         b_pad = len(queries)

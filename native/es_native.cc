@@ -15,407 +15,9 @@
 // Every function is allocation-free: callers pass numpy-owned buffers.
 
 #include <algorithm>
-#include <thread>
-#include <vector>
-#include <cmath>
 #include <cstdint>
-#include <cstdlib>
-#include <cstring>
-
-#if defined(__x86_64__)
-#include <immintrin.h>
-#endif
-
-namespace {
-
-// ---------------------------------------------------------------------------
-// Host-side int8 kNN (the latency serving path).
-//
-// A TPU dispatch costs a fixed host<->device round trip; for corpora small
-// enough that one CPU pass beats that overhead, the serving layer routes
-// searches here instead (serving/batcher.py's cost model). The reference has
-// no analog -- Lucene scores vectors per-doc in Java (ScoreScriptUtils.java);
-// this kernel is a cache-blocked u8*i8 GEMM + per-query top-k heap, using
-// AVX512-VNNI (vpdpbusd) when the host has it.
-//
-// Layout: the corpus is PRE-PACKED into 16-row groups, interleaved so one
-// 64-byte load covers 4 dims x 16 rows: pack[g][j][row 0..15][4 dims], with
-// j in [0, d4), d4 = row_stride/4 -- stored u8 with a +128 offset so the
-// corpus sits in vpdpbusd's UNSIGNED operand. Queries are quantized i8 and
-// stay compact ([16][d4*4], L1-resident); each inner step feeds vpdpbusd an
-// EMBEDDED 4-byte broadcast of the query (m32{1to16}), so the loop is one
-// 64B corpus load + 16 broadcast-fused vpdpbusd over 16 register
-// accumulators -- port-throughput bound, and the only streamed operand is
-// the corpus itself.
-//
-//   dot(q, row) ~ qscale * rscale * (sum(qi8 * (r+128)u8) - 128 * sum(qi8))
-//   score       = dot_mul * dot + row_bias[row]
-//
-// (the +128 correction is per-QUERY, a scalar hoisted out of the row loop;
-// cosine/dot: dot_mul 1, bias null; l2: dot_mul 2, bias = -||row||^2.)
-// Per-row metadata arrays are padded to ng*16 entries by the caller.
-
-struct TopK {
-    // (score desc, row asc) -- same tie-break as es_topk_f32 below
-    float* s;
-    int32_t* r;
-    int64_t k, size;
-    inline bool better(float xs, int32_t xr, float ys, int32_t yr) const {
-        if (xs != ys) return xs > ys;
-        return xr < yr;
-    }
-    inline void sift_up(int64_t i) {
-        while (i > 0) {
-            int64_t p = (i - 1) >> 1;
-            // heap top = worst retained; parent must be <= child
-            if (better(s[p], r[p], s[i], r[i])) {
-                std::swap(s[p], s[i]);
-                std::swap(r[p], r[i]);
-                i = p;
-            } else break;
-        }
-    }
-    inline void sift_down() {
-        int64_t i = 0;
-        for (;;) {
-            int64_t l = 2 * i + 1, m = i;
-            if (l < size && better(s[m], r[m], s[l], r[l])) m = l;
-            if (l + 1 < size && better(s[m], r[m], s[l + 1], r[l + 1])) m = l + 1;
-            if (m == i) break;
-            std::swap(s[m], s[i]);
-            std::swap(r[m], r[i]);
-            i = m;
-        }
-    }
-    inline void push(float score, int32_t row) {
-        if (size < k) {
-            s[size] = score; r[size] = row;
-            ++size;
-            sift_up(size - 1);
-        } else if (better(score, row, s[0], r[0])) {
-            // full (score desc, row asc) comparison — for the row-ascending
-            // scan this equals `score > s[0]`, but the cross-thread merge
-            // pushes candidates in heap-array order, where a score tie must
-            // still prefer the smaller row or the merged result would
-            // depend on the partition
-            s[0] = score; r[0] = row;
-            sift_down();
-        }
-    }
-};
-
-struct KnnPArgs {
-    const float* queries; int64_t b, d;
-    const uint8_t* packed; int64_t n, d4;   // u8, +128 offset
-    const float* row_scales;   // [ng*16]
-    const float* row_bias;     // null or [ng*16]
-    float dot_mul;
-    const uint8_t* mask;       // null, [ng*16] shared, or [b][mask_stride]
-    int64_t mask_stride;       // 0 = shared
-    int64_t k;
-    float* out_scores;         // [b, k]
-    int32_t* out_rows;         // [b, k]
-};
-
-// Quantize one query group to compact i8 rows ([qi][d4*4], zero-padded) --
-// small enough to stay L1-resident; the VNNI loop broadcasts 4-byte groups
-// straight from it via vpdpbusd's embedded-broadcast memory operand.
-void quantize_queries_i8(const float* q, int64_t nb, int64_t d, int64_t d4,
-                         int8_t* qi8, float* qscales, int32_t* qsums) {
-    std::memset(qi8, 0, 16 * d4 * 4);
-    for (int64_t qi = 0; qi < 16; ++qi) {
-        qscales[qi] = 1.0f;
-        qsums[qi] = 0;
-        if (qi >= nb) continue;
-        const float* row = q + qi * d;
-        float amax = 0.0f;
-        for (int64_t j = 0; j < d; ++j)
-            amax = std::max(amax, std::fabs(row[j]));
-        const float scale = amax > 0.0f ? amax / 127.0f : 1.0f;
-        qscales[qi] = scale;
-        const float inv = 1.0f / scale;
-        int32_t sum = 0;
-        for (int64_t j = 0; j < d; ++j) {
-            int32_t v = static_cast<int32_t>(std::lround(row[j] * inv));
-            v = std::min(std::max(v, -127), 127);
-            qi8[qi * d4 * 4 + j] = static_cast<int8_t>(v);
-            sum += v;
-        }
-        qsums[qi] = sum;
-    }
-}
-
-inline void emit_topk(TopK& h, int64_t k, float* os, int32_t* orow) {
-    for (int64_t x = 0; x < k; ++x) { os[x] = -INFINITY; orow[x] = -1; }
-    while (h.size > 0) {  // pop worst-first into descending positions
-        os[h.size - 1] = h.s[0];
-        orow[h.size - 1] = h.r[0];
-        h.s[0] = h.s[h.size - 1];
-        h.r[0] = h.r[h.size - 1];
-        --h.size;
-        h.sift_down();
-    }
-}
-
-void knn_i8p_scalar(const KnnPArgs& a) {
-    const int64_t ng = (a.n + 15) / 16;
-    float* hs = new float[16 * a.k];
-    int32_t* hr = new int32_t[16 * a.k];
-    int8_t* qi8 = new int8_t[16 * a.d4 * 4];
-    for (int64_t q0 = 0; q0 < a.b; q0 += 16) {
-        const int64_t nb = std::min<int64_t>(16, a.b - q0);
-        float qscales[16];
-        int32_t qsums[16];
-        quantize_queries_i8(a.queries + q0 * a.d, nb, a.d, a.d4,
-                            qi8, qscales, qsums);
-        TopK heaps[16];
-        for (int64_t qi = 0; qi < nb; ++qi)
-            heaps[qi] = TopK{hs + qi * a.k, hr + qi * a.k, a.k, 0};
-        for (int64_t g = 0; g < ng; ++g) {
-            const int64_t lanes = std::min<int64_t>(16, a.n - g * 16);
-            const uint8_t* gp = a.packed + g * a.d4 * 64;
-            for (int64_t qi = 0; qi < nb; ++qi) {
-                const int8_t* qrow = qi8 + qi * a.d4 * 4;
-                const float corr = 128.0f * static_cast<float>(qsums[qi]);
-                const float qmul = qscales[qi] * a.dot_mul;
-                for (int64_t t = 0; t < lanes; ++t) {
-                    const int64_t r = g * 16 + t;
-                    if (a.mask) {
-                        const uint8_t* mrow = a.mask_stride
-                            ? a.mask + (q0 + qi) * a.mask_stride : a.mask;
-                        if (!mrow[r]) continue;
-                    }
-                    int32_t acc = 0;
-                    for (int64_t j = 0; j < a.d4; ++j) {
-                        const uint8_t* rb = gp + j * 64 + t * 4;
-                        for (int64_t u = 0; u < 4; ++u)
-                            acc += static_cast<int32_t>(qrow[j * 4 + u]) *
-                                   static_cast<int32_t>(rb[u]);
-                    }
-                    float s = (static_cast<float>(acc) - corr) * qmul;
-                    s = s * a.row_scales[r] +
-                        (a.row_bias ? a.row_bias[r] : 0.0f);
-                    heaps[qi].push(s, static_cast<int32_t>(r));
-                }
-            }
-        }
-        for (int64_t qi = 0; qi < nb; ++qi)
-            emit_topk(heaps[qi], a.k,
-                      a.out_scores + (q0 + qi) * a.k,
-                      a.out_rows + (q0 + qi) * a.k);
-    }
-    delete[] qi8;
-    delete[] hs;
-    delete[] hr;
-}
-
-#if defined(__x86_64__)
-// One 16-query x [g_lo, g_hi) row-group scan with private heaps — the unit
-// a worker thread executes. Scores are identical however the range is
-// partitioned, and TopK's (score desc, row asc) tie-break makes the merged
-// result bit-identical to the single-threaded scan.
-__attribute__((target("avx512f,avx512bw,avx512vl,avx512vnni")))
-void knn_i8p_vnni_range(const KnnPArgs& a, const int8_t* qi8,
-                        const float* qscales, const int32_t* qsums,
-                        int64_t q0, int64_t nb,
-                        int64_t g_lo, int64_t g_hi, int64_t ng,
-                        TopK* heaps, float* heapmin) {
-    {
-        const bool shared_mask = a.mask && a.mask_stride == 0;
-        const int64_t qstride = a.d4 * 4;
-        for (int64_t g = g_lo; g < g_hi; ++g) {
-            uint16_t gmask = 0xFFFF;
-            if (g == ng - 1 && (a.n & 15))
-                gmask = static_cast<uint16_t>((1u << (a.n & 15)) - 1);
-            if (shared_mask) {
-                const __m128i mb = _mm_loadu_si128(
-                    reinterpret_cast<const __m128i*>(a.mask + g * 16));
-                gmask &= _mm_test_epi8_mask(mb, mb);
-                if (!gmask) continue;
-            }
-            const uint8_t* gp = a.packed + g * a.d4 * 64;
-            // named accumulators: an acc ARRAY makes gcc keep it in stack
-            // memory, storing every zmm each iteration -- 16 named locals
-            // stay in registers (32 zmm available under AVX512). The query
-            // operand is a 4-byte embedded broadcast (m32{1to16}) from the
-            // compact L1-resident qi8 rows; the only streamed load per step
-            // is the 64B corpus line.
-#define ES_ACC_EACH(OP) \
-    OP(0) OP(1) OP(2) OP(3) OP(4) OP(5) OP(6) OP(7) \
-    OP(8) OP(9) OP(10) OP(11) OP(12) OP(13) OP(14) OP(15)
-#define ES_ACC_DECL(i) __m512i acc##i = _mm512_setzero_si512();
-            ES_ACC_EACH(ES_ACC_DECL)
-            for (int64_t j = 0; j < a.d4; ++j) {
-                // stream the corpus ~1.5KB ahead: the VM's hardware
-                // prefetcher alone leaves the scan demand-miss bound
-                _mm_prefetch(reinterpret_cast<const char*>(gp + j * 64 + 1536),
-                             _MM_HINT_T0);
-                const __m512i rv = _mm512_loadu_si512(gp + j * 64);
-                const int8_t* qj = qi8 + j * 4;
-                int32_t qw;
-#define ES_ACC_DP(i) \
-    std::memcpy(&qw, qj + i * qstride, 4); \
-    acc##i = _mm512_dpbusd_epi32(acc##i, rv, _mm512_set1_epi32(qw));
-                ES_ACC_EACH(ES_ACC_DP)
-#undef ES_ACC_DP
-            }
-            __m512i acc[16];
-#define ES_ACC_STORE(i) acc[i] = acc##i;
-            ES_ACC_EACH(ES_ACC_STORE)
-#undef ES_ACC_STORE
-#undef ES_ACC_DECL
-#undef ES_ACC_EACH
-            const __m512 scales16 = _mm512_loadu_ps(a.row_scales + g * 16);
-            const __m512 bias16 = a.row_bias
-                ? _mm512_loadu_ps(a.row_bias + g * 16) : _mm512_setzero_ps();
-            for (int64_t qi = 0; qi < nb; ++qi) {
-                __m512 sc = _mm512_sub_ps(
-                    _mm512_cvtepi32_ps(acc[qi]),
-                    _mm512_set1_ps(128.0f * static_cast<float>(qsums[qi])));
-                sc = _mm512_mul_ps(sc, _mm512_set1_ps(qscales[qi] * a.dot_mul));
-                // mul+add (not fmadd) so scores bit-match the scalar path
-                sc = _mm512_add_ps(_mm512_mul_ps(sc, scales16), bias16);
-                uint16_t m = gmask & _mm512_cmp_ps_mask(
-                    sc, _mm512_set1_ps(heapmin[qi]), _CMP_GT_OQ);
-                if (!m) continue;
-                if (a.mask && a.mask_stride) {
-                    const __m128i mb = _mm_loadu_si128(
-                        reinterpret_cast<const __m128i*>(
-                            a.mask + (q0 + qi) * a.mask_stride + g * 16));
-                    m &= _mm_test_epi8_mask(mb, mb);
-                    if (!m) continue;
-                }
-                alignas(64) float svals[16];
-                _mm512_store_ps(svals, sc);
-                TopK& h = heaps[qi];
-                do {
-                    const int lane = __builtin_ctz(m);
-                    h.push(svals[lane], static_cast<int32_t>(g * 16 + lane));
-                    m &= static_cast<uint16_t>(m - 1);
-                } while (m);
-                if (h.size == a.k) heapmin[qi] = h.s[0];
-            }
-        }
-    }
-}
-
-__attribute__((target("avx512f,avx512bw,avx512vl,avx512vnni")))
-void knn_i8p_vnni(const KnnPArgs& a) {
-    const int64_t ng = (a.n + 15) / 16;
-    int8_t* qi8 = static_cast<int8_t*>(
-        ::operator new(16 * a.d4 * 4, std::align_val_t(64)));
-    // thread count: scale with the scan volume (dpbusd steps) so tiny
-    // corpora never pay thread spawn; ES_NATIVE_THREADS pins it
-    int64_t nthreads = 1;
-    const int64_t work = ng * a.d4;
-    if (work >= (64 << 10)) {
-        unsigned hc = std::thread::hardware_concurrency();
-        nthreads = std::min<int64_t>(hc ? hc : 1, 8);
-        nthreads = std::min<int64_t>(nthreads, work / (32 << 10) + 1);
-    }
-    if (const char* env = std::getenv("ES_NATIVE_THREADS")) {
-        const long v = std::strtol(env, nullptr, 10);
-        if (v > 0) nthreads = std::min<long>(v, 64);
-    }
-    nthreads = std::min<int64_t>(nthreads, std::max<int64_t>(ng, 1));
-
-    std::vector<float> hs(static_cast<size_t>(nthreads) * 16 * a.k);
-    std::vector<int32_t> hr(static_cast<size_t>(nthreads) * 16 * a.k);
-    // hoisted out of the block loop; re-initialized per 16-query block.
-    // Threads are (re)spawned per block: the per-block scan is >= ~0.3 ms
-    // per worker at the engagement threshold, so spawn cost stays a few
-    // percent — a pool would only matter for very large query batches
-    std::vector<TopK> heaps(static_cast<size_t>(nthreads) * 16);
-    std::vector<float> heapmin(static_cast<size_t>(nthreads) * 16);
-    for (int64_t q0 = 0; q0 < a.b; q0 += 16) {
-        const int64_t nb = std::min<int64_t>(16, a.b - q0);
-        float qscales[16];
-        int32_t qsums[16];
-        quantize_queries_i8(a.queries + q0 * a.d, nb, a.d, a.d4,
-                            qi8, qscales, qsums);
-        std::fill(heapmin.begin(), heapmin.end(), -INFINITY);
-        for (int64_t t = 0; t < nthreads; ++t)
-            for (int64_t qi = 0; qi < nb; ++qi)
-                heaps[t * 16 + qi] = TopK{
-                    hs.data() + (t * 16 + qi) * a.k,
-                    hr.data() + (t * 16 + qi) * a.k, a.k, 0};
-        if (nthreads == 1) {
-            knn_i8p_vnni_range(a, qi8, qscales, qsums, q0, nb, 0, ng, ng,
-                               heaps.data(), heapmin.data());
-        } else {
-            const int64_t per = (ng + nthreads - 1) / nthreads;
-            std::vector<std::thread> workers;
-            workers.reserve(static_cast<size_t>(nthreads));
-            for (int64_t t = 0; t < nthreads; ++t) {
-                const int64_t lo = t * per;
-                const int64_t hi = std::min(ng, lo + per);
-                if (lo >= hi) break;
-                workers.emplace_back([&, t, lo, hi]() {
-                    knn_i8p_vnni_range(a, qi8, qscales, qsums, q0, nb,
-                                       lo, hi, ng,
-                                       heaps.data() + t * 16,
-                                       heapmin.data() + t * 16);
-                });
-            }
-            for (auto& w : workers) w.join();
-            // ordered merge into thread 0's heaps: TopK's total order on
-            // (score, row) makes the result partition-independent
-            for (int64_t qi = 0; qi < nb; ++qi) {
-                TopK& dst = heaps[qi];
-                for (int64_t t = 1; t < nthreads; ++t) {
-                    TopK& src = heaps[t * 16 + qi];
-                    for (int64_t x = 0; x < src.size; ++x)
-                        dst.push(src.s[x], src.r[x]);
-                }
-            }
-        }
-        for (int64_t qi = 0; qi < nb; ++qi)
-            emit_topk(heaps[qi], a.k,
-                      a.out_scores + (q0 + qi) * a.k,
-                      a.out_rows + (q0 + qi) * a.k);
-    }
-    ::operator delete(qi8, std::align_val_t(64));
-}
-#endif
-
-}  // namespace
 
 extern "C" {
-
-// Batched int8 kNN over a 16-row-interleaved packed corpus (see the layout
-// comment above; `packed` is u8 with a +128 offset). scores[b,k] /
-// rows[b,k], -inf/-1 padding. queries must be metric-prepped f32; per-row
-// arrays padded to ceil(n/16)*16.
-void es_knn_i8p_topk(const float* queries, int64_t b, int64_t d,
-                     const uint8_t* packed, int64_t n, int64_t d4,
-                     const float* row_scales, const float* row_bias,
-                     float dot_mul,
-                     const uint8_t* mask, int64_t mask_stride, int64_t k,
-                     float* out_scores, int32_t* out_rows) {
-    KnnPArgs a{queries, b, d, packed, n, d4, row_scales,
-               row_bias, dot_mul, mask, mask_stride, k,
-               out_scores, out_rows};
-#if defined(__x86_64__)
-    if (__builtin_cpu_supports("avx512vnni") &&
-        __builtin_cpu_supports("avx512bw")) {
-        knn_i8p_vnni(a);
-        return;
-    }
-#endif
-    knn_i8p_scalar(a);
-}
-
-// 1 when es_knn_i8p_topk will take the VNNI path on this host, 0 when it
-// falls back to the ~100x-slower scalar loop (the serving cost model prices
-// the scan accordingly).
-int32_t es_knn_i8p_has_vnni(void) {
-#if defined(__x86_64__)
-    return __builtin_cpu_supports("avx512vnni") &&
-           __builtin_cpu_supports("avx512bw") ? 1 : 0;
-#else
-    return 0;
-#endif
-}
 
 // Fused BM25: score[i] = boost * idf * (k1+1) * f / (f + k1*(1-b+b*len/avg))
 // (reference formula: LuceneBM25Similarity; queries.py:137 numpy version)

@@ -132,8 +132,8 @@ def node():
     n = Node(tempfile.mkdtemp())
     # aggs cost-router OFF: its probe legs add wall-clock between the
     # warm/cached/off searches, which lets the background merge's
-    # host-mirror flip (a different f32 reduce order) land INSIDE a
-    # parity triple instead of between rounds
+    # install (a different f32 reduce order) land INSIDE a parity
+    # triple instead of between rounds
     n.settings["search.aggs.cost_router"] = "false"
     mappings = {"properties": {
         "body": {"type": "text"},

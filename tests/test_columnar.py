@@ -292,7 +292,6 @@ class TestDeltaRefresh:
         rng = np.random.default_rng(SEED)
         mapper = _mapper()
         vstore = VectorStoreShard(segments_enabled=True,
-                                  host_mirror_max_bytes=0,
                                   segments_background_merge=False)
         astore = AggFieldStore(warmup=False)
         segs = [_seg(0, 0, rng.standard_normal((32, DIMS))
@@ -386,12 +385,10 @@ class TestMergeDoesNotPin:
         rng = np.random.default_rng(SEED)
         mapper = _mapper()
         gen_store = VectorStoreShard(segments_enabled=True,
-                                     host_mirror_max_bytes=0,
                                      segments_background_merge=False,
                                      segments_tier_size=2,
                                      segments_max_l0=2)
-        mono = VectorStoreShard(segments_enabled=False,
-                                host_mirror_max_bytes=0)
+        mono = VectorStoreShard(segments_enabled=False)
         segs = [_seg(0, 0, rng.standard_normal((64, DIMS))
                      .astype(np.float32))]
         for i in range(4):
@@ -431,7 +428,6 @@ class TestMergeDoesNotPin:
         rng = np.random.default_rng(SEED)
         mapper = _mapper()
         store = VectorStoreShard(segments_enabled=True,
-                                 host_mirror_max_bytes=0,
                                  segments_background_merge=False)
         segs = [_seg(0, 0, rng.standard_normal((32, DIMS))
                      .astype(np.float32))]

@@ -342,7 +342,7 @@ class TestDonationSafety:
 class TestDispatcherMechanics:
     def test_tracer_calls_inline(self):
         """A dispatched kernel inside an enclosing jit inlines instead of
-        touching the executable cache (bench_matrix's scan wrapper)."""
+        touching the executable cache."""
         import jax
         import jax.numpy as jnp
         from elasticsearch_tpu.ops import topk as topk_ops
@@ -415,3 +415,63 @@ class TestDispatcherMechanics:
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         assert dispatch.DEFAULT_COMPILE_CACHE_DIR == os.path.join(
             repo, ".jax_cache")
+
+
+# ---------------------------------------------------------------------------
+# no kernel key without a caller
+# ---------------------------------------------------------------------------
+
+def _const_str(node):
+    import ast
+    return node.value if (isinstance(node, ast.Constant)
+                          and isinstance(node.value, str)) else None
+
+
+def test_every_knn_kernel_key_has_a_caller_in_the_package():
+    """An executable nobody serves is a compile at every boot and a key
+    the next reader has to rule out. Each `knn.*` / `segments.knn` key
+    is named by a `dispatch.call` in a package module other than the one
+    that registers it, or reached through a public function of its own
+    module that names the key and that another package module uses."""
+    import ast
+    import pathlib
+
+    import elasticsearch_tpu.ops.pallas_knn_binned  # noqa: F401 registers
+    import elasticsearch_tpu.segments  # noqa: F401 registers
+    keys = {name for name in dispatch.DISPATCH._kernels
+            if name.startswith("knn.") or name == "segments.knn"}
+    assert {"knn.exact", "knn.binned", "knn.binned_rescored_packed",
+            "segments.knn"} <= keys
+    assert not {"knn.binned_rescored", "knn.binned_rescored_hybrid"} & keys
+
+    package = pathlib.Path(dispatch.__file__).resolve().parents[1]
+    registered_in, called_in, wrappers, used = {}, {}, {}, {}
+    for path in package.rglob("*.py"):
+        mod = str(path.relative_to(package))
+        tree = ast.parse(path.read_text())
+        used[mod] = ({n.id for n in ast.walk(tree)
+                      if isinstance(n, ast.Name)}
+                     | {n.attr for n in ast.walk(tree)
+                        if isinstance(n, ast.Attribute)})
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute) and node.args):
+                key = _const_str(node.args[0])
+                if key in keys and node.func.attr == "register":
+                    registered_in[key] = mod
+                elif key in keys and node.func.attr in ("call",
+                                                        "call_async"):
+                    called_in.setdefault(key, set()).add(mod)
+        for fn in tree.body:
+            if (isinstance(fn, ast.FunctionDef)
+                    and not fn.name.startswith("_")):
+                for key in keys & {_const_str(n) for n in ast.walk(fn)}:
+                    wrappers.setdefault((key, mod), set()).add(fn.name)
+    for key in sorted(keys):
+        home = registered_in[key]
+        elsewhere = called_in.get(key, set()) - {home}
+        through = {fn for fn in wrappers.get((key, home), ())
+                   if any(fn in names for mod, names in used.items()
+                          if mod != home)}
+        assert elsewhere or through, \
+            f"[{key}] (registered in {home}) has no caller in the package"

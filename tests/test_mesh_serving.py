@@ -281,18 +281,11 @@ def _strip_took(resp):
 
 class TestRestParity:
     def test_knn_and_rrf_response_parity_and_strict_second_pass(
-            self, mesh_serving, monkeypatch):
+            self, mesh_serving):
         """One node, three serving legs (exact kNN, IVF via a second
         index, fused rank.rrf), each compared mesh-vs-single-device
         through the REST-facing search entry, then re-run under strict
-        dispatch asserting the sharded grid is closed (zero compiles).
-
-        The host int8 latency mirror is pinned OFF: the mesh replaces the
-        DEVICE path, so that's the parity oracle (host-vs-device parity
-        has its own suite in test_serving.py)."""
-        from elasticsearch_tpu.serving.batcher import CostModel
-        monkeypatch.setattr(CostModel, "prefer_host",
-                            staticmethod(lambda *a, **kw: False))
+        dispatch asserting the sharded grid is closed (zero compiles)."""
         node, rng = _make_node(tempfile.mkdtemp())
         try:
             qv = rng.standard_normal(16).tolist()
@@ -599,8 +592,7 @@ class TestDpReplicatedServing:
             "group_dispatches"]
         assert len(spread) == 2  # dispatches landed on both groups
 
-    def test_replica_consistent_merge_graduation(self, mesh_serving_dp,
-                                                 monkeypatch):
+    def test_replica_consistent_merge_graduation(self, mesh_serving_dp):
         """Generational merge graduation under dp > 1: a search
         dispatched BEFORE the install keeps one coherent (old) snapshot;
         after the install every dp replica serves the merged corpus
@@ -609,10 +601,6 @@ class TestDpReplicatedServing:
         from elasticsearch_tpu.parallel import mesh as mesh_lib
         from elasticsearch_tpu.parallel.sharded_knn import (
             distributed_knn_search)
-        from elasticsearch_tpu.serving.batcher import CostModel
-
-        monkeypatch.setattr(CostModel, "prefer_host",
-                            staticmethod(lambda *a, **kw: False))
         node, rng = _make_node(tempfile.mkdtemp(), n=600, seed=23)
         try:
             store = node.indices.get("m").shards[0].vector_store
@@ -728,16 +716,11 @@ class TestDpReplicatedServing:
             before["out_of_grid_compiles"]
         assert after["hits"] > before["hits"]
 
-    def test_dp_serving_through_store_parity(self, mesh_serving_dp,
-                                             monkeypatch):
+    def test_dp_serving_through_store_parity(self, mesh_serving_dp):
         """End-to-end through Node.search on the (dp=2, shard=4) mesh:
         responses byte-identical to the mesh-off single-device path, and
         the mesh router actually routed (the store feeds batch + live
         queue depth into the dp split)."""
-        from elasticsearch_tpu.serving.batcher import CostModel
-
-        monkeypatch.setattr(CostModel, "prefer_host",
-                            staticmethod(lambda *a, **kw: False))
         node, rng = _make_node(tempfile.mkdtemp(), n=800, seed=25)
         try:
             qv = rng.standard_normal(16).tolist()

@@ -1,16 +1,21 @@
 """Nothing on the serving path may answer from another route behind the
 caller's back: a device error fails the shard the response reports, a
 cost-model input that cannot be had raises, a mesh that was asked for
-and cannot be built raises."""
+and cannot be built raises, and one engine scores vectors: the device."""
 
+import ast
+import inspect
+import pathlib
 import tempfile
 
 import numpy as np
 import pytest
 
 from elasticsearch_tpu.common.errors import SearchPhaseExecutionError
+from elasticsearch_tpu.ops import dispatch
 from elasticsearch_tpu.ops import knn as knn_ops
-from elasticsearch_tpu.serving import batcher
+
+PACKAGE = pathlib.Path(knn_ops.__file__).resolve().parents[1]
 
 
 @pytest.fixture()
@@ -103,42 +108,74 @@ def test_device_agg_error_fails_the_shard_and_is_counted(node, monkeypatch):
     assert engine.stats["host_nodes"] == 0
 
 
-def test_peak_table_knows_v5e_and_refuses_an_unknown_tpu(monkeypatch):
-    import jax
+def test_one_engine_scores_vectors(node):
+    """There is no second kNN engine on the host: no mirror beside a
+    synced bf16 field, no module, no native symbol, no option."""
+    import importlib
 
-    class Dev:
-        def __init__(self, platform, kind):
-            self.platform, self.device_kind = platform, kind
+    from elasticsearch_tpu import native
+    from elasticsearch_tpu.segments.generation import Generation
+    from elasticsearch_tpu.vectors.store import FieldCorpus, VectorStoreShard
+    n, _rng = node
+    store = n.indices.get("a").shards[0].vector_store
+    fc = store.field("v")
+    assert fc.encoding == "bf16" and fc.corpus is not None
+    assert isinstance(fc, FieldCorpus) and not hasattr(fc, "host")
+    (base,) = fc.gens.snapshot().generations
+    assert isinstance(base, Generation) and not hasattr(base, "host")
+    assert "host" not in FieldCorpus.__slots__ + Generation.__slots__
+    with pytest.raises(ModuleNotFoundError):
+        importlib.import_module("elasticsearch_tpu.vectors.host_corpus")
+    native.require()
+    assert b"es_knn_" not in pathlib.Path(native._SO_PATH).read_bytes()
+    assert not hasattr(native, "knn_i8p_topk")
+    assert "host_mirror_max_bytes" not in inspect.signature(
+        VectorStoreShard.__init__).parameters
 
-    def peak_for(dev):
-        monkeypatch.setattr(jax, "devices", lambda *a: [dev])
-        return batcher.device_peak_ops()
 
-    assert peak_for(Dev("tpu", "TPU v5 lite")) == 197.0e12
-    assert batcher.DEVICE_PEAKS["TPU v5 lite"] == (197.0e12, 393.0e12,
-                                                   819.0e9)
-    with pytest.raises(RuntimeError, match="no published peak"):
-        peak_for(Dev("tpu", "TPU v9 imaginary"))
+def test_route_is_the_fields_not_the_loads(node):
+    """Which program answers, and what it answers, depend on the field
+    and the request, not on how many requests ride along: one query
+    alone and as row 0 of a batch of 16 takes the same kind of handle
+    and lands the same ids and scores."""
+    n, rng = node
+    store = n.indices.get("a").shards[0].vector_store
+    fc = store.field("v")
+    queries = rng.standard_normal((16, 8)).astype(np.float32)
+    landed = []
+    for reqs in ([(queries[0], None)], [(q, None) for q in queries]):
+        handle = store._dispatch_many(fc, 10, "bf16", reqs, field="v")
+        landed.append((handle[0], store.finalize_many(handle)[0]))
+    (kind_1, (rows_1, scores_1)), (kind_16, (rows_16, scores_16)) = landed
+    assert kind_1 == kind_16 == "pending"
+    np.testing.assert_array_equal(rows_1, rows_16)
+    np.testing.assert_array_max_ulp(scores_1, scores_16, maxulp=1)
 
 
-def test_cpu_backend_never_prefers_the_host_mirror():
-    """With the CPU as JAX's backend there is no device to price: the
-    route is the device one by that fact, at any size and batch."""
-    assert batcher.device_peak_ops() is None
-    for batch, rows in ((1, 128), (1, 4096), (64, 1 << 20)):
-        assert batcher.CostModel.prefer_host(batch, rows, 128) is False
+def test_ops_does_not_import_serving():
+    """`ops/` is the layer under `serving/`: it imports nothing of it."""
+    for path in sorted((PACKAGE / "ops").glob("*.py")):
+        for stmt in ast.walk(ast.parse(path.read_text())):
+            names = []
+            if isinstance(stmt, ast.ImportFrom):
+                names = [stmt.module or ""] + [
+                    f"{stmt.module}.{a.name}" for a in stmt.names]
+            elif isinstance(stmt, ast.Import):
+                names = [a.name for a in stmt.names]
+            assert not [m for m in names
+                        if m.startswith("elasticsearch_tpu.serving")], \
+                f"{path.name}:{stmt.lineno} imports serving/"
 
 
 def test_overhead_probe_failure_raises(monkeypatch):
-    from elasticsearch_tpu.ops import dispatch
-    monkeypatch.setattr(batcher, "_overhead_ms", None)
+    monkeypatch.setattr(dispatch, "_overhead_ms", None)
 
     def boom(*a, **kw):
         raise RuntimeError("no backend (injected)")
-    monkeypatch.setattr(dispatch, "call", boom)
+    monkeypatch.setattr(dispatch.DISPATCH, "call", boom)
     with pytest.raises(RuntimeError, match="no backend"):
-        batcher.device_overhead_ms()
-    assert batcher._overhead_ms is None     # nothing latched
+        dispatch.device_overhead_ms()
+    assert dispatch._overhead_ms is None     # nothing latched
 
 
 def test_mesh_enabled_but_unbuildable_raises(monkeypatch):
@@ -170,6 +207,7 @@ def test_nodes_stats_reports_what_ran(node):
     assert dev["platform"] == "cpu" and dev["count"] >= 1
     assert isinstance(dev["device_kind"], str) and dev["device_kind"]
     assert len(dev["memory"]) == dev["count"]
-    assert dev["cost_model"]["device_peak_ops"] is None   # CPU backend
+    assert set(dev["cost_model"]) == {"device_overhead_ms"}
     knn = n.local_node_stats()["indices"]["knn"]
-    assert "host_mirror_searches" in knn
+    # the benchmark's kinds and chip_smoke.py still read this key
+    assert knn["host_mirror_searches"] == 0

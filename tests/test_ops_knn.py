@@ -171,9 +171,9 @@ def test_knn_search_auto_cpu_fallback():
 
 
 def test_binned_rescore_variants_interpret_mode():
-    """Packed-candidate and hybrid rescore agree with (or beat) the base
-    binned kernel's recall against exact f32, and only return valid rows
-    (interpret-mode CPU check of the TPU recall-headroom variants)."""
+    """Packed-candidate rescore agrees with (or beats) the base binned
+    kernel's recall against exact f32, and only returns valid rows
+    (interpret-mode CPU check of the TPU recall-headroom variant)."""
     import jax.numpy as jnp
 
     from elasticsearch_tpu.ops import knn as knn_ops
@@ -202,22 +202,16 @@ def test_binned_rescore_variants_interpret_mode():
     q = jnp.asarray(queries)
     _, i0 = binned.binned_knn_search(q, corpus, k, interpret=True)
     base = recall(i0)
-    for fn in (
-        lambda: binned.binned_knn_search_rescored_packed(
-            q, corpus, k, rescore_candidates=64, interpret=True),
-        lambda: binned.binned_knn_search_rescored_hybrid(
-            q, corpus, k, rescore_bins=4, rescore_candidates=64,
-            interpret=True),
-    ):
-        s, ids = fn()
-        ids = np.asarray(ids)
-        assert ids.shape == (nq, k)
-        assert (ids >= 0).all() and (ids < n).all()
-        # rescoring may only help
-        assert recall(ids) >= base - 1e-9
-        # scores descend
-        s = np.asarray(s)
-        assert (np.diff(s, axis=1) <= 1e-5).all()
+    s, ids = binned.binned_knn_search_rescored_packed(
+        q, corpus, k, rescore_candidates=64, interpret=True)
+    ids = np.asarray(ids)
+    assert ids.shape == (nq, k)
+    assert (ids >= 0).all() and (ids < n).all()
+    # rescoring may only help
+    assert recall(ids) >= base - 1e-9
+    # scores descend
+    s = np.asarray(s)
+    assert (np.diff(s, axis=1) <= 1e-5).all()
 
 
 def test_int8_residual_reconstruction():

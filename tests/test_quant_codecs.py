@@ -201,7 +201,6 @@ def _mapper(otype=None, extra=None):
 
 
 def _store(**kw):
-    kw.setdefault("host_mirror_max_bytes", 0)
     kw.setdefault("segments_background_merge", False)
     return VectorStoreShard(**kw)
 

@@ -267,8 +267,7 @@ def _mapper(otype):
 
 
 def _store():
-    return VectorStoreShard(host_mirror_max_bytes=0,
-                            segments_background_merge=False)
+    return VectorStoreShard(segments_background_merge=False)
 
 
 def test_ivf_layout_restore_skips_training():

@@ -48,13 +48,11 @@ def _mapper(similarity="cosine"):
 
 
 def _stores(**gen_kwargs):
-    """(generational, monolithic) store pair; host mirrors off so both
-    run the DEVICE path — that is the byte-parity oracle (host-vs-device
-    routing parity has its own suite in test_serving.py)."""
-    gen = VectorStoreShard(segments_enabled=True, host_mirror_max_bytes=0,
+    """(generational, monolithic) store pair: the monolithic store is
+    the byte-parity oracle."""
+    gen = VectorStoreShard(segments_enabled=True,
                            segments_background_merge=False, **gen_kwargs)
-    mono = VectorStoreShard(segments_enabled=False,
-                            host_mirror_max_bytes=0)
+    mono = VectorStoreShard(segments_enabled=False)
     return gen, mono
 
 
@@ -251,7 +249,6 @@ class TestGenerationalParity:
     def test_background_merge_thread_drains(self):
         rng = np.random.default_rng(SEED + 5)
         gen = VectorStoreShard(segments_enabled=True,
-                               host_mirror_max_bytes=0,
                                segments_tier_size=3,
                                segments_merge_budget_ms=5.0)
         mapper = _mapper()
@@ -286,8 +283,7 @@ class TestGenerationalParity:
         full-corpus rebuild — now counted + reasoned so the bench can
         hold the pre-subsystem cost against the generational row."""
         rng = np.random.default_rng(SEED + 7)
-        mono = VectorStoreShard(segments_enabled=False,
-                                host_mirror_max_bytes=0)
+        mono = VectorStoreShard(segments_enabled=False)
         mapper = _mapper()
         segs = _corpus_segments(rng, [200, 40])
         mono.sync(ShardReader([SegmentView(segs[0])]), {"v": mapper})
@@ -343,8 +339,7 @@ class TestCopyOnWriteAndGrid:
         gen, _ = _stores()
         mapper = _mapper()
         segs = _corpus_segments(rng, [200, 40])
-        _sync_both(gen, VectorStoreShard(segments_enabled=False,
-                                         host_mirror_max_bytes=0),
+        _sync_both(gen, VectorStoreShard(segments_enabled=False),
                    mapper, [SegmentView(s) for s in segs])
         gc = gen._gens["v"]
         snap = gc.snapshot()

@@ -1,9 +1,6 @@
-"""Serving layer: host VNNI kNN kernel, combining batcher, cost routing.
-
-Covers the round-4 serving redesign: the native int8 packed-corpus kernel
-(native/es_native.cc es_knn_i8p_topk), the HostFieldCorpus mirror with bf16
-rescore, the CombiningBatcher coalescing concurrent requests into one
-dispatch, and the host/device routing inside VectorStoreShard.
+"""Serving layer: the CombiningBatcher coalescing concurrent requests into
+one dispatch, and what VectorStoreShard answers through it, held against
+plain float32 numpy.
 """
 
 import threading
@@ -11,98 +8,12 @@ import threading
 import numpy as np
 import pytest
 
-from elasticsearch_tpu import native
-from elasticsearch_tpu.ops import similarity as sim
-from elasticsearch_tpu.serving.batcher import CombiningBatcher, CostModel
-from elasticsearch_tpu.vectors.host_corpus import HostFieldCorpus
+from elasticsearch_tpu.serving.batcher import CombiningBatcher
 
 
 def _exact_topk(raw, k):
     order = np.lexsort((np.arange(raw.shape[-1]), -raw))
     return order[:k]
-
-
-class TestHostCorpus:
-    def test_cosine_matches_exact_ranking(self):
-        rng = np.random.default_rng(0)
-        vecs = rng.standard_normal((5000, 96)).astype(np.float32)
-        hc = HostFieldCorpus(vecs, sim.COSINE)
-        q = rng.standard_normal((4, 96)).astype(np.float32)
-        scores, rows = hc.search(q, 10)
-        qn = q / np.linalg.norm(q, axis=-1, keepdims=True)
-        vn = vecs / np.linalg.norm(vecs, axis=-1, keepdims=True)
-        exact = qn @ vn.T
-        for i in range(4):
-            ref = set(_exact_topk(exact[i], 10).tolist())
-            got = set(rows[i].tolist())
-            # int8 + bf16 rescore: allow at most 1 swap at the boundary
-            assert len(ref & got) >= 9
-            # scores are raw cosine, descending
-            assert np.all(np.diff(scores[i]) <= 1e-6)
-            assert scores[i][0] == pytest.approx(exact[i].max(), abs=2e-2)
-
-    def test_l2_raw_convention(self):
-        rng = np.random.default_rng(1)
-        vecs = rng.standard_normal((1000, 32)).astype(np.float32)
-        hc = HostFieldCorpus(vecs, sim.L2_NORM)
-        q = rng.standard_normal((2, 32)).astype(np.float32)
-        scores, rows = hc.search(q, 5)
-        for i in range(2):
-            d2 = ((vecs[rows[i]] - q[i]) ** 2).sum(axis=-1)
-            # raw = -||q - c||^2
-            np.testing.assert_allclose(scores[i], -d2, rtol=2e-2, atol=2e-2)
-            ref = np.argsort(d2)
-            assert np.all(np.diff(scores[i]) <= 1e-6)
-
-    def test_shared_and_per_query_masks(self):
-        rng = np.random.default_rng(2)
-        vecs = rng.standard_normal((800, 48)).astype(np.float32)
-        hc = HostFieldCorpus(vecs, sim.COSINE)
-        q = rng.standard_normal((3, 48)).astype(np.float32)
-        shared = rng.random(800) < 0.3
-        _, rows = hc.search(q, 20, mask=shared)
-        assert np.all(shared[rows[rows >= 0]])
-        perq = rng.random((3, 800)) < 0.3
-        _, rows = hc.search(q, 20, mask=perq)
-        for i in range(3):
-            r = rows[i][rows[i] >= 0]
-            assert np.all(perq[i][r])
-
-    def test_fewer_than_k_eligible(self):
-        rng = np.random.default_rng(3)
-        vecs = rng.standard_normal((50, 16)).astype(np.float32)
-        hc = HostFieldCorpus(vecs, sim.COSINE)
-        q = rng.standard_normal((1, 16)).astype(np.float32)
-        mask = np.zeros(50, dtype=bool)
-        mask[:7] = True
-        scores, rows = hc.search(q, 20, mask=mask)
-        got = rows[0][rows[0] >= 0]
-        assert set(got.tolist()) == set(range(7))
-        assert np.all(np.isneginf(scores[0][7:]))
-
-
-@pytest.mark.skipif(not native.AVAILABLE, reason="native kernels unavailable")
-class TestNativeKernelExact:
-    def test_matches_int8_emulation(self):
-        """Kernel scores must equal the exact int8 quantized dot product."""
-        rng = np.random.default_rng(4)
-        n, d, b, k = 3001, 65, 18, 9
-        vecs = rng.standard_normal((n, d)).astype(np.float32)
-        hc = HostFieldCorpus(vecs, sim.DOT_PRODUCT)
-        q = rng.standard_normal((b, d)).astype(np.float32)
-        scores, rows = hc.search(q, k, rescore=False)
-        # emulate: symmetric int8 rows, i8 queries
-        rs = np.abs(vecs).max(axis=1) / 127.0
-        ri = np.clip(np.rint(vecs / rs[:, None]), -127, 127)
-        qs = np.abs(q).max(axis=1) / 127.0
-        qi = np.clip(np.rint(q / qs[:, None]), -127, 127)
-        ref = (qi @ ri.T) * qs[:, None] * rs[None, :]
-        for i in range(b):
-            top = _exact_topk(ref[i].astype(np.float32), k)
-            assert set(rows[i].tolist()) == set(top.tolist())
-            np.testing.assert_allclose(
-                np.sort(scores[i]), np.sort(ref[i][top]).astype(np.float32),
-                rtol=1e-5, atol=1e-5)
 
 
 class TestCombiningBatcher:
@@ -263,7 +174,7 @@ class TestCombiningBatcher:
         assert isinstance(results["bad"], ValueError)
 
 
-def _build_store(n=400, dims=32, seed=5):
+def _build_store(n=400, dims=32, seed=5, similarity="cosine", mat=None):
     from elasticsearch_tpu.index.mapping import DenseVectorFieldMapper
     from elasticsearch_tpu.vectors.store import VectorStoreShard
 
@@ -284,9 +195,10 @@ def _build_store(n=400, dims=32, seed=5):
             self.views = [FakeView(FakeSeg(mat))]
 
     rng = np.random.default_rng(seed)
-    mat = rng.standard_normal((n, dims)).astype(np.float32)
-    mapper = DenseVectorFieldMapper("v", {"dims": dims,
-                                          "similarity": "cosine"})
+    if mat is None:
+        mat = rng.standard_normal((n, dims)).astype(np.float32)
+    mapper = DenseVectorFieldMapper("v", {"dims": mat.shape[1],
+                                          "similarity": similarity})
     store = VectorStoreShard()
     store.sync(FakeReader(mat), {"v": mapper})
     return store, mat, rng
@@ -296,32 +208,99 @@ class TestStoreRouting:
     def _store(self, n=400, dims=32, seed=5):
         return _build_store(n=n, dims=dims, seed=seed)
 
-    def test_host_and_device_paths_agree(self, monkeypatch):
-        store, mat, rng = self._store()
-        q = rng.standard_normal(32).astype(np.float32)
+    def test_cosine_top10_is_the_exact_f32_ranking(self):
+        """On a corpus whose leading cosines sit 0.02 apart (far above
+        bf16's rounding) the served ids ARE the float32 ranking, in
+        order, and the scores the raw cosines."""
+        rng = np.random.default_rng(0)
+        dims, nq, planted = 64, 4, 12
+        mat = rng.standard_normal((600, dims)).astype(np.float32)
+        queries = rng.standard_normal((nq, dims)).astype(np.float32)
+        for i, q in enumerate(queries):
+            u = q / np.linalg.norm(q)
+            for j in range(planted):
+                w = rng.standard_normal(dims).astype(np.float32)
+                w -= (w @ u) * u
+                w /= np.linalg.norm(w)
+                c = 0.98 - 0.02 * j
+                mat[i * planted + j] = 3.0 * (c * u + np.sqrt(1 - c * c) * w)
+        store, mat, _ = _build_store(mat=mat)
+        out = store.search_many("v", [(q, None) for q in queries], 10)
+        vn = mat / np.linalg.norm(mat, axis=-1, keepdims=True)
+        for q, (rows, scores) in zip(queries, out):
+            exact = vn @ (q / np.linalg.norm(q))
+            ref = _exact_topk(exact, 10)
+            np.testing.assert_array_equal(rows, ref)
+            np.testing.assert_allclose(scores, exact[ref], atol=1e-2)
+            assert np.all(np.diff(scores) <= 0)
 
-        monkeypatch.setattr(CostModel, "prefer_host",
-                            classmethod(lambda cls, *a: True))
-        rows_h, scores_h = store.search("v", q, 10)
-        store._batchers.clear()
-        monkeypatch.setattr(CostModel, "prefer_host",
-                            classmethod(lambda cls, *a: False))
-        rows_d, scores_d = store.search("v", q, 10)
-        # same corpus, same query: both paths must retrieve ~the same set
-        assert len(set(rows_h.tolist()) & set(rows_d.tolist())) >= 9
-        np.testing.assert_allclose(scores_h[:5], scores_d[:5], atol=2e-2)
+    @pytest.mark.parametrize("similarity", ["l2_norm", "dot_product"])
+    def test_raw_score_convention(self, similarity):
+        """`l2_norm` lands -||q - c||^2 and `dot_product` q.c: bigger is
+        better, descending, the rows the float32 reference ranks first."""
+        rng = np.random.default_rng(1)
+        mat = rng.standard_normal((1000, 32)).astype(np.float32)
+        queries = rng.standard_normal((2, 32)).astype(np.float32)
+        if similarity == "dot_product":     # the mapping wants unit rows
+            mat /= np.linalg.norm(mat, axis=-1, keepdims=True)
+            queries /= np.linalg.norm(queries, axis=-1, keepdims=True)
+        store, mat, _ = _build_store(similarity=similarity, mat=mat)
+        out = store.search_many("v", [(q, None) for q in queries], 5)
+        for q, (rows, scores) in zip(queries, out):
+            exact = (-((mat - q) ** 2).sum(axis=-1)
+                     if similarity == "l2_norm" else mat @ q)
+            assert len(rows) == 5
+            np.testing.assert_allclose(scores, exact[rows],
+                                       rtol=2e-2, atol=2e-2)
+            assert np.all(np.diff(scores) <= 0)
+            assert len(set(rows.tolist())
+                       & set(_exact_topk(exact, 5).tolist())) >= 4
 
-    def test_filtered_search_respects_filter_on_both_paths(self, monkeypatch):
+    def test_one_batch_mixes_unfiltered_shared_and_per_request_filters(self):
+        """One dispatch, three kinds of request: no filter, two requests
+        under the SAME filter, one under its own. Each is held to its own
+        filter and to the float32 ranking inside it."""
+        store, mat, rng = self._store(n=800, dims=48, seed=2)
+        queries = rng.standard_normal((4, 48)).astype(np.float32)
+        shared = np.flatnonzero(rng.random(800) < 0.3).astype(np.int64)
+        own = np.flatnonzero(rng.random(800) < 0.3).astype(np.int64)
+        filters = [None, shared, shared, own]
+        out = store.search_many("v", list(zip(queries, filters)), 20)
+        vn = mat / np.linalg.norm(mat, axis=-1, keepdims=True)
+        for q, fr, (rows, scores) in zip(queries, filters, out):
+            assert len(rows) == 20
+            exact = vn @ (q / np.linalg.norm(q))
+            if fr is not None:
+                assert np.all(np.isin(rows, fr))
+                outside = np.ones(800, dtype=bool)
+                outside[fr] = False
+                exact[outside] = -np.inf
+            assert len(set(rows.tolist())
+                       & set(_exact_topk(exact, 20).tolist())) >= 18
+
+    def test_fewer_than_k_eligible_returns_them_and_no_padding(self):
+        store, mat, rng = self._store(n=50, dims=16, seed=3)
+        q = rng.standard_normal(16).astype(np.float32)
+        allowed = np.arange(7, dtype=np.int64)
+        (rows, scores), = store.search_many("v", [(q, allowed)], 20)
+        assert sorted(rows.tolist()) == list(range(7))
+        assert len(scores) == 7 and np.all(np.isfinite(scores))
+        assert np.all(scores > -1e37)
+
+    def test_k_larger_than_the_corpus_returns_every_row_once(self):
+        store, mat, rng = self._store(n=50, dims=16, seed=4)
+        q = rng.standard_normal(16).astype(np.float32)
+        (rows, scores), = store.search_many("v", [(q, None)], 200)
+        assert sorted(rows.tolist()) == list(range(50))
+        assert np.all(np.isfinite(scores)) and np.all(np.diff(scores) <= 0)
+
+    def test_filtered_search_respects_filter(self):
         store, mat, rng = self._store()
         q = rng.standard_normal(32).astype(np.float32)
         filter_rows = np.arange(0, 400, 3, dtype=np.int64)
-        for prefer in (True, False):
-            store._batchers.clear()
-            monkeypatch.setattr(CostModel, "prefer_host",
-                                classmethod(lambda cls, *a, _p=prefer: _p))
-            rows, _ = store.search("v", q, 15, filter_rows=filter_rows)
-            assert len(rows) == 15
-            assert np.all(np.isin(rows, filter_rows))
+        rows, _ = store.search("v", q, 15, filter_rows=filter_rows)
+        assert len(rows) == 15
+        assert np.all(np.isin(rows, filter_rows))
 
     def test_concurrent_store_searches(self):
         store, mat, rng = self._store(n=2000)
@@ -394,8 +373,7 @@ class TestContinuousScheduler:
         assert f_far.result(timeout=1) == "far"
         assert executed == [["near"], ["far"]]
 
-    def test_topup_batch_byte_identical_and_zero_recompiles(self,
-                                                            monkeypatch):
+    def test_topup_batch_byte_identical_and_zero_recompiles(self):
         """Late arrivals joining a forming batch at the bucket boundary
         return byte-identical results to the same requests batched up
         front — and the topped-up dispatch compiles NOTHING new (the
@@ -409,8 +387,6 @@ class TestContinuousScheduler:
         from elasticsearch_tpu.serving.batcher import CombiningBatcher
 
         store, mat, rng = _build_store(n=512)
-        monkeypatch.setattr(CostModel, "prefer_host",
-                            classmethod(lambda cls, *a: False))
         queries = rng.standard_normal((8, 32)).astype(np.float32)
         baseline = store.search_many("v", [(q, None) for q in queries], 10)
 
