@@ -465,7 +465,11 @@ class Dispatcher:
         around that fact (the continuous batcher's pipelined dispatch
         stage): it promises the caller launches work and DEFERS the sync
         to response-assembly time, letting batch N's host hydrate overlap
-        batch N+1's device dispatch. Feeds the `async_calls` counter
+        batch N+1's device dispatch. Every read of a result is a round
+        trip to the device and a hand-over of the interpreter lock, so a
+        kernel whose result is read on the host returns ONE array (the
+        served kNN board, `ops/topk.pack_board`) and its caller reads it
+        once. Feeds the `async_calls` counter
         (as does `note_async` for wrapped dispatches)."""
         self.note_async()
         return self.call(name, *args, **static_kwargs)

@@ -349,8 +349,11 @@ def knn_search_auto(
     filter_mask: Optional[jax.Array] = None,
     precision: str = "bf16",
     rescore_candidates: int = 128,
+    board: bool = False,
 ):
-    """Route to the kernel that serves this shape.
+    """Route to the kernel that serves this shape. `board`: the result as
+    ONE array (`topk_ops.pack_board`, packed inside the same program): the
+    form the store's serving call reads back in one crossing.
 
     Preference order:
       1. binned Pallas kernel where `binned_route` says it serves (TPU,
@@ -378,10 +381,11 @@ def knn_search_auto(
             # default-oversample value
             return binned.binned_knn_search_rescored_packed(
                 queries, corpus, k, metric=metric,
-                rescore_candidates=rescore_candidates)
-        return binned.binned_knn_search(queries, corpus, k, metric=metric)
+                rescore_candidates=rescore_candidates, board=board)
+        return binned.binned_knn_search(queries, corpus, k, metric=metric,
+                                        board=board)
     return knn_search(queries, corpus, k, metric=metric, filter_mask=filter_mask,
-                      precision=precision)
+                      precision=precision, board=board)
 
 
 def _knn_search_impl(
@@ -392,7 +396,15 @@ def _knn_search_impl(
     metric: str = sim.COSINE,
     precision: str = "bf16",
     block_size: Optional[int] = None,
+    board: bool = False,
 ):
+    pair = _knn_pair(queries, corpus, filter_mask, k, metric, precision,
+                     block_size)
+    return topk_ops.pack_board(*pair) if board else pair
+
+
+def _knn_pair(queries, corpus, filter_mask, k, metric, precision,
+              block_size):
     n_pad = corpus.matrix.shape[0]
     q = _prep_queries(queries, metric)
     # cosine corpus rows are already normalized; its sq_norms are 1 for valid
@@ -451,7 +463,7 @@ def _grid_knn(statics, sigs) -> bool:
 
 dispatch.DISPATCH.register(
     "knn.exact", _knn_search_impl,
-    static_argnames=("k", "metric", "precision", "block_size"),
+    static_argnames=("k", "metric", "precision", "block_size", "board"),
     grid_check=_grid_knn)
 
 
@@ -463,6 +475,7 @@ def knn_search(
     filter_mask: Optional[jax.Array] = None,
     precision: str = "bf16",
     block_size: Optional[int] = None,
+    board: bool = False,
 ):
     """Exact top-k search of `queries` [Q, D] against `corpus`.
 
@@ -471,7 +484,7 @@ def knn_search(
 
     Returns (scores [Q, k] raw similarity, ids [Q, k] int32 row indices).
     Padded / filtered-out rows return score NEG_INF (callers treat those as
-    "fewer than k hits").
+    "fewer than k hits"). With `board`, the pair as one packed array.
 
     Executes through the shape-bucketed dispatch cache (`ops/dispatch.py`):
     serving callers pad queries to pow-2 buckets and round k up the bucket
@@ -479,4 +492,5 @@ def knn_search(
     """
     return dispatch.call("knn.exact", queries, corpus, filter_mask,
                          k=k, metric=metric, precision=precision,
-                         block_size=block_size)
+                         block_size=block_size,
+                         **topk_ops.board_static(board))

@@ -38,6 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from elasticsearch_tpu.ops import dispatch
 from elasticsearch_tpu.ops import similarity as sim
+from elasticsearch_tpu.ops import topk as topk_ops
 from elasticsearch_tpu.ops.knn import Corpus, _prep_queries
 
 BLOCK_N = 8192
@@ -175,9 +176,11 @@ def _tile_patterns(n_pad: int, num_valid) -> tuple:
     return valid, tpat
 
 
-def _binned_impl(queries, corpus, k: int, metric: str, interpret: bool):
+def _binned_impl(queries, corpus, k: int, metric: str, interpret: bool,
+                 board: bool = False):
     packed, _q = _binned_packed(queries, corpus, metric, interpret)
-    return _decode(packed, k)
+    pair = _decode(packed, k)
+    return topk_ops.pack_board(*pair) if board else pair
 
 
 def _grid_binned(statics, sigs) -> bool:
@@ -186,9 +189,10 @@ def _grid_binned(statics, sigs) -> bool:
                                    limit=sigs[1][0][0]))
 
 
-dispatch.DISPATCH.register("knn.binned", _binned_impl,
-                           static_argnames=("k", "metric", "interpret"),
-                           grid_check=_grid_binned)
+dispatch.DISPATCH.register(
+    "knn.binned", _binned_impl,
+    static_argnames=("k", "metric", "interpret", "board"),
+    grid_check=_grid_binned)
 
 
 def binned_knn_search(
@@ -197,20 +201,24 @@ def binned_knn_search(
     k: int,
     metric: str = sim.COSINE,
     interpret: Optional[bool] = None,
+    board: bool = False,
 ):
     """Approximate (recall ≈ 1 - C(k,2)·BIN_SIZE/N) top-k.
 
     Supports dot-metric corpora (cosine pre-normalized / dot_product) in
     bf16/f32 or int8 storage; callers route l2 / filtered / tiny corpora
-    to the exact XLA path. Returns (raw_scores [Q, k], ids [Q, k]).
+    to the exact XLA path. Returns (raw_scores [Q, k], ids [Q, k]), or
+    with `board` the two as one packed array (`topk_ops.pack_board`).
     interpret=None auto-detects (interpret mode off TPU backends).
     """
     return dispatch.call("knn.binned", queries, corpus, k=k, metric=metric,
-                         interpret=dispatch.pallas_interpret(interpret))
+                         interpret=dispatch.pallas_interpret(interpret),
+                         **topk_ops.board_static(board))
 
 
 def _rescored_packed_impl(queries, corpus, k: int, metric: str,
-                          rescore_candidates: int, interpret: bool):
+                          rescore_candidates: int, interpret: bool,
+                          board: bool = False):
     packed, q = _binned_packed(queries, corpus, metric, interpret)
     nq, ncols = packed.shape
     cand_s = jax.lax.bitcast_convert_type(
@@ -226,12 +234,14 @@ def _rescored_packed_impl(queries, corpus, k: int, metric: str,
     valid = rows < corpus.num_valid
     scores = jnp.where(valid, scores, -jnp.inf)
     vals, p2 = jax.lax.top_k(scores, k)
-    return vals, jnp.take_along_axis(rows, p2, axis=1)
+    ids = jnp.take_along_axis(rows, p2, axis=1)
+    return topk_ops.pack_board(vals, ids) if board else (vals, ids)
 
 
 dispatch.DISPATCH.register(
     "knn.binned_rescored_packed", _rescored_packed_impl,
-    static_argnames=("k", "metric", "rescore_candidates", "interpret"),
+    static_argnames=("k", "metric", "rescore_candidates", "interpret",
+                     "board"),
     grid_check=_grid_binned)
 
 
@@ -242,6 +252,7 @@ def binned_knn_search_rescored_packed(
     metric: str = sim.COSINE,
     rescore_candidates: int = 128,
     interpret: Optional[bool] = None,
+    board: bool = False,
 ):
     """Binned pass + re-scoring of the top PACKED candidates with the
     unquantized query.
@@ -256,7 +267,8 @@ def binned_knn_search_rescored_packed(
     return dispatch.call("knn.binned_rescored_packed", queries, corpus,
                          k=k, metric=metric,
                          rescore_candidates=rescore_candidates,
-                         interpret=dispatch.pallas_interpret(interpret))
+                         interpret=dispatch.pallas_interpret(interpret),
+                         **topk_ops.board_static(board))
 
 
 # v5e has 128 MiB of VMEM per core; the compiler's default scoped limit is

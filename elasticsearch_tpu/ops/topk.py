@@ -14,9 +14,45 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from elasticsearch_tpu.ops import dispatch
 from elasticsearch_tpu.ops.similarity import NEG_INF
+
+
+# ---------------------------------------------------------------------------
+# The served board: one array a batch, so its result crosses to the host
+# in one read. This module owns the format: kernels pack with `pack_board`,
+# `vectors/store.py`'s finalizer and the generational fan-out's legs read
+# it back with `split_board`.
+# ---------------------------------------------------------------------------
+
+def pack_board(scores: jax.Array, ids: jax.Array) -> jax.Array:
+    """(float32 scores [Q, k], int32 ids [Q, k]) -> int32 [Q, 2k]: the
+    scores' bit patterns beside the ids. The last operation INSIDE the
+    program that computed the pair: no second launch, no arithmetic."""
+    return jnp.concatenate(
+        [jax.lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.int32),
+         ids.astype(jnp.int32)], axis=1)
+
+
+def split_board(board):
+    """`pack_board` undone, (scores, ids). On the host (a numpy board, as
+    the store's finalizer reads it) two views of the one buffer, no copy;
+    on a device array two un-synced slices, for a caller that composes
+    further device work on the pair (the generational fan-out's legs)."""
+    k = board.shape[1] // 2
+    scores, ids = board[:, :k], board[:, k:]
+    if isinstance(board, np.ndarray):
+        return scores.view(np.float32), ids
+    return jax.lax.bitcast_convert_type(scores, jnp.float32), ids
+
+
+def board_static(board: bool) -> dict:
+    """The static argument that asks a kernel for `pack_board`'s form. It
+    joins a dispatch key only when set, so the pair's keys (and the
+    executables every other caller warmed) stay what they were."""
+    return {"board": True} if board else {}
 
 
 def _top_k_impl(scores: jax.Array, k: int):
@@ -29,12 +65,13 @@ def _masked_top_k_impl(scores: jax.Array, mask: jax.Array, k: int):
 
 
 def _merge_top_k_impl(scores_blocks: jax.Array, index_blocks: jax.Array,
-                      k: int):
+                      k: int, board: bool = False):
     b, q, kb = scores_blocks.shape
     flat_scores = jnp.transpose(scores_blocks, (1, 0, 2)).reshape(q, b * kb)
     flat_ids = jnp.transpose(index_blocks, (1, 0, 2)).reshape(q, b * kb)
     vals, pos = jax.lax.top_k(flat_scores, k)
-    return vals, jnp.take_along_axis(flat_ids, pos, axis=1)
+    ids = jnp.take_along_axis(flat_ids, pos, axis=1)
+    return pack_board(vals, ids) if board else (vals, ids)
 
 
 def _grid_topk(statics, sigs) -> bool:
@@ -54,7 +91,7 @@ dispatch.DISPATCH.register("topk.top_k", _top_k_impl,
 dispatch.DISPATCH.register("topk.masked_top_k", _masked_top_k_impl,
                            static_argnames=("k",), grid_check=_grid_topk)
 dispatch.DISPATCH.register("topk.merge_top_k", _merge_top_k_impl,
-                           static_argnames=("k",))
+                           static_argnames=("k", "board"))
 
 
 def top_k(scores: jax.Array, k: int):
@@ -74,15 +111,18 @@ def masked_top_k(scores: jax.Array, mask: jax.Array, k: int):
     return dispatch.call("topk.masked_top_k", scores, mask, k=k)
 
 
-def merge_top_k(scores_blocks: jax.Array, index_blocks: jax.Array, k: int):
+def merge_top_k(scores_blocks: jax.Array, index_blocks: jax.Array, k: int,
+                board: bool = False):
     """Merge per-block top-k results into a global top-k.
 
     scores_blocks: [B, Q, k_b] per-block descending scores
     index_blocks:  [B, Q, k_b] matching global doc ids
-    Returns (scores [Q, k], ids [Q, k]).
+    Returns (scores [Q, k], ids [Q, k]); with `board`, the two as
+    `pack_board`'s one array.
 
     Concatenation is ordered by block (shard) index, so lax.top_k's stability
     gives the reference's tie-break (`mergeTopDocs:221` breaks equal scores by
     shard index).
     """
-    return dispatch.call("topk.merge_top_k", scores_blocks, index_blocks, k=k)
+    return dispatch.call("topk.merge_top_k", scores_blocks, index_blocks, k=k,
+                         **board_static(board))

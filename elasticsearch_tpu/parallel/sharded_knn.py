@@ -44,6 +44,7 @@ from elasticsearch_tpu.ops import dispatch
 from elasticsearch_tpu.ops import knn as knn_ops
 from elasticsearch_tpu.ops import similarity as sim
 from elasticsearch_tpu.ops.similarity import NEG_INF
+from elasticsearch_tpu.ops.topk import board_static
 from elasticsearch_tpu.parallel import layout
 from elasticsearch_tpu.parallel import mesh as mesh_lib
 
@@ -162,11 +163,11 @@ def build_sharded_corpus(
 # ---------------------------------------------------------------------------
 
 def _knn_step(q, mat, sqn, scl, nvalid, fmask, *, k, metric, precision,
-              block_size):
+              block_size, board):
     """Per-shard body: local exact kNN, padding masked OUT before the
     gather (a ragged shard whose num_valid < k would otherwise feed
     aliased padding ids into the merge), then the ICI candidate merge."""
-    from elasticsearch_tpu.ops.topk import merge_top_k
+    from elasticsearch_tpu.ops.topk import merge_top_k, pack_board
 
     # the three scopes name the program's parts in a device trace: the
     # shard's own scoring and top-k, the candidates' way over ICI, and
@@ -189,19 +190,23 @@ def _knn_step(q, mat, sqn, scl, nvalid, fmask, *, k, metric, precision,
         all_s = jax.lax.all_gather(s, mesh_lib.SHARD_AXIS)  # [S, Qdp, k], ICI
         all_i = jax.lax.all_gather(gids, mesh_lib.SHARD_AXIS)
     with jax.named_scope("es.mesh.merge"):
-        return merge_top_k(all_s, all_i, k)
+        pair = merge_top_k(all_s, all_i, k)
+        # every device holds the merged pair: each packs its own copy
+        return pack_board(*pair) if board else pair
 
 
 def _distributed_knn_impl(queries, corpus, filter_mask, k, mesh,
                           metric=sim.COSINE, precision="bf16",
-                          block_size=None):
+                          block_size=None, board=False):
     # in_specs from the SAME rule table that laid the corpus out
     # (parallel/layout.py) — specs can't drift from residency, and the
     # dp axis applies here without widening any hand-built spec
     corpus_specs = layout.in_specs_for(corpus)
-    out_specs = (layout.query_spec(2), layout.query_spec(2))
+    out_specs = (layout.query_spec(2) if board
+                 else (layout.query_spec(2), layout.query_spec(2)))
     step = functools.partial(_knn_step, k=k, metric=metric,
-                             precision=precision, block_size=block_size)
+                             precision=precision, block_size=block_size,
+                             board=board)
     if filter_mask is None:
         def step_nf(q, mat, sqn, scl, nvalid):
             return step(q, mat, sqn, scl, nvalid, None)
@@ -234,7 +239,8 @@ def _grid_mesh_knn(statics, sigs) -> bool:
 
 dispatch.DISPATCH.register(
     "mesh.knn", _distributed_knn_impl,
-    static_argnames=("k", "mesh", "metric", "precision", "block_size"),
+    static_argnames=("k", "mesh", "metric", "precision", "block_size",
+                     "board"),
     grid_check=_grid_mesh_knn)
 
 
@@ -247,13 +253,16 @@ def distributed_knn_search(
     filter_mask: Optional[jax.Array] = None,
     precision: str = "bf16",
     block_size: Optional[int] = None,
+    board: bool = False,
 ):
     """Search queries [Q, D] against a mesh-sharded corpus.
 
     Q must be divisible by the dp axis size. filter_mask is [S * per] (one
     shared searchable-set) or [Q, S * per] (per-query pre-filters).
     Returns (scores [Q, k], global_ids [Q, k]) fully replicated across the
-    mesh; empty/padding slots come back as (-inf, -1).
+    mesh; empty/padding slots come back as (-inf, -1). With `board`, the
+    pair as one array [Q, 2k] (`topk.pack_board`), packed by the same
+    program after its merge: the store's serving form.
 
     Executes through the shape-bucketed dispatch cache (kernel
     ``mesh.knn``, AOT executables keyed on (mesh, bucket)); calls from
@@ -265,7 +274,8 @@ def distributed_knn_search(
     with mesh_lib.launch_guard(mesh):
         return dispatch.call("mesh.knn", queries, corpus, filter_mask,
                              k=k, mesh=mesh, metric=metric,
-                             precision=precision, block_size=block_size)
+                             precision=precision, block_size=block_size,
+                             **board_static(board))
 
 
 # ---------------------------------------------------------------------------
@@ -519,7 +529,8 @@ class ShardedFieldState:
     def warmup_entries(self, dims: int, precision: str = "bf16"):
         """(kernel, arg specs, statics) entries pre-compiling the sharded
         serving grid — mirrors `vectors/store._schedule_warmup` but with
-        mesh-sharded input layouts baked into the AOT specs. Every rung
+        mesh-sharded input layouts baked into the AOT specs, and like it
+        in the packed form the store's serving call launches. Every rung
         of the query ladder up to the grid's top is there (a burst forms
         the rungs between as well, and a compile of this program on the
         serving path is seconds), with the precision the caller serves
@@ -550,7 +561,8 @@ class ShardedFieldState:
                     entries.append((
                         "mesh.knn", (qspec, corpus_spec, None),
                         {"k": k_b, "mesh": mesh, "metric": self.metric,
-                         "precision": precision, "block_size": None}))
+                         "precision": precision, "block_size": None,
+                         "board": True}))
         return entries
 
 

@@ -125,9 +125,11 @@ class GenerationSet:
 
         queries: [B_pad, D] f32, already padded to the query bucket.
         filters: per-request allowed engine-row arrays (or None), length
-        n_real. Returns (scores, flat_ids, phases): un-synced [B_pad,
-        k_t] device boards in the FLAT row space — the caller lands them
-        at response-assembly time (`finalize_many`)."""
+        n_real. Returns (board, phases): the un-synced [B_pad, 2·k_t]
+        packed board (`topk_ops.pack_board`: scores beside ids in the
+        FLAT row space), packed by the merge program itself — the
+        caller lands it in one read at response-assembly time
+        (`finalize_many`)."""
         import jax.numpy as jnp
 
         b_pad = len(queries)
@@ -148,19 +150,20 @@ class GenerationSet:
             board_i.append(ids)
             legs.append(leg)
         if not board_s:
-            return (np.full((b_pad, k_t), _NEG_INF_F32, dtype=np.float32),
-                    np.full((b_pad, k_t), -1, dtype=np.int32),
-                    {"engine": "tpu_generational", "generations": 0})
+            return (topk_ops.pack_board(
+                jnp.full((b_pad, k_t), _NEG_INF_F32, dtype=jnp.float32),
+                jnp.full((b_pad, k_t), -1, dtype=jnp.int32)),
+                {"engine": "tpu_generational", "generations": 0})
         # stable concat in generation order == flat-order tie-break ==
         # the monolithic corpus's lower-row-index tie-break
-        s, i = topk_ops.merge_top_k(jnp.stack(board_s), jnp.stack(board_i),
-                                    k=k_t)
+        board = topk_ops.merge_top_k(jnp.stack(board_s),
+                                     jnp.stack(board_i), k=k_t, board=True)
         phases = {"engine": "tpu_generational",
                   "generations": len(self.generations),
                   "l0_generations": self.l0_count,
                   "tombstoned_rows": self.dead_rows,
                   "legs": legs}
-        return s, i, phases
+        return board, phases
 
     def _search_generation(self, gen: Generation, off: int, qj,
                            queries: np.ndarray, n_real: int, b_pad: int,
@@ -216,11 +219,12 @@ class GenerationSet:
         corpus = knn_ops.resident(gen.corpus)
         if gen.kernel == "knn.exact" and mask is None:
             # the initial base rides the monolithic auto-router (binned
-            # Pallas fast path on TPU, warmed grid) — byte-identical to
-            # the pre-generational serving path by construction
-            s, ids = knn_ops.knn_search_auto(qj, corpus, k=k_g,
-                                             metric=metric,
-                                             precision=precision)
+            # Pallas fast path on TPU) in the serving call's packed form,
+            # which is the one the store's grid warmed — byte-identical
+            # to the pre-generational serving path by construction
+            s, ids = topk_ops.split_board(knn_ops.knn_search_auto(
+                qj, corpus, k=k_g, metric=metric, precision=precision,
+                board=True))
         else:
             s, ids = dispatch.call(gen.kernel, qj, corpus, mask,
                                    k=k_g, metric=metric,
@@ -301,12 +305,12 @@ class GenerationSet:
                 mask = jax.device_put(jnp.asarray(ms.filter_mask(live)),
                                       ms.mask_sharding(1, mesh))
         q = jax.device_put(jnp.asarray(queries), ms.query_sharding(mesh))
-        scores, gids = distributed_knn_search(
-            q, ms.corpus_for(mesh), k_b, mesh, metric=metric,
-            filter_mask=mask, precision=precision)
-        gids.block_until_ready()
-        scores = np.asarray(scores, dtype=np.float32)
-        local = ms.map_ids(np.asarray(gids))   # flat rows of this gen
+        # the packed form: the one the mesh's grid warmed, and one read
+        scores, gids = topk_ops.split_board(np.asarray(
+            distributed_knn_search(
+                q, ms.corpus_for(mesh), k_b, mesh, metric=metric,
+                filter_mask=mask, precision=precision, board=True)))
+        local = ms.map_ids(gids)               # flat rows of this gen
         ids = np.where(local >= 0, local + off, -1).astype(np.int32)
         if k_b < k_t:
             pad = ((0, 0), (0, k_t - k_b))

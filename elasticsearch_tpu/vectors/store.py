@@ -34,6 +34,7 @@ from elasticsearch_tpu.index.segment import ShardReader
 from elasticsearch_tpu.ops import dispatch
 from elasticsearch_tpu.ops import knn as knn_ops
 from elasticsearch_tpu.ops import similarity as sim
+from elasticsearch_tpu.ops import topk as topk_ops
 from elasticsearch_tpu.quant import rescore as quant_rescore
 from elasticsearch_tpu.serving.batcher import IDLE, CombiningBatcher
 from elasticsearch_tpu.telemetry import metrics as _telemetry_metrics
@@ -740,7 +741,8 @@ class VectorStoreShard:
         background thread (warmup-at-open): the first real query of any
         interactive bucket then finds its executable cached instead of
         stalling the serving queue behind an XLA compile. Entries mirror
-        `knn_search_auto`'s routing so the warmed program IS the one the
+        `knn_search_auto`'s routing, in the packed form (`board`) that
+        `_launch_single` asks for, so the warmed program IS the one the
         serving path executes."""
         if fc.corpus is None or not self.warmup_enabled():
             return
@@ -770,17 +772,18 @@ class VectorStoreShard:
                             (qspec, corpus_spec),
                             {"k": k_b, "metric": fc.metric,
                              "rescore_candidates": fc.rescore_candidates,
-                             "interpret": False}))
+                             "interpret": False, "board": True}))
                     else:
                         entries.append((
                             "knn.binned", (qspec, corpus_spec),
                             {"k": k_b, "metric": fc.metric,
-                             "interpret": False}))
+                             "interpret": False, "board": True}))
                 else:
                     entries.append((
                         "knn.exact", (qspec, corpus_spec, None),
                         {"k": k_b, "metric": fc.metric,
-                         "precision": "bf16", "block_size": None}))
+                         "precision": "bf16", "block_size": None,
+                         "board": True}))
         if fc.mesh_state is not None:
             # the sharded serving grid pre-compiles alongside the
             # single-device one, so the first mesh-routed query of any
@@ -1002,10 +1005,12 @@ class VectorStoreShard:
                                    field=field)
 
     def finalize_many(self, handle) -> list:
-        """Land the results of a `search_many_async` handle: one bulk
-        device→host transfer of the score/id boards, then the validity
-        mask + row-map join. The blocking sync lives HERE, at response-
-        assembly time, never inside the dispatch critical section."""
+        """Land the results of a `search_many_async` handle: ONE
+        device→host read of the packed board (`topk_ops.pack_board`,
+        its copy started at the launch), the split into scores and ids
+        on the host, then the validity mask + row-map join. The blocking
+        sync lives HERE, at response-assembly time, never inside the
+        dispatch critical section."""
         kind, payload, *rest = handle
         if kind == "done":
             return payload
@@ -1028,21 +1033,8 @@ class VectorStoreShard:
         try:
             if kind == "mesh":
                 return self._finalize_mesh(payload)
-            fc, s, i, k_eff, n_valid, n_real, rescore_ctx = payload
-            # the two reads below are the ones this path always made, in
-            # their order, each now under its own name. An explicit
-            # `block_until_ready` before them would split waiting from
-            # copying cleanly, and cost a third round trip to the device
-            # and a third hand-over of the interpreter lock a batch
-            # (measured on the chip, PERF.md PR 26): so
-            # `dispatch.sync_wait` is the first read, which waits for what
-            # is left of the device's work and copies the score board,
-            # and `dispatch.d2h` the second, which copies the id board (as
-            # large): the wait alone is their difference
-            with _stage("dispatch.sync_wait"):
-                scores = np.asarray(s)[:, :k_eff]
-            with _stage("dispatch.d2h"):
-                ids = np.asarray(i)[:, :k_eff]
+            fc, board, k_eff, n_valid, n_real, rescore_ctx = payload
+            scores, ids = self._read_board(board, k_eff)
             with _stage("dispatch.land"):
                 if rescore_ctx is not None:
                     # phase two: exact f32 re-rank of the coarse window
@@ -1058,6 +1050,34 @@ class VectorStoreShard:
             # its slot releases the gauge exactly once
             for slot in rest:
                 slot.release()
+
+    @staticmethod
+    def _launched(board):
+        """The end of a launch: start the board's copy to the host while
+        the device still works, so the finalizer's one read finds it on
+        its way. Counts the deferred sync, so `_nodes/stats
+        indices.dispatch` shows how much serving load pipelines."""
+        board.copy_to_host_async()
+        dispatch.DISPATCH.note_async()
+        return board
+
+    @staticmethod
+    def _read_board(board, k_eff: int):
+        """A served batch's ONE crossing down, on every exhaustive route:
+        `dispatch.sync_wait` is the read, which waits for what is left of
+        the device's work and of the copy `_launched` started (every
+        crossing more costs a round trip to the device and a hand-over
+        of the interpreter lock a batch; an explicit `block_until_ready`
+        alone was worth 2.7 ms at the median, PERF.md PR 26), and
+        `dispatch.d2h` what is left of bringing the result down, the
+        split of the board on the host. Returns (scores, ids), each
+        [B_pad, k_eff]."""
+        with _stage("dispatch.sync_wait"):
+            host = np.asarray(board)
+            _telemetry_metrics.counter("dispatch.host_reads").inc()
+        with _stage("dispatch.d2h"):
+            scores, ids = topk_ops.split_board(host)
+            return scores[:, :k_eff], ids[:, :k_eff]
 
     def _execute_batch(self, fc: FieldCorpus, k: int, precision: str,
                        requests, num_candidates: Optional[int] = None,
@@ -1132,10 +1152,10 @@ class VectorStoreShard:
                              precision: str, requests,
                              num_candidates: Optional[int] = None):
         """Route, build masks, and LAUNCH the device program. The
-        exhaustive device paths (single-device AND mesh) return
-        un-synced arrays in the handle; the IVF route syncs internally
-        and completes here. Tracks the in-flight gauge the dp router
-        reads."""
+        exhaustive device paths (single-device AND mesh) return one
+        un-synced packed board in the handle; the IVF route syncs
+        internally and completes here. Tracks the in-flight gauge the
+        dp router reads."""
         # `serving.device_dispatch` in three: `dispatch.prepare` (the
         # host's work on the batch before a byte moves: the in-flight
         # books, stack, route, pad, mask), then what the chosen route
@@ -1257,12 +1277,14 @@ class VectorStoreShard:
                        m: Optional[np.ndarray], k_eff: int, n_valid: int,
                        n_real: int, precision: str, rescore_ctx):
         """The single-device exhaustive route after its preparation:
-        upload, then launch WITHOUT syncing."""
-        import jax.numpy as jnp
+        launch WITHOUT syncing. The padded queries ride the launch as
+        host numpy (the dispatcher keys them like a device array), so
+        `dispatch.h2d` holds only what is uploaded ahead of it: a
+        filter's [Q, N] mask, else nothing."""
+        import jax
 
         with _stage("dispatch.h2d"):
-            mask = None if m is None else jnp.asarray(m)
-            q = jnp.asarray(queries)
+            mask = None if m is None else jax.device_put(m)
         # k rounds up the dispatch bucket ladder so a workload that
         # sweeps k (10, 12, 13, ...) reuses one compiled program per
         # rung; the extra columns slice away at finalize (top-k prefixes
@@ -1270,15 +1292,11 @@ class VectorStoreShard:
         k_b = dispatch.bucket_k(k_eff,
                                 limit=fc.corpus.matrix.shape[0])
         with _stage("dispatch.launch"):
-            s, i = knn_ops.knn_search_auto(
-                q, knn_ops.resident(fc.corpus), k=k_b, metric=fc.metric,
-                filter_mask=mask, precision=precision,
-                rescore_candidates=fc.rescore_candidates)
-            # un-synced: s/i are device futures until finalize_many
-            # reads them — count the deferred sync so `_nodes/stats
-            # indices.dispatch` shows how much serving load pipelines
-            dispatch.DISPATCH.note_async()
-        return ("pending", (fc, s, i, k_eff, n_valid, n_real,
+            board = self._launched(knn_ops.knn_search_auto(
+                queries, knn_ops.resident(fc.corpus), k=k_b,
+                metric=fc.metric, filter_mask=mask, precision=precision,
+                rescore_candidates=fc.rescore_candidates, board=True))
+        return ("pending", (fc, board, k_eff, n_valid, n_real,
                             rescore_ctx))
 
     def _dispatch_generational(self, snap, fc: FieldCorpus, k: int,
@@ -1288,9 +1306,10 @@ class VectorStoreShard:
         `merge_top_k` (`segments/generational.py`) — the serving shape
         between merges: L0 seals and tombstoned generations search as a
         stable-ordered board merge, byte-identical to the monolithic
-        corpus. Returns a pending handle whose flat-space boards land in
-        `finalize_many` (the snapshot rides in the handle, so a merge
-        installing mid-flight cannot swap the row map under us)."""
+        corpus. Returns a pending handle whose flat-space board (packed
+        by the merge program) lands in `finalize_many` (the snapshot
+        rides in the handle, so a merge installing mid-flight cannot
+        swap the row map under us)."""
         n_valid = len(snap.row_map)
         queries_real = np.stack([q for q, _ in requests])
         k_req = min(k, snap.total_pad)
@@ -1313,15 +1332,14 @@ class VectorStoreShard:
         queries = _pad_batch(queries_real, len(requests))
         self.knn_stats["searches"] += 1
         self.last_knn_phases = {}
-        s, i, phases = snap.search_async(
+        board, phases = snap.search_async(
             queries, len(requests), k_eff, [fr for _, fr in requests],
             fc.metric, precision, num_candidates=num_candidates,
             knn_stats=self.knn_stats)
         self.last_knn_phases = phases
-        # un-synced boards: the device sync happens at response-assembly
+        # un-synced board: the device sync happens at response-assembly
         # time in finalize_many, like the monolithic pipelined path
-        dispatch.DISPATCH.note_async()
-        return ("pending", (snap, s, i, k_eff, n_valid, len(requests),
+        return ("pending", (snap, self._launched(board), k_eff, n_valid, len(requests),
                             rescore_ctx))
 
     @staticmethod
@@ -1385,7 +1403,7 @@ class VectorStoreShard:
         (the corpus view for a group is a free re-layout of the
         dp-replicated arrays). Pads and masks on the host (inside the
         caller's `dispatch.prepare`) and returns the launch, which
-        returns an UN-SYNCED handle: the device
+        returns an UN-SYNCED handle (one packed board): the device
         sync lands in `_finalize_mesh` at response-assembly time, so
         batch N's merge overlaps batch N+1's dispatch — with dp > 1 the
         overlapping dispatch runs on a DIFFERENT device group, which is
@@ -1424,38 +1442,36 @@ class VectorStoreShard:
                      b_pad: int, n_valid: int, n_real: int, t0: int,
                      precision: str, rescore_ctx):
         import jax
-        import jax.numpy as jnp
 
         from elasticsearch_tpu.parallel.sharded_knn import (
             distributed_knn_search)
 
+        # the host arrays go to their devices in ONE placement each (no
+        # staging copy on device 0 that is re-laid out from there). The
+        # placement stays a call of its own: a sharded argument has to
+        # carry its `NamedSharding` into the dispatcher's key, or the
+        # warmed executable is missed
         with _stage("dispatch.h2d"):
             mask = None if m is None else jax.device_put(
-                jnp.asarray(m), ms.mask_sharding(2, mesh))
-            q = jax.device_put(jnp.asarray(queries),
-                               ms.query_sharding(mesh))
+                m, ms.mask_sharding(2, mesh))
+            q = jax.device_put(queries, ms.query_sharding(mesh))
         with _stage("dispatch.launch"):
-            scores, gids = distributed_knn_search(
+            board = self._launched(distributed_knn_search(
                 q, ms.corpus_for(mesh), k_b, mesh, metric=fc.metric,
-                filter_mask=mask, precision=precision)
-        # un-synced boards: the device sync is deferred to finalize
-        dispatch.DISPATCH.note_async()
-        return ("mesh", (fc, ms, mesh, scores, gids, k_eff, k_b, b_pad,
+                filter_mask=mask, precision=precision, board=True))
+        return ("mesh", (fc, ms, mesh, board, k_eff, k_b, b_pad,
                          n_valid, n_real, t0, rescore_ctx))
 
     def _finalize_mesh(self, payload) -> list:
-        """Land one mesh dispatch: device sync, k slice-back, slot-map
-        join, and the router/leg accounting."""
+        """Land one mesh dispatch: the one read of its packed board (the
+        read is the wait: no `block_until_ready` ahead of it), k
+        slice-back, slot-map join, and the router/leg accounting."""
         from elasticsearch_tpu.parallel import mesh as mesh_lib
         from elasticsearch_tpu.parallel import policy as mesh_policy
 
-        (fc, ms, mesh, scores, gids, k_eff, k_b, b_pad, n_valid, n_real,
+        (fc, ms, mesh, board, k_eff, k_b, b_pad, n_valid, n_real,
          t0, rescore_ctx) = payload
-        with _stage("dispatch.sync_wait") as wait:
-            gids.block_until_ready()
-        with _stage("dispatch.d2h"):
-            scores = np.asarray(scores)[:, :k_eff]
-            gids = np.asarray(gids)[:, :k_eff]
+        scores, gids = self._read_board(board, k_eff)
         rescore_info = None
         with _stage("dispatch.land") as land:
             flat = ms.map_ids(gids)
@@ -1469,9 +1485,9 @@ class VectorStoreShard:
             out = self._land_results(fc, scores, flat, -1e37, n_valid,
                                      n_real)
         # the profile's phase split, from the stages' own clock
-        # readings: dispatch start -> boards ready, then d2h + merge
-        t1 = wait.start_ns + wait.nanos
-        t2 = land.start_ns + land.nanos
+        # readings: dispatch start -> board on the host, then the merge
+        t1 = land.start_ns
+        t2 = t1 + land.nanos
         n_shards = mesh_lib.shard_size(mesh)
         gather = mesh_policy.gather_bytes(n_shards, b_pad, k_b)
         mesh_policy.record_leg("knn", gather)

@@ -237,10 +237,57 @@ def test_x64_agg_kernel_compiles(one_chip):
     assert total.dtype == jnp.float64
 
 
+# ---------------------------------------------------------------------------
+# the served form: every serving kernel returns ONE packed board
+# ---------------------------------------------------------------------------
+
+def _board_binned(sh, nq):
+    # the benchmark's corpus: 290,000 x 256-d bf16 rows in 36 kernel tiles
+    return _compile(
+        binned._binned_impl, ("k", "metric", "interpret", "board"),
+        _sds(sh, (nq, 256), jnp.float32),
+        _corpus_spec(sh, 36 * binned.BLOCK_N, 256, jnp.bfloat16),
+        k=10, metric=sim.COSINE, interpret=False, board=True)
+
+
+def _board_rescored_packed(sh, nq):
+    return _compile(
+        binned._rescored_packed_impl,
+        ("k", "metric", "rescore_candidates", "interpret", "board"),
+        _sds(sh, (nq, 768), jnp.float32),
+        _corpus_spec(sh, N_ROWS, 768, jnp.int8, residual=True),
+        k=10, metric=sim.COSINE, rescore_candidates=128, interpret=False,
+        board=True)
+
+
+def _board_exact_filtered(sh, nq):
+    n = 1 << 18
+    return _compile(
+        knn_ops._knn_search_impl,
+        ("k", "metric", "precision", "block_size", "board"),
+        _sds(sh, (nq, 128), jnp.float32),
+        _corpus_spec(sh, n, 128, jnp.bfloat16),
+        _sds(sh, (nq, n), jnp.bool_),
+        k=10, metric=sim.COSINE, precision="bf16", block_size=None,
+        board=True)
+
+
+@pytest.mark.parametrize("nq", [1, 8])
+@pytest.mark.parametrize("served", [_board_binned, _board_rescored_packed,
+                                    _board_exact_filtered],
+                         ids=["knn.binned", "knn.binned_rescored_packed",
+                              "knn.exact-filtered"])
+def test_served_board_compiles(one_chip, served, nq):
+    """The form the store launches (`board=True`): the pair packed into
+    one int32 [Q, 2k] array as the program's last operation."""
+    (board,) = jax.tree_util.tree_leaves(served(one_chip, nq).out_info)
+    assert board.dtype == jnp.int32 and board.shape == (nq, 20)
+
+
 def test_four_shard_mesh_knn_compiles(topo):
-    """`mesh.knn` on a Mesh of the four described devices: int8 768-d
-    rows sharded over the `shard` axis, shard-local exact kNN, the
-    candidate merge an all-gather over ICI."""
+    """`mesh.knn` (as the store launches it) on a Mesh of the four
+    described devices: int8 768-d rows sharded over the `shard` axis,
+    shard-local exact kNN, the candidate merge an all-gather over ICI."""
     mesh = mesh_lib.make_mesh(num_shards=4, dp=1, devices=topo.devices)
     n, d, nq, k = 1 << 22, 768, 64, 10
     from jax.sharding import NamedSharding
@@ -254,10 +301,13 @@ def test_four_shard_mesh_knn_compiles(topo):
         sharding=NamedSharding(mesh, layout.query_spec(2)))
     compiled = _compile(
         sharded_knn._distributed_knn_impl,
-        ("k", "mesh", "metric", "precision", "block_size"),
+        ("k", "mesh", "metric", "precision", "block_size", "board"),
         queries, corpus, None, k=k, mesh=mesh, metric=sim.COSINE,
-        precision="bf16", block_size=None)
+        precision="bf16", block_size=None, board=True)
     assert "all-gather" in compiled.as_text()
+    # the served form: one packed board, the merged pair side by side
+    (board,) = jax.tree_util.tree_leaves(compiled.out_info)
+    assert board.dtype == jnp.int32 and board.shape == (nq, 2 * k)
     # every device holds a quarter of the matrix, not the whole
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert per_device < (n * d) // 2

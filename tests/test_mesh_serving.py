@@ -170,7 +170,11 @@ class TestShardedKnnParity:
                                  background=False)
         before = dispatch.stats(per_bucket=False)
         queries = rng.standard_normal((8, 16)).astype(np.float32)
-        _mesh_knn(state, queries, 10, precision="bf16")
+        # the grid is warmed in the form the store serves: one packed
+        # board, the queries placed from host numpy in one call
+        distributed_knn_search(
+            jax.device_put(queries, state.query_sharding()), state.corpus,
+            10, state.mesh, precision="bf16", board=True)
         after = dispatch.stats(per_bucket=False)
         assert after["compiles"] == before["compiles"]
         assert after["hits"] > before["hits"]
@@ -707,7 +711,7 @@ class TestDpReplicatedServing:
                         .query_sharding(route))
                     distributed_knn_search(q, state.corpus_for(route),
                                            10, route, metric="cosine",
-                                           precision="bf16")
+                                           precision="bf16", board=True)
         finally:
             dispatch.DISPATCH.strict = old_strict
         after = dispatch.stats(per_bucket=False)
