@@ -414,8 +414,12 @@ def _knn_pair(queries, corpus, filter_mask, k, metric, precision,
         valid = valid & filter_mask  # broadcasts [N] or [Q, N]
 
     if block_size is None or block_size >= n_pad:
-        scores = _block_scores(q, corpus.matrix, corpus.sq_norms, corpus.scales, metric, precision)
-        return topk_ops.masked_top_k(scores, valid, k)
+        # two named scopes, so that a profile splits the [Q, N] board from
+        # the selection over it
+        with jax.named_scope("es.knn.exact.score"):
+            scores = _block_scores(q, corpus.matrix, corpus.sq_norms, corpus.scales, metric, precision)
+        with jax.named_scope("es.knn.exact.masked_top_k"):
+            return topk_ops.masked_top_k(scores, valid, k)
 
     # Blocked path: scan corpus tiles with a running top-k. Keeps peak HBM at
     # [Q, block_size] scores instead of [Q, N].

@@ -51,6 +51,7 @@ from elasticsearch_tpu.search.service import (
     ShardSearchResult, execute_fetch_phase, execute_query_phase,
 )
 from elasticsearch_tpu.serving.batcher import BoundedBatcher
+from elasticsearch_tpu.telemetry import stage as _stage
 from elasticsearch_tpu.telemetry import stage_done as _stage_done
 
 DEFAULT_RANK_CONSTANT = 60
@@ -1113,8 +1114,9 @@ class HybridExecutor:
             for _bi, _li, leg in entries:
                 filter_rows = None
                 if leg.filter_spec is not None:
-                    filter_rows = parse_query(
-                        leg.filter_spec).execute(ctx).rows
+                    with _stage("knn.filter_resolve"):
+                        filter_rows = parse_query(
+                            leg.filter_spec).execute(ctx).rows
                 reqs.append((leg.query_vector, filter_rows))
             # launch only: the device arrays stay un-synced until the
             # finalize stage lands them (batch N's host work overlaps

@@ -21,6 +21,7 @@ from elasticsearch_tpu.common.errors import IllegalArgumentError
 from elasticsearch_tpu.index.mapping import DenseVectorFieldMapper
 from elasticsearch_tpu.ops import similarity as sim
 from elasticsearch_tpu.search.queries import DocSet, Query, SearchContext
+from elasticsearch_tpu.telemetry import stage
 
 
 class KnnQuery(Query):
@@ -50,7 +51,8 @@ class KnnQuery(Query):
         metric = self._metric(ctx)
         filter_rows = None
         if self.filter_query is not None:
-            filter_rows = self.filter_query.execute(ctx).rows
+            with stage("knn.filter_resolve"):
+                filter_rows = self.filter_query.execute(ctx).rows
 
         store = getattr(ctx, "vector_store", None)
         if store is not None and store.field(self.field) is not None:

@@ -46,6 +46,8 @@ from elasticsearch_tpu.ops import topk as topk_ops
 from elasticsearch_tpu.segments.generation import (
     Generation, build_generation)
 from elasticsearch_tpu.segments.policy import MergeSpec, TieredMergePolicy
+from elasticsearch_tpu.telemetry import stage as _stage
+from elasticsearch_tpu.vectors import filter_mask
 
 logger = logging.getLogger("elasticsearch_tpu.segments")
 
@@ -136,16 +138,25 @@ class GenerationSet:
         k_t = dispatch.bucket_k(k_eff, limit=self.total_pad)
         any_filter = any(fr is not None for fr in filters)
         qj = jnp.asarray(queries)
+        # the batch's filters against every generation's row map, in ONE
+        # stage a batch; the legs lay the rows out as their program
+        # reads them (padded, or through a mesh's slot map)
+        allowed = [None] * len(self.generations)
+        if any_filter:
+            with _stage("dispatch.mask_build"):
+                allowed = [filter_mask.allowed_rows(
+                    gen.row_map, filters[:n_real], live=gen.live_mask())
+                    if gen.n_rows else None for gen in self.generations]
         board_s: List = []
         board_i: List = []
         legs: List[str] = []
-        for gen, off in zip(self.generations, self.offsets[:-1]):
+        for gen, off, allow in zip(self.generations, self.offsets[:-1],
+                                   allowed):
             if gen.n_rows == 0:
                 continue
             s, ids, leg = self._search_generation(
-                gen, int(off), qj, queries, n_real, b_pad, k_t,
-                any_filter, filters, metric, precision, num_candidates,
-                knn_stats)
+                gen, int(off), qj, queries, b_pad, k_t, allow, metric,
+                precision, num_candidates, knn_stats)
             board_s.append(s)
             board_i.append(ids)
             legs.append(leg)
@@ -166,17 +177,19 @@ class GenerationSet:
         return board, phases
 
     def _search_generation(self, gen: Generation, off: int, qj,
-                           queries: np.ndarray, n_real: int, b_pad: int,
-                           k_t: int, any_filter: bool, filters,
+                           queries: np.ndarray, b_pad: int, k_t: int,
+                           allowed: Optional[np.ndarray],
                            metric: str, precision: str,
                            num_candidates: Optional[int],
                            knn_stats: Optional[dict]):
         """One generation's board [B_pad, k_t] in flat ids: mesh / IVF /
-        exhaustive leg selection mirrors the monolithic router."""
+        exhaustive leg selection mirrors the monolithic router.
+        `allowed`: [n_real, n_rows] bool where the batch carries a filter
+        (`filter_mask.allowed_rows`, tombstones already taken out)."""
         import jax.numpy as jnp
 
         n_pad = gen.n_pad
-        need_mask = gen.has_tombstones or any_filter
+        need_mask = gen.has_tombstones or allowed is not None
         # -------- IVF leg (graduated base; tombstones drop the router)
         if gen.router is not None and not need_mask:
             reason = gen.router.should_fallback(
@@ -193,26 +206,21 @@ class GenerationSet:
                                       has_mesh_state=True, batch=b_pad)
             if mesh is not None:
                 if k_t <= gen.mesh_state.layout.rows_per_shard:
-                    return self._mesh_board(gen, off, queries, n_real,
-                                            b_pad, k_t, any_filter,
-                                            filters, metric, precision,
+                    return self._mesh_board(gen, off, queries, b_pad, k_t,
+                                            allowed, metric, precision,
                                             knn_stats, mesh)
                 mesh_policy.reclassify_single("knn_k_deeper_than_shard")
         # -------- exhaustive leg (un-synced device board)
         k_g = dispatch.bucket_k(min(k_t, n_pad), limit=n_pad)
         mask = None
         if need_mask:
-            live = gen.live_mask()
-            if any_filter:
+            if allowed is not None:
                 m = np.zeros((b_pad, n_pad), dtype=bool)
-                for qi in range(n_real):
-                    fr = filters[qi]
-                    allow = live if fr is None \
-                        else live & np.isin(gen.row_map, fr)
-                    m[qi, :gen.n_rows] = allow
+                m[:len(allowed), :gen.n_rows] = allowed
+                filter_mask.note_upload(m)
             else:
                 m = np.zeros(n_pad, dtype=bool)
-                m[:gen.n_rows] = live
+                m[:gen.n_rows] = gen.live_mask()
             mask = jnp.asarray(m)
         # a base the mesh answers defers its single-device copy
         # (`knn_ops.DeferredCorpus`): this leg is its first use
@@ -263,8 +271,8 @@ class GenerationSet:
         return scores, ids, "ivf"
 
     def _mesh_board(self, gen: Generation, off: int, queries: np.ndarray,
-                    n_real: int, b_pad: int, k_t: int, any_filter: bool,
-                    filters, metric: str, precision: str,
+                    b_pad: int, k_t: int, allowed: Optional[np.ndarray],
+                    metric: str, precision: str,
                     knn_stats: Optional[dict], mesh):
         """Graduated base served as ONE SPMD program over its sharded
         copy; tombstones and per-query filters map through the slot map.
@@ -290,20 +298,14 @@ class GenerationSet:
         per = ms.layout.rows_per_shard
         k_b = dispatch.bucket_k(min(k_t, per), limit=per)
         mask = None
-        if any_filter or gen.has_tombstones:
-            live = gen.live_mask()
-            if any_filter:
-                m = np.zeros((b_pad, len(ms.slot_map)), dtype=bool)
-                for qi in range(n_real):
-                    fr = filters[qi]
-                    allow = live if fr is None \
-                        else live & np.isin(gen.row_map, fr)
-                    m[qi] = ms.filter_mask(allow)
-                mask = jax.device_put(jnp.asarray(m),
-                                      ms.mask_sharding(2, mesh))
-            else:
-                mask = jax.device_put(jnp.asarray(ms.filter_mask(live)),
-                                      ms.mask_sharding(1, mesh))
+        if allowed is not None:
+            m = filter_mask.through_slots(ms, allowed, b_pad)
+            mask = jax.device_put(jnp.asarray(filter_mask.note_upload(m)),
+                                  ms.mask_sharding(2, mesh))
+        elif gen.has_tombstones:
+            mask = jax.device_put(
+                jnp.asarray(ms.filter_mask(gen.live_mask())),
+                ms.mask_sharding(1, mesh))
         q = jax.device_put(jnp.asarray(queries), ms.query_sharding(mesh))
         # the packed form: the one the mesh's grid warmed, and one read
         scores, gids = topk_ops.split_board(np.asarray(

@@ -39,6 +39,7 @@ from elasticsearch_tpu.quant import rescore as quant_rescore
 from elasticsearch_tpu.serving.batcher import IDLE, CombiningBatcher
 from elasticsearch_tpu.telemetry import metrics as _telemetry_metrics
 from elasticsearch_tpu.telemetry import stage as _stage
+from elasticsearch_tpu.vectors import filter_mask
 
 # below this many rows the exhaustive matmul beats IVF routing overhead;
 # tpu_ivf fields smaller than this quietly serve exhaustive
@@ -1166,6 +1167,7 @@ class VectorStoreShard:
             with _stage("dispatch.prepare"):
                 others = self._begin_dispatch()
                 slot = _InflightSlot(self)
+                filter_mask.note_requests([fr for _, fr in requests])
                 launch = self._route_prepared(fc, k, precision, requests,
                                               others, num_candidates)
             handle = launch()
@@ -1262,13 +1264,11 @@ class VectorStoreShard:
         b_pad = len(queries)
         m = None
         if any_filter:
-            n_pad = fc.corpus.matrix.shape[0]
-            m = np.zeros((b_pad, n_pad), dtype=bool)
-            for i, (_, fr) in enumerate(requests):
-                if fr is None:
-                    m[i, :n_valid] = True
-                else:
-                    m[i, :n_valid] = np.isin(fc.row_map, fr)
+            with _stage("dispatch.mask_build"):
+                m = np.zeros((b_pad, fc.corpus.matrix.shape[0]), dtype=bool)
+                filter_mask.allowed_rows(
+                    fc.row_map, [fr for _, fr in requests],
+                    out=m[:len(requests), :n_valid])
         return functools.partial(self._launch_single, fc, queries, m, k_eff,
                                  n_valid, len(requests), precision,
                                  rescore_ctx)
@@ -1284,7 +1284,8 @@ class VectorStoreShard:
         import jax
 
         with _stage("dispatch.h2d"):
-            mask = None if m is None else jax.device_put(m)
+            mask = None if m is None else jax.device_put(
+                filter_mask.note_upload(m))
         # k rounds up the dispatch bucket ladder so a workload that
         # sweeps k (10, 12, 13, ...) reuses one compiled program per
         # rung; the extra columns slice away at finalize (top-k prefixes
@@ -1426,13 +1427,9 @@ class VectorStoreShard:
         t0 = time.monotonic_ns()
         m = None
         if any_filter:
-            m = np.zeros((b_pad, len(ms.slot_map)), dtype=bool)
-            valid_slots = ms.slot_map >= 0  # == filter_mask(all-ones)
-            for i, (_, fr) in enumerate(requests):
-                if fr is None:
-                    m[i] = valid_slots
-                else:
-                    m[i] = ms.filter_mask(np.isin(fc.row_map, fr))
+            with _stage("dispatch.mask_build"):
+                m = filter_mask.through_slots(ms, filter_mask.allowed_rows(
+                    fc.row_map, [fr for _, fr in requests]), b_pad)
         return functools.partial(
             self._launch_mesh, fc, ms, mesh, queries, m, k_eff, k_b, b_pad,
             n_valid, len(requests), t0, precision, rescore_ctx)
@@ -1453,7 +1450,7 @@ class VectorStoreShard:
         # warmed executable is missed
         with _stage("dispatch.h2d"):
             mask = None if m is None else jax.device_put(
-                m, ms.mask_sharding(2, mesh))
+                filter_mask.note_upload(m), ms.mask_sharding(2, mesh))
             q = jax.device_put(queries, ms.query_sharding(mesh))
         with _stage("dispatch.launch"):
             board = self._launched(distributed_knn_search(

@@ -168,6 +168,27 @@ def test_exact_filtered_route_compiles(one_chip, d, metric):
              k=10, metric=metric, precision="bf16", block_size=None)
 
 
+@pytest.mark.parametrize("nq", [1, 8, 64])
+def test_filtered_l2_board_of_the_tags_deployment_compiles(one_chip, nq):
+    """`yfcc-192-uint8-tags` as `filtered-steady` launches it: l2 over
+    327,680 x 192-d bf16 rows (the configuration's `rows`) with a [Q, N]
+    mask, the packed board, k on the store's ladder, at the cell's
+    smallest, usual and largest batch rung. The whole [64, N] float32
+    board and the mask fit the chip beside the 126 MB corpus."""
+    from elasticsearch_tpu.ops import dispatch
+    n = 327_680
+    compiled = _compile(
+        knn_ops._knn_search_impl,
+        ("k", "metric", "precision", "block_size", "board"),
+        _sds(one_chip, (nq, 192), jnp.float32),
+        _corpus_spec(one_chip, n, 192, jnp.bfloat16),
+        _sds(one_chip, (nq, n), jnp.bool_),
+        k=dispatch.bucket_k(10, limit=n), metric=sim.L2_NORM,
+        precision="bf16", block_size=None, board=True)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 2 << 30
+
+
 # ---------------------------------------------------------------------------
 # scalar-prefetch kernels: fused IVF probe, MaxSim rescore
 # ---------------------------------------------------------------------------

@@ -293,3 +293,37 @@ def test_mapping_render_roundtrip():
     assert rendered["properties"]["embedding"]["dims"] == 4
     ms2 = MapperService(rendered)
     assert ms2.get("embedding").dims == 4
+
+
+# ---------------------------------------------------------------------------
+# a commit of a keyword ARRAY a document beside a text field (ISSUE 33's
+# mapping): every posting comes back from the disk
+# ---------------------------------------------------------------------------
+
+def _postings_of(engine):
+    return {(seg.seg_id, field, term): (p.doc_ids.tolist(), p.freqs.tolist(),
+                                        p.positions)
+            for seg in engine.segments
+            for field, terms in seg.postings.items()
+            for term, p in terms.items()}
+
+
+def test_a_commit_keeps_every_posting_of_a_keyword_array_and_of_text(tmp_path):
+    path = str(tmp_path / "shard")
+    e = Engine(path, MapperService(MAPPING))
+    for i in range(40):
+        e.index(str(i), {"title": f"doc number {i} of forty doc",
+                         "tag": [f"t{i % 7}", f"u{i % 3}", "all"]})
+    e.refresh()
+    e.index("last", {"tag": ["t0"]})      # a second, small segment
+    e.flush()
+    before = _postings_of(e)
+    assert any(pos is not None for _d, _f, pos in before.values())
+    tags = {term for _s, field, term in before if field == "tag"}
+    assert len(tags) == 7 + 3 + 1
+    assert len(before[(e.segments[0].seg_id, "tag", "all")][0]) == 40
+    e.close()
+    e2 = Engine(path, MapperService(MAPPING))
+    assert _postings_of(e2) == before
+    assert e2.doc_count() == 41
+    e2.close()
