@@ -28,6 +28,7 @@ import numpy as np
 
 from elasticsearch_tpu.ops import dispatch
 from elasticsearch_tpu.ops import knn as knn_ops
+from elasticsearch_tpu.vectors import filter_mask
 
 
 def generation_tier(n_rows: int) -> int:
@@ -62,17 +63,22 @@ dispatch.DISPATCH.register(
 class Generation:
     """Immutable device generation + host bookkeeping."""
 
-    __slots__ = ("gen_id", "corpus", "row_map", "source",
+    __slots__ = ("gen_id", "corpus", "row_map", "locator", "source",
                  "tombstones", "kernel", "router", "mesh_state",
                  "_live_cache")
 
     def __init__(self, gen_id: int, corpus, row_map: np.ndarray,
                  source, tombstones: Optional[np.ndarray] = None,
                  kernel: str = "segments.knn", router=None,
-                 mesh_state=None):
+                 mesh_state=None, locator=None):
         self.gen_id = gen_id
         self.corpus = corpus              # knn_ops.Corpus (device pytree)
         self.row_map = row_map            # [n_rows] engine global rows
+        # engine global row -> row of this generation, for a filter's
+        # mask (`vectors/filter_mask.py`): built here, at the seal or the
+        # merge, and shared by the tombstone copies
+        self.locator = (locator if locator is not None
+                        else filter_mask.RowLocator(row_map))
         # columnar.RowSource: the merge scheduler's host-row input,
         # resolved through the SHARED segment block store on demand — a
         # generation never retains a private corpus-sized f32 copy
@@ -149,7 +155,7 @@ class Generation:
         return Generation(self.gen_id, self.corpus, self.row_map,
                           self.source, tombstones=tombstones,
                           kernel=self.kernel, router=None,
-                          mesh_state=self.mesh_state)
+                          mesh_state=self.mesh_state, locator=self.locator)
 
     def live_mask(self) -> np.ndarray:
         """[n_rows] bool — True for live (non-tombstoned) rows."""

@@ -145,8 +145,11 @@ class GenerationSet:
         if any_filter:
             with _stage("dispatch.mask_build"):
                 allowed = [filter_mask.allowed_rows(
-                    gen.row_map, filters[:n_real], live=gen.live_mask())
+                    gen.locator, filters[:n_real], live=gen.live_mask())
                     if gen.n_rows else None for gen in self.generations]
+                filter_mask.note_built(
+                    filters[:n_real],
+                    [gen.locator for gen in self.generations if gen.n_rows])
         board_s: List = []
         board_i: List = []
         legs: List[str] = []

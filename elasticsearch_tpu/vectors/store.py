@@ -86,17 +86,25 @@ class _InflightSlot:
 class FieldCorpus:
     """Device corpus for one vector field + host-side row maps."""
 
-    __slots__ = ("corpus", "row_map", "metric", "dims", "version",
-                 "router", "mesh_state", "gens", "encoding", "rescore",
-                 "rescore_oversample", "rescore_candidates", "source")
+    __slots__ = ("corpus", "row_map", "_locator", "metric", "dims",
+                 "version", "router", "mesh_state", "gens", "encoding",
+                 "rescore", "rescore_oversample", "rescore_candidates",
+                 "source")
 
     def __init__(self, corpus, row_map: np.ndarray, metric: str, dims: int,
                  version: tuple, router=None, mesh_state=None,
                  gens=None, encoding: str = "bf16", rescore: bool = False,
                  rescore_oversample: int = 4,
-                 rescore_candidates: int = 128, source=None):
+                 rescore_candidates: int = 128, source=None,
+                 locator=None):
         self.corpus = corpus          # knn_ops.Corpus (device pytree)
         self.row_map = row_map        # device row -> engine global row
+        # engine global row -> device row, for a filter's mask: one pass
+        # over the row map, made by `sync` (a view made on the search
+        # path is handed the `locator` of the generation whose row map
+        # it takes; the flat view of several generations, which no
+        # search reads a locator from, is handed none and builds none)
+        self._locator = locator
         self.metric = metric
         self.dims = dims
         self.version = version        # cache key: segment/tombstone fingerprint
@@ -120,6 +128,12 @@ class FieldCorpus:
         self.rescore_oversample = rescore_oversample
         self.rescore_candidates = rescore_candidates
         self.source = source
+
+    @property
+    def locator(self) -> filter_mask.RowLocator:
+        if self._locator is None:
+            self._locator = filter_mask.RowLocator(self.row_map)
+        return self._locator
 
 
 def _pad_batch(queries: np.ndarray, n_real: int) -> np.ndarray:
@@ -588,7 +602,10 @@ class VectorStoreShard:
                     encoding=dtype, rescore=plan["rescore"],
                     rescore_oversample=plan["rescore_oversample"],
                     rescore_candidates=plan["rescore_candidates"],
-                    source=view.as_source())
+                    source=view.as_source(),
+                    locator=(filter_mask.RowLocator(row_map)
+                             if gens is None else
+                             gens.snapshot().generations[0].locator))
             with self._batchers_lock:
                 for key in [k for k in self._batchers if k[0] == field]:
                     self._retire_sched(self._batchers.pop(key))
@@ -682,7 +699,8 @@ class VectorStoreShard:
             rescore_oversample=plan.get(
                 "rescore_oversample",
                 quant_rescore.DEFAULT_OVERSAMPLE.get(enc, 4)),
-            rescore_candidates=plan.get("rescore_candidates", 128))
+            rescore_candidates=plan.get("rescore_candidates", 128),
+            locator=base.locator if len(snap.generations) == 1 else None)
 
     def _reinstall_view(self, field: str, gc) -> None:
         """Refresh the installed view after a background merge installs
@@ -1207,7 +1225,7 @@ class VectorStoreShard:
                                  rescore=fc.rescore,
                                  rescore_oversample=fc.rescore_oversample,
                                  rescore_candidates=fc.rescore_candidates,
-                                 source=base.source)
+                                 source=base.source, locator=base.locator)
 
         n_valid = len(fc.row_map)
         queries = np.stack([q for q, _ in requests])
@@ -1265,10 +1283,16 @@ class VectorStoreShard:
         m = None
         if any_filter:
             with _stage("dispatch.mask_build"):
-                m = np.zeros((b_pad, fc.corpus.matrix.shape[0]), dtype=bool)
-                filter_mask.allowed_rows(
-                    fc.row_map, [fr for _, fr in requests],
-                    out=m[:len(requests), :n_valid])
+                # every byte written once: the pad here (requests and
+                # rows nobody asked for), the batch's rows whole by
+                # `allowed_rows`
+                filters = [fr for _, fr in requests]
+                m = np.empty((b_pad, fc.corpus.matrix.shape[0]), dtype=bool)
+                m[len(filters):] = False
+                m[:len(filters), n_valid:] = False
+                filter_mask.allowed_rows(fc.locator, filters,
+                                         out=m[:len(filters), :n_valid])
+                filter_mask.note_built(filters, [fc.locator])
         return functools.partial(self._launch_single, fc, queries, m, k_eff,
                                  n_valid, len(requests), precision,
                                  rescore_ctx)
@@ -1428,8 +1452,10 @@ class VectorStoreShard:
         m = None
         if any_filter:
             with _stage("dispatch.mask_build"):
+                filters = [fr for _, fr in requests]
                 m = filter_mask.through_slots(ms, filter_mask.allowed_rows(
-                    fc.row_map, [fr for _, fr in requests]), b_pad)
+                    fc.locator, filters), b_pad)
+                filter_mask.note_built(filters, [fc.locator])
         return functools.partial(
             self._launch_mesh, fc, ms, mesh, queries, m, k_eff, k_b, b_pad,
             n_valid, len(requests), t0, precision, rescore_ctx)

@@ -3,14 +3,19 @@
 
     knn.filter_resolve        once a filtered search (the filter query to
                               sorted rows), never for an unfiltered one
-    dispatch.mask_build       once a BATCH that carries a filter (the
-                              `np.isin` loop), inside `dispatch.prepare` on
-                              the single-device route; never for a batch of
+    dispatch.mask_build       once a BATCH that carries a filter (a
+                              scatter of each filter's rows, ISSUE 34),
+                              inside `dispatch.prepare` on the
+                              single-device route; never for a batch of
                               unfiltered requests
     knn.filtered_searches     requests that reached the store with filter
                               rows, `knn.filter_matched_rows` the sum of
                               their lengths, `dispatch.mask_bytes` the
                               bytes of mask handed to `device_put`
+    dispatch.mask_scattered   of those requests, the ones whose rows were
+                              written through the row map's locator;
+                              `dispatch.mask_searched` the ones that took
+                              the `np.isin` fallback (none here)
 
 and the unfiltered route's stage counts are what they were.
 """
@@ -26,7 +31,8 @@ STAGES = ("knn.filter_resolve", "dispatch.mask_build", "dispatch.prepare",
           "dispatch.d2h", "dispatch.land", "serving.device_dispatch",
           "serving.device_sync", "search.took")
 COUNTERS = ("knn.filtered_searches", "knn.filter_matched_rows",
-            "dispatch.mask_bytes")
+            "dispatch.mask_bytes", "dispatch.mask_scattered",
+            "dispatch.mask_searched")
 
 
 def _read():
@@ -93,6 +99,8 @@ def test_a_filtered_search_records_each_stage_once(node):
     store = node.indices.get("idx").shards[0].vector_store
     n_pad = store.field("v").corpus.matrix.shape[0]
     assert counters["dispatch.mask_bytes"] == 1 * n_pad    # a batch of one
+    assert counters["dispatch.mask_scattered"] == 1
+    assert counters["dispatch.mask_searched"] == 0
 
 
 def test_an_unfiltered_search_records_neither(node):
@@ -141,6 +149,8 @@ def test_the_counters_add_up_on_a_batch_of_mixed_requests(node):
     n_pad = store.field("v").corpus.matrix.shape[0]
     assert counters["dispatch.mask_bytes"] == \
         dispatch.bucket_queries(4) * n_pad
+    assert counters["dispatch.mask_scattered"] == 2
+    assert counters["dispatch.mask_searched"] == 0
 
 
 def test_the_generational_fan_out_builds_its_masks_in_one_stage(node):
@@ -166,6 +176,10 @@ def test_the_generational_fan_out_builds_its_masks_in_one_stage(node):
     gens = store.field("v").gens.snapshot().generations
     assert len(gens) == 2
     assert counters["dispatch.mask_bytes"] == sum(g.n_pad for g in gens)
+    # once a request, not once a generation
+    assert [g.locator.form for g in gens] == ["contiguous", "contiguous"]
+    assert counters["dispatch.mask_scattered"] == 1
+    assert counters["dispatch.mask_searched"] == 0
 
 
 def test_a_traced_filtered_search_hangs_the_new_spans_in_its_trace(node):
