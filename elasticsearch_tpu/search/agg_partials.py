@@ -932,7 +932,13 @@ def _finalize_bucket_agg(kind: str, spec: dict, node, sub_spec: dict):
             buckets = _fill_gaps(buckets, float(spec["interval"]), date=False)
         elif buckets and kind == "date_histogram":
             interval_ms, calendar = A._date_interval(spec)
-            if not calendar:
+            if calendar:
+                tz = A._resolve_tz(spec.get("time_zone"))
+                keys = A._calendar_keys(
+                    float(buckets[0]["key"]), float(buckets[-1]["key"]),
+                    calendar, tz, A._date_offset_ms(spec.get("offset")))
+                buckets = _fill_date_keys(buckets, keys, tz)
+            else:
                 buckets = _fill_gaps(buckets, interval_ms, date=True)
         return {"buckets": buckets}
 
@@ -967,6 +973,18 @@ def _finalize_one_bucket(bucket: dict, sub_spec: dict) -> dict:
         subs = {n: bucket[n] for n in sub_spec if n in bucket}
         out.update(finalize_aggs(subs, sub_spec))
     return out
+
+
+def _fill_date_keys(buckets: List[dict], keys: List[float],
+                    tz=None) -> List[dict]:
+    """`buckets` with a zero bucket at every one of `keys` that none of
+    them holds (a calendar interval's gaps: the keys are boundaries, not
+    an arithmetic progression)."""
+    by_key = {float(b["key"]): b for b in buckets}
+    return [by_key.get(float(k))
+            or {"key": int(k), "doc_count": 0,
+                "key_as_string": A._millis_to_iso_tz(int(k), tz)}
+            for k in keys]
 
 
 def _fill_gaps(buckets: List[dict], interval: float, date: bool) -> List[dict]:

@@ -19,7 +19,10 @@ tests/test_device_aggs.py:
   histogram        interval, offset, missing, min_doc_count,
                    extended_bounds, format
   date_histogram   fixed intervals (+ offset, format, time_zone
-                   rendering); calendar intervals fall back
+                   rendering) by an affine floor; calendar intervals
+                   (hour .. year, any time_zone) by a search of the
+                   boundary table `_calendar_bounds` walks once a
+                   column version (`aggs.cal_counts` / `cal_metric`)
   range            numeric from/to/key ranges (overlaps allowed)
   metrics          avg, sum, min, max, stats, value_count — top-level and
                    as one-level sub-aggs of any bucket agg above
@@ -30,6 +33,12 @@ fields — falls through PER NODE to the host path (`compute_aggs` /
 `compute_partial_aggs`), and sum-bearing metrics (sum/avg/stats) ride the
 device only for integral columns where f64 scatter-adds are provably
 order-free (see ops/aggs.py): exactness is a contract, not a tolerance.
+
+Every request files its stages and counters through `telemetry.stage`
+(`aggs.plan`, `aggs.mask`, `aggs.device` with `aggs.launch` and
+`aggs.sync_wait` inside it, `aggs.assemble`, `aggs.host`; README
+"End-to-end telemetry"); `indices.aggs`'s `device_nanos`,
+`assemble_nanos` and `host_nanos` are sums of the same clock marks.
 
 Partial mode emits the SAME `$p`-tagged partial-reduction states
 `search/agg_partials.py` merges today, so mesh/multi-index serving gets
@@ -43,11 +52,11 @@ from __future__ import annotations
 import logging
 import math
 import threading
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from elasticsearch_tpu import telemetry
 from elasticsearch_tpu.common.errors import (
     IllegalArgumentError, ParsingError, SearchEngineError,
 )
@@ -78,13 +87,6 @@ _CARD_ALLOWED_KEYS = {"field", "precision_threshold", "missing"}
 # composite sub-agg trees: bucket-in-bucket nesting compiles to ONE flat
 # board per (depth, metric) whose lane is parent_id * k_child + child_id
 MAX_TREE_DEPTH = aggs_ops.TREE_MAX_DEPTH
-
-# nominal calendar-unit lengths in millis — probe steps for the boundary
-# walk, NOT bucket widths (DST/leap realities come from _calendar_floor)
-_CAL_NOMINAL = {"T": 60_000, "H": 3_600_000, "D": 86_400_000,
-                "W": 604_800_000, "M": 28 * 86_400_000,
-                "Q": 90 * 86_400_000, "Y": 365 * 86_400_000}
-
 
 
 def _mesh_call(name, *args, mesh, **kw):
@@ -552,6 +554,9 @@ class CostRouter:
                     "host_ns_per_doc": dict(self._host)}
 
 
+_counter = telemetry.metrics.counter
+
+
 def _family(node: _Node) -> str:
     """Cost-model family: the top-level mode, with '_tree' marking the
     composite multi-board shape (very different cost profile)."""
@@ -635,13 +640,18 @@ class AggEngine:
                 partial: bool = False) -> Optional[Tuple[dict, dict]]:
         if getattr(ctx, "nested_path", None):
             return None
-        plan = self.plan_for(aggs_spec)
+        with telemetry.stage("aggs.plan"):
+            plan = self.plan_for(aggs_spec)
         if plan.device_count == 0:
             return None
         self._count("searches")
+        _counter("aggs.matched_rows").inc(len(rows))
         # one immutable row-space snapshot for the whole pass: a refresh
-        # resync advancing the store mid-request can't skew the mask
-        mask_box: Dict[str, Any] = {"snap": self.store.snapshot(ctx.reader)}
+        # resync advancing the store mid-request can't skew the mask.
+        # The box is the request's: its mask, and what its nodes handed
+        # to the device and read back
+        mask_box: Dict[str, Any] = {"snap": self.store.snapshot(ctx.reader),
+                                    "dispatches": 0}
         out: Dict[str, Any] = {}
         pipelines: List[Tuple[str, str, dict]] = []
         prof_nodes: List[dict] = []
@@ -679,19 +689,26 @@ class AggEngine:
                 else:
                     if route == "probe":
                         self._count("router_probes")
+                    if "mask" not in mask_box:
+                        # the request's rows to a [r_pad] bool: the
+                        # first device node pays it, the others share it
+                        with telemetry.stage("aggs.mask"):
+                            self._mask_for(rows, mask_box)
+                    compiles0 = dispatch.DISPATCH.compile_count()
+                    launched0 = mask_box["dispatches"]
+                    dev = telemetry.stage("aggs.device")
+                    asm = None
                     try:
-                        compiles0 = dispatch.DISPATCH.compile_count()
-                        t0 = time.perf_counter_ns()
-                        boards, mesh_used = self._run_device_node(
-                            ctx, node, spec, rows, mask_box, partial)
-                        t1 = time.perf_counter_ns()
-                        res = self._assemble_node(
-                            ctx, node, spec, rows, boards, partial)
-                        t2 = time.perf_counter_ns()
-                        device_nanos += t1 - t0
-                        assemble_nanos += t2 - t1
+                        with dev:
+                            boards, mesh_used = self._run_device_node(
+                                ctx, node, spec, rows, mask_box, partial)
+                        asm = telemetry.stage("aggs.assemble")
+                        with asm:
+                            res = self._assemble_node(
+                                ctx, node, spec, rows, boards, partial)
                         engine = "device_mesh" if mesh_used else "device"
                         self._count("device_nodes")
+                        _counter("aggs.device_nodes").inc()
                         # a call that compiled is not a sample of what
                         # the device costs: booked as one, the compile
                         # seconds sent the family's next REPROBE requests
@@ -699,7 +716,8 @@ class AggEngine:
                         if self.cost_router is not None and \
                                 dispatch.DISPATCH.compile_count() \
                                 == compiles0:
-                            self.cost_router.observe_device(fam, t2 - t0)
+                            self.cost_router.observe_device(
+                                fam, dev.nanos + asm.nanos)
                     except _Fallback as fb:
                         reason = fb.reason
                         self._reason(fb.reason, docs=len(rows),
@@ -714,23 +732,32 @@ class AggEngine:
                         # broken dispatcher for a whole release
                         self._reason("device_error", docs=len(rows))
                         raise
+                    finally:
+                        # what the stages recorded, an attempt that fell
+                        # back included: `indices.aggs` sums the marks
+                        device_nanos += dev.nanos
+                        if asm is not None:
+                            assemble_nanos += asm.nanos
+                        launched = mask_box["dispatches"] - launched0
+                        if launched:
+                            _counter("aggs.dispatches." + fam).inc(launched)
             if res is None:
                 if node is not None and node.mode == "host" \
                         and node.host_reason:
                     self._reason(node.host_reason, docs=len(rows))
                 sub = {name: spec}
-                th0 = time.perf_counter_ns()
-                if partial:
-                    from elasticsearch_tpu.search.agg_partials import (
-                        compute_partial_aggs)
-                    res = compute_partial_aggs(ctx, rows, sub).get(name)
-                else:
-                    res = A.compute_aggs(ctx, rows, sub).get(name)
-                th1 = time.perf_counter_ns()
-                host_nanos += th1 - th0
+                with telemetry.stage("aggs.host") as walked:
+                    if partial:
+                        from elasticsearch_tpu.search.agg_partials import (
+                            compute_partial_aggs)
+                        res = compute_partial_aggs(ctx, rows, sub).get(name)
+                    else:
+                        res = A.compute_aggs(ctx, rows, sub).get(name)
+                host_nanos += walked.nanos
                 self._count("host_nodes")
+                _counter("aggs.host_nodes").inc()
                 if self.cost_router is not None and fam is not None:
-                    self.cost_router.observe_host(fam, th1 - th0,
+                    self.cost_router.observe_host(fam, walked.nanos,
                                                   len(rows))
             elif not partial and isinstance(spec.get("meta"), dict) \
                     and isinstance(res, dict):
@@ -769,6 +796,34 @@ class AggEngine:
             mask = mask_box["snap"].filter_mask(rows)
             mask_box["mask"] = mask
         return mask
+
+    @staticmethod
+    def _launch(mask_box, name, *args, mesh=None, **statics):
+        """One program of this request handed to the device: bind, what
+        rides the call (the host mask among it) and the enqueue, not the
+        wait. A mask still on the host is uploaded by the call, every
+        call anew: `aggs.mask_bytes` counts it there."""
+        mask_box["dispatches"] += 1
+        host_mask = mask_box.get("mask")
+        if host_mask is not None and any(a is host_mask for a in args):
+            _counter("aggs.mask_bytes").inc(host_mask.nbytes)
+        with telemetry.stage("aggs.launch"):
+            if mesh is not None:
+                return _mesh_call(name, *args, mesh=mesh, **statics)
+            return dispatch.call(name, *args, **statics)
+
+    @staticmethod
+    def _read(board) -> np.ndarray:
+        """One board back on the host as numpy: the wait for its
+        program and the copy."""
+        with telemetry.stage("aggs.sync_wait"):
+            out = np.asarray(board)
+        _counter("aggs.board_lanes").inc(out.size)
+        return out
+
+    def _read_boards(self, mboards: dict) -> dict:
+        return {n: tuple(self._read(x) for x in b)
+                for n, b in mboards.items()}
 
     def _mesh_for(self, mask_box):
         """Route this node's reduce: mesh or single-device (counted by
@@ -815,6 +870,8 @@ class AggEngine:
         from elasticsearch_tpu.ops.dispatch import _x64_scope
         from elasticsearch_tpu.parallel import mesh as mesh_lib
         row = NamedSharding(mesh, P(mesh_lib.SHARD_AXIS))
+        # the request's mask, sharded by rows ahead of the launch
+        _counter("aggs.mask_bytes").inc(sum(a.nbytes for a in arrays))
         with _x64_scope(True):
             return [jax.device_put(jnp.asarray(a), row) for a in arrays]
 
@@ -832,6 +889,9 @@ class AggEngine:
         boards: Dict[str, Any] = {"n_matched": int(len(rows))}
         mesh_used = False
 
+        def launch(name, *args, **statics):
+            return self._launch(mask_box, name, *args, **statics)
+
         if node.mode == "terms":
             col = store.column(reader, node.field, want_ords=True,
                                snap=snap)
@@ -845,29 +905,30 @@ class AggEngine:
             if mesh is not None:
                 vals_d, pres_d, ords_d = col.device_arrays_mesh(mesh)
                 (mask_d,) = self._sharded(mesh, [mask])
-                counts = _mesh_call("aggs.mesh_ord_counts", ords_d,
-                                       mask_d, n_buckets=b, mesh=mesh)
+                counts = launch("aggs.mesh_ord_counts", ords_d,
+                                mask_d, n_buckets=b, mesh=mesh)
                 mboards = {}
                 for mname, (m, mc) in mcols.items():
                     mv_d, mp_d, _ = mc.device_arrays_mesh(mesh)
-                    mboards[mname] = _mesh_call(
+                    mboards[mname] = launch(
                         "aggs.mesh_ord_metric", ords_d, mask_d, mv_d,
                         mp_d, self._mparams(_sub_body(spec, mname)),
                         n_buckets=b, mesh=mesh)
                 mesh_used = True
             else:
                 _v, _p, ords_d = col.device_arrays()
-                counts = dispatch.call("aggs.ord_counts", ords_d, mask,
-                                       n_buckets=b)
+                counts = launch("aggs.ord_counts", ords_d, mask,
+                                n_buckets=b)
                 mboards = {}
                 for mname, (m, mc) in mcols.items():
                     mv_d, mp_d, _ = mc.device_arrays()
-                    mboards[mname] = dispatch.call(
+                    mboards[mname] = launch(
                         "aggs.ord_metric", ords_d, mask,
                         self._mparams(_sub_body(spec, mname)), mv_d,
                         mp_d, n_buckets=b)
-            boards.update(counts=np.asarray(counts),
-                          metrics=_np_boards(mboards), col=col, mask=mask)
+            boards.update(counts=self._read(counts),
+                          metrics=self._read_boards(mboards), col=col,
+                          mask=mask)
 
         elif node.mode in ("histogram", "date_histogram"):
             col = store.column(reader, node.field, snap=snap)
@@ -891,25 +952,25 @@ class AggEngine:
                 (mask_d,) = self._sharded(mesh, [mask])
                 if cal_args is not None:
                     cbounds, cparams = cal_args
-                    counts = _mesh_call("aggs.mesh_cal_counts", keys_d,
-                                        kp_d, mask_d, cbounds, cparams,
-                                        n_buckets=b, mesh=mesh)
+                    counts = launch("aggs.mesh_cal_counts", keys_d,
+                                    kp_d, mask_d, cbounds, cparams,
+                                    n_buckets=b, mesh=mesh)
                 else:
-                    counts = _mesh_call("aggs.mesh_hist_counts", keys_d,
-                                        kp_d, mask_d, hparams,
-                                        n_buckets=b, mesh=mesh)
+                    counts = launch("aggs.mesh_hist_counts", keys_d,
+                                    kp_d, mask_d, hparams,
+                                    n_buckets=b, mesh=mesh)
                 mboards = {}
                 for mname, (m, mc) in mcols.items():
                     mv_d, mp_d, _ = mc.device_arrays_mesh(mesh)
                     if cal_args is not None:
                         cbounds, cparams = cal_args
-                        mboards[mname] = _mesh_call(
+                        mboards[mname] = launch(
                             "aggs.mesh_cal_metric", keys_d, kp_d, mask_d,
                             mv_d, mp_d, cbounds, cparams,
                             self._mparams(_sub_body(spec, mname)),
                             n_buckets=b, mesh=mesh)
                     else:
-                        mboards[mname] = _mesh_call(
+                        mboards[mname] = launch(
                             "aggs.mesh_hist_metric", keys_d, kp_d, mask_d,
                             mv_d, mp_d, hparams,
                             self._mparams(_sub_body(spec, mname)),
@@ -919,30 +980,30 @@ class AggEngine:
                 keys_d, kp_d, _ = col.device_arrays()
                 if cal_args is not None:
                     cbounds, cparams = cal_args
-                    counts = dispatch.call("aggs.cal_counts", keys_d,
-                                           kp_d, mask, cbounds, cparams,
-                                           n_buckets=b)
+                    counts = launch("aggs.cal_counts", keys_d,
+                                    kp_d, mask, cbounds, cparams,
+                                    n_buckets=b)
                 else:
-                    counts = dispatch.call("aggs.hist_counts", keys_d,
-                                           kp_d, mask, hparams,
-                                           n_buckets=b)
+                    counts = launch("aggs.hist_counts", keys_d,
+                                    kp_d, mask, hparams,
+                                    n_buckets=b)
                 mboards = {}
                 for mname, (m, mc) in mcols.items():
                     mv_d, mp_d, _ = mc.device_arrays()
                     if cal_args is not None:
                         cbounds, cparams = cal_args
-                        mboards[mname] = dispatch.call(
+                        mboards[mname] = launch(
                             "aggs.cal_metric", keys_d, kp_d, mask,
                             cbounds, cparams,
                             self._mparams(_sub_body(spec, mname)), mv_d,
                             mp_d, n_buckets=b)
                     else:
-                        mboards[mname] = dispatch.call(
+                        mboards[mname] = launch(
                             "aggs.hist_metric", keys_d, kp_d, mask,
                             hparams, self._mparams(_sub_body(spec, mname)),
                             mv_d, mp_d, n_buckets=b)
-            boards.update(counts=np.asarray(counts),
-                          metrics=_np_boards(mboards), col=col)
+            boards.update(counts=self._read(counts),
+                          metrics=self._read_boards(mboards), col=col)
 
         elif node.mode == "range":
             col = store.column(reader, node.field, snap=snap)
@@ -953,30 +1014,30 @@ class AggEngine:
             if mesh is not None:
                 keys_d, kp_d, _ = col.device_arrays_mesh(mesh)
                 (mask_d,) = self._sharded(mesh, [mask])
-                counts = _mesh_call("aggs.mesh_range_counts", keys_d,
-                                       kp_d, mask_d, bounds, rparams,
-                                       mesh=mesh)
+                counts = launch("aggs.mesh_range_counts", keys_d,
+                                kp_d, mask_d, bounds, rparams,
+                                mesh=mesh)
                 mboards = {}
                 for mname, (m, mc) in mcols.items():
                     mv_d, mp_d, _ = mc.device_arrays_mesh(mesh)
-                    mboards[mname] = _mesh_call(
+                    mboards[mname] = launch(
                         "aggs.mesh_range_metric", keys_d, kp_d, mask_d,
                         mv_d, mp_d, bounds, rparams,
                         self._mparams(_sub_body(spec, mname)), mesh=mesh)
                 mesh_used = True
             else:
                 keys_d, kp_d, _ = col.device_arrays()
-                counts = dispatch.call("aggs.range_counts", keys_d, kp_d,
-                                       mask, bounds, rparams)
+                counts = launch("aggs.range_counts", keys_d, kp_d,
+                                mask, bounds, rparams)
                 mboards = {}
                 for mname, (m, mc) in mcols.items():
                     mv_d, mp_d, _ = mc.device_arrays()
-                    mboards[mname] = dispatch.call(
+                    mboards[mname] = launch(
                         "aggs.range_metric", keys_d, kp_d, mask, bounds,
                         rparams, self._mparams(_sub_body(spec, mname)),
                         mv_d, mp_d)
-            boards.update(counts=np.asarray(counts),
-                          metrics=_np_boards(mboards), col=col)
+            boards.update(counts=self._read(counts),
+                          metrics=self._read_boards(mboards), col=col)
 
         elif node.mode == "metric":
             col = store.column(reader, node.field, snap=snap)
@@ -987,16 +1048,17 @@ class AggEngine:
                              if mesh is not None else col.device_arrays())
             if mesh is not None:
                 (mask_d,) = self._sharded(mesh, [mask])
-                board = _mesh_call("aggs.mesh_ord_metric", zeros,
-                                      mask_d, mv_d, mp_d, mparams,
-                                      n_buckets=aggs_ops.AGG_B_LADDER[0],
-                                      mesh=mesh)
+                board = launch("aggs.mesh_ord_metric", zeros,
+                               mask_d, mv_d, mp_d, mparams,
+                               n_buckets=aggs_ops.AGG_B_LADDER[0],
+                               mesh=mesh)
                 mesh_used = True
             else:
-                board = dispatch.call("aggs.ord_metric", zeros, mask,
-                                      mparams, mv_d, mp_d,
-                                      n_buckets=aggs_ops.AGG_B_LADDER[0])
-            boards.update(metric=_np_board(board), col=col)
+                board = launch("aggs.ord_metric", zeros, mask,
+                               mparams, mv_d, mp_d,
+                               n_buckets=aggs_ops.AGG_B_LADDER[0])
+            boards.update(metric=tuple(self._read(x) for x in board),
+                          col=col)
 
         if mesh_used:
             from elasticsearch_tpu.parallel import mesh as mesh_lib
@@ -1040,9 +1102,9 @@ class AggEngine:
         def call(name, *args, **statics):
             n_dispatch[0] += 1
             if mesh is not None:
-                return _mesh_call(name.replace("aggs.", "aggs.mesh_"),
-                                  *args, mesh=mesh, **statics)
-            return dispatch.call(name, *args, **statics)
+                name = name.replace("aggs.", "aggs.mesh_")
+            return self._launch(mask_box, name, *args, mesh=mesh,
+                                **statics)
 
         def bind_level(child, body):
             if child.kind == "terms":
@@ -1104,7 +1166,7 @@ class AggEngine:
                 board = call("aggs.hll_board", mask_io, hh[0], hh[1],
                              *flat, levels=levels, n_buckets=ks)
                 lanes_out[0] += (total + 1) * aggs_ops.HLL_M
-                return {"partial": True, "board": np.asarray(board),
+                return {"partial": True, "board": self._read(board),
                         "col": col, "body": body}
             # final mode is EXACT (host counts a distinct set): the card
             # field rides one more ord level on the counts board
@@ -1129,7 +1191,7 @@ class AggEngine:
                          oparams, levels=levels + ("ord",),
                          n_buckets=ks + (k_card,))
             lanes_out[0] += total * k_card + 1
-            return {"partial": False, "board": np.asarray(board),
+            return {"partial": False, "board": self._read(board),
                     "k": k_card, "col": col, "miss": miss, "body": body}
 
         def run_node(node_, spec_node, chain):
@@ -1147,7 +1209,7 @@ class AggEngine:
             if empty:
                 tnode["counts"] = None
             else:
-                tnode["counts"] = np.asarray(call(
+                tnode["counts"] = self._read(call(
                     "aggs.tree_counts", mask_io, *flat, levels=levels,
                     n_buckets=ks))
                 lanes_out[0] += total + 1
@@ -1160,7 +1222,7 @@ class AggEngine:
                     continue
                 mv_d, mp_d, _ = level_arrays(mcol)
                 mp = self._mparams(_sub_body(spec_node, m.name))
-                metrics[m.name] = _np_board(call(
+                metrics[m.name] = tuple(self._read(x) for x in call(
                     "aggs.tree_metric", mask_io, mp, mv_d, mp_d, *flat,
                     levels=levels, n_buckets=ks))
                 lanes_out[0] += 4 * (total + 1)
@@ -1204,9 +1266,9 @@ class AggEngine:
         """Sorted `_calendar_floor` boundary table spanning the column's
         [vmin, vmax] for one (unit, tz): host wall-clock math runs ONCE
         here (cached per column version), the kernel only searchsorts.
-        Walks boundary-to-boundary by probing a nominal step then
-        correcting with the true floor, so DST-shifted days and variable
-        months/years land exactly where the host walker puts them."""
+        Walks boundary to boundary by `A._calendar_next`, so DST-shifted
+        days and variable months/years land exactly where the host
+        walker puts them."""
         key = (field, col.version, unit, str(tz_spec), offset, div)
         cached = self._cal_cache.get(key)
         if cached is not None:
@@ -1214,30 +1276,17 @@ class AggEngine:
         tz = A._resolve_tz(tz_spec)
         lo = math.trunc(col.vmin / div - offset)
         hi = math.trunc(col.vmax / div - offset)
-        nominal = _CAL_NOMINAL[unit]
-        if (hi - lo) / nominal + 2 > aggs_ops.AGG_B_LADDER[-1]:
+        if (hi - lo) / A._CAL_NOMINAL[unit] + 2 > aggs_ops.AGG_B_LADDER[-1]:
             raise _Fallback("span_off_grid")
         start = A._calendar_floor(int(lo), unit, tz)
         bounds = [start]
         cur = start
         limit = aggs_ops.AGG_B_LADDER[-1] + 2
         while True:
-            # probe past the current boundary, escalating if a short
-            # nominal step lands inside the same bucket (long months)
-            step = nominal
-            nxt = A._calendar_floor(int(cur + step), unit, tz)
-            while nxt <= cur:
-                step += 3_600_000
-                nxt = A._calendar_floor(int(cur + step), unit, tz)
-            # back up if the probe overshot a boundary (DST-short days)
-            back = A._calendar_floor(int(nxt - 1), unit, tz)
-            while back > cur:
-                nxt = back
-                back = A._calendar_floor(int(nxt - 1), unit, tz)
-            if nxt > hi:
+            cur = A._calendar_next(cur, unit, tz)
+            if cur > hi:
                 break
-            bounds.append(nxt)
-            cur = nxt
+            bounds.append(cur)
             if len(bounds) > limit:
                 raise _Fallback("span_off_grid")
         entry = (tuple(bounds), tz)
@@ -1614,6 +1663,8 @@ class AggEngine:
                 full.append(round(cur, 10))
                 cur += interval
             all_keys = full
+        elif min_count == 0 and all_keys and cal_bounds is not None:
+            all_keys = _cal_keys_between(cal_bounds, offset, all_keys)
         A._check_max_buckets(ctx, len(all_keys))
         buckets = []
         for key in all_keys:
@@ -1941,6 +1992,8 @@ class AggEngine:
                 full.append(round(cur, 10))
                 cur += interval
             all_keys = full
+        elif min_count == 0 and all_keys and cal_bounds is not None:
+            all_keys = _cal_keys_between(cal_bounds, offset, all_keys)
         A._check_max_buckets(ctx, len(all_keys))
         buckets = []
         for key in all_keys:
@@ -1965,6 +2018,15 @@ class AggEngine:
         return out
 
 
+def _cal_keys_between(cal_bounds, offset, keys: List[float]) -> List[float]:
+    """Every calendar bucket key from the first to the last of `keys`
+    (the buckets that hold documents): the boundary table spans the
+    column, so the empty buckets between them are read off it
+    (`min_doc_count` 0, as `A._histo_buckets` fills them)."""
+    return [float(b + offset) for b in cal_bounds
+            if keys[0] <= b + offset <= keys[-1]]
+
+
 def _sub_body(spec: dict, sub_name: str) -> dict:
     sub = spec.get("aggs") or spec.get("aggregations") or {}
     sspec = sub.get(sub_name) or {}
@@ -1974,9 +2036,3 @@ def _sub_body(spec: dict, sub_name: str) -> dict:
     return {}
 
 
-def _np_board(board) -> tuple:
-    return tuple(np.asarray(x) for x in board)
-
-
-def _np_boards(mboards: dict) -> dict:
-    return {n: _np_board(b) for n, b in mboards.items()}

@@ -1264,7 +1264,9 @@ def _compute_bucket(ctx: SearchContext, rows: np.ndarray, kind: str,
                 + offset_ms
         return _histo_buckets(ctx, rows, sub_aggs, keys, present, min_count,
                               None, interval_ms, date=True, recurse=recurse,
-                              fmt=spec.get("format"), tz=tz)
+                              fmt=spec.get("format"), tz=tz,
+                              calendar=(calendar, offset_ms) if calendar
+                              else None)
 
     if kind == "auto_date_histogram":
         target = int(spec.get("buckets", 10))
@@ -1854,7 +1856,7 @@ MAX_BUCKETS = 65536  # reference: search.max_buckets default
 
 def _histo_buckets(ctx, rows, sub_aggs, keys, present, min_count,
                    extended_bounds, interval, date=False, recurse=None,
-                   fmt=None, tz=None) -> dict:
+                   fmt=None, tz=None, calendar=None) -> dict:
     recurse = recurse or compute_aggs
     groups: Dict[float, np.ndarray] = {}
     valid = present & ~np.isnan(keys)
@@ -1888,6 +1890,9 @@ def _histo_buckets(ctx, rows, sub_aggs, keys, present, min_count,
             full.append(round(cur, 10))
             cur += interval
         all_keys = full
+    elif min_count == 0 and all_keys and calendar:
+        all_keys = _calendar_keys(all_keys[0], all_keys[-1], calendar[0],
+                                  tz, calendar[1])
     _check_max_buckets(ctx, len(all_keys))
     buckets = []
     for key in all_keys:
@@ -2033,6 +2038,48 @@ def _calendar_floor(millis: int, unit: str, tz=None) -> float:
     elif unit == "Y":
         d = d.replace(month=1, day=1, hour=0, minute=0, second=0, microsecond=0)
     return float(int(d.timestamp() * 1000))
+
+
+# nominal calendar-unit lengths in millis: probe steps for the boundary
+# walk, NOT bucket widths (DST and leap realities come from _calendar_floor)
+_CAL_NOMINAL = {"T": 60_000, "H": 3_600_000, "D": 86_400_000,
+                "W": 604_800_000, "M": 28 * 86_400_000,
+                "Q": 90 * 86_400_000, "Y": 365 * 86_400_000}
+
+
+def _calendar_next(cur: float, unit: str, tz=None) -> float:
+    """The calendar boundary after the boundary `cur`: probe a nominal
+    step, then correct with the true floor, so DST-shifted days and
+    variable months and years land where `_calendar_floor` puts them."""
+    step = _CAL_NOMINAL[unit]
+    nxt = _calendar_floor(int(cur + step), unit, tz)
+    while nxt <= cur:           # a short step inside a long month
+        step += 3_600_000
+        nxt = _calendar_floor(int(cur + step), unit, tz)
+    back = _calendar_floor(int(nxt - 1), unit, tz)
+    while back > cur:           # the probe overshot a boundary
+        nxt = back
+        back = _calendar_floor(int(nxt - 1), unit, tz)
+    return nxt
+
+
+def _calendar_keys(first: float, last: float, unit: str, tz=None,
+                   offset: float = 0.0) -> List[float]:
+    """Every bucket key of a calendar-interval histogram from `first` to
+    `last` (`min_doc_count` 0: the empty buckets between two that hold
+    documents exist too, as with a fixed interval). Keys are boundaries
+    plus `offset`."""
+    if (last - first) / _CAL_NOMINAL[unit] > MAX_BUCKETS:
+        raise IllegalArgumentError(
+            f"Trying to create too many buckets. Must be less than or "
+            f"equal to: [{MAX_BUCKETS}].")
+    keys = [first]
+    cur = first - offset
+    while True:
+        cur = _calendar_next(cur, unit, tz)
+        if cur + offset > last:
+            return keys
+        keys.append(cur + offset)
 
 
 def _millis_to_iso(millis: int) -> str:

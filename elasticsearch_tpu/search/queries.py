@@ -449,6 +449,12 @@ class RangeQuery(Query):
                     return float(mapper._parse(value))
                 except Exception:
                     pass
+            if "epoch_second" in fmt and not isinstance(value, bool) and (
+                    isinstance(value, (int, float))
+                    or re.fullmatch(r"-?\d{5,}(\.\d+)?", str(value))):
+                # a bound parses with the field's format, as its values
+                # do (`DateFieldMapper._parse`): a number is SECONDS
+                return float(value) * 1000.0
             return float(parse_date_millis(value, round_up=round_up))
         if isinstance(mapper, IpFieldMapper):
             return float(mapper.coerce(value))

@@ -258,6 +258,74 @@ def test_x64_agg_kernel_compiles(one_chip):
     assert total.dtype == jnp.float64
 
 
+def _dash_panel_programs(sh):
+    """The programs the four panels of `dash-aggs-steady` dispatch over
+    `http-logs-dash`'s row bucket of 2^20, by name: (kernel, static
+    names, argument specs, statics, lanes of the counts board). The rungs
+    are the ones the engine forms there: a histogram's from the COLUMN's
+    span (1,176 hours -> 2,048, whatever the request's range), `status`'s
+    eight values -> 8 (16 where a ninth appears)."""
+    r = 1 << 20
+    f64 = lambda *shape: _sds(sh, shape, jnp.float64)  # noqa: E731
+    flag = _sds(sh, (r,), jnp.bool_)
+    ords = _sds(sh, (r,), jnp.int32)
+    keys, hp, mp, op = f64(r), f64(6), f64(2), f64(1)
+    return {
+        "hourly.cal_counts-2048": (
+            agg_ops._agg_cal_counts, ("n_buckets",),
+            (keys, flag, flag, f64(2048), mp), {"n_buckets": 2048}, 2049),
+        "bytes-by-hour.hist_counts-2048": (
+            agg_ops._agg_hist_counts, ("n_buckets",),
+            (keys, flag, flag, hp), {"n_buckets": 2048}, 2049),
+        "bytes-by-hour.hist_metric-2048": (
+            agg_ops._agg_hist_metric, ("n_buckets",),
+            (keys, flag, flag, hp, mp, keys, flag), {"n_buckets": 2048},
+            2049),
+        "bytes-by-hour.hist_metric-256": (
+            agg_ops._agg_hist_metric, ("n_buckets",),
+            (keys, flag, flag, hp, mp, keys, flag), {"n_buckets": 256},
+            257),
+        "bytes-by-hour.total.ord_metric-8": (
+            agg_ops._agg_ord_metric, ("n_buckets",),
+            (ords, flag, mp, keys, flag), {"n_buckets": 8}, 9),
+        "status-in-range.ord_counts-8": (
+            agg_ops._agg_ord_counts, ("n_buckets",), (ords, flag),
+            {"n_buckets": 8}, 9),
+        "status-in-range.ord_counts-16": (
+            agg_ops._agg_ord_counts, ("n_buckets",), (ords, flag),
+            {"n_buckets": 16}, 17),
+        "status-by-hour.tree_counts-2048x8": (
+            agg_ops._agg_tree_counts, ("levels", "n_buckets"),
+            (flag, keys, flag, hp, ords, op),
+            {"levels": ("hist", "ord"), "n_buckets": (2048, 8)},
+            2048 * 8 + 1),
+        "status-by-hour.tree_counts-32x8": (
+            agg_ops._agg_tree_counts, ("levels", "n_buckets"),
+            (flag, keys, flag, hp, ords, op),
+            {"levels": ("hist", "ord"), "n_buckets": (32, 8)}, 32 * 8 + 1),
+    }
+
+
+@pytest.mark.parametrize("program", [
+    "hourly.cal_counts-2048", "bytes-by-hour.hist_counts-2048",
+    "bytes-by-hour.hist_metric-2048", "bytes-by-hour.hist_metric-256",
+    "bytes-by-hour.total.ord_metric-8", "status-in-range.ord_counts-8",
+    "status-in-range.ord_counts-16", "status-by-hour.tree_counts-2048x8",
+    "status-by-hour.tree_counts-32x8"])
+def test_dash_panel_program_compiles_at_the_cells_row_bucket(one_chip,
+                                                              program):
+    """The x64 scatter programs of the cell `dash-aggs-steady` at its
+    row bucket (2^20) and rungs: int64 counts and f64 sums by
+    `.at[].add` over a million rows, for the described chip."""
+    fn, static_names, args, statics, lanes = \
+        _dash_panel_programs(one_chip)[program]
+    with jax.enable_x64(True):
+        compiled = _compile(fn, static_names, *args, **statics)
+    counts = compiled.out_info
+    counts = counts[0] if isinstance(counts, (tuple, list)) else counts
+    assert counts.dtype == jnp.int64 and counts.shape == (lanes,)
+
+
 # ---------------------------------------------------------------------------
 # the served form: every serving kernel returns ONE packed board
 # ---------------------------------------------------------------------------

@@ -1,0 +1,205 @@
+"""The stages and counters of the device aggregation engine (ISSUE 35),
+in `telemetry.stage`'s one call form, always on:
+
+    aggs.plan          once a request with aggregations (`plan_for`)
+    aggs.mask          once a request that has a device node: the first
+                       node pays it, the others share the mask
+    aggs.device        once a node the device answers, with inside it
+    aggs.launch        once a program handed to the device, and
+    aggs.sync_wait     once a board read back as numpy
+    aggs.assemble      once a node the device answers
+    aggs.host          once a node the host walker answers, never else
+    counters           aggs.device_nodes, aggs.host_nodes, aggs.mask_bytes
+                       (the padded row bucket, once a LAUNCH: the host
+                       mask rides every call), aggs.board_lanes,
+                       aggs.matched_rows, aggs.dispatches.<family>
+
+`indices.aggs`'s `device_nanos`, `assemble_nanos` and `host_nanos` are the
+sums of the same clock marks, and a request without aggregations records
+none of it.
+"""
+
+import pytest
+
+from elasticsearch_tpu.telemetry import metrics
+
+ROWS = 300
+T0 = 893894400          # 1998-04-30T00:00:00Z, in seconds
+STAGES = ("aggs.plan", "aggs.mask", "aggs.device", "aggs.launch",
+          "aggs.sync_wait", "aggs.assemble", "aggs.host", "search.took")
+COUNTERS = ("aggs.device_nodes", "aggs.host_nodes", "aggs.mask_bytes",
+            "aggs.board_lanes", "aggs.matched_rows",
+            "aggs.dispatches.date_histogram", "aggs.dispatches.metric",
+            "aggs.dispatches.terms", "aggs.dispatches.date_histogram_tree")
+BY_HOUR = {"date_histogram": {"field": "@timestamp",
+                              "fixed_interval": "1h"}}
+
+
+def _read():
+    hist = {n: (metrics.histogram(n).count, metrics.histogram(n).sum_ns)
+            for n in STAGES}
+    return hist, {n: metrics.counter(n).value for n in COUNTERS}
+
+
+def _delta(before):
+    hist, count = _read()
+    return ({n: hist[n][0] - before[0][n][0] for n in STAGES},
+            {n: hist[n][1] - before[0][n][1] for n in STAGES},
+            {n: count[n] - before[1][n] for n in COUNTERS})
+
+
+@pytest.fixture()
+def node(tmp_path):
+    from elasticsearch_tpu.node import Node
+    n = Node(str(tmp_path / "n"),
+             settings={"telemetry.tracing.sample_rate": 0.0,
+                       "search.aggs.cost_router": "false"})
+    n.create_index_with_templates("logs", settings={}, mappings={
+        "properties": {
+            "@timestamp": {"type": "date", "format":
+                           "strict_date_optional_time||epoch_second"},
+            "status": {"type": "integer"}, "size": {"type": "integer"},
+            "clientip": {"type": "ip"}}})
+    ops = []
+    for i in range(ROWS):
+        ops.append({"index": {"_index": "logs"}})
+        ops.append({"@timestamp": T0 + 977 * i, "status": (200, 304, 404)[i % 3],
+                    "size": 100 + i, "clientip": f"40.0.{i % 7}.0"})
+    n.bulk(ops)
+    n.indices.get("logs").refresh()
+    yield n
+    n.close()
+
+
+def _r_pad(node):
+    (_svc, engine), = node._aggs.values()
+    return engine.store.snapshot(
+        node.indices.get("logs").combined_reader()).r_pad
+
+
+def _search(node, aggs, query=None):
+    body = {"size": 0, "aggs": aggs, "request_cache": False}
+    if query:
+        body["query"] = query
+    return node.search("logs", body)
+
+
+def test_a_device_node_records_each_stage_once(node):
+    aggs = {"by_status": {"terms": {"field": "status"}}}
+    first_day = {"range": {"@timestamp": {"gte": T0, "lt": T0 + 86400}}}
+    _search(node, aggs, first_day)                       # warm: compiles
+    before = _read()
+    resp = _search(node, aggs, first_day)
+    matched = resp["hits"]["total"]["value"]
+    assert 0 < matched < ROWS
+    assert sum(b["doc_count"] for b in resp["aggregations"]["by_status"]
+               ["buckets"]) == matched
+    counts, nanos, counters = _delta(before)
+    for name in ("aggs.plan", "aggs.mask", "aggs.device", "aggs.launch",
+                 "aggs.sync_wait", "aggs.assemble", "search.took"):
+        assert counts[name] == 1, f"{name} recorded {counts[name]} times"
+    assert counts["aggs.host"] == 0
+    # launch and the wait lie inside the device leg, all of it inside took
+    assert nanos["aggs.launch"] + nanos["aggs.sync_wait"] \
+        <= nanos["aggs.device"]
+    assert nanos["aggs.plan"] + nanos["aggs.mask"] + nanos["aggs.device"] \
+        + nanos["aggs.assemble"] <= nanos["search.took"]
+    assert counters["aggs.device_nodes"] == 1
+    assert counters["aggs.host_nodes"] == 0
+    assert counters["aggs.matched_rows"] == matched
+    assert counters["aggs.mask_bytes"] == _r_pad(node)   # one launch
+    assert counters["aggs.dispatches.terms"] == 1
+    assert counters["aggs.board_lanes"] == 8 + 1         # the rung + trash
+
+
+def test_the_mask_is_built_once_a_request_and_rides_every_launch(node):
+    """`bytes-by-hour`'s shape: a histogram with a `sum` under it and a
+    top-level `sum`: two nodes, three programs, one mask."""
+    aggs = {"by_hour": dict(BY_HOUR,
+                            aggs={"bytes": {"sum": {"field": "size"}}}),
+            "total_bytes": {"sum": {"field": "size"}}}
+    _search(node, aggs)
+    before = _read()
+    resp = _search(node, aggs)
+    assert resp["aggregations"]["total_bytes"]["value"] == sum(
+        100 + i for i in range(ROWS))
+    counts, _nanos, counters = _delta(before)
+    assert counts["aggs.plan"] == 1 and counts["aggs.mask"] == 1
+    assert counts["aggs.device"] == 2 and counts["aggs.assemble"] == 2
+    assert counts["aggs.launch"] == 3
+    assert counts["aggs.sync_wait"] == 1 + 4 + 4     # counts, 2 x 4 boards
+    assert counts["aggs.host"] == 0
+    assert counters["aggs.device_nodes"] == 2
+    assert counters["aggs.mask_bytes"] == 3 * _r_pad(node)
+    assert counters["aggs.dispatches.date_histogram"] == 2
+    assert counters["aggs.dispatches.metric"] == 1
+    assert counters["aggs.matched_rows"] == ROWS
+
+
+def test_the_two_level_tree_is_one_node_and_a_program_a_level(node):
+    """A counts board for the hours, one for hours x statuses: each
+    launched and read before the next (`_run_tree_node`)."""
+    aggs = {"by_hour": dict(BY_HOUR, aggs={
+        "by_status": {"terms": {"field": "status"}}})}
+    _search(node, aggs)
+    before = _read()
+    _search(node, aggs)
+    counts, _nanos, counters = _delta(before)
+    assert counts["aggs.device"] == 1 and counts["aggs.launch"] == 2
+    assert counters["aggs.dispatches.date_histogram_tree"] == 2
+    assert counters["aggs.mask_bytes"] == 2 * _r_pad(node)
+
+
+def test_a_node_the_walker_answers_records_aggs_host_and_no_other(node):
+    """`percentiles` has no device form: its node falls to the walker,
+    the `terms` beside it stays on the device."""
+    aggs = {"p": {"percentiles": {"field": "size"}},
+            "by_status": {"terms": {"field": "status"}}}
+    _search(node, aggs)
+    before = _read()
+    _search(node, aggs)
+    counts, _nanos, counters = _delta(before)
+    assert counts["aggs.host"] == 1 and counters["aggs.host_nodes"] == 1
+    assert counts["aggs.device"] == 1 and counters["aggs.device_nodes"] == 1
+    assert counts["aggs.mask"] == 1 and counts["aggs.assemble"] == 1
+    # a body with no device-eligible node at all: the plan, nothing more
+    before = _read()
+    _search(node, {"p": {"percentiles": {"field": "size"}}})
+    counts, _nanos, counters = _delta(before)
+    assert counts["aggs.plan"] == 1
+    assert sum(counts[n] for n in STAGES[1:7]) == 0
+    assert all(v == 0 for v in counters.values()), counters
+
+
+def test_indices_aggs_sums_the_stages_own_clock_marks(node):
+    aggs = {"by_hour": dict(BY_HOUR,
+                            aggs={"bytes": {"sum": {"field": "size"}}}),
+            "p": {"percentiles": {"field": "size"}}}
+    _search(node, aggs)
+    stats0 = node._aggs_stats_section()
+    before = _read()
+    for _ in range(3):
+        _search(node, aggs)
+    stats = node._aggs_stats_section()
+    _counts, nanos, _counters = _delta(before)
+    assert stats["device_nanos"] - stats0["device_nanos"] \
+        == nanos["aggs.device"] > 0
+    assert stats["assemble_nanos"] - stats0["assemble_nanos"] \
+        == nanos["aggs.assemble"] > 0
+    assert stats["host_nanos"] - stats0["host_nanos"] \
+        == nanos["aggs.host"] > 0
+    assert stats["device_nodes"] - stats0["device_nodes"] == 3
+    assert stats["host_nodes"] - stats0["host_nodes"] == 3
+
+
+def test_a_request_without_aggregations_records_none_of_them(node):
+    _search(node, {"by_status": {"terms": {"field": "status"}}})
+    before = _read()
+    for _ in range(3):
+        resp = node.search("logs", {"size": 2, "query": {"range": {
+            "@timestamp": {"gte": T0, "lt": T0 + 3600}}}})
+        assert len(resp["hits"]["hits"]) == 2
+    counts, _nanos, counters = _delta(before)
+    assert counts["search.took"] == 3
+    assert sum(counts[n] for n in STAGES[:7]) == 0
+    assert all(v == 0 for v in counters.values()), counters
