@@ -14,24 +14,34 @@ module gives doc-value fields the treatment `vectors/store.py` gives
   aggregated field becomes an `AggColumn` — an f64 value column + presence
   mask over the reader's live rows (padded to a pow-2 row bucket so the
   compiled shapes survive refreshes), plus, for terms aggs, a global
-  ordinal column (int32 ord per row over the sorted-unique value set).
+  ordinal column (int32 ord per row over the sorted-unique value set),
+  plus, where every value is an integer and the span fits, the column's
+  32-bit form `k32` ((v - least) / unit as int32, -1 for no value).
   Per-segment extractions cache by segment fingerprint, so append-only
   refreshes re-extract only delta segments (copy-on-write rebuild — an
-  in-flight search keeps the previous column's arrays).
+  in-flight search keeps the previous column's arrays). Each array goes
+  to the device on its first use: a column that runs the 32-bit programs
+  never uploads its f64 pair.
 
 * search: ONE dispatch per (bucket-source, metric) pair computes the fused
   filter→aggregate: the query's matched rows arrive as a boolean mask over
   the row bucket, bucket ids derive in-kernel from the resident key column
-  (ordinals for terms, affine floor for histogram/date_histogram, bound
-  comparisons for range), and a scatter-add reduces counts / sums / mins /
-  maxs per bucket into a board of `n_buckets + 1` lanes (the trash lane
-  collects pad rows and, for terms, the `missing` bucket).
+  (ordinals for terms, a table of bounds or an affine floor for
+  histogram/date_histogram, bound comparisons for range), and the counts /
+  sums / mins / maxs of every bucket come back as a board.
 
-* exactness: every kernel traces and executes under the dispatcher's
-  scoped x64 flag — counts accumulate in int64 (order-free, exact), sums
-  in f64. Host parity for sums is guaranteed only for *integral* columns
-  (every value integer-valued, sum of |values| < 2^53 — dates, longs,
-  counts), where any accumulation order reproduces numpy's pairwise sum
+* exactness, in one of two arithmetics, chosen from the COLUMNS alone:
+  the 32-bit programs (`aggs.n32_counts`, `aggs.n32_metric`: int32 counts,
+  an integer sum in limbs of at most 8 bits that no accumulator can
+  overflow at the row bucket, ids by integer comparison with an int32
+  table the host maps from its own key math; the host widens the boards
+  to int64 / f64) where the key and value columns have their `k32`; else
+  the x64 programs (`aggs.tree_counts`, `aggs.tree_metric`, `aggs.range_*`,
+  traced and executed under the dispatcher's scoped x64 flag: int64
+  counts, f64 sums, emulated on a TPU and 12 to 60 times slower there).
+  Host parity for sums is guaranteed only for *integral* columns (every
+  value integer-valued, sum of |values| < 2^53 — dates, longs, counts),
+  where any accumulation order reproduces numpy's pairwise sum
   bit-for-bit; `search/agg_plan.py` routes sum-bearing aggs on other
   columns to the host path. min/max/counts are order-insensitive and run
   on device for any numeric column.
@@ -42,9 +52,11 @@ module gives doc-value fields the treatment `vectors/store.py` gives
   psum/pmin/pmax — exact for the integral-sum contract above, so the
   per-shard device partials merge like every other mesh kernel.
 
-Kernel keys (`ops/dispatch.py`, strict closed grid): rows pad to the
-pow-2 row bucket fixed at column build; `n_buckets` rounds up
-AGG_B_LADDER; warmup pre-compiles the interactive rungs at column build.
+Kernel keys (`ops/dispatch.py`, strict closed grid; 14 of them: counts and
+metric in each arithmetic, range counts and metric, the HLL board, and
+their mesh twins): rows pad to the pow-2 row bucket fixed at column build;
+`n_buckets` rounds up AGG_B_LADDER; warmup pre-compiles the interactive
+rungs at column build.
 """
 
 from __future__ import annotations
@@ -92,9 +104,10 @@ HLL_MAX_LANES = 256
 
 # per-level kernel-arg arity for the composite tree kernels: level args
 # flatten in level order, each level contributing (row-shaped..., then
-# replicated params...) — see _split_level_args
-_LEVEL_ROW = {"ord": 1, "hist": 2, "cal": 2}
-_LEVEL_REPL = {"ord": 1, "hist": 1, "cal": 2}
+# replicated params...) — see _split_level_args; "ords" and "bounds" are
+# the 32-bit programs' levels: (ords) and (k32, lower bounds int32[k + 1])
+_LEVEL_ROW = {"ord": 1, "hist": 2, "cal": 2, "ords": 1, "bounds": 1}
+_LEVEL_REPL = {"ord": 1, "hist": 1, "cal": 2, "ords": 0, "bounds": 1}
 
 
 def bucket_count(n: int) -> Optional[int]:
@@ -119,24 +132,9 @@ def in_b_grid(b: int) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# kernels (traced under scoped x64 — see ops/dispatch.py _Kernel.x64)
+# x64 kernels (traced under scoped x64 — see ops/dispatch.py _Kernel.x64):
+# what a column without its 32-bit form runs
 # ---------------------------------------------------------------------------
-
-
-def _ord_targets(ords, n_buckets: int):
-    import jax.numpy as jnp
-    in_range = (ords >= 0) & (ords < n_buckets)
-    return jnp.where(in_range, ords, n_buckets)
-
-
-def _agg_ord_counts(ords, mask, n_buckets: int):
-    """Doc counts per ordinal: [B+1] int64; lane B collects matched rows
-    whose key is missing (the terms `missing` bucket) — pad rows have
-    mask False and never land anywhere."""
-    import jax.numpy as jnp
-    tgt = _ord_targets(ords, n_buckets)
-    return jnp.zeros(n_buckets + 1, dtype=jnp.int64).at[tgt].add(
-        jnp.where(mask, jnp.int64(1), jnp.int64(0)))
 
 
 def _metric_boards(tgt, ok, v_eff, n_buckets: int):
@@ -162,14 +160,6 @@ def _metric_eff(vals, present, mparams):
     return v_eff, p_eff
 
 
-def _agg_ord_metric(ords, mask, mparams, vals, present, n_buckets: int):
-    """Per-ordinal numeric metric boards (count/sum/min/max); lane B is
-    the missing-key bucket's metrics."""
-    v_eff, p_eff = _metric_eff(vals, present, mparams)
-    tgt = _ord_targets(ords, n_buckets)
-    return _metric_boards(tgt, mask & p_eff, v_eff, n_buckets)
-
-
 def _hist_ids(keys, kpresent, hparams, n_buckets: int):
     """Bucket ids from the resident key column: hparams f64[6] =
     (interval, offset, base, div, kflag, kmissing). `div` pre-divides
@@ -185,20 +175,6 @@ def _hist_ids(keys, kpresent, hparams, n_buckets: int):
     ids = (m - base).astype(jnp.int32)
     ok = p_eff & (ids >= 0) & (ids < n_buckets)
     return jnp.where(ok, ids, n_buckets), ok
-
-
-def _agg_hist_counts(keys, kpresent, mask, hparams, n_buckets: int):
-    import jax.numpy as jnp
-    tgt, ok = _hist_ids(keys, kpresent, hparams, n_buckets)
-    return jnp.zeros(n_buckets + 1, dtype=jnp.int64).at[tgt].add(
-        jnp.where(mask & ok, jnp.int64(1), jnp.int64(0)))
-
-
-def _agg_hist_metric(keys, kpresent, mask, hparams, mparams, vals, present,
-                     n_buckets: int):
-    tgt, ok = _hist_ids(keys, kpresent, hparams, n_buckets)
-    v_eff, p_eff = _metric_eff(vals, present, mparams)
-    return _metric_boards(tgt, mask & ok & p_eff, v_eff, n_buckets)
 
 
 def _range_members(keys, kpresent, mask, bounds, rparams):
@@ -251,34 +227,19 @@ def _cal_ids(keys, kpresent, cbounds, cparams, n_buckets: int):
     return jnp.where(ok, ids, 0), ok
 
 
-def _agg_cal_counts(keys, kpresent, mask, cbounds, cparams, n_buckets: int):
-    import jax.numpy as jnp
-    ids, ok = _cal_ids(keys, kpresent, cbounds, cparams, n_buckets)
-    tgt = jnp.where(ok, ids, n_buckets)
-    return jnp.zeros(n_buckets + 1, dtype=jnp.int64).at[tgt].add(
-        jnp.where(mask & ok, jnp.int64(1), jnp.int64(0)))
-
-
-def _agg_cal_metric(keys, kpresent, mask, cbounds, cparams, mparams, vals,
-                    present, n_buckets: int):
-    import jax.numpy as jnp
-    ids, ok = _cal_ids(keys, kpresent, cbounds, cparams, n_buckets)
-    tgt = jnp.where(ok, ids, n_buckets)
-    v_eff, p_eff = _metric_eff(vals, present, mparams)
-    return _metric_boards(tgt, mask & ok & p_eff, v_eff, n_buckets)
-
-
 def _tree_targets(mask, levels, n_buckets, flat_args):
     """Composite bucket ids over a chain of bucket levels: per level the
     id derives like the single-level kernels, the composite folds as
     `cid = cid * k_level + id`. A row is ok only if EVERY level resolves
     (the global trash lane catches the rest). Level arg layout:
-    ord → (ords, oparams f64[1]: missing-lane flag), hist → (keys,
+    ord → (ords, oparams f64[1]: missing-lane flag; under 0: absent keys
+    count in the trash lane), hist → (keys,
     kpresent, hparams), cal → (keys, kpresent, cbounds, cparams).
     Returns (tgt, ok, total) with tgt == total for not-ok rows."""
     import jax.numpy as jnp
     cid = jnp.zeros(mask.shape, dtype=jnp.int32)
     ok = mask
+    trashed = jnp.zeros(mask.shape, dtype=bool)
     total = 1
     i = 0
     for kind, k in zip(levels, n_buckets):
@@ -287,9 +248,12 @@ def _tree_targets(mask, levels, n_buckets, flat_args):
             i += 2
             absent = ords < 0
             # with a `missing` param the level's last lane IS the missing
-            # bucket (k was sized for it); otherwise absent rows drop out
+            # bucket (k was sized for it); otherwise absent rows drop out.
+            # A flag under 0 (a terms with no level under it) keeps them,
+            # in the board's last lane: that node's `missing` bucket
             ids = jnp.where(absent, jnp.int32(k - 1), ords)
-            lok = (~absent) | (op[0] > 0.0)
+            lok = (~absent) | (op[0] != 0.0)
+            trashed = trashed | (absent & (op[0] < 0.0))
         elif kind == "hist":
             keys, kp, hp = flat_args[i], flat_args[i + 1], flat_args[i + 2]
             i += 3
@@ -303,7 +267,7 @@ def _tree_targets(mask, levels, n_buckets, flat_args):
         cid = cid * k + jnp.where(lok, ids, 0)
         ok = ok & lok
         total *= k
-    return jnp.where(ok, cid, total), ok, total
+    return jnp.where(ok & ~trashed, cid, total), ok, total
 
 
 def _agg_tree_counts(mask, *level_args, levels, n_buckets):
@@ -334,6 +298,241 @@ def _agg_hll_board(mask, hidx, hrho, *level_args, levels, n_buckets):
     rho = jnp.where(ok, hrho, 0)
     board = jnp.zeros((total + 1, HLL_M), dtype=jnp.int32)
     return board.at[tgt, hidx].max(rho)
+
+
+# ---------------------------------------------------- 32-bit programs ----
+#
+# What a column of whole numbers that spans under 2^31 units runs instead
+# of the x64 programs above (64-bit integers and floats are
+# emulated on a TPU: PERF.md section 6, PR 36). The key and the value are
+# the column's `k32` (`AggColumn`: (v - vmin) / unit as int32, -1 where
+# the row holds no value), a level's bucket ids come from an int32 table
+# of lower bounds in that rebased domain (the host's own key math, mapped
+# with exact integer arithmetic), counts are int32, and a sum is split
+# into limbs narrow enough that no accumulator can overflow at the row
+# bucket (`limb_bits`); the host widens the boards to what the assembly
+# reads (`search/agg_plan.py` `_widen_*`).
+#
+# Lanes: a level of k buckets has k + 1 lanes, lane 0 for the rows whose
+# key is ABSENT and lane 1 + i for bucket i, so a table that starts with
+# -1 derives both; the flat board is the product of the levels' lanes,
+# the first level most significant. A row outside the request's mask
+# adds 0 wherever it lands. The host folds a level's lane 0 into its
+# `missing` bucket or drops it.
+
+I32_MAX = (1 << 31) - 1
+
+# the largest row bucket whose counts an int32 holds with a limb left
+N32_MAX_ROWS = 1 << 30
+
+# One-hot product: rows a tile (a tile's f32 sums stay exact under 2^24:
+# `_grid_n32` holds tile * (2^limb_bits - 1) to it; 512 to 8,192 rows read
+# the same on the chip), and the lanes a column past which ordinals are
+# scattered instead. Read in `board_form` alone.
+ONEHOT_TILE = 2048
+ONEHOT_LANES_A_COLUMN = 8192
+
+N32_KERNELS = frozenset({"aggs.n32_counts", "aggs.n32_metric"})
+
+
+def limb_bits(r_pad: int) -> int:
+    """Bits of one limb of a sum at this row bucket: r_pad rows of
+    (2^bits - 1) stay under 2^31 in an int32 accumulator (on the mesh
+    the psum is bounded by the whole bucket, so the GLOBAL one is
+    passed), and at most 8, what a bf16 operand holds exactly."""
+    return max(0, min(8, 31 - (int(r_pad) - 1).bit_length()))
+
+
+def n_limbs_for(k_max: int, bits: int) -> int:
+    """Limbs of `bits` bits that hold every value of 0..k_max."""
+    return max(1, -(-max(int(k_max), 1).bit_length() // bits))
+
+
+def board_form(lanes: int, kind: str, cols: int = 1) -> str:
+    """How a 32-bit board is filled, from its shape alone: 'onehot' (a
+    product of the widest level's one-hot with the other levels' and the
+    columns, tile after tile) or 'scatter' (int32 `.at[].add`, a column
+    after the other). `lanes` and `kind` are the widest level's, `cols`
+    what a row adds (1 for counts, 1 + the limbs for a sum). As the chip
+    read them at 2^19 rows (PERF.md section 6, PR 36; both forms take
+    twice as long at 2^20, so the row bucket drops out): the product 3.4
+    ms at 2,049 lanes, 4.9 at 8,193, 8.0 at 16,385, 30.4 at 65,537,
+    hardly more with four columns than with one; a scatter 5.2 ms a
+    column whatever the lanes, ONCE IT HAS THE LANES: ordinals are
+    theirs, a table has to be searched, which costs what the product
+    costs. So only ordinals scatter, past ONEHOT_LANES_A_COLUMN lanes a
+    column."""
+    if kind == "ords" and lanes > ONEHOT_LANES_A_COLUMN * cols:
+        return "scatter"
+    return "onehot"
+
+
+def _split_n32_levels(levels, level_args):
+    """[(kind, row array, table | None)] from the flat per-level args:
+    'ords' -> (ords,), 'bounds' -> (k32, lo)."""
+    out = []
+    i = 0
+    for kind in levels:
+        n = _LEVEL_ROW[kind] + _LEVEL_REPL[kind]
+        out.append((kind,) + tuple(level_args[i:i + n]) + (None,) * (2 - n))
+        i += n
+    return out
+
+
+def _n32_lane(kind, rows, table):
+    """A level's lane of every row: ord + 1, or the last bound at or
+    under the key (lane 0 where the key is -1, absent) by counting the
+    bounds at or under it: a bisection's gathers read 45 ms at 2^19 rows
+    and 2,049 bounds on the chip, the fused comparisons 2."""
+    import jax.numpy as jnp
+    if kind == "ords":
+        return rows + 1
+    return (jnp.searchsorted(table, rows, side="right",
+                             method="compare_all") - 1).astype(jnp.int32)
+
+
+def _n32_columns(mask, mk32, mmiss, bits: int, n_limbs: int):
+    """What a row adds: [ok] for counts, [ok, limb 0, ...] for a metric
+    (ok = matched and a value, the field's `missing` substitute put in),
+    each int32 [R]; and the value for min / max."""
+    import jax.numpy as jnp
+    if mk32 is None:
+        return [mask.astype(jnp.int32)], None, mask
+    v = jnp.where(mk32 >= 0, mk32, mmiss)
+    ok = mask & (v >= 0)
+    cols = [ok.astype(jnp.int32)]
+    for j in range(n_limbs):
+        limb = (v >> (bits * j)) & ((1 << bits) - 1)
+        cols.append(jnp.where(ok, limb, 0))
+    return cols, v, ok
+
+
+def _n32_scatter(lane, total: int, cols):
+    import jax.numpy as jnp
+    return jnp.stack([jnp.zeros(total, jnp.int32).at[lane].add(c)
+                      for c in cols])
+
+
+def _n32_onehot(lvls, lanes, cols, tile: int):
+    """[len(cols), prod(lanes)] int32 by products: the widest level m is
+    the one-hot side A [T, lanes_m] (for a table the THERMOMETER
+    key >= lo, whose board is the difference of neighbouring lanes: one
+    comparison an element, not two), the other side W [C, T] is the
+    one-hot of the other levels' composite lane times the columns. 0/1
+    and limbs of at most 8 bits are exact in bf16, a tile's sums exact in
+    f32 (under 2^24), tiles add in int32."""
+    import jax
+    import jax.numpy as jnp
+    m = max(range(len(lanes)), key=lambda j: lanes[j]) if lanes else None
+    minor = [j for j in range(len(lanes)) if j != m]
+    n_minor = 1
+    for j in minor:
+        n_minor *= lanes[j]
+    n_major = lanes[m] if m is not None else 1
+    r = cols[0].shape[0]
+    t = min(tile, r)
+    n_tiles = r // t
+    xs = {"cols": jnp.stack(cols).reshape(len(cols), n_tiles, t)
+          .swapaxes(0, 1),
+          "rows": [lv[1].reshape(n_tiles, t) for lv in lvls]}
+
+    def body(acc, x):
+        w = x["cols"].astype(jnp.bfloat16)                    # [c, T]
+        if n_minor > 1:
+            mlane = jnp.zeros(t, jnp.int32)
+            for j in minor:
+                kind, _rows, table = lvls[j]
+                mlane = mlane * lanes[j] + _n32_lane(
+                    kind, x["rows"][j], table)
+            oh = (jnp.arange(n_minor, dtype=jnp.int32)[:, None]
+                  == mlane[None, :]).astype(jnp.bfloat16)     # [minor, T]
+            w = (oh[:, None, :] * w[None, :, :]).reshape(-1, t)
+        if m is None:
+            a = jnp.ones((t, 1), jnp.bfloat16)
+        elif lvls[m][0] == "ords":
+            a = ((x["rows"][m] + 1)[:, None] == jnp.arange(
+                n_major, dtype=jnp.int32)[None, :]).astype(jnp.bfloat16)
+        else:
+            a = (x["rows"][m][:, None] >= lvls[m][2][None, :]).astype(
+                jnp.bfloat16)
+        part = jax.lax.dot_general(
+            w, a, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [C, major]
+        return acc + part.astype(jnp.int32), None
+
+    acc0 = jnp.zeros((n_minor * len(cols), n_major), jnp.int32)
+    g, _ = jax.lax.scan(body, acc0, xs)
+    if m is not None and lvls[m][0] == "bounds":
+        # thermometer: lane b holds the rows AT OR ABOVE bound b
+        g = g - jnp.concatenate(
+            [g[:, 1:], jnp.zeros((g.shape[0], 1), jnp.int32)], axis=1)
+    # [minor, cols, major] -> [cols, levels in order]
+    pre = 1
+    for j in minor:
+        if m is not None and j < m:
+            pre *= lanes[j]
+    g = g.reshape(pre, n_minor // pre, len(cols), n_major)
+    return g.transpose(2, 0, 3, 1).reshape(len(cols), -1)
+
+
+def _n32_boards(mask, mk32, mmiss, level_args, levels, n_buckets, parts,
+                bits, n_limbs, form):
+    import jax.numpy as jnp
+    lvls = _split_n32_levels(levels, level_args)
+    lanes = tuple(int(k) + 1 for k in n_buckets)
+    total = 1
+    for n in lanes:
+        total *= n
+    want_sum = mk32 is not None and "sum" in parts
+    cols, v, ok = _n32_columns(mask, mk32, mmiss, bits,
+                               n_limbs if want_sum else 0)
+    extrema = [p for p in ("min", "max") if mk32 is not None and p in parts]
+    lane = None
+    if form == "scatter" or extrema:
+        lane = jnp.zeros(mask.shape, jnp.int32)
+        for (kind, rows, table), n in zip(lvls, lanes):
+            lane = lane * n + _n32_lane(kind, rows, table)
+    if form == "scatter":
+        board = _n32_scatter(lane, total, cols)
+    else:
+        board = _n32_onehot(lvls, lanes, cols, ONEHOT_TILE)
+    if mk32 is None:
+        return board[0]
+    # an extremum is a scatter whatever the form: the product sums
+    out = [board]
+    for p in extrema:
+        if p == "min":
+            out.append(jnp.full(total, I32_MAX, jnp.int32).at[lane].min(
+                jnp.where(ok, v, I32_MAX)))
+        else:
+            out.append(jnp.full(total, -1, jnp.int32).at[lane].max(
+                jnp.where(ok, v, -1)))
+    return tuple(out)
+
+
+def _n32_pack(boards):
+    """ONE array a program: a read is a round trip to the device."""
+    import jax.numpy as jnp
+    return jnp.concatenate([boards[0]] + [b[None, :] for b in boards[1:]])
+
+
+def _agg_n32_counts(mask, *level_args, levels, n_buckets, form):
+    """Doc counts, int32 [prod(k + 1)]."""
+    return _n32_boards(mask, None, None, level_args, levels, n_buckets,
+                       (), 0, 0, form)
+
+
+def _agg_n32_metric(mask, mk32, mmiss, *level_args, levels, n_buckets,
+                    parts, limb_bits, n_limbs, form):
+    """A metric field's boards over the same lanes, ONE int32 array
+    [1 + n_limbs + extrema, L]: row 0 the count of rows with a value,
+    rows 1.. the sum's limbs (where `parts` names 'sum'), then the min,
+    then the max (where named), values in the field's rebased domain
+    (an empty lane's min is I32_MAX, its max -1). `mmiss` int32 [] is
+    the field's `missing` substitute there, -1 for none."""
+    return _n32_pack(_n32_boards(
+        mask, mk32, mmiss, level_args, levels, n_buckets, parts,
+        limb_bits, n_limbs, form))
 
 
 # ----------------------------------------------------------------- mesh ----
@@ -383,34 +582,6 @@ def _mesh_reduce(local_fn, mesh, row_args, repl_args, n_boards,
 # shard reduces its own row range into a full [B+1] board, then the boards
 # merge in-program (psum for counts/sums, pmin/pmax for extrema).
 
-def _agg_mesh_ord_counts(ords, mask, n_buckets: int, mesh=None):
-    return _mesh_reduce(
-        lambda o, m: _agg_ord_counts(o, m, n_buckets), mesh,
-        (ords, mask), (), 1)
-
-
-def _agg_mesh_ord_metric(ords, mask, vals, present, mparams,
-                         n_buckets: int, mesh=None):
-    return _mesh_reduce(
-        lambda o, m, v, p, mp: _agg_ord_metric(o, m, mp, v, p, n_buckets),
-        mesh, (ords, mask, vals, present), (mparams,), 4)
-
-
-def _agg_mesh_hist_counts(keys, kpresent, mask, hparams, n_buckets: int,
-                          mesh=None):
-    return _mesh_reduce(
-        lambda k, kp, m, hp: _agg_hist_counts(k, kp, m, hp, n_buckets),
-        mesh, (keys, kpresent, mask), (hparams,), 1)
-
-
-def _agg_mesh_hist_metric(keys, kpresent, mask, vals, present, hparams,
-                          mparams, n_buckets: int, mesh=None):
-    return _mesh_reduce(
-        lambda k, kp, m, v, p, hp, mp: _agg_hist_metric(
-            k, kp, m, hp, mp, v, p, n_buckets),
-        mesh, (keys, kpresent, mask, vals, present), (hparams, mparams), 4)
-
-
 def _agg_mesh_range_counts(keys, kpresent, mask, bounds, rparams, mesh=None):
     return _mesh_reduce(
         _agg_range_counts, mesh, (keys, kpresent, mask), (bounds, rparams),
@@ -424,23 +595,6 @@ def _agg_mesh_range_metric(keys, kpresent, mask, vals, present, bounds,
             k, kp, m, b, rp, mp, v, p),
         mesh, (keys, kpresent, mask, vals, present),
         (bounds, rparams, mparams), 4)
-
-
-def _agg_mesh_cal_counts(keys, kpresent, mask, cbounds, cparams,
-                         n_buckets: int, mesh=None):
-    return _mesh_reduce(
-        lambda k, kp, m, cb, cp: _agg_cal_counts(k, kp, m, cb, cp,
-                                                 n_buckets),
-        mesh, (keys, kpresent, mask), (cbounds, cparams), 1)
-
-
-def _agg_mesh_cal_metric(keys, kpresent, mask, vals, present, cbounds,
-                         cparams, mparams, n_buckets: int, mesh=None):
-    return _mesh_reduce(
-        lambda k, kp, m, v, p, cb, cp, mp: _agg_cal_metric(
-            k, kp, m, cb, cp, mp, v, p, n_buckets),
-        mesh, (keys, kpresent, mask, vals, present),
-        (cbounds, cparams, mparams), 4)
 
 
 def _split_level_args(levels, level_args):
@@ -512,20 +666,41 @@ def _agg_mesh_hll_board(mask, hidx, hrho, *level_args, levels, n_buckets,
                         merges=("max",))
 
 
+def _agg_mesh_n32_counts(mask, *level_args, levels, n_buckets, form,
+                         mesh=None):
+    rows, repls, rebuild = _split_level_args(levels, level_args)
+    nr = len(rows)
+
+    def local(m, *args):
+        return _agg_n32_counts(m, *rebuild(args[:nr], args[nr:]),
+                               levels=levels, n_buckets=n_buckets,
+                               form=form)
+
+    return _mesh_reduce(local, mesh, (mask,) + rows, repls, 1)
+
+
+def _agg_mesh_n32_metric(mask, mk32, mmiss, *level_args, levels, n_buckets,
+                         parts, limb_bits, n_limbs, form, mesh=None):
+    """The shards' int32 boards merge by psum / pmin / pmax: `limb_bits`
+    is the GLOBAL row bucket's, so the psum cannot overflow either."""
+    rows, repls, rebuild = _split_level_args(levels, level_args)
+    nr = len(rows)
+
+    def local(m, v, *args):
+        return _n32_boards(
+            m, v, args[-1], rebuild(args[:nr], args[nr:-1]), levels,
+            n_buckets, parts, limb_bits, n_limbs, form)
+
+    merges = ("sum",) + tuple(p for p in ("min", "max") if p in parts)
+    merged = _mesh_reduce(local, mesh, (mask, mk32) + rows,
+                          repls + (mmiss,), len(merges), merges=merges)
+    return _n32_pack(merged if isinstance(merged, tuple) else (merged,))
+
+
 # ------------------------------------------------------------ grid checks --
 
 def _row_bucket_ok(r: int) -> bool:
     return r >= 1 and (r & (r - 1)) == 0
-
-
-def _grid_ord(statics, sigs) -> bool:
-    r = sigs[0][0][0]
-    return _row_bucket_ok(int(r)) and in_b_grid(int(statics["n_buckets"]))
-
-
-def _grid_hist(statics, sigs) -> bool:
-    r = sigs[0][0][0]
-    return _row_bucket_ok(int(r)) and in_b_grid(int(statics["n_buckets"]))
 
 
 def _grid_range(statics, sigs) -> bool:
@@ -537,11 +712,6 @@ def _grid_range(statics, sigs) -> bool:
             b = s[0][0]
             break
     return _row_bucket_ok(int(r)) and (b is None or in_b_grid(int(b)))
-
-
-def _grid_cal(statics, sigs) -> bool:
-    r = sigs[0][0][0]
-    return _row_bucket_ok(int(r)) and in_b_grid(int(statics["n_buckets"]))
 
 
 def _tree_lanes(statics):
@@ -571,40 +741,48 @@ def _grid_hll(statics, sigs) -> bool:
             and total <= HLL_MAX_LANES)
 
 
+def _grid_n32(statics, sigs) -> bool:
+    """The 32-bit programs' grid, and the bound that makes their
+    accumulators exact: rows of the (global) bucket times the largest
+    limb under 2^31, a tile's under 2^24."""
+    r = int(sigs[0][0][0])
+    nb = tuple(int(k) for k in statics["n_buckets"])
+    total = 1
+    for k in nb:
+        total *= k
+    ok = (_row_bucket_ok(r) and r <= N32_MAX_ROWS
+          and len(nb) == len(statics["levels"]) <= TREE_MAX_DEPTH + 1
+          and all(in_b_grid(k) for k in nb) and total <= TREE_MAX_LANES
+          and statics["form"] in ("onehot", "scatter"))
+    if ok and "sum" in statics.get("parts", ()):
+        top = (1 << int(statics["limb_bits"])) - 1
+        ok = (1 <= statics["limb_bits"] <= 8
+              and 1 <= statics["n_limbs"] * statics["limb_bits"] <= 38
+              and r * top < (1 << 31)
+              and min(ONEHOT_TILE, r) * top < (1 << 24))
+    return ok
+
+
 def _register():
     reg = dispatch.DISPATCH.register
-    reg("aggs.ord_counts", _agg_ord_counts,
-        static_argnames=("n_buckets",), grid_check=_grid_ord, x64=True)
-    reg("aggs.ord_metric", _agg_ord_metric,
-        static_argnames=("n_buckets",), grid_check=_grid_ord, x64=True)
-    reg("aggs.hist_counts", _agg_hist_counts,
-        static_argnames=("n_buckets",), grid_check=_grid_hist, x64=True)
-    reg("aggs.hist_metric", _agg_hist_metric,
-        static_argnames=("n_buckets",), grid_check=_grid_hist, x64=True)
+    n32 = ("levels", "n_buckets", "form")
+    n32m = n32 + ("parts", "limb_bits", "n_limbs")
+    reg("aggs.n32_counts", _agg_n32_counts, static_argnames=n32,
+        grid_check=_grid_n32)
+    reg("aggs.n32_metric", _agg_n32_metric, static_argnames=n32m,
+        grid_check=_grid_n32)
+    reg("aggs.mesh_n32_counts", _agg_mesh_n32_counts,
+        static_argnames=n32 + ("mesh",), grid_check=_grid_n32)
+    reg("aggs.mesh_n32_metric", _agg_mesh_n32_metric,
+        static_argnames=n32m + ("mesh",), grid_check=_grid_n32)
     reg("aggs.range_counts", _agg_range_counts,
         grid_check=_grid_range, x64=True)
     reg("aggs.range_metric", _agg_range_metric,
         grid_check=_grid_range, x64=True)
-    reg("aggs.mesh_ord_counts", _agg_mesh_ord_counts,
-        static_argnames=("n_buckets", "mesh"), grid_check=_grid_ord,
-        x64=True)
-    reg("aggs.mesh_ord_metric", _agg_mesh_ord_metric,
-        static_argnames=("n_buckets", "mesh"), grid_check=_grid_ord,
-        x64=True)
-    reg("aggs.mesh_hist_counts", _agg_mesh_hist_counts,
-        static_argnames=("n_buckets", "mesh"), grid_check=_grid_hist,
-        x64=True)
-    reg("aggs.mesh_hist_metric", _agg_mesh_hist_metric,
-        static_argnames=("n_buckets", "mesh"), grid_check=_grid_hist,
-        x64=True)
     reg("aggs.mesh_range_counts", _agg_mesh_range_counts,
         static_argnames=("mesh",), grid_check=_grid_range, x64=True)
     reg("aggs.mesh_range_metric", _agg_mesh_range_metric,
         static_argnames=("mesh",), grid_check=_grid_range, x64=True)
-    reg("aggs.cal_counts", _agg_cal_counts,
-        static_argnames=("n_buckets",), grid_check=_grid_cal, x64=True)
-    reg("aggs.cal_metric", _agg_cal_metric,
-        static_argnames=("n_buckets",), grid_check=_grid_cal, x64=True)
     reg("aggs.tree_counts", _agg_tree_counts,
         static_argnames=("levels", "n_buckets"), grid_check=_grid_tree,
         x64=True)
@@ -613,12 +791,6 @@ def _register():
         x64=True)
     reg("aggs.hll_board", _agg_hll_board,
         static_argnames=("levels", "n_buckets"), grid_check=_grid_hll,
-        x64=True)
-    reg("aggs.mesh_cal_counts", _agg_mesh_cal_counts,
-        static_argnames=("n_buckets", "mesh"), grid_check=_grid_cal,
-        x64=True)
-    reg("aggs.mesh_cal_metric", _agg_mesh_cal_metric,
-        static_argnames=("n_buckets", "mesh"), grid_check=_grid_cal,
         x64=True)
     reg("aggs.mesh_tree_counts", _agg_mesh_tree_counts,
         static_argnames=("levels", "n_buckets", "mesh"),
@@ -649,16 +821,22 @@ _register()
 
 class AggColumn:
     """One field's columnar agg data over a reader snapshot, padded to the
-    store's pow-2 row bucket. Device mirrors upload lazily (under the
-    scoped x64 flag so f64 survives) and a mesh-sharded copy is kept when
-    the serving policy would route this corpus to the mesh."""
+    store's pow-2 row bucket. Each array is uploaded on its first use
+    (`device`: the f64 pair under the scoped x64 flag so f64 survives),
+    row-sharded when the serving policy routes this corpus to the mesh.
+
+    `k32` is the 32-bit resident form, there where every value is an
+    integer and the span fits: (v - k_base) / k_unit
+    as int32, -1 where the row holds no value; `k_unit` is the gcd of
+    the values' distances from the least (1000 for an `epoch_second`
+    date held in millis), `k_max` the largest of them. A column that
+    has it runs the 32-bit programs and its f64 pair stays on the host."""
 
     __slots__ = ("field", "version", "n_rows", "r_pad", "vals", "present",
                  "numeric", "integral_exact", "multi_valued", "ords_built",
                  "ords", "ord_keys", "vmin", "vmax",
-                 "hll_built", "hll_idx", "hll_rho",
-                 "_device", "_device_mesh", "_device_mesh_key",
-                 "_device_hll", "_device_hll_mesh", "_device_hll_mesh_key")
+                 "k32", "k_base", "k_unit", "k_max",
+                 "hll_built", "hll_idx", "hll_rho", "_dev")
 
     def __init__(self, field: str):
         self.field = field
@@ -675,77 +853,74 @@ class AggColumn:
         self.ord_keys: List[Any] = []             # ord -> raw key value
         self.vmin = None
         self.vmax = None
+        self.k32: Optional[np.ndarray] = None     # int32[r_pad], -1 absent
+        self.k_base = 0
+        self.k_unit = 1
+        self.k_max = 0
         self.hll_built = False
         self.hll_idx: Optional[np.ndarray] = None  # int32[r_pad] register
         self.hll_rho: Optional[np.ndarray] = None  # int32[r_pad], 0 absent
-        self._device = None
-        self._device_mesh = None
-        self._device_mesh_key = None
-        self._device_hll = None
-        self._device_hll_mesh = None
-        self._device_hll_mesh_key = None
+        self._dev: Dict[tuple, tuple] = {}  # (name, sharded) -> (mesh, arr)
+
+    def build_k32(self, integral: bool) -> None:
+        """The 32-bit form of `vals`, or None where it cannot hold them:
+        a value that is no integer (`integral`: every present value is
+        one; a KEY needs no more, `integral_exact` is the sums' matter),
+        a span of 2^31 - 1 units or more, or rows x span past what the
+        host's int64 widening holds."""
+        self.k32 = None
+        pv = self.vals[self.present]
+        if not (integral and len(pv) and self.r_pad <= N32_MAX_ROWS
+                and max(abs(self.vmin), abs(self.vmax)) < _EXACT_INT):
+            return
+        iv = pv.astype(np.int64)
+        base = int(iv.min())
+        dist = iv - base
+        unit = int(np.gcd.reduce(dist)) or 1
+        k_max = int(dist.max()) // unit
+        if k_max >= I32_MAX or k_max * unit * self.r_pad >= (1 << 62):
+            return
+        k32 = np.full(self.r_pad, -1, dtype=np.int32)
+        k32[self.present] = dist // unit
+        self.k32, self.k_base, self.k_unit, self.k_max = (
+            k32, base, unit, k_max)
+
+    def to_k32(self, value) -> Optional[int]:
+        """A value (a `missing` substitute) in the rebased domain, or
+        None where it is not on the column's lattice or past int32."""
+        try:
+            f = float(value)
+        except (TypeError, ValueError):
+            return None
+        if self.k32 is None or not f.is_integer() or abs(f) >= _EXACT_INT:
+            return None
+        k, rem = divmod(int(f) - self.k_base, self.k_unit)
+        return k if rem == 0 and 0 <= k < I32_MAX else None
 
     # ------------------------------------------------------------- device
-    def device_arrays(self):
-        """(vals f64, present, ords int32|None) resident jax arrays."""
-        if self._device is not None:
-            return self._device
-        import jax.numpy as jnp
-        from elasticsearch_tpu.ops.dispatch import _x64_scope
-        with _x64_scope(True):
-            vals = jnp.asarray(self.vals)
-            present = jnp.asarray(self.present)
-            ords = None if self.ords is None else jnp.asarray(self.ords)
-        self._device = (vals, present, ords)
-        return self._device
-
-    def device_arrays_mesh(self, mesh):
-        """Row-sharded device copies for the mesh kernels (r_pad must
-        divide by the shard count; the caller checks)."""
-        if (self._device_mesh is not None
-                and self._device_mesh_key is mesh):
-            return self._device_mesh
+    def device(self, name: str, mesh=None):
+        """One of this column's arrays resident on the device ('vals',
+        'present', 'ords', 'k32', 'hll_idx', 'hll_rho'), uploaded on
+        first use; with a mesh, sharded by rows (r_pad must divide by
+        the shard count; the caller checks)."""
+        slot = (name, mesh is not None)
+        got = self._dev.get(slot)
+        if got is not None and got[0] is mesh:
+            return got[1]
         import jax
         import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
         from elasticsearch_tpu.ops.dispatch import _x64_scope
-        from elasticsearch_tpu.parallel import mesh as mesh_lib
-        row = NamedSharding(mesh, P(mesh_lib.SHARD_AXIS))
-        with _x64_scope(True):
-            vals = jax.device_put(jnp.asarray(self.vals), row)
-            present = jax.device_put(jnp.asarray(self.present), row)
-            ords = None if self.ords is None else \
-                jax.device_put(jnp.asarray(self.ords), row)
-        self._device_mesh = (vals, present, ords)
-        self._device_mesh_key = mesh
-        return self._device_mesh
+        host = getattr(self, name)
+        with _x64_scope(host.dtype.itemsize == 8):
+            arr = jnp.asarray(host)
+            if mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec as P
 
-    def hll_device_arrays(self):
-        """(hidx int32, hrho int32) resident jax arrays — the per-row HLL
-        register index and rank columns."""
-        if self._device_hll is not None:
-            return self._device_hll
-        import jax.numpy as jnp
-        self._device_hll = (jnp.asarray(self.hll_idx),
-                            jnp.asarray(self.hll_rho))
-        return self._device_hll
-
-    def hll_device_arrays_mesh(self, mesh):
-        if (self._device_hll_mesh is not None
-                and self._device_hll_mesh_key is mesh):
-            return self._device_hll_mesh
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from elasticsearch_tpu.parallel import mesh as mesh_lib
-        row = NamedSharding(mesh, P(mesh_lib.SHARD_AXIS))
-        self._device_hll_mesh = (
-            jax.device_put(jnp.asarray(self.hll_idx), row),
-            jax.device_put(jnp.asarray(self.hll_rho), row))
-        self._device_hll_mesh_key = mesh
-        return self._device_hll_mesh
+                from elasticsearch_tpu.parallel import mesh as mesh_lib
+                arr = jax.device_put(
+                    arr, NamedSharding(mesh, P(mesh_lib.SHARD_AXIS)))
+        self._dev[slot] = (mesh, arr)
+        return arr
 
 
 class StoreSnapshot:
@@ -837,6 +1012,7 @@ class AggFieldStore:
             self.stats["bytes"] = sum(
                 c.vals.nbytes + c.present.nbytes
                 + (c.ords.nbytes if c.ords is not None else 0)
+                + (c.k32.nbytes if c.k32 is not None else 0)
                 + (c.hll_idx.nbytes + c.hll_rho.nbytes
                    if c.hll_idx is not None else 0)
                 for c in self._columns.values())
@@ -892,10 +1068,11 @@ class AggFieldStore:
         if len(pv):
             col.vmin = float(pv.min())
             col.vmax = float(pv.max())
-            finite = np.isfinite(pv)
+            integral = bool(np.isfinite(pv).all()
+                            and np.all(pv == np.floor(pv)))
             col.integral_exact = bool(
-                finite.all() and np.all(pv == np.floor(pv))
-                and float(np.abs(pv).sum()) < _EXACT_INT)
+                integral and float(np.abs(pv).sum()) < _EXACT_INT)
+            col.build_k32(integral)
         else:
             col.integral_exact = True  # empty sums are trivially exact
         if want_ords and not multi:
@@ -946,9 +1123,9 @@ class AggFieldStore:
     # ------------------------------------------------------------- warmup
     def warmup_entries(self, col: AggColumn, mesh=None) -> list:
         """Dispatch warmup grid for one freshly-built column (shape-only
-        specs — no data materialized)."""
+        specs — no data materialized): the 32-bit programs where the
+        column has its `k32`, the x64 ones where it has not."""
         import jax
-        import jax.numpy as jnp
         r = col.r_pad
         f64 = jax.ShapeDtypeStruct((r,), np.dtype(np.float64))
         b1 = jax.ShapeDtypeStruct((r,), np.dtype(bool))
@@ -956,7 +1133,24 @@ class AggFieldStore:
         hp = jax.ShapeDtypeStruct((6,), np.dtype(np.float64))
         mp = jax.ShapeDtypeStruct((2,), np.dtype(np.float64))
         op = jax.ShapeDtypeStruct((1,), np.dtype(np.float64))
+        mm = jax.ShapeDtypeStruct((), np.dtype(np.int32))
+        narrow = col.k32 is not None
         entries = []
+
+        bits = limb_bits(r)
+        n_limbs = n_limbs_for(col.k_max, bits)
+
+        def n32(levels, ks, level_specs, metric):
+            st = {"levels": levels, "n_buckets": ks}
+            entries.append(("aggs.n32_counts", (b1,) + level_specs, dict(
+                st, form=board_form(ks[0] + 1, levels[0]))))
+            if metric:
+                entries.append((
+                    "aggs.n32_metric", (b1, i32, mm) + level_specs,
+                    dict(st, parts=("sum",), limb_bits=bits,
+                         n_limbs=n_limbs, form=board_form(
+                             ks[0] + 1, levels[0], 1 + n_limbs))))
+
         rungs = set(WARMUP_AGG_BUCKETS)
         if col.ords is not None and col.ord_keys:
             b_ord = bucket_count(len(col.ord_keys))
@@ -964,29 +1158,32 @@ class AggFieldStore:
                 # clamp: one pathological high-cardinality field must not
                 # AOT-compile the giant rungs for every column build
                 rungs.add(min(b_ord, WARMUP_MAX_ORD_B))
+        if narrow:
+            # a whole-match metric of this field (no level)
+            entries.append((
+                "aggs.n32_metric", (b1, i32, mm),
+                {"levels": (), "n_buckets": (), "form": "onehot",
+                 "parts": ("sum",), "limb_bits": bits,
+                 "n_limbs": n_limbs}))
         for b in sorted(rungs):
             if col.ords is not None:
-                entries.append(("aggs.ord_counts", (i32, b1),
-                                {"n_buckets": b}))
-                entries.append(("aggs.ord_metric", (i32, b1, mp, f64, b1),
-                                {"n_buckets": b}))
-                entries.append(("aggs.tree_counts", (b1, i32, op),
-                                {"levels": ("ord",), "n_buckets": (b,)}))
-                entries.append(("aggs.tree_metric",
-                                (b1, mp, f64, b1, i32, op),
-                                {"levels": ("ord",), "n_buckets": (b,)}))
-            if col.numeric:
-                entries.append(("aggs.hist_counts", (f64, b1, b1, hp),
-                                {"n_buckets": b}))
-                entries.append(("aggs.hist_metric",
-                                (f64, b1, b1, hp, mp, f64, b1),
-                                {"n_buckets": b}))
+                n32(("ords",), (b,), (i32,), narrow)
+                if not narrow:
+                    entries.append(("aggs.tree_metric",
+                                    (b1, mp, f64, b1, i32, op),
+                                    {"levels": ("ord",),
+                                     "n_buckets": (b,)}))
+            if narrow:
+                lo = jax.ShapeDtypeStruct((b + 1,), np.dtype(np.int32))
+                n32(("bounds",), (b,), (i32, lo), True)
+            elif col.numeric:
                 cb = jax.ShapeDtypeStruct((b,), np.dtype(np.float64))
-                entries.append(("aggs.cal_counts", (f64, b1, b1, cb, mp),
-                                {"n_buckets": b}))
-                entries.append(("aggs.cal_metric",
-                                (f64, b1, b1, cb, mp, mp, f64, b1),
-                                {"n_buckets": b}))
+                for kind, level in (("hist", (f64, b1, hp)),
+                                    ("cal", (f64, b1, cb, mp))):
+                    st = {"levels": (kind,), "n_buckets": (b,)}
+                    entries.append(("aggs.tree_counts", (b1,) + level, st))
+                    entries.append(("aggs.tree_metric",
+                                    (b1, mp, f64, b1) + level, st))
         if col.numeric:
             bounds = jax.ShapeDtypeStruct((AGG_B_LADDER[0], 2),
                                           np.dtype(np.float64))

@@ -6,8 +6,12 @@ template trick generalized to agg bodies, so a dashboard's repeated shape
 plans once and only the per-query numeric slots re-bind), and each search
 executes the plan as a handful of pre-compiled dispatches: the matched row
 set becomes a boolean mask over the columnar store's row bucket, bucket
-ids derive in-kernel from resident key columns, and scatter-add boards
-come back as `n_buckets + 1` lanes of counts / sums / mins / maxs.
+ids derive in-kernel from resident key columns, and boards come back as
+`n_buckets + 1` lanes of counts / sums / mins / maxs. A node's levels
+bind once (`_bind_level`) to what either arithmetic takes; each program
+(`_launch_counts`, `_launch_metric`) is the 32-bit one where every column
+it reads has its 32-bit form and the x64 one otherwise (`ops/aggs.py`),
+and its boards are read back into one layout (`_Pending.widen`).
 
 Supported on device — numerically IDENTICAL to `compute_aggs` (final
 mode) and `compute_partial_aggs` (distributed partial mode), pinned by
@@ -19,10 +23,10 @@ tests/test_device_aggs.py:
   histogram        interval, offset, missing, min_doc_count,
                    extended_bounds, format
   date_histogram   fixed intervals (+ offset, format, time_zone
-                   rendering) by an affine floor; calendar intervals
-                   (hour .. year, any time_zone) by a search of the
-                   boundary table `_calendar_bounds` walks once a
-                   column version (`aggs.cal_counts` / `cal_metric`)
+                   rendering) and calendar intervals (hour .. year,
+                   any time_zone) by the table of bounds the host's
+                   own key math gives (`_n32_bounds`; the calendar's
+                   is walked once a column version, `_calendar_bounds`)
   range            numeric from/to/key ranges (overlaps allowed)
   metrics          avg, sum, min, max, stats, value_count — top-level and
                    as one-level sub-aggs of any bucket agg above
@@ -110,6 +114,79 @@ class _Fallback(Exception):
         super().__init__(reason)
         self.reason = reason
         self.observed = observed
+
+
+_TWO52 = float(1 << 52)
+
+# the boards a metric's kind reads: the 32-bit program computes no other
+_N32_PARTS = {"value_count": (), "sum": ("sum",), "avg": ("sum",),
+              "min": ("min",), "max": ("max",),
+              "stats": ("sum", "min", "max")}
+
+
+class _Pending:
+    """A launched program: its boards, still on the device, and how the
+    host reads them (`widen`: the list of numpy boards to what the
+    assembly consumes)."""
+
+    __slots__ = ("boards", "widen")
+
+    def __init__(self, boards: tuple, widen):
+        self.boards = boards
+        self.widen = widen
+
+
+def _assembly_lanes(board: np.ndarray, ks, folds, merge=np.add,
+                  empty=0) -> np.ndarray:
+    """A 32-bit program's board (last axis: (k + 1) lanes a level, lane 0
+    the rows whose key is absent) as the assembly reads it: prod(k)
+    lanes and one more. A level's absent lane is merged into the lane
+    `folds` names for it (its `missing` bucket) or dropped; the last
+    lane is the absent keys' of a single level (a terms' `missing`
+    bucket, as the x64 board has it) and empty under a tree. `board` is
+    the host's own widened copy and is written to."""
+    lead = board.ndim - 1
+    a = board.reshape(board.shape[:lead] + tuple(k + 1 for k in ks))
+    last = np.full(board.shape[:lead] + (1,), empty, dtype=board.dtype)
+    for j, fold in enumerate(folds):
+        a = np.moveaxis(a, lead + j, 0)
+        if fold is not None and 0 <= fold < ks[j]:
+            a[fold + 1] = merge(a[fold + 1], a[0])
+        elif len(ks) == 1:
+            last = a[0][..., None]
+        a = np.moveaxis(a[1:], 0, lead + j)
+    return np.concatenate(
+        [a.reshape(board.shape[:lead] + (-1,)), last], axis=-1)
+
+
+def _widen_metric(board, parts, bits, n_limbs, col, ks, folds):
+    """(count int64, sum f64, min f64, max f64) over the assembly's lanes
+    from a 32-bit metric program's board (`ops/aggs._agg_n32_metric`):
+    the limbs recombined in int64 (sum of limb_j * 2^(bits * j), times
+    the column's unit, plus count times its base: under 2^62 by
+    `AggColumn.build_k32`), the extrema moved back from the rebased
+    domain, an empty lane's to +-inf."""
+    wide = board.astype(np.int64)
+    cnt = _assembly_lanes(wide[0], ks, folds)
+    total = np.zeros(cnt.shape, dtype=np.float64)
+    mn = np.full(cnt.shape, np.inf)
+    mx = np.full(cnt.shape, -np.inf)
+    row = 1
+    if "sum" in parts:
+        units = np.zeros(wide.shape[1:], dtype=np.int64)
+        for j in range(n_limbs):
+            units += wide[row + j] << (bits * j)
+        row += n_limbs
+        total = (_assembly_lanes(units, ks, folds) * col.k_unit
+                 + cnt * col.k_base).astype(np.float64)
+    has = cnt > 0
+    for part, merge, empty, out in (("min", np.minimum, aggs_ops.I32_MAX, mn),
+                                    ("max", np.maximum, -1, mx)):
+        if part in parts:
+            k = _assembly_lanes(wide[row], ks, folds, merge=merge, empty=empty)
+            out[has] = (k[has] * col.k_unit + col.k_base).astype(np.float64)
+            row += 1
+    return cnt, total, mn, mx
 
 
 class _SubMetric:
@@ -802,14 +879,20 @@ class AggEngine:
         """One program of this request handed to the device: bind, what
         rides the call (the host mask among it) and the enqueue, not the
         wait. A mask still on the host is uploaded by the call, every
-        call anew: `aggs.mask_bytes` counts it there."""
+        call anew: `aggs.mask_bytes` counts it there. With a mesh the
+        program is the kernel's `aggs.mesh_*` twin. `aggs.programs.narrow`
+        and `.x64` count the programs by their arithmetic."""
         mask_box["dispatches"] += 1
+        narrow = name in aggs_ops.N32_KERNELS
+        _counter("aggs.programs.narrow").inc(int(narrow))
+        _counter("aggs.programs.x64").inc(int(not narrow))
         host_mask = mask_box.get("mask")
         if host_mask is not None and any(a is host_mask for a in args):
             _counter("aggs.mask_bytes").inc(host_mask.nbytes)
         with telemetry.stage("aggs.launch"):
             if mesh is not None:
-                return _mesh_call(name, *args, mesh=mesh, **statics)
+                return _mesh_call(name.replace("aggs.", "aggs.mesh_"),
+                                  *args, mesh=mesh, **statics)
             return dispatch.call(name, *args, **statics)
 
     @staticmethod
@@ -821,9 +904,8 @@ class AggEngine:
         _counter("aggs.board_lanes").inc(out.size)
         return out
 
-    def _read_boards(self, mboards: dict) -> dict:
-        return {n: tuple(self._read(x) for x in b)
-                for n, b in mboards.items()}
+    def _read_pending(self, pend: "_Pending"):
+        return pend.widen([self._read(b) for b in pend.boards])
 
     def _mesh_for(self, mask_box):
         """Route this node's reduce: mesh or single-device (counted by
@@ -875,6 +957,188 @@ class AggEngine:
         with _x64_scope(True):
             return [jax.device_put(jnp.asarray(a), row) for a in arrays]
 
+    # ------------------------------------------------------------ levels --
+    def _bind_level(self, ctx, node, body, snap, mesh, single=False):
+        """One bucket level of a node's chain: its rung `k`, what the
+        x64 program takes for it (`x64`, fetched only if that program
+        runs: the column's f64 pair is uploaded there) and, where the
+        column's 32-bit form gives the host's own ids, what the 32-bit
+        program takes (`n32`: kind, arguments, the lane its absent keys
+        fold into). `single`: the node has no level under it, and a
+        terms' absent keys stay in the board's last lane."""
+        reader = ctx.reader
+        if node.kind == "terms":
+            return dict(self._ords_level(ctx, node.field, body, snap, mesh,
+                                         single), meta=None, body=body)
+        col = self.store.column(reader, node.field, snap=snap)
+        hparams, meta = self._hist_params(node, body, col)
+        k = meta["n_buckets"]
+        lvl = {"kind": "hist", "k": k, "col": col, "miss": False,
+               "meta": meta, "body": body, "n32": None}
+        if k == 0:
+            # empty key column and no missing substitute: the whole
+            # subtree reduces to zero boards (assembly-only)
+            return dict(lvl, kind="empty")
+        if meta.get("cal_args") is not None:
+            cbounds, cparams = meta["cal_args"]
+            lvl.update(kind="cal", x64=lambda: (
+                col.device("vals", mesh), col.device("present", mesh),
+                cbounds, cparams))
+        else:
+            lvl["x64"] = lambda: (col.device("vals", mesh),
+                                  col.device("present", mesh), hparams)
+        table = self._n32_bounds(col, meta)
+        if table is not None:
+            lvl["n32"] = ("bounds",
+                          lambda: (col.device("k32", mesh), table),
+                          meta["miss_lane"])
+        return lvl
+
+    def _ords_level(self, ctx, field, body, snap, mesh, single=False):
+        """A level of a field's global ordinals (a terms, or the extra
+        level an exact cardinality counts over). Absent keys: dropped;
+        with a `missing` in `body` the level's last lane, sized for it;
+        `single` (a terms with no level under it): the board's last lane
+        whatever `body` says, where the assembly looks for them."""
+        col = self.store.column(ctx.reader, field, want_ords=True,
+                                snap=snap)
+        if col.multi_valued:
+            raise _Fallback("multi_valued_field")
+        n_keys = len(col.ord_keys)
+        miss = body.get("missing") is not None and not single
+        k = aggs_ops.bucket_count(max(n_keys, 1) + (1 if miss else 0))
+        if k is None:
+            raise _Fallback("cardinality_off_grid", observed=n_keys)
+        oparams = np.asarray([-1.0 if single else float(miss)],
+                             dtype=np.float64)
+        return {"kind": "ord", "k": k, "col": col, "miss": miss,
+                "x64": lambda: (col.device("ords", mesh), oparams),
+                "n32": ("ords", lambda: (col.device("ords", mesh),),
+                        k - 1 if miss else None)}
+
+    @staticmethod
+    def _n32_bounds(col, meta) -> Optional[np.ndarray]:
+        """The level's int32 table in the column's rebased domain: entry
+        0 is -1 (the lane of absent keys), entry 1 + j the least k32
+        inside bucket j, ceil((bound_j - k_base) / k_unit) in exact
+        integer arithmetic over the host's own bounds (the calendar
+        table, or (base + j) * interval + offset): for an integral v,
+        v >= bound <=> k32 >= that. None where the 32-bit form cannot
+        give the host's ids: a column without `k32`, date_nanos'
+        division, an interval or offset that is no integer (the host's
+        f64 floor may round at a boundary), magnitudes past 2^52."""
+        k = meta["n_buckets"]
+        offset = meta["offset"]
+        if col.k32 is None or meta["div"] != 1.0 \
+                or not float(offset).is_integer() \
+                or max(abs(col.vmin - offset),
+                       abs(col.vmax - offset)) >= _TWO52:
+            return None
+        cal = meta.get("cal_bounds")
+        if cal is not None:
+            real = np.asarray(cal, dtype=np.float64).astype(np.int64)
+        else:
+            interval, base = meta["interval"], meta["base"]
+            if not (float(interval).is_integer()
+                    and (abs(base) + k) * interval < _TWO52):
+                return None
+            real = (int(base) + np.arange(k, dtype=np.int64)) \
+                * int(interval)
+        lo = -((col.k_base - (real + int(offset))) // col.k_unit)
+        table = np.full(k + 1, aggs_ops.I32_MAX, dtype=np.int32)
+        table[0] = -1
+        table[1:1 + len(lo)] = np.clip(lo, 0, col.k_max + 1)
+        return table
+
+    def _zero_level(self, snap, mesh):
+        """The one-lane level of a whole-match metric under the x64
+        program: every row's ordinal is 0."""
+        zeros = self.store.zero_ords(snap.r_pad, mesh)
+        oparams = np.zeros(1, dtype=np.float64)
+        return {"kind": "ord", "k": aggs_ops.AGG_B_LADDER[0],
+                "x64": lambda: (zeros, oparams), "n32": None}
+
+    @staticmethod
+    def _chain_n32(chain, cols=1):
+        """(levels, n_buckets, form, flat arguments, folds) of a chain
+        every level of which has its 32-bit form, else None. `cols`:
+        what a row adds to a board (`ops/aggs.board_form`)."""
+        if any(lv["n32"] is None for lv in chain):
+            return None
+        wide = max(chain, key=lambda lv: lv["k"], default=None)
+        return (tuple(lv["n32"][0] for lv in chain),
+                tuple(lv["k"] for lv in chain),
+                aggs_ops.board_form(wide["k"] + 1, wide["n32"][0], cols)
+                if chain else "onehot",
+                tuple(a for lv in chain for a in lv["n32"][1]()),
+                [lv["n32"][2] for lv in chain])
+
+    @staticmethod
+    def _chain_x64(chain):
+        return (tuple(lv["kind"] for lv in chain),
+                tuple(lv["k"] for lv in chain),
+                tuple(a for lv in chain for a in lv["x64"]()))
+
+    def _launch_counts(self, mask_box, mask_io, chain, mesh) -> "_Pending":
+        """The chain's doc counts: one program, 32-bit where every level
+        has that form. Read back as int64 [prod(k) + 1], the last lane
+        the rows no bucket took."""
+        n32 = self._chain_n32(chain)
+        if n32 is not None:
+            levels, ks, form, flat, folds = n32
+            board = self._launch(mask_box, "aggs.n32_counts", mask_io,
+                                 *flat, mesh=mesh, levels=levels,
+                                 n_buckets=ks, form=form)
+            return _Pending((board,), lambda got: _assembly_lanes(
+                got[0].astype(np.int64), ks, folds))
+        levels, ks, flat = self._chain_x64(chain)
+        board = self._launch(mask_box, "aggs.tree_counts", mask_io, *flat,
+                             mesh=mesh, levels=levels, n_buckets=ks)
+        return _Pending((board,), lambda got: got[0])
+
+    def _launch_metric(self, mask_box, mask_io, chain, kind, mcol, mbody,
+                       mesh) -> "_Pending":
+        """One metric field's boards over the chain's lanes, read back as
+        (count int64, sum f64, min f64, max f64). 32-bit where the chain
+        and the field's column have that form and the `missing`
+        substitute lies on the column's lattice; there only the boards
+        the metric's kind reads are computed."""
+        snap = mask_box["snap"]
+        narrow = mcol.k32 is not None \
+            and all(lv["n32"] is not None for lv in chain)
+        mmiss = -1
+        if narrow and mbody.get("missing") is not None:
+            mmiss = mcol.to_k32(mbody["missing"])
+            narrow = mmiss is not None
+        if narrow:
+            parts = _N32_PARTS[kind]
+            bits = aggs_ops.limb_bits(snap.r_pad) if "sum" in parts else 0
+            n_limbs = aggs_ops.n_limbs_for(max(mcol.k_max, mmiss), bits) \
+                if bits else 0
+            levels, ks, form, flat, folds = self._chain_n32(
+                chain, 1 + n_limbs)
+            out = self._launch(
+                mask_box, "aggs.n32_metric", mask_io,
+                mcol.device("k32", mesh), np.int32(mmiss), *flat, mesh=mesh,
+                levels=levels, n_buckets=ks, parts=parts, limb_bits=bits,
+                n_limbs=n_limbs, form=form)
+            return _Pending((out,), lambda got: _widen_metric(
+                got[0], parts, bits, n_limbs, mcol, ks, folds))
+        levels, ks, flat = self._chain_x64(
+            chain or [self._zero_level(snap, mesh)])
+        out = self._launch(
+            mask_box, "aggs.tree_metric", mask_io, self._mparams(mbody),
+            mcol.device("vals", mesh), mcol.device("present", mesh), *flat,
+            mesh=mesh, levels=levels, n_buckets=ks)
+        return _Pending(tuple(out), tuple)
+
+    def _record_mesh_leg(self, mesh, n_boards, lanes) -> None:
+        from elasticsearch_tpu.parallel import mesh as mesh_lib
+        from elasticsearch_tpu.parallel import policy
+        s = int(mesh.shape[mesh_lib.SHARD_AXIS])
+        policy.record_leg("aggs", policy.gather_bytes(s, n_boards, lanes))
+        self._count("mesh_dispatches")
+
     def _run_device_node(self, ctx, node, spec, rows, mask_box,
                          partial=False):
         store = self.store
@@ -887,56 +1151,50 @@ class AggEngine:
         mask = self._mask_for(rows, mask_box)
         mesh = self._mesh_for(mask_box)
         boards: Dict[str, Any] = {"n_matched": int(len(rows))}
-        mesh_used = False
+        mask_io = mask
+        if mesh is not None:
+            (mask_io,) = self._sharded(mesh, [mask])
 
-        def launch(name, *args, **statics):
-            return self._launch(mask_box, name, *args, **statics)
-
-        if node.mode == "terms":
-            col = store.column(reader, node.field, want_ords=True,
-                               snap=snap)
-            if col.multi_valued:
-                raise _Fallback("multi_valued_field")
-            b = aggs_ops.bucket_count(max(len(col.ord_keys), 1))
-            if b is None:
-                raise _Fallback("cardinality_off_grid",
-                                observed=len(col.ord_keys))
-            mcols = self._metric_cols(ctx, node, snap)
-            if mesh is not None:
-                vals_d, pres_d, ords_d = col.device_arrays_mesh(mesh)
-                (mask_d,) = self._sharded(mesh, [mask])
-                counts = launch("aggs.mesh_ord_counts", ords_d,
-                                mask_d, n_buckets=b, mesh=mesh)
-                mboards = {}
-                for mname, (m, mc) in mcols.items():
-                    mv_d, mp_d, _ = mc.device_arrays_mesh(mesh)
-                    mboards[mname] = launch(
-                        "aggs.mesh_ord_metric", ords_d, mask_d, mv_d,
-                        mp_d, self._mparams(_sub_body(spec, mname)),
-                        n_buckets=b, mesh=mesh)
-                mesh_used = True
-            else:
-                _v, _p, ords_d = col.device_arrays()
-                counts = launch("aggs.ord_counts", ords_d, mask,
-                                n_buckets=b)
-                mboards = {}
-                for mname, (m, mc) in mcols.items():
-                    mv_d, mp_d, _ = mc.device_arrays()
-                    mboards[mname] = launch(
-                        "aggs.ord_metric", ords_d, mask,
-                        self._mparams(_sub_body(spec, mname)), mv_d,
-                        mp_d, n_buckets=b)
-            boards.update(counts=self._read(counts),
-                          metrics=self._read_boards(mboards), col=col,
-                          mask=mask)
-
-        elif node.mode in ("histogram", "date_histogram"):
+        if node.mode == "range":
             col = store.column(reader, node.field, snap=snap)
-            hparams, meta = self._hist_params(node, body, col)
-            boards["hist_meta"] = meta
-            b = meta["n_buckets"]
+            bounds, frm_to = self._range_bounds(body)
+            boards["frm_to"] = frm_to
+            rparams = self._mparams(body)
             mcols = self._metric_cols(ctx, node, snap)
-            if b == 0:
+            keys_d = col.device("vals", mesh)
+            kp_d = col.device("present", mesh)
+            tail = (bounds, rparams)
+            counts = self._launch(mask_box, "aggs.range_counts", keys_d,
+                                  kp_d, mask_io, *tail, mesh=mesh)
+            mboards = {}
+            for mname, (m, mc) in mcols.items():
+                mv_d = mc.device("vals", mesh)
+                mp_d = mc.device("present", mesh)
+                mp = self._mparams(_sub_body(spec, mname))
+                # the mesh twin takes the row-shaped arrays first
+                args = (mv_d, mp_d) + tail + (mp,) if mesh is not None \
+                    else tail + (mp, mv_d, mp_d)
+                mboards[mname] = self._launch(
+                    mask_box, "aggs.range_metric", keys_d, kp_d, mask_io,
+                    *args, mesh=mesh)
+            boards.update(
+                counts=self._read(counts),
+                metrics={n: tuple(self._read(x) for x in b)
+                         for n, b in mboards.items()}, col=col)
+
+        elif node.mode == "metric":
+            col = store.column(reader, node.field, snap=snap)
+            self._check_metric_col(node.kind, col)
+            pend = self._launch_metric(mask_box, mask_io, [], node.kind,
+                                       col, body, mesh)
+            boards.update(metric=self._read_pending(pend), col=col)
+
+        else:  # terms, histogram, date_histogram: one level
+            lvl = self._bind_level(ctx, node, body, snap, mesh, single=True)
+            mcols = self._metric_cols(ctx, node, snap)
+            if node.mode != "terms":
+                boards["hist_meta"] = lvl["meta"]
+            if lvl["kind"] == "empty":
                 # nothing present and no missing substitute: zero boards
                 boards.update(
                     counts=np.zeros(1, dtype=np.int64),
@@ -944,142 +1202,33 @@ class AggEngine:
                                  np.zeros(1, np.float64),
                                  np.full(1, np.inf), np.full(1, -np.inf))
                              for n in mcols},
-                    col=col)
+                    col=lvl["col"])
                 return boards, False
-            cal_args = meta.get("cal_args")
-            if mesh is not None:
-                keys_d, kp_d, _ = col.device_arrays_mesh(mesh)
-                (mask_d,) = self._sharded(mesh, [mask])
-                if cal_args is not None:
-                    cbounds, cparams = cal_args
-                    counts = launch("aggs.mesh_cal_counts", keys_d,
-                                    kp_d, mask_d, cbounds, cparams,
-                                    n_buckets=b, mesh=mesh)
-                else:
-                    counts = launch("aggs.mesh_hist_counts", keys_d,
-                                    kp_d, mask_d, hparams,
-                                    n_buckets=b, mesh=mesh)
-                mboards = {}
-                for mname, (m, mc) in mcols.items():
-                    mv_d, mp_d, _ = mc.device_arrays_mesh(mesh)
-                    if cal_args is not None:
-                        cbounds, cparams = cal_args
-                        mboards[mname] = launch(
-                            "aggs.mesh_cal_metric", keys_d, kp_d, mask_d,
-                            mv_d, mp_d, cbounds, cparams,
-                            self._mparams(_sub_body(spec, mname)),
-                            n_buckets=b, mesh=mesh)
-                    else:
-                        mboards[mname] = launch(
-                            "aggs.mesh_hist_metric", keys_d, kp_d, mask_d,
-                            mv_d, mp_d, hparams,
-                            self._mparams(_sub_body(spec, mname)),
-                            n_buckets=b, mesh=mesh)
-                mesh_used = True
-            else:
-                keys_d, kp_d, _ = col.device_arrays()
-                if cal_args is not None:
-                    cbounds, cparams = cal_args
-                    counts = launch("aggs.cal_counts", keys_d,
-                                    kp_d, mask, cbounds, cparams,
-                                    n_buckets=b)
-                else:
-                    counts = launch("aggs.hist_counts", keys_d,
-                                    kp_d, mask, hparams,
-                                    n_buckets=b)
-                mboards = {}
-                for mname, (m, mc) in mcols.items():
-                    mv_d, mp_d, _ = mc.device_arrays()
-                    if cal_args is not None:
-                        cbounds, cparams = cal_args
-                        mboards[mname] = launch(
-                            "aggs.cal_metric", keys_d, kp_d, mask,
-                            cbounds, cparams,
-                            self._mparams(_sub_body(spec, mname)), mv_d,
-                            mp_d, n_buckets=b)
-                    else:
-                        mboards[mname] = launch(
-                            "aggs.hist_metric", keys_d, kp_d, mask,
-                            hparams, self._mparams(_sub_body(spec, mname)),
-                            mv_d, mp_d, n_buckets=b)
-            boards.update(counts=self._read(counts),
-                          metrics=self._read_boards(mboards), col=col)
+            counts = self._launch_counts(mask_box, mask_io, [lvl], mesh)
+            mpend = {mname: self._launch_metric(
+                mask_box, mask_io, [lvl], m.kind, mc,
+                _sub_body(spec, mname), mesh)
+                for mname, (m, mc) in mcols.items()}
+            boards.update(counts=self._read_pending(counts),
+                          metrics={n: self._read_pending(p)
+                                   for n, p in mpend.items()},
+                          col=lvl["col"], mask=mask)
 
-        elif node.mode == "range":
-            col = store.column(reader, node.field, snap=snap)
-            bounds, frm_to = self._range_bounds(body)
-            boards["frm_to"] = frm_to
-            rparams = self._mparams(body)
-            mcols = self._metric_cols(ctx, node, snap)
-            if mesh is not None:
-                keys_d, kp_d, _ = col.device_arrays_mesh(mesh)
-                (mask_d,) = self._sharded(mesh, [mask])
-                counts = launch("aggs.mesh_range_counts", keys_d,
-                                kp_d, mask_d, bounds, rparams,
-                                mesh=mesh)
-                mboards = {}
-                for mname, (m, mc) in mcols.items():
-                    mv_d, mp_d, _ = mc.device_arrays_mesh(mesh)
-                    mboards[mname] = launch(
-                        "aggs.mesh_range_metric", keys_d, kp_d, mask_d,
-                        mv_d, mp_d, bounds, rparams,
-                        self._mparams(_sub_body(spec, mname)), mesh=mesh)
-                mesh_used = True
-            else:
-                keys_d, kp_d, _ = col.device_arrays()
-                counts = launch("aggs.range_counts", keys_d, kp_d,
-                                mask, bounds, rparams)
-                mboards = {}
-                for mname, (m, mc) in mcols.items():
-                    mv_d, mp_d, _ = mc.device_arrays()
-                    mboards[mname] = launch(
-                        "aggs.range_metric", keys_d, kp_d, mask, bounds,
-                        rparams, self._mparams(_sub_body(spec, mname)),
-                        mv_d, mp_d)
-            boards.update(counts=self._read(counts),
-                          metrics=self._read_boards(mboards), col=col)
-
-        elif node.mode == "metric":
-            col = store.column(reader, node.field, snap=snap)
-            self._check_metric_col(node.kind, col)
-            zeros = store.zero_ords(snap.r_pad, mesh)
-            mparams = self._mparams(body)
-            mv_d, mp_d, _ = (col.device_arrays_mesh(mesh)
-                             if mesh is not None else col.device_arrays())
-            if mesh is not None:
-                (mask_d,) = self._sharded(mesh, [mask])
-                board = launch("aggs.mesh_ord_metric", zeros,
-                               mask_d, mv_d, mp_d, mparams,
-                               n_buckets=aggs_ops.AGG_B_LADDER[0],
-                               mesh=mesh)
-                mesh_used = True
-            else:
-                board = launch("aggs.ord_metric", zeros, mask,
-                               mparams, mv_d, mp_d,
-                               n_buckets=aggs_ops.AGG_B_LADDER[0])
-            boards.update(metric=tuple(self._read(x) for x in board),
-                          col=col)
-
-        if mesh_used:
-            from elasticsearch_tpu.parallel import mesh as mesh_lib
-            from elasticsearch_tpu.parallel import policy
-            s = int(mesh.shape[mesh_lib.SHARD_AXIS])
-            n_boards = 1 + 4 * len(node.subs)
+        if mesh is not None:
             b_len = len(boards.get("counts",
                                    boards.get("metric", (np.zeros(1),))[0]))
-            policy.record_leg("aggs",
-                              policy.gather_bytes(s, n_boards, b_len))
-            self._count("mesh_dispatches")
-        return boards, mesh_used
+            self._record_mesh_leg(mesh, 1 + 4 * len(node.subs), b_len)
+        return boards, mesh is not None
 
     # ------------------------------------------------- composite trees --
     def _run_tree_node(self, ctx, node, spec, rows, mask_box, partial):
         """Composite-id tree dispatch: each bucket level along a path
-        binds an in-kernel id source (ordinals / histogram floor /
-        calendar table), and every tree node gets ONE flat board per
-        (counts | metric leaf | cardinality leaf) whose lane is the
-        composite `parent_id * k_child + child_id` over its level chain.
-        Top-level `cardinality` is the zero-level degenerate case."""
+        binds an in-kernel id source (ordinals / a table of bounds /
+        the x64 histogram floor), and every tree node gets ONE flat
+        board per (counts | metric leaf | cardinality leaf) whose lane is
+        the composite `parent_id * k_child + child_id` over its level
+        chain. Top-level `cardinality` is the zero-level degenerate
+        case."""
         store = self.store
         reader = ctx.reader
         snap = mask_box["snap"]
@@ -1087,73 +1236,26 @@ class AggEngine:
         mesh = self._mesh_for(mask_box)
         boards: Dict[str, Any] = {"n_matched": int(len(rows)),
                                   "mask": mask}
-        mesh_used = mesh is not None
-        n_dispatch = [0]
         lanes_out = [0]
+        mask_io = mask
         if mesh is not None:
             (mask_io,) = self._sharded(mesh, [mask])
-        else:
-            mask_io = mask
 
-        def level_arrays(col):
-            return (col.device_arrays_mesh(mesh) if mesh is not None
-                    else col.device_arrays())
+        def read(pend):
+            got = self._read_pending(pend)
+            lanes_out[0] += sum(int(np.size(x)) for x in (
+                got if isinstance(got, tuple) else (got,)))
+            return got
 
-        def call(name, *args, **statics):
-            n_dispatch[0] += 1
-            if mesh is not None:
-                name = name.replace("aggs.", "aggs.mesh_")
-            return self._launch(mask_box, name, *args, mesh=mesh,
-                                **statics)
-
-        def bind_level(child, body):
-            if child.kind == "terms":
-                col = store.column(reader, child.field, want_ords=True,
-                                   snap=snap)
-                if col.multi_valued:
-                    raise _Fallback("multi_valued_field")
-                n_keys = len(col.ord_keys)
-                miss = body.get("missing") is not None
-                k = aggs_ops.bucket_count(max(n_keys, 1)
-                                          + (1 if miss else 0))
-                if k is None:
-                    raise _Fallback("cardinality_off_grid",
-                                    observed=n_keys)
-                _v, _p, ords_d = level_arrays(col)
-                oparams = np.asarray([1.0 if miss else 0.0],
-                                     dtype=np.float64)
-                return {"kind": "ord", "k": k, "args": (ords_d, oparams),
-                        "col": col, "miss": miss, "meta": None,
-                        "body": body}
-            col = store.column(reader, child.field, snap=snap)
-            hparams, meta = self._hist_params(child, body, col)
-            k = meta["n_buckets"]
-            if k == 0:
-                # empty key column and no missing substitute: the whole
-                # subtree reduces to zero boards (assembly-only)
-                return {"kind": "empty", "k": 0, "args": (), "col": col,
-                        "miss": False, "meta": meta, "body": body}
-            keys_d, kp_d, _ = level_arrays(col)
-            if meta.get("cal_args") is not None:
-                cbounds, cparams = meta["cal_args"]
-                return {"kind": "cal", "k": k,
-                        "args": (keys_d, kp_d, cbounds, cparams),
-                        "col": col, "miss": False, "meta": meta,
-                        "body": body}
-            return {"kind": "hist", "k": k,
-                    "args": (keys_d, kp_d, hparams), "col": col,
-                    "miss": False, "meta": meta, "body": body}
-
-        def bind_card(body, levels, ks, flat, empty):
-            field = body.get("field")
+        def bind_card(body, chain, empty):
             total = 1
-            for kk in ks:
-                total *= kk
+            for lv in chain:
+                total *= lv["k"]
             if partial:
                 # partial mode mirrors the host's HLL walker (which
                 # ignores `missing` — host parity, not an oversight)
-                col = store.column(reader, field, want_hll=True,
-                                   snap=snap)
+                col = store.column(reader, body.get("field"),
+                                   want_hll=True, snap=snap)
                 if col.multi_valued:
                     raise _Fallback("multi_valued_field")
                 if total > aggs_ops.HLL_MAX_LANES:
@@ -1161,85 +1263,63 @@ class AggEngine:
                 if empty:
                     return {"partial": True, "board": None, "col": col,
                             "body": body}
-                hh = (col.hll_device_arrays_mesh(mesh)
-                      if mesh is not None else col.hll_device_arrays())
-                board = call("aggs.hll_board", mask_io, hh[0], hh[1],
-                             *flat, levels=levels, n_buckets=ks)
-                lanes_out[0] += (total + 1) * aggs_ops.HLL_M
-                return {"partial": True, "board": self._read(board),
+                levels, ks, flat = self._chain_x64(chain)
+                board = self._launch(
+                    mask_box, "aggs.hll_board", mask_io,
+                    col.device("hll_idx", mesh),
+                    col.device("hll_rho", mesh), *flat, mesh=mesh,
+                    levels=levels, n_buckets=ks)
+                return {"partial": True,
+                        "board": read(_Pending((board,),
+                                               lambda got: got[0])),
                         "col": col, "body": body}
-            # final mode is EXACT (host counts a distinct set): the card
-            # field rides one more ord level on the counts board
-            col = store.column(reader, field, want_ords=True, snap=snap)
-            if col.multi_valued:
-                raise _Fallback("multi_valued_field")
-            n_keys = len(col.ord_keys)
-            miss = body.get("missing") is not None
-            k_card = aggs_ops.bucket_count(max(n_keys, 1)
-                                           + (1 if miss else 0))
-            if k_card is None:
-                raise _Fallback("cardinality_off_grid", observed=n_keys)
-            if total * k_card > aggs_ops.TREE_MAX_LANES:
+            # final mode is EXACT (the host counts a distinct set): the
+            # field rides one more level of ordinals on the counts board
+            lvl = self._ords_level(ctx, body.get("field"), body, snap, mesh)
+            if total * lvl["k"] > aggs_ops.TREE_MAX_LANES:
                 raise _Fallback("tree_off_grid")
-            if empty:
-                return {"partial": False, "board": None, "k": k_card,
-                        "col": col, "miss": miss, "body": body}
-            _v, _p, ords_d = level_arrays(col)
-            oparams = np.asarray([1.0 if miss else 0.0],
-                                 dtype=np.float64)
-            board = call("aggs.tree_counts", mask_io, *flat, ords_d,
-                         oparams, levels=levels + ("ord",),
-                         n_buckets=ks + (k_card,))
-            lanes_out[0] += total * k_card + 1
-            return {"partial": False, "board": self._read(board),
-                    "k": k_card, "col": col, "miss": miss, "body": body}
+            rec = {"partial": False, "board": None, "k": lvl["k"],
+                   "col": lvl["col"], "miss": lvl["miss"], "body": body}
+            if not empty:
+                rec["board"] = read(self._launch_counts(
+                    mask_box, mask_io, chain + [lvl], mesh))
+            return rec
 
         def run_node(node_, spec_node, chain):
-            levels = tuple(lv["kind"] for lv in chain)
             ks = tuple(lv["k"] for lv in chain)
-            empty = "empty" in levels
+            empty = any(lv["kind"] == "empty" for lv in chain)
             total = 1
             for kk in ks:
                 total *= kk
             if not empty and total > aggs_ops.TREE_MAX_LANES:
                 raise _Fallback("tree_off_grid")
-            flat = tuple(a for lv in chain for a in lv["args"])
             tnode: Dict[str, Any] = {"node": node_, "chain": chain,
                                      "ks": ks}
-            if empty:
-                tnode["counts"] = None
-            else:
-                tnode["counts"] = self._read(call(
-                    "aggs.tree_counts", mask_io, *flat, levels=levels,
-                    n_buckets=ks))
-                lanes_out[0] += total + 1
-            metrics = {}
+            # every program of this node is launched, then read
+            counts = None if empty else self._launch_counts(
+                mask_box, mask_io, chain, mesh)
+            mpend = {}
             for m in node_.subs:
                 mcol = store.column(reader, m.field, snap=snap)
                 self._check_metric_col(m.kind, mcol)
-                if empty:
-                    metrics[m.name] = None
-                    continue
-                mv_d, mp_d, _ = level_arrays(mcol)
-                mp = self._mparams(_sub_body(spec_node, m.name))
-                metrics[m.name] = tuple(self._read(x) for x in call(
-                    "aggs.tree_metric", mask_io, mp, mv_d, mp_d, *flat,
-                    levels=levels, n_buckets=ks))
-                lanes_out[0] += 4 * (total + 1)
-            tnode["metrics"] = metrics
-            cards = {}
-            for c in node_.cards:
-                cards[c.name] = bind_card(_sub_body(spec_node, c.name),
-                                          levels, ks, flat, empty)
-            tnode["cards"] = cards
+                mpend[m.name] = None if empty else self._launch_metric(
+                    mask_box, mask_io, chain, m.kind, mcol,
+                    _sub_body(spec_node, m.name), mesh)
+            tnode["counts"] = None if counts is None else read(counts)
+            tnode["metrics"] = {n: None if p is None else read(p)
+                                for n, p in mpend.items()}
+            tnode["cards"] = {
+                c.name: bind_card(_sub_body(spec_node, c.name), chain,
+                                  empty)
+                for c in node_.cards}
             children = {}
             sub_spec = (spec_node.get("aggs")
                         or spec_node.get("aggregations") or {})
             for ch in node_.children:
                 ch_spec = sub_spec[ch.name]
-                lvl = bind_level(ch, ch_spec[ch.kind])
-                children[ch.name] = run_node(ch, ch_spec,
-                                             chain + [lvl])
+                lvl = self._bind_level(ctx, ch, ch_spec[ch.kind], snap,
+                                       mesh)
+                children[ch.name] = run_node(ch, ch_spec, chain + [lvl])
             tnode["children"] = children
             return tnode
 
@@ -1247,20 +1327,15 @@ class AggEngine:
             troot: Dict[str, Any] = {
                 "node": node, "chain": [], "ks": (), "counts": None,
                 "metrics": {}, "children": {},
-                "cards": {node.name: bind_card(spec[node.kind], (), (),
-                                               (), False)}}
+                "cards": {node.name: bind_card(spec[node.kind], [],
+                                               False)}}
         else:
-            lvl0 = bind_level(node, spec[node.kind])
+            lvl0 = self._bind_level(ctx, node, spec[node.kind], snap, mesh)
             troot = run_node(node, spec, [lvl0])
         boards["tree"] = troot
-        if mesh is not None and n_dispatch[0]:
-            from elasticsearch_tpu.parallel import mesh as mesh_lib
-            from elasticsearch_tpu.parallel import policy
-            s = int(mesh.shape[mesh_lib.SHARD_AXIS])
-            policy.record_leg("aggs",
-                              policy.gather_bytes(s, 1, lanes_out[0]))
-            self._count("mesh_dispatches")
-        return boards, mesh_used
+        if mesh is not None and lanes_out[0]:
+            self._record_mesh_leg(mesh, 1, lanes_out[0])
+        return boards, mesh is not None
 
     def _calendar_bounds(self, field, col, unit, tz_spec, offset, div):
         """Sorted `_calendar_floor` boundary table spanning the column's
@@ -1308,7 +1383,8 @@ class AggEngine:
                     meta = {"interval": 0.0, "offset": offset, "base": 0.0,
                             "date": True, "n_buckets": 0, "fmt": fmt,
                             "tz": A._resolve_tz(body.get("time_zone")),
-                            "cal_bounds": ()}
+                            "cal_bounds": (), "div": div,
+                            "miss_lane": None}
                     return None, meta
                 if not (math.isfinite(col.vmin)
                         and math.isfinite(col.vmax)):
@@ -1325,7 +1401,8 @@ class AggEngine:
                 meta = {"interval": 0.0, "offset": offset, "base": 0.0,
                         "date": True, "n_buckets": b, "fmt": fmt,
                         "tz": tz, "cal_bounds": real,
-                        "cal_args": (cbounds, cparams)}
+                        "cal_args": (cbounds, cparams), "div": div,
+                        "miss_lane": None}
                 return None, meta
         else:
             try:
@@ -1368,8 +1445,13 @@ class AggEngine:
             n_buckets = bb
         hparams = np.asarray([interval, offset, base, div, kflag, kmiss],
                              dtype=np.float64)
+        # the key's `missing` substitute as ONE precomputed lane, by the
+        # host's own f64 key math
+        miss_lane = int(math.floor((kmiss - offset) / interval) - base) \
+            if kflag and n_buckets else None
         meta = {"interval": interval, "offset": offset, "base": base,
-                "date": date, "n_buckets": n_buckets,
+                "date": date, "n_buckets": n_buckets, "div": div,
+                "miss_lane": miss_lane,
                 "fmt": body.get("format"),
                 "tz": A._resolve_tz(body.get("time_zone")) if date
                 else None}

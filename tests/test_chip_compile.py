@@ -242,67 +242,141 @@ def test_maxsim_rescore_compiles(one_chip, dtype):
 # ---------------------------------------------------------------------------
 
 def test_x64_agg_kernel_compiles(one_chip):
-    """`aggs.hist_metric` (date_histogram + stats sub-agg) over 131,072
-    rows, traced under the dispatcher's scoped x64 flag: f64 keys and
-    sums, int64 counts — types the chip emulates."""
+    """`aggs.tree_metric` over one histogram level (date_histogram +
+    stats sub-agg; `aggs.hist_metric` until ISSUE 36 folded the one-level
+    x64 kernels into the tree's) over 131,072 rows, traced under the
+    dispatcher's scoped x64 flag: f64 keys and sums, int64 counts —
+    types the chip emulates."""
     r, b = 1 << 17, 64
     with jax.enable_x64(True):
         f64 = lambda *shape: _sds(one_chip, shape, jnp.float64)  # noqa: E731
         flag = lambda: _sds(one_chip, (r,), jnp.bool_)           # noqa: E731
         compiled = _compile(
-            agg_ops._agg_hist_metric, ("n_buckets",),
-            f64(r), flag(), flag(), f64(6), f64(2), f64(r), flag(),
-            n_buckets=b)
+            agg_ops._agg_tree_metric, ("levels", "n_buckets"),
+            flag(), f64(2), f64(r), flag(), f64(r), flag(), f64(6),
+            levels=("hist",), n_buckets=(b,))
     cnt, total = compiled.out_info[:2]
     assert cnt.dtype == jnp.int64 and cnt.shape == (b + 1,)
     assert total.dtype == jnp.float64
 
 
+X64, N32 = "x64", "n32"
+
+
 def _dash_panel_programs(sh):
     """The programs the four panels of `dash-aggs-steady` dispatch over
-    `http-logs-dash`'s row bucket of 2^20, by name: (kernel, static
-    names, argument specs, statics, lanes of the counts board). The rungs
-    are the ones the engine forms there: a histogram's from the COLUMN's
-    span (1,176 hours -> 2,048, whatever the request's range), `status`'s
-    eight values -> 8 (16 where a ninth appears)."""
+    `http-logs-dash`'s row bucket of 2^20, by name: (arithmetic, kernel,
+    static names, argument specs, statics, dtype and shape of the first
+    board). The rungs are the ones the engine forms there: a histogram's
+    from the COLUMN's span (1,176 hours -> 2,048, whatever the request's
+    range), `status`'s eight values -> 8 (16 where a ninth appears).
+
+    Since ISSUE 36 the cell's columns (whole seconds, integers) run the
+    32-bit programs (`n32.*`: int32 boards of (k + 1) lanes a level; a
+    metric's is ONE array, the count's row and the sum's limbs: `size`
+    up to 2^24 in three of 8 bits). The x64 programs they replaced stay
+    what a column of fractions or of a span past 2^31 units runs: one
+    level or two of `aggs.tree_counts` / `aggs.tree_metric`."""
     r = 1 << 20
     f64 = lambda *shape: _sds(sh, shape, jnp.float64)  # noqa: E731
+    i32 = lambda *shape: _sds(sh, shape, jnp.int32)    # noqa: E731
     flag = _sds(sh, (r,), jnp.bool_)
-    ords = _sds(sh, (r,), jnp.int32)
+    ords = i32(r)
     keys, hp, mp, op = f64(r), f64(6), f64(2), f64(1)
+    tree = ("levels", "n_buckets")
+    counts32 = ("levels", "n_buckets", "form")
+    metric32 = counts32 + ("parts", "limb_bits", "n_limbs")
+    bits = agg_ops.limb_bits(r)
+    limbs = agg_ops.n_limbs_for(1 << 24, bits)
+    assert (bits, limbs) == (8, 4)
+
+    def hist(k):
+        return {"levels": ("hist",), "n_buckets": (k,)}
+
+    def bounds(k, cols=1):
+        return {"levels": ("bounds",), "n_buckets": (k,),
+                "form": agg_ops.board_form(k + 1, "bounds", cols)}
+
+    def ordinals(k):
+        return {"levels": ("ords",), "n_buckets": (k,),
+                "form": agg_ops.board_form(k + 1, "ords")}
+
+    def sum32(statics):
+        return dict(statics, parts=("sum",), limb_bits=bits, n_limbs=limbs)
+
     return {
         "hourly.cal_counts-2048": (
-            agg_ops._agg_cal_counts, ("n_buckets",),
-            (keys, flag, flag, f64(2048), mp), {"n_buckets": 2048}, 2049),
+            X64, agg_ops._agg_tree_counts, tree,
+            (flag, keys, flag, f64(2048), mp),
+            {"levels": ("cal",), "n_buckets": (2048,)},
+            (jnp.int64, (2049,))),
         "bytes-by-hour.hist_counts-2048": (
-            agg_ops._agg_hist_counts, ("n_buckets",),
-            (keys, flag, flag, hp), {"n_buckets": 2048}, 2049),
+            X64, agg_ops._agg_tree_counts, tree, (flag, keys, flag, hp),
+            hist(2048), (jnp.int64, (2049,))),
         "bytes-by-hour.hist_metric-2048": (
-            agg_ops._agg_hist_metric, ("n_buckets",),
-            (keys, flag, flag, hp, mp, keys, flag), {"n_buckets": 2048},
-            2049),
+            X64, agg_ops._agg_tree_metric, tree,
+            (flag, mp, keys, flag, keys, flag, hp), hist(2048),
+            (jnp.int64, (2049,))),
         "bytes-by-hour.hist_metric-256": (
-            agg_ops._agg_hist_metric, ("n_buckets",),
-            (keys, flag, flag, hp, mp, keys, flag), {"n_buckets": 256},
-            257),
+            X64, agg_ops._agg_tree_metric, tree,
+            (flag, mp, keys, flag, keys, flag, hp), hist(256),
+            (jnp.int64, (257,))),
         "bytes-by-hour.total.ord_metric-8": (
-            agg_ops._agg_ord_metric, ("n_buckets",),
-            (ords, flag, mp, keys, flag), {"n_buckets": 8}, 9),
+            X64, agg_ops._agg_tree_metric, tree,
+            (flag, mp, keys, flag, ords, op),
+            {"levels": ("ord",), "n_buckets": (8,)}, (jnp.int64, (9,))),
         "status-in-range.ord_counts-8": (
-            agg_ops._agg_ord_counts, ("n_buckets",), (ords, flag),
-            {"n_buckets": 8}, 9),
+            X64, agg_ops._agg_tree_counts, tree, (flag, ords, op),
+            {"levels": ("ord",), "n_buckets": (8,)}, (jnp.int64, (9,))),
         "status-in-range.ord_counts-16": (
-            agg_ops._agg_ord_counts, ("n_buckets",), (ords, flag),
-            {"n_buckets": 16}, 17),
+            X64, agg_ops._agg_tree_counts, tree, (flag, ords, op),
+            {"levels": ("ord",), "n_buckets": (16,)}, (jnp.int64, (17,))),
         "status-by-hour.tree_counts-2048x8": (
-            agg_ops._agg_tree_counts, ("levels", "n_buckets"),
+            X64, agg_ops._agg_tree_counts, tree,
             (flag, keys, flag, hp, ords, op),
             {"levels": ("hist", "ord"), "n_buckets": (2048, 8)},
-            2048 * 8 + 1),
+            (jnp.int64, (2048 * 8 + 1,))),
         "status-by-hour.tree_counts-32x8": (
-            agg_ops._agg_tree_counts, ("levels", "n_buckets"),
+            X64, agg_ops._agg_tree_counts, tree,
             (flag, keys, flag, hp, ords, op),
-            {"levels": ("hist", "ord"), "n_buckets": (32, 8)}, 32 * 8 + 1),
+            {"levels": ("hist", "ord"), "n_buckets": (32, 8)},
+            (jnp.int64, (32 * 8 + 1,))),
+        # hourly's calendar hours and bytes-by-hour's fixed ones: the same
+        # program, they differ in their table of bounds alone
+        "n32.by-hour.counts-2048": (
+            N32, agg_ops._agg_n32_counts, counts32,
+            (flag, i32(r), i32(2049)), bounds(2048), (jnp.int32, (2049,))),
+        "n32.bytes-by-hour.sum-2048": (
+            N32, agg_ops._agg_n32_metric, metric32,
+            (flag, i32(r), i32(), i32(r), i32(2049)),
+            sum32(bounds(2048, 1 + limbs)),
+            (jnp.int32, (1 + limbs, 2049))),
+        "n32.bytes-by-hour.total.sum": (
+            N32, agg_ops._agg_n32_metric, metric32, (flag, i32(r), i32()),
+            sum32({"levels": (), "n_buckets": (), "form": "onehot"}),
+            (jnp.int32, (1 + limbs, 1))),
+        "n32.status-in-range.counts-8": (
+            N32, agg_ops._agg_n32_counts, counts32, (flag, ords),
+            ordinals(8), (jnp.int32, (9,))),
+        "n32.status-in-range.counts-16": (
+            N32, agg_ops._agg_n32_counts, counts32, (flag, ords),
+            ordinals(16), (jnp.int32, (17,))),
+        "n32.status-by-hour.counts-2048x8": (
+            N32, agg_ops._agg_n32_counts, counts32,
+            (flag, i32(r), i32(2049), ords),
+            {"levels": ("bounds", "ords"), "n_buckets": (2048, 8),
+             "form": "onehot"}, (jnp.int32, (2049 * 9,))),
+        # shapes the cell does not send: the extrema, and ordinals past
+        # the lanes a column at which `board_form` scatters
+        "n32.stats-2048": (
+            N32, agg_ops._agg_n32_metric, metric32,
+            (flag, i32(r), i32(), i32(r), i32(2049)),
+            dict(sum32(bounds(2048, 1 + limbs)),
+                 parts=("sum", "min", "max")),
+            (jnp.int32, (3 + limbs, 2049))),
+        "n32.ordinals.counts-65536": (
+            N32, agg_ops._agg_n32_counts, counts32, (flag, ords),
+            ordinals(65536), (jnp.int32, (65537,))),
     }
 
 
@@ -311,19 +385,31 @@ def _dash_panel_programs(sh):
     "bytes-by-hour.hist_metric-2048", "bytes-by-hour.hist_metric-256",
     "bytes-by-hour.total.ord_metric-8", "status-in-range.ord_counts-8",
     "status-in-range.ord_counts-16", "status-by-hour.tree_counts-2048x8",
-    "status-by-hour.tree_counts-32x8"])
+    "status-by-hour.tree_counts-32x8",
+    "n32.by-hour.counts-2048", "n32.bytes-by-hour.sum-2048",
+    "n32.bytes-by-hour.total.sum", "n32.status-in-range.counts-8",
+    "n32.status-in-range.counts-16", "n32.status-by-hour.counts-2048x8",
+    "n32.stats-2048", "n32.ordinals.counts-65536"])
 def test_dash_panel_program_compiles_at_the_cells_row_bucket(one_chip,
                                                               program):
-    """The x64 scatter programs of the cell `dash-aggs-steady` at its
-    row bucket (2^20) and rungs: int64 counts and f64 sums by
-    `.at[].add` over a million rows, for the described chip."""
-    fn, static_names, args, statics, lanes = \
+    """The programs of the cell `dash-aggs-steady` at its row bucket
+    (2^20) and rungs, for the described chip: the x64 scatter programs
+    (int64 counts and f64 sums by `.at[].add` over a million rows) and,
+    beside them, the 32-bit ones, none of whose boards is 64 bits wide."""
+    arithmetic, fn, static_names, args, statics, (dtype, shape) = \
         _dash_panel_programs(one_chip)[program]
-    with jax.enable_x64(True):
+    if arithmetic == X64:
+        with jax.enable_x64(True):
+            compiled = _compile(fn, static_names, *args, **statics)
+    else:
         compiled = _compile(fn, static_names, *args, **statics)
-    counts = compiled.out_info
-    counts = counts[0] if isinstance(counts, (tuple, list)) else counts
-    assert counts.dtype == jnp.int64 and counts.shape == (lanes,)
+        assert agg_ops._grid_n32(statics, [((1 << 20,), "bool", None)])
+    boards = jax.tree_util.tree_leaves(compiled.out_info)
+    assert boards[0].dtype == dtype and boards[0].shape == shape
+    if arithmetic == N32:
+        assert all(b.dtype == jnp.int32 for b in boards)
+        assert "f64" not in compiled.as_text() \
+            and "s64" not in compiled.as_text()
 
 
 # ---------------------------------------------------------------------------

@@ -193,6 +193,57 @@ def test_a_range_that_matches_nothing_and_one_that_overhangs(device, walker):
     assert nothing["aggregations"]["total_bytes"]["value"] == 0
 
 
+@pytest.fixture(scope="module")
+def rehearsal_size(tmp_path_factory):
+    """32,768 rows, the size the cell is rehearsed at: a week's bytes
+    pass 2^24 there (the float32 control's trap) and a panel's board is
+    the cell's own 2,049 lanes. ONE node: the same requests are answered
+    again by the host walker with the device engine switched off."""
+    n = 32768
+    s = Served(tmp_path_factory.mktemp("rehearsal"),
+               reference.LogCorpus(SEED + 1, CONFIG, n),
+               {"search.aggs.cost_router": "false"})
+    yield s, s.load(_blocks(n, s.corpus.block_docs))
+    s.close()
+
+
+def _programs():
+    return {k: metrics.counter("aggs.programs." + k).value
+            for k in ("narrow", "x64")}
+
+
+@pytest.mark.parametrize("panel", PANELS)
+def test_a_panel_at_32768_rows_is_answered_by_the_32_bit_programs(
+        rehearsal_size, panel):
+    """ISSUE 36: every program of the four panels is a 32-bit one
+    (`@timestamp` in whole seconds, `size` and `status` integers), and
+    its answer is the reference's and the host walker's, byte for
+    byte."""
+    served, rows = rehearsal_size
+    times = _times(rows, panel, 2)
+    before, stats = _programs(), served.aggs_stats()
+    got = [served.panel(panel, t) for t in times]
+    after = _programs()
+    assert after["narrow"] > before["narrow"]
+    assert after["x64"] == before["x64"]
+    assert served.aggs_stats()["host_nodes"] == stats["host_nodes"]
+    served.node.settings["search.aggs.device_enabled"] = "false"
+    try:
+        walked = [served.panel(panel, t) for t in times]
+    finally:
+        del served.node.settings["search.aggs.device_enabled"]
+    assert _programs() == after                 # the walker launched none
+    for t, g, w in zip(times, got, walked):
+        assert not reference.differs(g, rows.answer(panel, t)), (panel, t)
+        assert _tree(g) == _tree(w), (panel, t)
+    if panel == "bytes-by-hour":
+        # the trap the control falls into: a week's bytes in float32
+        week = got[0]["aggregations"]["total_bytes"]["value"]
+        assert week > 2 ** 24 and week == float(int(week))
+        assert reference.differs(got[0], rows.answer(
+            panel, times[0], sum_dtype=np.float32))
+
+
 def test_a_week_counts_past_the_default_track_total_hits(tmp_path, corpus):
     """`hits.total` is exact up to 10,000 and a lower bound past it."""
     s = Served(tmp_path / "n", reference.LogCorpus(SEED, CONFIG, 12288),

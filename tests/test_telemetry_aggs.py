@@ -12,7 +12,9 @@ in `telemetry.stage`'s one call form, always on:
     counters           aggs.device_nodes, aggs.host_nodes, aggs.mask_bytes
                        (the padded row bucket, once a LAUNCH: the host
                        mask rides every call), aggs.board_lanes,
-                       aggs.matched_rows, aggs.dispatches.<family>
+                       aggs.matched_rows, aggs.dispatches.<family>,
+                       aggs.programs.narrow and aggs.programs.x64 (ISSUE
+                       36: one of the two a program, by its arithmetic)
 
 `indices.aggs`'s `device_nanos`, `assemble_nanos` and `host_nanos` are the
 sums of the same clock marks, and a request without aggregations records
@@ -30,7 +32,9 @@ STAGES = ("aggs.plan", "aggs.mask", "aggs.device", "aggs.launch",
 COUNTERS = ("aggs.device_nodes", "aggs.host_nodes", "aggs.mask_bytes",
             "aggs.board_lanes", "aggs.matched_rows",
             "aggs.dispatches.date_histogram", "aggs.dispatches.metric",
-            "aggs.dispatches.terms", "aggs.dispatches.date_histogram_tree")
+            "aggs.dispatches.terms", "aggs.dispatches.date_histogram_tree",
+            "aggs.dispatches.range", "aggs.programs.narrow",
+            "aggs.programs.x64")
 BY_HOUR = {"date_histogram": {"field": "@timestamp",
                               "fixed_interval": "1h"}}
 
@@ -127,13 +131,62 @@ def test_the_mask_is_built_once_a_request_and_rides_every_launch(node):
     assert counts["aggs.plan"] == 1 and counts["aggs.mask"] == 1
     assert counts["aggs.device"] == 2 and counts["aggs.assemble"] == 2
     assert counts["aggs.launch"] == 3
-    assert counts["aggs.sync_wait"] == 1 + 4 + 4     # counts, 2 x 4 boards
+    assert counts["aggs.sync_wait"] == 3     # ONE packed board a program
     assert counts["aggs.host"] == 0
     assert counters["aggs.device_nodes"] == 2
     assert counters["aggs.mask_bytes"] == 3 * _r_pad(node)
     assert counters["aggs.dispatches.date_histogram"] == 2
     assert counters["aggs.dispatches.metric"] == 1
     assert counters["aggs.matched_rows"] == ROWS
+
+
+PROGRAMS = {
+    # aggs -> (32-bit programs, x64 programs)
+    "integral columns: every program 32-bit": (
+        {"by_status": {"terms": {"field": "status"},
+                       "aggs": {"bytes": {"sum": {"field": "size"}}}},
+         "by_hour": BY_HOUR, "total": {"stats": {"field": "size"}}},
+        4, 0),
+    "a range keeps the x64 programs": (
+        {"sizes": {"range": {"field": "size",
+                             "ranges": [{"to": 200}, {"from": 200}]},
+                   "aggs": {"bytes": {"sum": {"field": "size"}}}}},
+        0, 2),
+    "both in one request": (
+        {"sizes": {"range": {"field": "size", "ranges": [{"to": 200}]}},
+         "by_hour": dict(BY_HOUR, aggs={"by_status": {
+             "terms": {"field": "status"}}})},
+        2, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PROGRAMS))
+def test_a_program_counts_once_as_narrow_or_as_x64(node, monkeypatch, case):
+    """`aggs.programs.narrow` + `aggs.programs.x64` is the request's
+    programs: `mask_box["dispatches"]`, the launches, the families'."""
+    from elasticsearch_tpu.search.agg_plan import AggEngine
+    aggs, narrow, x64 = PROGRAMS[case]
+    boxes = []
+    mask_for = AggEngine._mask_for
+
+    def spy(self, rows, mask_box):
+        if not any(b is mask_box for b in boxes):
+            boxes.append(mask_box)
+        return mask_for(self, rows, mask_box)
+
+    monkeypatch.setattr(AggEngine, "_mask_for", spy)
+    _search(node, aggs)
+    del boxes[:]
+    before = _read()
+    _search(node, aggs)
+    counts, _nanos, counters = _delta(before)
+    (box,) = boxes
+    assert counters["aggs.programs.narrow"] == narrow
+    assert counters["aggs.programs.x64"] == x64
+    assert narrow + x64 == box["dispatches"] == counts["aggs.launch"] \
+        == sum(v for n, v in counters.items()
+               if n.startswith("aggs.dispatches."))
+    assert counts["aggs.host"] == 0
 
 
 def test_the_two_level_tree_is_one_node_and_a_program_a_level(node):
