@@ -9,7 +9,7 @@ Serving threads carry subsystem-identifying names so a busy stack is
 attributable at a glance: the node thread pools prefix `es[<pool>]`
 (common/threadpool.py), background workers name themselves at spawn
 (`segments-merge`, `dispatch-warmup`, `batcher-warmup`,
-`agg-column-resync`), and the combining batcher — which runs on BORROWED
+`agg-column-resync`, `telemetry-beat`), and the combining batcher — which runs on BORROWED
 submitter threads — tags the current thread for the duration of its
 dispatch and finalize stages (the `section` of the same `telemetry.stage`
 call that times `serving.device_dispatch` / `serving.device_sync`:
@@ -24,7 +24,7 @@ import sys
 import threading
 import time
 import traceback
-from typing import Dict
+from typing import Dict, List
 
 # thread-name fragment -> subsystem label, most specific first
 _SUBSYSTEMS = (
@@ -34,6 +34,7 @@ _SUBSYSTEMS = (
     ("segments-merge", "segments background merge"),
     ("dispatch-warmup", "ops/dispatch warmup"),
     ("agg-column-resync", "aggs column resync"),
+    ("telemetry-beat", "telemetry heartbeat"),
     ("es[search_throttled]", "search_throttled pool"),
     ("es[search]", "search pool"),
     ("es[write]", "write pool"),
@@ -82,3 +83,14 @@ def hot_threads_report(interval_s: float = 0.05, top_n: int = 3,
 
 def _top_frame_key(frame) -> str:
     return f"{frame.f_code.co_filename}:{frame.f_lineno}"
+
+
+def frame_keys(frame, depth: int) -> List[str]:
+    """A thread's innermost `depth` frames, innermost first, each as
+    `file:line function`: the bounded form a stall's record keeps
+    (`telemetry/beat.py`)."""
+    out: List[str] = []
+    while frame is not None and len(out) < depth:
+        out.append(f"{_top_frame_key(frame)} {frame.f_code.co_name}")
+        frame = frame.f_back
+    return out

@@ -529,6 +529,9 @@ class Node:
         # the collector's pauses and the device-starved counters exist
         # from the node's start, so that one that never moved reads 0
         _telemetry.time_gc()
+        # the heartbeat (telemetry/beat.py): the wait for the interpreter
+        # lock, a stall's record, the profiler's clock anchor
+        _telemetry.BEAT.watch(self.thread_pool)
         from elasticsearch_tpu.serving.batcher import IDLE
         IDLE.ensure_counters()
         # set by the server bootstrap after native hardening runs; embedded
@@ -2807,13 +2810,16 @@ class Node:
         process-wide metrics registry's histograms (end-to-end search
         latency, queue wait, device dispatch/sync, fan-out leg latency —
         p50/p90/p99/p999 each, no bench harness required) plus the
-        tracer's sampling/ring counters. Process-wide like the dispatch
-        section. The device-starved counters are booked up to this
-        read first, so that two reads bracket exactly their window."""
+        tracer's sampling/ring counters and the heartbeat's stalls (the
+        two counters and the newest records of what held the server).
+        Process-wide like the dispatch section. The device-starved
+        counters are booked up to this read first, so that two reads
+        bracket exactly their window."""
         from elasticsearch_tpu.serving.batcher import IDLE
-        from elasticsearch_tpu.telemetry import REGISTRY, TRACER
+        from elasticsearch_tpu.telemetry import BEAT, REGISTRY, TRACER
         IDLE.flush()
-        return {**REGISTRY.snapshot(), "tracing": TRACER.snapshot()}
+        return {**REGISTRY.snapshot(), "tracing": TRACER.snapshot(),
+                "stalls": BEAT.snapshot()}
 
     def local_traces_section(self, limit: int = 50) -> dict:
         """This node's completed-trace ring (`GET _nodes/traces`): most

@@ -1,7 +1,8 @@
 """End-to-end request telemetry: traces, metrics, live tasks.
 
-Three coupled pieces (ISSUE 14), one always-on low-overhead layer, and
-ONE call form that feeds them all (`telemetry.stage`, ISSUE 26):
+Three coupled pieces (ISSUE 14), one always-on low-overhead layer, ONE
+call form that feeds them all (`telemetry.stage`, ISSUE 26), and one
+heartbeat that looks at the server from outside a request (ISSUE 37):
 
 * `telemetry.stage` — `with stage(name):` / `stage_done(name, start_ns,
   end_ns, ctx)` time one boundary: the histogram `name`, a span of that
@@ -20,6 +21,17 @@ ONE call form that feeds them all (`telemetry.stage`, ISSUE 26):
   histograms; `_nodes/stats telemetry` reports live p50/p90/p99/p999 for
   end-to-end search latency, queue wait, device dispatch/sync and
   fan-out leg latency without a bench harness.
+* `telemetry.beat` — the one look at the server from OUTSIDE a request
+  (ISSUE 37): a daemon thread, `telemetry-beat`, every 10 ms. Histogram
+  `runtime.lock_wait` (what a beat overslept: the wait of a runnable
+  thread for the interpreter lock); counters `runtime.stalls` and
+  `runtime.stall_nanos` with a record of what held the server (work in
+  flight and no response out for 50 ms, or a beat 50 ms late:
+  `_nodes/stats telemetry.stalls`, and a WARN line); the profiler event
+  `es.runtime.beat` whose stat `mono_ns` ties `time.monotonic_ns()` to
+  the profiler's clock. Beside it the counter `rest.handle.cpu_nanos`
+  (`Front`): the handlers' own processor time, so that `rest.handle`
+  splits into work and wait.
 * the tasks binding below — `rest_request` registers every instrumented
   REST request with the node's TaskManager (action, opaque id, trace id,
   current span); `GET _tasks` lists them live, and `POST
@@ -47,6 +59,7 @@ from typing import Optional, Tuple
 from elasticsearch_tpu.telemetry import metrics
 from elasticsearch_tpu.telemetry import trace as trace_mod
 from elasticsearch_tpu.telemetry.metrics import REGISTRY
+from elasticsearch_tpu.telemetry.beat import BEAT
 from elasticsearch_tpu.telemetry.stages import (
     UNSAMPLED,
     Front,
@@ -67,7 +80,7 @@ from elasticsearch_tpu.telemetry.trace import (
 )
 
 __all__ = [
-    "metrics", "trace_mod", "REGISTRY", "TRACER", "Trace", "Front",
+    "metrics", "trace_mod", "BEAT", "REGISTRY", "TRACER", "Trace", "Front",
     "UNSAMPLED", "annotation", "capture", "current_span_id",
     "current_task", "current_trace",
     "new_span_id", "stage", "stage_done", "time_gc", "use",

@@ -65,12 +65,13 @@ def _session_on() -> bool:
     return on()
 
 
-def _annotation(name: str):
+def _annotation(name: str, **stats):
     """An ENTERED profiler annotation `es.<name>`, or None with no
-    session on."""
+    session on. `stats` ride the event (the reader finds them under
+    `event.stats`)."""
     if not _session_on():
         return None
-    ann = _ANNOTATION("es." + name)
+    ann = _ANNOTATION("es." + name, **stats)
     ann.__enter__()
     return ann
 
@@ -86,6 +87,12 @@ def annotation(name: str):
 
 
 UNSAMPLED = (None, None, None)   # a context that is explicitly no trace
+
+# the handlers' own processor time (`time.thread_time_ns()` around
+# `rest.handle`): over that histogram's `sum_nanos`, the share of a
+# handler's wall time in which its thread ran; the rest it waited (the
+# batcher's future, the device, the interpreter lock)
+HANDLE_CPU_NANOS = "rest.handle.cpu_nanos"
 
 
 class Front:
@@ -112,11 +119,15 @@ class Front:
                    the loop's lag where the loop has to -> http.respond
         (finish)   the bytes handed to the socket whole (worker), or
                    written and drained (loop)
+
+    Inside `rest.handle` the worker's own processor time is read
+    (`time.thread_time_ns()`, twice a request) into the counter
+    `rest.handle.cpu_nanos`: what is left of the stage is wait.
     """
 
     __slots__ = ("idle_ns", "start_ns", "read_ns", "submit_ns", "handle_ns",
                  "return_ns", "wake_ns", "trace", "status", "handle_id",
-                 "_ann")
+                 "_ann", "_cpu_ns")
 
     def __init__(self, idle_ns: int, start_ns: int):
         self.idle_ns = idle_ns
@@ -129,9 +140,12 @@ class Front:
         _CTX.front = self
         self._ann = _annotation("rest.handle")
         self.handle_ns = time.monotonic_ns()
+        # the processor time is read INSIDE the wall time it is a share of
+        self._cpu_ns = time.thread_time_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        cpu_ns = time.thread_time_ns() - self._cpu_ns
         self.return_ns = time.monotonic_ns()
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
@@ -144,6 +158,7 @@ class Front:
         stage_done("http.pool_wait", self.submit_ns, self.handle_ns, ctx)
         stage_done("rest.handle", self.handle_ns, self.return_ns, ctx,
                    span_id=None if self.trace is None else self.handle_id)
+        metrics.counter(HANDLE_CPU_NANOS).inc(cpu_ns)
         return False
 
     def _ctx(self):
@@ -280,13 +295,34 @@ _gc_lock = threading.Lock()
 _gc_hooked = False
 
 
+class _GcMark:
+    """The newest collection the hook saw. Written only inside the
+    collector's callbacks (one collection runs at a time, and holds the
+    interpreter), read by the heartbeat."""
+
+    __slots__ = ("start_ns", "stop_ns")
+
+    def __init__(self) -> None:
+        self.start_ns = self.stop_ns = 0
+
+
+_GC_MARK = _GcMark()
+
+
+def gc_mark():
+    """(start_ns, stop_ns) of the newest collection the hook saw; one is
+    running while the start is the later of the two."""
+    return _GC_MARK.start_ns, _GC_MARK.stop_ns
+
+
 def time_gc() -> None:
     """Time the collector's pauses from inside the program, always on:
     histogram `runtime.gc_pause` (collector start to stop), counters
     `runtime.gc_collections.gen0/1/2`, and a profiler event
     `es.runtime.gc_pause` (start and stop run on the thread that tripped
-    the collection). Hooked once a process, when the first node starts;
-    the counters exist from then on, so one that never moved reads 0."""
+    the collection), and the mark a stall's record reads (`gc_mark`).
+    Hooked once a process, when the first node starts; the counters
+    exist from then on, so one that never moved reads 0."""
     global _gc_hooked
     for name in GC_COUNTERS:
         metrics.counter(name)
@@ -295,20 +331,20 @@ def time_gc() -> None:
         if _gc_hooked:
             return
         _gc_hooked = True
-    began = [0, None]
+    running = [None]    # the running collection's annotation
 
     def on_gc(phase: str, info: dict) -> None:
         if phase == "start":
-            began[1] = _annotation("runtime.gc_pause")
-            began[0] = time.monotonic_ns()
+            running[0] = _annotation("runtime.gc_pause")
+            _GC_MARK.start_ns = time.monotonic_ns()
             return
-        end_ns = time.monotonic_ns()
-        ann, began[1] = began[1], None
+        _GC_MARK.stop_ns = end_ns = time.monotonic_ns()
+        ann, running[0] = running[0], None
         if ann is not None:
             ann.__exit__(None, None, None)
         # resolved per call: a test-time `REGISTRY.reset()` must not
         # detach the hook from the registry
-        metrics.record("runtime.gc_pause", end_ns - began[0])
+        metrics.record("runtime.gc_pause", end_ns - _GC_MARK.start_ns)
         metrics.counter(GC_COUNTERS[min(info.get("generation", 0),
                                         2)]).inc()
 
