@@ -68,6 +68,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from elasticsearch_tpu.ops import dispatch
+from elasticsearch_tpu.vectors.filter_mask import RowLocator
 
 logger = logging.getLogger("elasticsearch_tpu.aggs")
 
@@ -927,22 +928,43 @@ class StoreSnapshot:
     """Immutable per-reader row-space description: built once per segment
     composition and handed to the whole compute pass, so a concurrent
     refresh-resync (which advances the store to a NEWER reader) can never
-    swap the row map out from under an in-flight search's mask."""
+    swap the row map out from under an in-flight search's mask. With it
+    the map's `RowLocator` (`vectors/filter_mask.py`), which turns a
+    request's rows into positions without a search of the map."""
 
-    __slots__ = ("version", "row_map", "n_rows", "r_pad")
+    __slots__ = ("version", "row_map", "n_rows", "r_pad", "locator")
 
     def __init__(self, version, row_map):
         self.version = version
         self.row_map = row_map
         self.n_rows = len(row_map)
         self.r_pad = _pow2(max(self.n_rows, 1))
+        self.locator = RowLocator(row_map)
 
     def filter_mask(self, rows: np.ndarray) -> np.ndarray:
         """Matched-row mask over the padded row bucket — the `filter` half
-        of the fused plan (vectorized; rows are engine global rows)."""
+        of the fused plan (rows are engine global rows, in any order;
+        one the map does not hold is dropped). Written in time by the
+        matched rows, in the form the input allows: a slice where `rows`
+        IS one run of the map (its ends as many positions apart as it
+        is long, and equal to the map between them: a time range over
+        an index written in time order, a match-all), else `True`
+        scattered at the locator's positions; only a map the locator
+        cannot hold (`form == "search"`) is searched whole."""
         mask = np.zeros(self.r_pad, dtype=bool)
-        if len(rows):
+        n = len(rows)
+        if not n:
+            return mask
+        loc = self.locator
+        if not loc.exact:
             mask[: self.n_rows] = np.isin(self.row_map, rows)
+            return mask
+        ends = loc.positions(rows[[0, -1]])
+        if len(ends) == 2 and ends[1] - ends[0] == n - 1 \
+                and np.array_equal(rows, self.row_map[ends[0]:ends[1] + 1]):
+            mask[ends[0]:ends[1] + 1] = True
+        else:
+            mask[loc.positions(rows)] = True
         return mask
 
 

@@ -728,7 +728,7 @@ class AggEngine:
         # The box is the request's: its mask, and what its nodes handed
         # to the device and read back
         mask_box: Dict[str, Any] = {"snap": self.store.snapshot(ctx.reader),
-                                    "dispatches": 0}
+                                    "dispatches": 0, "sharded": {}}
         out: Dict[str, Any] = {}
         pipelines: List[Tuple[str, str, dict]] = []
         prof_nodes: List[dict] = []
@@ -868,11 +868,38 @@ class AggEngine:
 
     # ----------------------------------------------------------- dispatch
     def _mask_for(self, rows, mask_box) -> np.ndarray:
+        """The request's host mask, built once (`aggs.mask_scattered` /
+        `aggs.mask_searched`: one of the two a request, by whether its
+        snapshot's locator placed the rows or the map was searched)."""
         mask = mask_box.get("mask")
         if mask is None:
-            mask = mask_box["snap"].filter_mask(rows)
-            mask_box["mask"] = mask
+            snap = mask_box["snap"]
+            mask = mask_box["mask"] = snap.filter_mask(rows)
+            located = snap.locator.exact
+            _counter("aggs.mask_scattered").inc(int(located))
+            _counter("aggs.mask_searched").inc(int(not located))
         return mask
+
+    @staticmethod
+    def _mask_io(mask_box, mesh):
+        """The request's mask as this route's programs take it. On one
+        device the host mask itself, which rides every call (`_launch`
+        counts it there). Under a mesh a copy sharded by rows ahead of
+        the launch, made ONCE a request and mesh, where
+        `aggs.mask_bytes` counts it."""
+        mask = mask_box["mask"]
+        if mesh is None:
+            return mask
+        sharded = mask_box["sharded"].get(mesh)
+        if sharded is None:
+            import jax
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            from elasticsearch_tpu.parallel import mesh as mesh_lib
+            _counter("aggs.mask_bytes").inc(mask.nbytes)
+            sharded = mask_box["sharded"][mesh] = jax.device_put(
+                mask, NamedSharding(mesh, P(mesh_lib.SHARD_AXIS)))
+        return sharded
 
     @staticmethod
     def _launch(mask_box, name, *args, mesh=None, **statics):
@@ -943,19 +970,6 @@ class AggEngine:
             return np.asarray([1.0, float(missing)], dtype=np.float64)
         except (TypeError, ValueError):
             raise _Fallback("bad_missing_value")
-
-    def _sharded(self, mesh, arrays):
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from elasticsearch_tpu.ops.dispatch import _x64_scope
-        from elasticsearch_tpu.parallel import mesh as mesh_lib
-        row = NamedSharding(mesh, P(mesh_lib.SHARD_AXIS))
-        # the request's mask, sharded by rows ahead of the launch
-        _counter("aggs.mask_bytes").inc(sum(a.nbytes for a in arrays))
-        with _x64_scope(True):
-            return [jax.device_put(jnp.asarray(a), row) for a in arrays]
 
     # ------------------------------------------------------------ levels --
     def _bind_level(self, ctx, node, body, snap, mesh, single=False):
@@ -1151,9 +1165,7 @@ class AggEngine:
         mask = self._mask_for(rows, mask_box)
         mesh = self._mesh_for(mask_box)
         boards: Dict[str, Any] = {"n_matched": int(len(rows))}
-        mask_io = mask
-        if mesh is not None:
-            (mask_io,) = self._sharded(mesh, [mask])
+        mask_io = self._mask_io(mask_box, mesh)
 
         if node.mode == "range":
             col = store.column(reader, node.field, snap=snap)
@@ -1237,9 +1249,7 @@ class AggEngine:
         boards: Dict[str, Any] = {"n_matched": int(len(rows)),
                                   "mask": mask}
         lanes_out = [0]
-        mask_io = mask
-        if mesh is not None:
-            (mask_io,) = self._sharded(mesh, [mask])
+        mask_io = self._mask_io(mask_box, mesh)
 
         def read(pend):
             got = self._read_pending(pend)

@@ -9,8 +9,10 @@ Every answer has to equal the plain reference's
 `search.aggs.device_enabled=false`), byte for byte in the aggregations
 tree, while the device answers every node: on the single-device route,
 after a second `_bulk` + `_refresh` (the column's delta rebuild), with the
-cost router on and off, and on the `aggs.mesh_*` twins over the
-conftest's virtual devices.
+cost router on and off, on the `aggs.mesh_*` twins over the conftest's
+virtual devices, and (ISSUE 38) after a delete-by-id of scattered rows
+and a second `_bulk` + `_refresh`: a row map of two segments with gaps,
+whose masks go through the locator's table.
 """
 
 import json
@@ -280,6 +282,99 @@ def test_after_a_second_bulk_and_refresh(tmp_path, corpus):
         assert stats["host_nodes"] == 0
     finally:
         s.close()
+
+
+def _snapshot(served):
+    svc = served.node.indices.get("bench")
+    return served.node._agg_engine(svc).store.snapshot(svc.combined_reader())
+
+
+def _masks():
+    return {k: metrics.counter("aggs.mask_" + k).value
+            for k in ("scattered", "searched")}
+
+
+def test_the_sealed_index_the_cell_runs_has_a_contiguous_row_map(device):
+    """One load, no delete: every panel's mask is written through the
+    locator (a slice for a time range over rows in time order), none by
+    a search of the map."""
+    served, rows = device
+    snap = _snapshot(served)
+    assert snap.locator.form == "contiguous" and snap.n_rows == ROWS
+    before = _masks()
+    for panel in PANELS:
+        t = _times(rows, panel, 1)[0]
+        assert not reference.differs(served.panel(panel, t),
+                                     rows.answer(panel, t)), panel
+        got = snap.filter_mask(np.sort(rows.panel_rows(panel, t)))
+        assert got.sum() == rows.matched_rows(panel, t)
+    after = _masks()
+    assert after["scattered"] - before["scattered"] == len(PANELS)
+    assert after["searched"] == before["searched"]
+
+
+@pytest.fixture(scope="module")
+def gapped(tmp_path_factory, corpus):
+    """Three quarters of the rows, a delete-by-id of scattered ones (and
+    of one whole run), a `_refresh`, then the last quarter and another:
+    two segments, the first with gaps. The reference holds the rows that
+    are left."""
+    s = Served(tmp_path_factory.mktemp("gapped"), corpus,
+               {"search.aggs.cost_router": "false"})
+    blocks = _blocks(ROWS, corpus.block_docs)
+    cut = 3 * len(blocks) // 4
+
+    def bulk(body):
+        status, resp = s.req("POST", "/_bulk", body)
+        assert status == 200 and not resp["errors"], resp
+        return [item["index"]["_id"] for item in resp["items"]
+                if "index" in item]
+
+    ids = [i for b, n in blocks[:cut]
+           for i in bulk(corpus.bulk_body(b, "bench", n))]
+    assert s.req("POST", "/bench/_refresh")[0] == 200
+    rng = np.random.default_rng(SEED)
+    gone = np.unique(np.r_[rng.choice(len(ids), len(ids) // 9,
+                                      replace=False),
+                           np.arange(700, 760), 0, len(ids) - 1])
+    bulk("".join('{"delete":{"_index":"bench","_id":"%s"}}\n' % ids[i]
+                 for i in gone).encode())
+    for b, n in blocks[cut:]:
+        bulk(corpus.bulk_body(b, "bench", n))
+    assert s.req("POST", "/bench/_refresh")[0] == 200
+    every = corpus.rows(blocks)
+    keep = np.ones(ROWS, dtype=bool)
+    keep[gone] = False
+    rows = reference.LogRows(corpus, every.ts[keep], every.status[keep],
+                             every.size[keep])
+    yield s, rows, len(gone)
+    s.close()
+
+
+@pytest.mark.parametrize("panel", PANELS)
+def test_a_panel_after_deletes_and_a_second_segment_goes_through_the_table(
+        gapped, walker, panel):
+    served, rows, n_gone = gapped
+    snap = _snapshot(served)
+    assert snap.n_rows == len(rows) == ROWS - n_gone
+    assert snap.locator.form == "table"
+    assert len(served.node.indices.get("bench").combined_reader().views) >= 2
+    before, stats = _masks(), served.aggs_stats()
+    times = _times(rows, panel, 3)
+    for t in times:
+        got = served.panel(panel, t)
+        assert not reference.differs(got, rows.answer(panel, t)), (panel, t)
+    after = _masks()
+    assert after["scattered"] - before["scattered"] == len(times)
+    assert after["searched"] == before["searched"]
+    assert served.aggs_stats()["host_nodes"] == stats["host_nodes"]
+    # and the walker over the same rows says the same
+    served.node.settings["search.aggs.device_enabled"] = "false"
+    try:
+        walked = served.panel(panel, times[0])
+    finally:
+        del served.node.settings["search.aggs.device_enabled"]
+    assert _tree(walked) == _tree(served.panel(panel, times[0]))
 
 
 def test_with_the_cost_router_on_every_answer_is_exact(tmp_path, corpus,

@@ -12,7 +12,11 @@ wrote:
   request once, by the way its rows were written, on the three routes;
 - the benchmark's `stats_ratio` reader, as it is, reads the scattered
   share as 100 from two `_nodes/stats` snapshots around a served batch,
-  and nothing from a program without the counter.
+  and nothing from a program without the counter;
+- the aggregation engine's caller (ISSUE 38): `ops/aggs.StoreSnapshot.
+  filter_mask(rows)` equals `np.isin(row_map, rows)` padded with `False`
+  to the row bucket, on each form of the map and whatever `rows` holds,
+  and searches only a map its locator cannot hold.
 """
 
 import contextlib
@@ -27,6 +31,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from benchmark.readers import stats_ratio  # noqa: E402
+from elasticsearch_tpu.ops.aggs import StoreSnapshot  # noqa: E402
 from elasticsearch_tpu.telemetry import metrics  # noqa: E402
 from elasticsearch_tpu.vectors import filter_mask  # noqa: E402
 from test_filtered_knn_tags import Served  # noqa: E402
@@ -152,6 +157,105 @@ def test_filter_rows_in_any_order_and_twice_are_the_same_rows():
         fr = _RNG.choice(np.r_[row_map, row_map[:9], row_map[0] - 2], 50)
         got = filter_mask.allowed_rows(filter_mask.RowLocator(row_map), [fr])
         np.testing.assert_array_equal(got[0], np.isin(row_map, fr))
+
+
+# ------------------------------------------- the aggregation engine's mask
+
+
+def _agg_rows(kind: str, row_map: np.ndarray) -> np.ndarray:
+    """A request's matched rows of one shape, as `AggEngine.compute`
+    hands them to `StoreSnapshot.filter_mask`."""
+    held = np.sort(row_map)
+    lo, hi = int(held[0]), int(held[-1])
+    if kind == "empty":
+        return np.zeros(0, dtype=np.int64)
+    if kind == "every_row":
+        return held
+    if kind == "one_run":                   # a time range over a log
+        return row_map[N // 4: N // 4 + N // 2].copy()
+    if kind == "one_row":
+        return row_map[N // 3: N // 3 + 1].copy()
+    if kind == "the_first_and_the_last_row":
+        return np.array([lo, hi], dtype=np.int64)
+    if kind == "scattered":
+        return np.unique(_RNG.choice(row_map, N // 3))
+    if kind == "rows_the_map_does_not_hold":
+        # below, above, and (where the map has gaps) between its rows
+        between = np.setdiff1d(np.arange(lo, hi + 1), row_map)[:5]
+        return np.sort(np.r_[lo - 9, lo - 1, hi + 1, hi + 70000, between,
+                             _RNG.choice(row_map, N // 3)]).astype(np.int64)
+    if kind == "only_rows_the_map_does_not_hold":
+        return np.array([lo - 2, hi + 1, hi + 5], dtype=np.int64)
+    if kind == "unsorted":
+        return _RNG.permutation(np.unique(_RNG.choice(row_map, N // 2)))
+    if kind == "unsorted_with_a_run_s_ends":
+        # the ends as far apart as the rows are many, and yet no run:
+        # the shape a test of the ends alone would mask wrong
+        return held[[10, 40, 42, 13]]
+    if kind == "a_run_s_ends_with_a_row_twice":
+        return held[[20, 21, 22, 22, 24]]   # five rows, ends four apart
+    if kind == "int32_rows":
+        return held[5:50].astype(np.int32)
+    raise AssertionError(kind)
+
+
+AGG_KINDS = ["empty", "every_row", "one_run", "one_row",
+             "the_first_and_the_last_row", "scattered",
+             "rows_the_map_does_not_hold", "only_rows_the_map_does_not_hold",
+             "unsorted", "unsorted_with_a_run_s_ends",
+             "a_run_s_ends_with_a_row_twice", "int32_rows"]
+
+
+@pytest.mark.parametrize("kind", AGG_KINDS)
+@pytest.mark.parametrize("name", list(MAPS))
+def test_the_snapshots_mask_equals_isin_padded_to_the_row_bucket(
+        name, kind, monkeypatch):
+    row_map, form = MAPS[name]
+    snap = StoreSnapshot(("v", name), row_map)
+    assert snap.locator.form == form and snap.locator.row_map is row_map
+    assert snap.r_pad == 128 and snap.n_rows == N
+    rows = _agg_rows(kind, row_map)
+    want = np.zeros(snap.r_pad, dtype=bool)
+    want[:N] = np.isin(row_map, rows)
+    calls = []
+    isin = np.isin
+    monkeypatch.setattr(np, "isin",
+                        lambda *a, **kw: calls.append(1) or isin(*a, **kw))
+    got = snap.filter_mask(rows)
+    monkeypatch.undo()
+    assert got.dtype == np.bool_ and got.shape == (snap.r_pad,)
+    np.testing.assert_array_equal(got, want)
+    assert not got[N:].any()                        # the pad stays False
+    # `np.isin` only behind a locator that is not exact
+    assert len(calls) == (1 if form == "search" and len(rows) else 0)
+
+
+@pytest.mark.parametrize("name", [n for n, (_, f) in MAPS.items()
+                                  if f != "search"])
+@pytest.mark.parametrize("kind", ["every_row", "one_run", "one_row"])
+def test_a_run_of_the_map_is_a_slice_and_no_scatter(name, kind, monkeypatch):
+    """Where `rows` is one run of the map the locator is asked for its
+    two ends alone: the run is proved against the map and written as a
+    slice."""
+    row_map, _form = MAPS[name]
+    snap = StoreSnapshot(("v", name), row_map)
+    rows = _agg_rows(kind, row_map)
+    asked = []
+    positions = snap.locator.positions
+    monkeypatch.setattr(
+        type(snap.locator), "positions",
+        lambda self, r: asked.append(len(r)) or positions(r))
+    got = snap.filter_mask(rows)
+    assert asked == [2]
+    assert got.sum() == len(rows)
+    np.testing.assert_array_equal(got[:N], np.isin(row_map, rows))
+
+
+def test_the_snapshot_of_an_empty_map_masks_nothing():
+    snap = StoreSnapshot(("v", 0), np.zeros(0, dtype=np.int64))
+    assert snap.r_pad == 1 and snap.locator.exact
+    for rows in (np.zeros(0, dtype=np.int64), np.array([0, 5])):
+        assert not snap.filter_mask(rows).any()
 
 
 COUNTERS = ("knn.filtered_searches", "dispatch.mask_scattered",

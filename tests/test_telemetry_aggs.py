@@ -10,20 +10,36 @@ in `telemetry.stage`'s one call form, always on:
     aggs.assemble      once a node the device answers
     aggs.host          once a node the host walker answers, never else
     counters           aggs.device_nodes, aggs.host_nodes, aggs.mask_bytes
-                       (the padded row bucket, once a LAUNCH: the host
-                       mask rides every call), aggs.board_lanes,
+                       (the padded row bucket, once a LAUNCH on one
+                       device: the host mask rides every call; once a
+                       REQUEST under a mesh: every program takes the one
+                       sharded copy, ISSUE 38), aggs.board_lanes,
                        aggs.matched_rows, aggs.dispatches.<family>,
                        aggs.programs.narrow and aggs.programs.x64 (ISSUE
-                       36: one of the two a program, by its arithmetic)
+                       36: one of the two a program, by its arithmetic),
+                       aggs.mask_scattered and aggs.mask_searched (ISSUE
+                       38: one of the two a request that built a mask, by
+                       whether the snapshot's locator placed its rows or
+                       the row map was searched; their sum is the count
+                       of aggs.mask)
 
 `indices.aggs`'s `device_nanos`, `assemble_nanos` and `host_nanos` are the
 sums of the same clock marks, and a request without aggregations records
 none of it.
 """
 
+import contextlib
+import json
+import os
+import sys
+
+import numpy as np
 import pytest
 
-from elasticsearch_tpu.telemetry import metrics
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.readers import stats_ratio  # noqa: E402
+from elasticsearch_tpu.telemetry import metrics  # noqa: E402
 
 ROWS = 300
 T0 = 893894400          # 1998-04-30T00:00:00Z, in seconds
@@ -34,7 +50,8 @@ COUNTERS = ("aggs.device_nodes", "aggs.host_nodes", "aggs.mask_bytes",
             "aggs.dispatches.date_histogram", "aggs.dispatches.metric",
             "aggs.dispatches.terms", "aggs.dispatches.date_histogram_tree",
             "aggs.dispatches.range", "aggs.programs.narrow",
-            "aggs.programs.x64")
+            "aggs.programs.x64", "aggs.mask_scattered",
+            "aggs.mask_searched")
 BY_HOUR = {"date_histogram": {"field": "@timestamp",
                               "fixed_interval": "1h"}}
 
@@ -75,10 +92,13 @@ def node(tmp_path):
     n.close()
 
 
-def _r_pad(node):
+def _snap(node):
     (_svc, engine), = node._aggs.values()
-    return engine.store.snapshot(
-        node.indices.get("logs").combined_reader()).r_pad
+    return engine.store.snapshot(node.indices.get("logs").combined_reader())
+
+
+def _r_pad(node):
+    return _snap(node).r_pad
 
 
 def _search(node, aggs, query=None):
@@ -111,20 +131,41 @@ def test_a_device_node_records_each_stage_once(node):
     assert counters["aggs.device_nodes"] == 1
     assert counters["aggs.host_nodes"] == 0
     assert counters["aggs.matched_rows"] == matched
-    assert counters["aggs.mask_bytes"] == _r_pad(node)   # one launch
+    assert counters["aggs.mask_bytes"] == _r_pad(node)
+    assert counters["aggs.mask_scattered"] == 1
+    assert counters["aggs.mask_searched"] == 0
     assert counters["aggs.dispatches.terms"] == 1
     assert counters["aggs.board_lanes"] == 8 + 1         # the rung + trash
 
 
-def test_the_mask_is_built_once_a_request_and_rides_every_launch(node):
-    """`bytes-by-hour`'s shape: a histogram with a `sum` under it and a
-    top-level `sum`: two nodes, three programs, one mask."""
-    aggs = {"by_hour": dict(BY_HOUR,
-                            aggs={"bytes": {"sum": {"field": "size"}}}),
-            "total_bytes": {"sum": {"field": "size"}}}
-    _search(node, aggs)
+THREE_PROGRAMS = {
+    # `bytes-by-hour`'s shape: a histogram with a `sum` under it and a
+    # top-level `sum`: two nodes, three programs, one mask
+    "by_hour": dict(BY_HOUR, aggs={"bytes": {"sum": {"field": "size"}}}),
+    "total_bytes": {"sum": {"field": "size"}}}
+
+
+def _spy_on_launches(monkeypatch):
+    """Every `_launch` of the engine: (the request's box, the program's
+    arguments, its mesh)."""
+    from elasticsearch_tpu.search.agg_plan import AggEngine
+    seen = []
+    launch = AggEngine._launch
+
+    def spy(mask_box, name, *args, mesh=None, **statics):
+        seen.append((mask_box, args, mesh))
+        return launch(mask_box, name, *args, mesh=mesh, **statics)
+
+    monkeypatch.setattr(AggEngine, "_launch", staticmethod(spy))
+    return seen
+
+
+def test_the_mask_is_built_once_a_request_and_rides_every_launch(
+        node, monkeypatch):
+    _search(node, THREE_PROGRAMS)
+    seen = _spy_on_launches(monkeypatch)
     before = _read()
-    resp = _search(node, aggs)
+    resp = _search(node, THREE_PROGRAMS)
     assert resp["aggregations"]["total_bytes"]["value"] == sum(
         100 + i for i in range(ROWS))
     counts, _nanos, counters = _delta(before)
@@ -135,9 +176,151 @@ def test_the_mask_is_built_once_a_request_and_rides_every_launch(node):
     assert counts["aggs.host"] == 0
     assert counters["aggs.device_nodes"] == 2
     assert counters["aggs.mask_bytes"] == 3 * _r_pad(node)
+    assert counters["aggs.mask_scattered"] == 1          # ONE mask built
+    assert counters["aggs.mask_searched"] == 0
     assert counters["aggs.dispatches.date_histogram"] == 2
     assert counters["aggs.dispatches.metric"] == 1
     assert counters["aggs.matched_rows"] == ROWS
+    # the three programs took the ONE host mask
+    assert len(seen) == 3
+    box = seen[0][0]
+    assert box["mask"].shape == (_r_pad(node),) and not box["sharded"]
+    for mask_box, args, mesh in seen:
+        assert mask_box is box and mesh is None
+        assert sum(a is box["mask"] for a in args) == 1
+
+
+@contextlib.contextmanager
+def _a_row_map_the_locator_cannot_hold(node):
+    """The cached snapshot's locator in the form a row map out of order
+    takes. The rows stay where they are, so the answers do too."""
+    snap = _snap(node)
+    assert snap.locator.form == "contiguous"
+    snap.locator.form = "search"
+    try:
+        yield snap
+    finally:
+        snap.locator.form = "contiguous"
+
+
+def test_a_row_map_out_of_order_is_searched_and_counted_as_searched(
+        node, monkeypatch):
+    first_day = {"range": {"@timestamp": {"gte": T0, "lt": T0 + 86400}}}
+    want = _search(node, THREE_PROGRAMS, first_day)["aggregations"]
+    calls = []
+    isin = np.isin
+    before = _read()
+    with _a_row_map_the_locator_cannot_hold(node) as snap:
+        monkeypatch.setattr(np, "isin", lambda *a, **kw: calls.append(
+            a[0] is snap.row_map) or isin(*a, **kw))
+        got = _search(node, THREE_PROGRAMS, first_day)["aggregations"]
+        monkeypatch.undo()
+    assert got == want
+    assert calls.count(True) == 1            # the whole map, once
+    counts, _nanos, counters = _delta(before)
+    assert counts["aggs.mask"] == 1
+    assert counters["aggs.mask_searched"] == 1
+    assert counters["aggs.mask_scattered"] == 0
+    assert counters["aggs.mask_bytes"] == 3 * _r_pad(node)
+
+
+def test_a_located_row_map_is_never_searched(node, monkeypatch):
+    first_day = {"range": {"@timestamp": {"gte": T0, "lt": T0 + 86400}}}
+    want = _search(node, THREE_PROGRAMS, first_day)["aggregations"]
+    snap = _snap(node)
+    isin = np.isin
+
+    def searched(*a, **kw):
+        assert a[0] is not snap.row_map, "np.isin over a located row map"
+        return isin(*a, **kw)
+    monkeypatch.setattr(np, "isin", searched)
+    assert _search(node, THREE_PROGRAMS, first_day)["aggregations"] == want
+
+
+def test_scattered_and_searched_sum_to_the_count_of_the_mask_stage(node):
+    """Over requests of one node, of three programs, of a node for the
+    walker beside one for the device, of the walker alone and of no
+    aggregation, on a located map and on a searched one."""
+    bodies = [{"by_status": {"terms": {"field": "status"}}}, THREE_PROGRAMS,
+              {"p": {"percentiles": {"field": "size"}},
+               "by_status": {"terms": {"field": "status"}}},
+              {"p": {"percentiles": {"field": "size"}}}]
+    before = _read()
+    for aggs in bodies:
+        _search(node, aggs)
+    node.search("logs", {"size": 1})
+    with _a_row_map_the_locator_cannot_hold(node):
+        for aggs in bodies[:2]:
+            _search(node, aggs)
+    counts, _nanos, counters = _delta(before)
+    assert counters["aggs.mask_scattered"] == 3
+    assert counters["aggs.mask_searched"] == 2
+    assert counts["aggs.mask"] == 5
+
+
+# what a `benchmark` PR's `layer_metrics/aggs_mask_scatter_share.json`
+# would hold (PERF.md section 7 (s): this PR could not add it,
+# `tests/benchmark/test_beat_metrics.py` holds the last entries of
+# `per_layer` to PR 37's seven); the reader is the benchmark's, as it is
+SHARE = {"reader": "stats_ratio",
+         "paths": ["telemetry/counters/aggs.mask_scattered"],
+         "over": ["telemetry/counters/aggs.mask_scattered",
+                  "telemetry/counters/aggs.mask_searched"], "scale": 100}
+
+
+def test_the_benchmarks_reader_reads_the_scattered_share(node):
+    _search(node, THREE_PROGRAMS)
+    before = node.local_node_stats()
+    for _ in range(3):
+        _search(node, THREE_PROGRAMS)
+    after = node.local_node_stats()
+    ctx = {"before": before, "after": after, "seconds": 1.0}
+    assert stats_ratio.read(SHARE, ctx) == 100.0
+    with _a_row_map_the_locator_cannot_hold(node):
+        _search(node, THREE_PROGRAMS)
+    ctx["after"] = node.local_node_stats()
+    assert stats_ratio.read(SHARE, ctx) == 75.0
+    # the parent commit: the same window, a program without the counters
+
+    def parent(stats):
+        stats = json.loads(json.dumps(stats))
+        for name in ("aggs.mask_scattered", "aggs.mask_searched"):
+            stats["telemetry"]["counters"].pop(name, None)
+        return stats
+    assert stats_ratio.read(SHARE, {"before": parent(before),
+                                    "after": parent(after),
+                                    "seconds": 1.0}) is None
+    # and a window that built no mask: no share, not 0 / 0
+    assert stats_ratio.read(SHARE, {"before": after, "after": after,
+                                    "seconds": 1.0}) is None
+
+
+@pytest.mark.multidevice
+def test_the_mesh_route_shards_the_mask_once_a_request(node, monkeypatch,
+                                                       mesh_serving):
+    """Two nodes, three programs, ONE sharded copy (a copy a node
+    before ISSUE 38)."""
+    from jax.sharding import NamedSharding
+    want = sum(100 + i for i in range(ROWS))
+    assert _search(node, THREE_PROGRAMS)["aggregations"]["total_bytes"][
+        "value"] == want
+    seen = _spy_on_launches(monkeypatch)
+    before = _read()
+    resp = _search(node, THREE_PROGRAMS)
+    assert resp["aggregations"]["total_bytes"]["value"] == want
+    counts, _nanos, counters = _delta(before)
+    assert counts["aggs.mask"] == 1 and counts["aggs.launch"] == 3
+    assert counters["aggs.mask_bytes"] == _r_pad(node)
+    assert counters["aggs.mask_scattered"] == 1
+    assert node._aggs_stats_section()["mesh_dispatches"] >= 2
+    box = seen[0][0]
+    ((mesh, held),) = box["sharded"].items()
+    assert mesh is not None and isinstance(held.sharding, NamedSharding)
+    assert len(held.sharding.device_set) == 8
+    for mask_box, args, used in seen:
+        assert mask_box is box and used is mesh
+        assert sum(a is held for a in args) == 1
+    np.testing.assert_array_equal(np.asarray(held), box["mask"])
 
 
 PROGRAMS = {
