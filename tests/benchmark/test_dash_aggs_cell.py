@@ -365,7 +365,7 @@ def test_the_rehearsal_prints_every_per_layer_metric_of_the_cell(rehearsal):
     last, _stderr = rehearsal
     want = {m["name"] for m in BENCH["per_layer"]
             if CELL in m.get("workloads", [])}
-    assert len(want) >= 23              # counted from BENCHMARK.json
+    assert want                         # counted from BENCHMARK.json
     got = {n: m["value"] for n, m in last["metrics"].items()}
     # a CPU has no chip to take a share of
     assert want - set(got) == {"aggs_roofline"}
@@ -373,6 +373,8 @@ def test_the_rehearsal_prints_every_per_layer_metric_of_the_cell(rehearsal):
     assert got["window_compiles.aggs"] == 0
     assert got["aggs_device_share"] == 100.0
     assert got["agg_plan_cache_hit_share"] == 100.0
+    # the rows are in time order: every mask a slice or a scatter
+    assert got["aggs_mask_scatter_share"] == 100.0
     # a stage lies inside what it splits
     assert 0 < got["aggs_launch_mean_ms"] + got["aggs_sync_wait_mean_ms"] \
         <= got["aggs_device_mean_ms"]

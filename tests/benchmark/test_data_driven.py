@@ -1,8 +1,10 @@
 """The harness is driven by data: a configuration, a traffic mix, a traffic
 kind (another request shape with its own comparison), an end-to-end metric,
 a per-layer metric and two cells written as NEW files in a copy of the
-benchmark run with no edit to a file that was there; and `BENCHMARK.json`
-and the data files keep to the characters and lengths the driver accepts."""
+benchmark run with no edit to a file that was there; a configuration of
+another kind and entries appended at the end pass the benchmark's own
+static tests; and `BENCHMARK.json` and the data files keep to the
+characters and lengths the driver accepts."""
 
 import json
 import os
@@ -26,6 +28,11 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
 def _line(text):
     return (isinstance(text, str) and 1 <= len(text) <= 200
             and "\n" not in text and "\t" not in text)
+
+
+def _vector_field(config):
+    """The vector field a configuration's data names, or None."""
+    return config.get("data", {}).get("vector_field")
 
 
 def test_benchmark_json_keeps_to_the_contract():
@@ -52,11 +59,15 @@ def test_benchmark_json_keeps_to_the_contract():
         with open(os.path.join(REPO, c["file"])) as f:
             held = json.load(f)
         # the file holds the configuration as it is run, and says what it
-        # cut (rows only: no width is ever cut)
+        # cut: for each key, the value run here and the source's
         assert held["name"] == c["name"] and held["source"] == c["source"]
-        assert set(held["reduced"]) == set(c["reduced"]) <= {"rows"}
-        assert held["index"]["mappings"]["properties"][
-            held["data"]["vector_field"]]["dims"] == held["dims"]
+        assert set(held["reduced"]) == set(c["reduced"])
+        for key in c["reduced"]:
+            assert key in held and "source_" + key in held, key
+        # a vector field is held to its mapping; no other kind needs one
+        if _vector_field(held):
+            assert held["index"]["mappings"]["properties"][
+                _vector_field(held)]["dims"] == held["dims"]
     cells = {w["name"]: w for w in BENCH["workloads"]}
     assert len(cells) == len(BENCH["workloads"]) <= 24
     pairs = set()
@@ -144,10 +155,14 @@ def test_every_data_file_is_there_and_named_as_the_driver_accepts():
     assert peaks["source"] and "TPU v5 lite" in peaks["devices"]
     for c in BENCH["configs"]:
         with open(os.path.join(REPO, c["file"])) as f:
-            limits = json.load(f)["limits"]
+            held = json.load(f)
+        limits = held["limits"]
+        assert limits
         assert all(v["must"] in (">=", "<=", "==") for v in limits.values())
-        assert limits["recall_at_k"] == {"limit": 0.95, "must": ">="}
-        assert limits["score_rms_err"]["limit"] > 0
+        # a search over vectors is held to the recall the source states
+        if _vector_field(held):
+            assert limits["recall_at_k"] == {"limit": 0.95, "must": ">="}
+            assert limits["score_rms_err"]["limit"] > 0
 
 
 # ---------------------------------------------------------------------------
@@ -325,6 +340,114 @@ def test_a_new_cell_is_files_and_entries_only(tmp_path):
     short = run(0)
     assert short.returncode != 0
     assert not [ln for ln in short.stdout.splitlines() if ln.startswith("{")]
+
+
+# the benchmark's own tests that read BENCHMARK.json and start no run
+STATIC = [
+    "test_data_driven.py::test_benchmark_json_keeps_to_the_contract",
+    "test_data_driven.py::"
+    "test_every_data_file_is_there_and_named_as_the_driver_accepts",
+    "test_beat_metrics.py::test_the_metric_is_data_over_a_reader_that_was_there",
+    "test_beat_metrics.py::"
+    "test_the_reader_reads_a_window_and_nothing_of_a_parent",
+    "test_beat_metrics.py::"
+    "test_the_benchmark_gained_seven_entries_and_no_cell_of_filtered",
+    "test_stage_metrics.py::"
+    "test_the_new_metrics_are_data_files_over_the_readers_that_were_there",
+]
+# metric -> (its file, the cells it lists)
+APPENDED = {
+    "aggs_plan_mean_ms.weekly": (
+        {"reader": "histogram_mean", "sum_of": ["aggs.plan"],
+         "count_of": "aggs.plan", "scale": 1e-06, "what": "a test's own"},
+        ["logs-weekly-steady"]),
+    "aggs_mask_scatter_share.weekly": (
+        {"reader": "stats_ratio",
+         "paths": ["telemetry/counters/aggs.mask_scattered"],
+         "over": ["telemetry/counters/aggs.mask_scattered",
+                  "telemetry/counters/aggs.mask_searched"],
+         "scale": 100, "what": "a test's own"},
+        ["logs-weekly-steady"]),
+    "server_took_mean_ms.both": (
+        {"reader": "histogram_mean", "sum_of": ["search.took"],
+         "count_of": "search.took", "scale": 1e-06, "what": "a test's own"},
+        ["logs-weekly-steady", "filtered-steady"]),
+}
+
+
+def _without_vectors(config):
+    """A deployment of log lines with no vector at all, cut in rows and in
+    indices (seven weekly indices held as one): no vector field, mapping,
+    width or limit of a search over vectors."""
+    config = json.loads(json.dumps(config))
+    field = config["data"].pop("vector_field")
+    del config["index"]["mappings"]["properties"][field], config["dims"]
+    for name in ("recall_at_k", "score_rms_err", "filter_violations"):
+        del config["limits"][name]
+    config.update(name="logs-weekly", indices=1, source_indices=7)
+    config["reduced"] = {"rows": "a test's own", "indices": "a test's own"}
+    return config
+
+
+def test_a_configuration_of_another_kind_and_appended_entries_pass(tmp_path):
+    """A configuration with no vector field, cut in a key besides `rows`,
+    a one-chip cell, and three per-layer entries appended at the END of
+    `per_layer` (one of them also in `filtered-steady`): the benchmark's
+    own static tests pass over them in a copy, and no file that was there
+    is edited; only `BENCHMARK.json` gains entries."""
+    work = tmp_path / "checkout"
+    ignore = shutil.ignore_patterns("out", "__pycache__")
+    shutil.copytree(BENCH_DIR, work / "benchmark", ignore=ignore)
+    shutil.copytree(os.path.join(REPO, "tests", "benchmark"),
+                    work / "tests" / "benchmark", ignore=ignore)
+    os.symlink(os.path.join(REPO, "elasticsearch_tpu"),
+               work / "elasticsearch_tpu")
+    before = {p: p.read_bytes() for d in ("benchmark", "tests")
+              for p in (work / d).rglob("*") if p.is_file()}
+
+    with open(os.path.join(BENCH_DIR, "configs", "http-logs-dash.json")) as f:
+        config = _without_vectors(json.load(f))
+    (work / "benchmark/configs/logs-weekly.json").write_text(
+        json.dumps(config))
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({
+        "name": "logs-weekly", "source": config["source"],
+        "file": "benchmark/configs/logs-weekly.json",
+        "reduced": ["rows", "indices"], "why": "a test's own"})
+    bench["workloads"].append({
+        "name": "logs-weekly-steady", "config": "logs-weekly",
+        "traffic": "dash-aggs-steady", "chips": 1, "why": "a test's own"})
+    for m in bench["end_to_end"]:
+        if m["name"] in ("search_p50_ms", "search_p95_ms"):
+            m["workloads"].append("logs-weekly-steady")
+    for name, (spec, cells) in APPENDED.items():
+        (work / "benchmark/layer_metrics" / f"{name}.json").write_text(
+            json.dumps(spec))
+        bench["per_layer"].append({
+            "name": name, "unit": "%" if "share" in name else "ms",
+            "better": "higher" if "share" in name else "lower",
+            "source": "program_counter" if "share" in name
+            else "program_span",
+            "layer": "aggregation engine", "moves": "search_p50_ms",
+            "workloads": cells})
+    (work / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    def pytest(*args):
+        return subprocess.run(
+            [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+             *args], cwd=work, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+            text=True, capture_output=True, timeout=240)
+
+    done = pytest(*[os.path.join("tests", "benchmark", t) for t in STATIC])
+    assert done.returncode == 0, done.stdout[-3000:]
+    assert " passed" in done.stdout and "failed" not in done.stdout
+    # the int8 control of a search over vectors is not asked of it
+    done = pytest("--collect-only", "tests/benchmark/test_control.py")
+    assert done.returncode == 0, done.stdout[-3000:]
+    assert "nytimes-256-angular" in done.stdout
+    assert "logs-weekly" not in done.stdout
+    for p, content in before.items():
+        assert p.read_bytes() == content, p
 
 
 def test_no_result_where_only_the_benchmark_is(tmp_path):

@@ -213,13 +213,17 @@ def test_the_rehearsal_prints_every_per_layer_metric_of_the_cell(rehearsal):
     last, _stderr = rehearsal
     want = {m["name"] for m in BENCH["per_layer"]
             if "filtered-steady" in m.get("workloads", [])}
-    assert len(want) == 29
+    assert want                         # counted from BENCHMARK.json
     got = {n: m["value"] for n, m in last["metrics"].items()}
     # a CPU has no chip to take a share of
     assert want - set(got) == {"knn_roofline.filtered"}
     assert set(got) <= want
     assert got["window_compiles.filtered"] == 0
     assert got["filtered_search_share"] == 100.0
+    # the cell's row map is one run: every mask row a scatter
+    assert got["mask_scatter_share"] == 100.0
+    assert 0 < got["rest_handle_cpu_share"] <= 100
+    assert got["lock_wait_mean_ms"] >= 0 and got["server_stalls"] >= 0
     assert 0 <= got["filter_cache_hit_share"] <= 100
     # a stage lies inside what it splits; a mask is a byte a padded cell
     assert 0 < got["mask_build_mean_ms"] <= \
