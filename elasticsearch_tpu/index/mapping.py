@@ -293,8 +293,17 @@ class ScaledFloatFieldMapper(_NumericMapper):
     py_type = float
 
     def doc_value(self, value):
+        """The stored long `Math.round(value x factor)` (half UP: 0.125
+        at 100 is 13 cents, -0.125 is -12), read back over the factor."""
         factor = self.params.get("scaling_factor", 100)
-        return round(self.coerce(value) * factor) / factor
+        return round_half_up(self.coerce(value) * factor) / factor
+
+
+def round_half_up(x: float) -> int:
+    """Java's `Math.round` of a double: the nearest whole number, a half
+    toward positive infinity (Python's `round` takes the even one)."""
+    r = math.floor(x)
+    return r + 1 if x - r >= 0.5 else r
 
 
 class BooleanFieldMapper(FieldMapper):
@@ -350,6 +359,40 @@ def parse_custom_date(value: str, fmt: str, locale: str = "") -> int:
     if d.tzinfo is None:
         d = d.replace(tzinfo=_dt.timezone.utc)
     return int(d.timestamp() * 1000)
+
+
+def parse_date_in_format(value: Any, fmt: Any,
+                         round_up: bool = False) -> Optional[int]:
+    """A date bound in a query's own `format` (`dd/MM/yyyy`): the first
+    pattern of `fmt` (`||`-separated) that reads it. With `round_up` (a
+    `gt` / `lte` bound) the fields the pattern leaves out take their
+    greatest values, as JavaDateMathParser's roundUp does: `21/01/2015`
+    is 23:59:59.999 of that day. None where `fmt` names no pattern that
+    reads it (a named format, date math): the caller parses as before."""
+    if not isinstance(value, str) or not fmt or "||" in value:
+        return None
+    for pat in str(fmt).split("||"):
+        pat = pat.strip()
+        if not re.search(r"yy|MM|dd|HH", pat):
+            continue
+        try:
+            millis = parse_custom_date(value, pat)
+        except (ValueError, MapperParsingError):
+            continue
+        if not round_up or "SSS" in pat:
+            return millis
+        for field, span in (("ss", 1000), ("mm", 60_000),
+                            ("HH", 3_600_000), ("dd", 86_400_000)):
+            if field in pat:
+                return millis + span - 1
+        d = _dt.datetime.fromtimestamp(millis / 1000, _dt.timezone.utc)
+        if "MM" in pat:
+            nxt = d.replace(year=d.year + d.month // 12,
+                            month=d.month % 12 + 1)
+        else:
+            nxt = d.replace(year=d.year + 1)
+        return int(nxt.timestamp() * 1000) - 1
+    return None
 
 
 class DateFieldMapper(FieldMapper):

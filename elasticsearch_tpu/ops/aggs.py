@@ -16,7 +16,9 @@ module gives doc-value fields the treatment `vectors/store.py` gives
   compiled shapes survive refreshes), plus, for terms aggs, a global
   ordinal column (int32 ord per row over the sorted-unique value set),
   plus, where every value is an integer and the span fits, the column's
-  32-bit form `k32` ((v - least) / unit as int32, -1 for no value).
+  32-bit form `k32` ((v - least) / unit as int32, -1 for no value); a
+  `scaled_float`'s values are first taken to their stored longs (its
+  lattice, `AggColumn.k_scale`).
   Per-segment extractions cache by segment fingerprint, so append-only
   refreshes re-extract only delta segments (copy-on-write rebuild — an
   in-flight search keeps the previous column's arrays). Each array goes
@@ -42,9 +44,13 @@ module gives doc-value fields the treatment `vectors/store.py` gives
   Host parity for sums is guaranteed only for *integral* columns (every
   value integer-valued, sum of |values| < 2^53 — dates, longs, counts),
   where any accumulation order reproduces numpy's pairwise sum
-  bit-for-bit; `search/agg_plan.py` routes sum-bearing aggs on other
-  columns to the host path. min/max/counts are order-insensitive and run
-  on device for any numeric column.
+  bit-for-bit; a `scaled_float` on its lattice sums its stored longs
+  exactly in the 32-bit program, divided by the factor once on the host
+  (the correctly rounded double of the exact decimal; the walker's f64
+  sum of the quotients may differ in its last bits); `search/agg_plan.py`
+  routes sum-bearing aggs on other columns to the host path.
+  min/max/counts are order-insensitive and run on device for any numeric
+  column.
 
 * mesh: columns past the `parallel/policy.py` row floor keep a row-sharded
   device copy; the `aggs.mesh_*` twins reduce each shard's row range
@@ -62,11 +68,13 @@ rungs at column build.
 from __future__ import annotations
 
 import logging
+import math
 import threading
 from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from elasticsearch_tpu import telemetry
 from elasticsearch_tpu.ops import dispatch
 from elasticsearch_tpu.vectors.filter_mask import RowLocator
 
@@ -831,12 +839,16 @@ class AggColumn:
     as int32, -1 where the row holds no value; `k_unit` is the gcd of
     the values' distances from the least (1000 for an `epoch_second`
     date held in millis), `k_max` the largest of them. A column that
-    has it runs the 32-bit programs and its f64 pair stays on the host."""
+    has it runs the 32-bit programs and its f64 pair stays on the host.
+    A `scaled_float`'s column is held on its LATTICE (`k_scale`, the
+    field's factor, above 1): its values are the stored longs v x
+    k_scale, so `k_base` and `k_unit` count in 1 / k_scale and a value
+    is (k_base + k32 x k_unit) / k_scale."""
 
     __slots__ = ("field", "version", "n_rows", "r_pad", "vals", "present",
                  "numeric", "integral_exact", "multi_valued", "ords_built",
                  "ords", "ord_keys", "vmin", "vmax",
-                 "k32", "k_base", "k_unit", "k_max",
+                 "k32", "k_base", "k_unit", "k_max", "k_scale",
                  "hll_built", "hll_idx", "hll_rho", "_dev")
 
     def __init__(self, field: str):
@@ -858,21 +870,33 @@ class AggColumn:
         self.k_base = 0
         self.k_unit = 1
         self.k_max = 0
+        self.k_scale = 1
         self.hll_built = False
         self.hll_idx: Optional[np.ndarray] = None  # int32[r_pad] register
         self.hll_rho: Optional[np.ndarray] = None  # int32[r_pad], 0 absent
         self._dev: Dict[tuple, tuple] = {}  # (name, sharded) -> (mesh, arr)
 
-    def build_k32(self, integral: bool) -> None:
+    def build_k32(self, integral: bool, scale: Optional[int] = None
+                  ) -> None:
         """The 32-bit form of `vals`, or None where it cannot hold them:
         a value that is no integer (`integral`: every present value is
         one; a KEY needs no more, `integral_exact` is the sums' matter),
         a span of 2^31 - 1 units or more, or rows x span past what the
-        host's int64 widening holds."""
+        host's int64 widening holds. With a `scale` (a `scaled_float`'s
+        factor) the values are first taken to their stored longs, where
+        each f64 value is exactly the quotient of its long by `scale`."""
         self.k32 = None
+        self.k_scale = 1
         pv = self.vals[self.present]
+        lattice = scale is not None and scale > 1 and len(pv) > 0
+        if lattice:
+            longs = np.rint(pv * scale)
+            if not (np.isfinite(pv).all()
+                    and np.array_equal(longs / scale, pv)):
+                return
+            pv, integral = longs, True
         if not (integral and len(pv) and self.r_pad <= N32_MAX_ROWS
-                and max(abs(self.vmin), abs(self.vmax)) < _EXACT_INT):
+                and float(np.abs(pv).max()) < _EXACT_INT):
             return
         iv = pv.astype(np.int64)
         base = int(iv.min())
@@ -885,6 +909,7 @@ class AggColumn:
         k32[self.present] = dist // unit
         self.k32, self.k_base, self.k_unit, self.k_max = (
             k32, base, unit, k_max)
+        self.k_scale = int(scale) if lattice else 1
 
     def to_k32(self, value) -> Optional[int]:
         """A value (a `missing` substitute) in the rebased domain, or
@@ -893,7 +918,14 @@ class AggColumn:
             f = float(value)
         except (TypeError, ValueError):
             return None
-        if self.k32 is None or not f.is_integer() or abs(f) >= _EXACT_INT:
+        if self.k32 is None or not math.isfinite(f):
+            return None
+        if self.k_scale != 1:
+            long = float(np.rint(f * self.k_scale))
+            if long / self.k_scale != f:
+                return None
+            f = long
+        if not f.is_integer() or abs(f) >= _EXACT_INT:
             return None
         k, rem = divmod(int(f) - self.k_base, self.k_unit)
         return k if rem == 0 and 0 <= k < I32_MAX else None
@@ -975,8 +1007,10 @@ class AggFieldStore:
     contract — most refreshes never serve an agg, so columns build on
     first agg use and re-extract only delta segments after that."""
 
-    def __init__(self, warmup: Optional[bool] = None):
+    def __init__(self, warmup: Optional[bool] = None, scale_of=None):
         self._columns: Dict[str, AggColumn] = {}
+        # field -> a scaled_float's factor, else None (`AggColumn.k_scale`)
+        self.scale_of = scale_of
         self._lock = threading.Lock()
         self._snap: Optional[StoreSnapshot] = None
         self.warmup = warmup
@@ -1094,7 +1128,10 @@ class AggFieldStore:
                             and np.all(pv == np.floor(pv)))
             col.integral_exact = bool(
                 integral and float(np.abs(pv).sum()) < _EXACT_INT)
-            col.build_k32(integral)
+            col.build_k32(integral, self.scale_of(field)
+                          if self.scale_of is not None else None)
+            if col.k_scale != 1:
+                telemetry.metrics.counter("aggs.columns.lattice").inc()
         else:
             col.integral_exact = True  # empty sums are trivially exact
         if want_ords and not multi:

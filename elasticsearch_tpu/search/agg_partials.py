@@ -30,6 +30,7 @@ import numpy as np
 
 from elasticsearch_tpu.common.errors import ParsingError
 from elasticsearch_tpu.search import aggregations as A
+from elasticsearch_tpu.search import auto_date as AD
 from elasticsearch_tpu.search.aggregations import (
     BUCKET_AGGS, METRIC_AGGS, PIPELINE_AGGS, SearchContext, _hashable,
     _sort_key, all_values, numeric_values,
@@ -229,9 +230,22 @@ def compute_partial_aggs(ctx: SearchContext, rows: np.ndarray,
             out[name] = A._compute_bucket(
                 ctx, rows, kind, _partial_spec(kind, spec[kind]), sub_normal,
                 recurse=compute_partial_aggs)
+            if kind in ("date_histogram", "auto_date_histogram"):
+                _carry_date_format(ctx, spec[kind], out[name])
         else:
             raise ParsingError(f"unknown aggregation type [{kind}]")
     return out
+
+
+def _carry_date_format(ctx, spec: dict, node) -> None:
+    """A date histogram's partial carries the pattern its keys print in
+    (`$fmt`: the aggregation's `format`, else the field's own), for the
+    keys the coordinator makes: empty buckets, re-rounded ones."""
+    field = spec.get("field")
+    fmt = spec.get("format") or A.field_date_format(
+        ctx.mapper_service.get(field) if field else None)
+    if fmt and isinstance(node, dict):
+        node["$fmt"] = fmt
 
 
 def _is_pipeline(sspec: dict) -> bool:
@@ -266,6 +280,8 @@ def _partial_spec(kind: str, spec: dict) -> dict:
         size = int(spec.get("size", 10000))
         return {**spec,
                 "size": int(spec.get("shard_size") or (size * 3 // 2 + 10))}
+    if kind == "auto_date_histogram":
+        return {**spec, AD.SHARD_KEY: True}
     if kind in ("histogram", "date_histogram"):
         # -1: disable threshold pruning WITHOUT enabling the per-shard
         # zero-fill that min_doc_count=0 implies (the coordinator
@@ -544,13 +560,18 @@ def _merge_bucket_agg(kind: str, spec: dict, a, b, sub_spec: dict):
         return {**a, "buckets": merged_list}
 
     if kind == "auto_date_histogram":
-        ia = int(str(a.get("interval", "1ms")).rstrip("ms") or 1)
-        ib = int(str(b.get("interval", "1ms")).rstrip("ms") or 1)
-        interval = max(ia, ib)
-        a_buckets = _rebucket(a.get("buckets", []), interval, sub_spec)
-        b_buckets = _rebucket(b.get("buckets", []), interval, sub_spec)
-        merged = _merge_keyed(kind, a_buckets, b_buckets, sub_spec)
-        return {"buckets": merged, "interval": f"{interval}ms"}
+        # both sides at the coarser of their roundings (`auto_date`)
+        r = max(AD.parse_interval(a.get("interval"))[0],
+                AD.parse_interval(b.get("interval"))[0])
+        fmt = a.get("$fmt") or b.get("$fmt")
+        merged = _merge_keyed(
+            kind, _rebucket(a.get("buckets", []), r, sub_spec, fmt),
+            _rebucket(b.get("buckets", []), r, sub_spec, fmt), sub_spec)
+        merged.sort(key=lambda bk: bk["key"])
+        out = {"buckets": merged, "interval": AD.interval(r, 1)}
+        if fmt:
+            out["$fmt"] = fmt
+        return out
 
     # keyed bucket lists: terms/histograms/ranges/grids/composite/adjacency
     merged = _merge_keyed(kind, a.get("buckets", []), b.get("buckets", []),
@@ -584,18 +605,20 @@ def _merge_keyed(kind: str, a_buckets: list, b_buckets: list,
     return merged
 
 
-def _rebucket(buckets: list, interval: int, sub_spec: dict) -> list:
-    """Re-floor date_histogram buckets onto a coarser interval, merging
-    sub-agg partials of collapsed buckets (auto_date_histogram reduce)."""
-    out: Dict[float, dict] = {}
+def _rebucket(buckets: list, rounding: int, sub_spec: dict,
+              fmt: Optional[str] = None) -> list:
+    """An auto_date_histogram's buckets with their keys rounded down to
+    rounding `rounding` (`auto_date.ROUNDINGS`), the sub-agg partials of
+    buckets that meet merged; keys print in `fmt` (ISO without one)."""
+    out: Dict[int, dict] = {}
     for bucket in buckets:
-        key = float(np.floor(float(bucket["key"]) / interval) * interval)
+        key = int(AD.round_down(float(bucket["key"]), rounding))
         if key in out:
             out[key] = _merge_buckets("date_histogram", out[key],
                                       {**bucket, "key": key}, sub_spec)
         else:
-            out[key] = {**bucket, "key": int(key),
-                        "key_as_string": A._millis_to_iso(int(key))}
+            out[key] = {**bucket, "key": key,
+                        "key_as_string": A.date_key_string(key, fmt)}
     return [out[k] for k in sorted(out)]
 
 
@@ -850,23 +873,31 @@ def _finalize_bucket_agg(kind: str, spec: dict, node, sub_spec: dict):
                             for b in node.get("buckets", [])]}
 
     if kind == "auto_date_histogram":
-        # coarsen on the RAW partial buckets (sub-agg states still
-        # mergeable), then finalize once
-        target = int(spec.get("buckets", 10))
-        interval = int(str(node.get("interval", "1ms")).rstrip("ms") or 1)
-        raw = node.get("buckets", [])
-        while len(raw) > target:
-            for unit in (1, 1000, 60_000, 3_600_000, 86_400_000,
-                         2_592_000_000, 31_536_000_000):
-                if unit > interval:
-                    interval = unit
-                    break
-            else:
-                interval *= 2
-            raw = _rebucket(raw, interval, sub_spec)
-        buckets = [_finalize_one_bucket(b, sub_spec) for b in raw]
-        buckets.sort(key=lambda b: float(b["key"]))
-        return {"buckets": buckets, "interval": f"{interval}ms"}
+        # choose and merge on the RAW partial buckets (sub-agg states
+        # still mergeable), then finalize once (`auto_date`)
+        raw = sorted(node.get("buckets", []), key=lambda b: b["key"])
+        if not raw:
+            return {"buckets": [], "interval": AD.interval(0, 1)}
+        fmt = node.get("$fmt")
+        r, m, _n = AD.choose(raw[0]["key"], raw[-1]["key"],
+                             int(spec.get("buckets", 10)),
+                             start=AD.parse_interval(node.get("interval"))[0])
+        raw = _rebucket(raw, r, sub_spec, fmt)
+        by_key = {b["key"]: b for b in raw}
+        keys = AD.keys_between(raw[0]["key"], raw[-1]["key"], r)
+        merged = []
+        for lo, hi in AD.groups(len(keys), m):
+            key = int(keys[lo])
+            out = {"key_as_string": A.date_key_string(key, fmt), "key": key,
+                   "doc_count": 0}
+            for k in keys[lo:hi]:
+                b = by_key.get(int(k))
+                if b is not None:
+                    out = _merge_buckets(kind, out, b, sub_spec)
+            merged.append(out)
+        return {"buckets": [_finalize_one_bucket(b, sub_spec)
+                            for b in merged],
+                "interval": AD.interval(r, m)}
 
     buckets = [_finalize_one_bucket(b, sub_spec)
                for b in node.get("buckets", [])]
@@ -937,9 +968,10 @@ def _finalize_bucket_agg(kind: str, spec: dict, node, sub_spec: dict):
                 keys = A._calendar_keys(
                     float(buckets[0]["key"]), float(buckets[-1]["key"]),
                     calendar, tz, A._date_offset_ms(spec.get("offset")))
-                buckets = _fill_date_keys(buckets, keys, tz)
+                buckets = _fill_date_keys(buckets, keys, tz, node.get("$fmt"))
             else:
-                buckets = _fill_gaps(buckets, interval_ms, date=True)
+                buckets = _fill_gaps(buckets, interval_ms, date=True,
+                                     fmt=node.get("$fmt"))
         return {"buckets": buckets}
 
     if kind in ("geohash_grid", "geotile_grid"):
@@ -976,18 +1008,19 @@ def _finalize_one_bucket(bucket: dict, sub_spec: dict) -> dict:
 
 
 def _fill_date_keys(buckets: List[dict], keys: List[float],
-                    tz=None) -> List[dict]:
+                    tz=None, fmt: Optional[str] = None) -> List[dict]:
     """`buckets` with a zero bucket at every one of `keys` that none of
     them holds (a calendar interval's gaps: the keys are boundaries, not
     an arithmetic progression)."""
     by_key = {float(b["key"]): b for b in buckets}
     return [by_key.get(float(k))
             or {"key": int(k), "doc_count": 0,
-                "key_as_string": A._millis_to_iso_tz(int(k), tz)}
+                "key_as_string": A.date_key_string(int(k), fmt, tz)}
             for k in keys]
 
 
-def _fill_gaps(buckets: List[dict], interval: float, date: bool) -> List[dict]:
+def _fill_gaps(buckets: List[dict], interval: float, date: bool,
+               fmt: Optional[str] = None) -> List[dict]:
     """Zero-fill inter-shard gaps after the merge (min_doc_count=0)."""
     if not buckets or interval <= 0:
         return buckets
@@ -1001,7 +1034,7 @@ def _fill_gaps(buckets: List[dict], interval: float, date: bool) -> List[dict]:
         if b is None:
             b = {"key": int(cur) if date else round(cur, 10), "doc_count": 0}
             if date:
-                b["key_as_string"] = A._millis_to_iso(int(cur))
+                b["key_as_string"] = A.date_key_string(int(cur), fmt)
         out.append(b)
         cur += interval
         guard += 1

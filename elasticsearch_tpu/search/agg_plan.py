@@ -27,6 +27,10 @@ tests/test_device_aggs.py:
                    any time_zone) by the table of bounds the host's
                    own key math gives (`_n32_bounds`; the calendar's
                    is walked once a column version, `_calendar_bounds`)
+  auto_date_histogram  `buckets`, `format`, a UTC `time_zone`, no
+                   sub-aggs: the matched stamps' extrema, the rounding
+                   chosen on the host (`search/auto_date.py`), then the
+                   counts at that rounding (`_run_auto_date`)
   range            numeric from/to/key ranges (overlaps allowed)
   metrics          avg, sum, min, max, stats, value_count — top-level and
                    as one-level sub-aggs of any bucket agg above
@@ -36,11 +40,15 @@ sub-aggs, scripted, include/exclude, nested, composite, multi-valued
 fields — falls through PER NODE to the host path (`compute_aggs` /
 `compute_partial_aggs`), and sum-bearing metrics (sum/avg/stats) ride the
 device only for integral columns where f64 scatter-adds are provably
-order-free (see ops/aggs.py): exactness is a contract, not a tolerance.
+order-free (see ops/aggs.py), or for a `scaled_float` column on its
+lattice, whose 32-bit program sums its stored longs exactly (the sum is
+that integer over the factor, the correctly rounded double of the exact
+decimal): exactness is a contract, not a tolerance.
 
 Every request files its stages and counters through `telemetry.stage`
-(`aggs.plan`, `aggs.mask`, `aggs.device` with `aggs.launch` and
-`aggs.sync_wait` inside it, `aggs.assemble`, `aggs.host`; README
+(`aggs.plan`, `aggs.mask`, `aggs.device` with `aggs.launch`,
+`aggs.sync_wait` and `aggs.auto_round` inside it, `aggs.assemble`,
+`aggs.host`; README
 "End-to-end telemetry"); `indices.aggs`'s `device_nanos`,
 `assemble_nanos` and `host_nanos` are sums of the same clock marks.
 
@@ -67,6 +75,7 @@ from elasticsearch_tpu.common.errors import (
 from elasticsearch_tpu.ops import aggs as aggs_ops
 from elasticsearch_tpu.ops import dispatch
 from elasticsearch_tpu.search import aggregations as A
+from elasticsearch_tpu.search import auto_date as AD
 
 logger = logging.getLogger("elasticsearch_tpu.agg_plan")
 
@@ -86,6 +95,8 @@ _DATE_HISTO_ALLOWED_KEYS = {"field", "interval", "fixed_interval",
                             "calendar_interval", "offset", "min_doc_count",
                             "format", "time_zone"}
 _RANGE_ALLOWED_KEYS = {"field", "ranges", "keyed"}
+_AUTO_DATE_ALLOWED_KEYS = {"field", "buckets", "format", "time_zone"}
+_UTC_NAMES = ("UTC", "Z", "+00:00", "+0000", "Etc/UTC", "GMT")
 _CARD_ALLOWED_KEYS = {"field", "precision_threshold", "missing"}
 
 # composite sub-agg trees: bucket-in-bucket nesting compiles to ONE flat
@@ -121,7 +132,7 @@ _TWO52 = float(1 << 52)
 # the boards a metric's kind reads: the 32-bit program computes no other
 _N32_PARTS = {"value_count": (), "sum": ("sum",), "avg": ("sum",),
               "min": ("min",), "max": ("max",),
-              "stats": ("sum", "min", "max")}
+              "stats": ("sum", "min", "max"), "extrema": ("min", "max")}
 
 
 class _Pending:
@@ -165,7 +176,9 @@ def _widen_metric(board, parts, bits, n_limbs, col, ks, folds):
     the limbs recombined in int64 (sum of limb_j * 2^(bits * j), times
     the column's unit, plus count times its base: under 2^62 by
     `AggColumn.build_k32`), the extrema moved back from the rebased
-    domain, an empty lane's to +-inf."""
+    domain, an empty lane's to +-inf. A column on its lattice is divided
+    by its factor once, here, from the exact integer: the sum is the
+    correctly rounded double of the exact decimal (while under 2^53)."""
     wide = board.astype(np.int64)
     cnt = _assembly_lanes(wide[0], ks, folds)
     total = np.zeros(cnt.shape, dtype=np.float64)
@@ -179,12 +192,16 @@ def _widen_metric(board, parts, bits, n_limbs, col, ks, folds):
         row += n_limbs
         total = (_assembly_lanes(units, ks, folds) * col.k_unit
                  + cnt * col.k_base).astype(np.float64)
+        if col.k_scale != 1:
+            total /= col.k_scale
     has = cnt > 0
     for part, merge, empty, out in (("min", np.minimum, aggs_ops.I32_MAX, mn),
                                     ("max", np.maximum, -1, mx)):
         if part in parts:
             k = _assembly_lanes(wide[row], ks, folds, merge=merge, empty=empty)
             out[has] = (k[has] * col.k_unit + col.k_base).astype(np.float64)
+            if col.k_scale != 1:
+                out[has] /= col.k_scale
             row += 1
     return cnt, total, mn, mx
 
@@ -430,6 +447,12 @@ def compile_plan(aggs_spec: dict, mapper_service) -> AggPlan:
                 nodes[name] = _Node(name, "host", kind=kind,
                                     host_reason=reason)
             continue
+        if kind == "auto_date_histogram":
+            reason = _classify_auto_date(body, sub_spec, mapper_service)
+            nodes[name] = _Node(name, kind, kind=kind, field=body["field"]) \
+                if reason is None else _Node(name, "host", kind=kind,
+                                             host_reason=reason)
+            continue
         if kind in ("terms", "histogram", "date_histogram", "range") \
                 and isinstance(body, dict):
             reason = _classify_bucket(kind, body, mapper_service)
@@ -453,6 +476,24 @@ def compile_plan(aggs_spec: dict, mapper_service) -> AggPlan:
         nodes[name] = _Node(name, "host", kind=kind,
                             host_reason="unsupported_agg")
     return AggPlan(nodes)
+
+
+def _classify_auto_date(body, sub_spec, mapper_service) -> Optional[str]:
+    """None = an `auto_date_histogram` the device answers (two programs:
+    `AggEngine._run_auto_date`); otherwise the fallback reason."""
+    if not isinstance(body, dict) or not isinstance(body.get("field"), str):
+        return "no_field"
+    if sub_spec:
+        return "auto_date_sub_aggs"
+    if not set(body) <= _AUTO_DATE_ALLOWED_KEYS:
+        return "unsupported_param"
+    if getattr(mapper_service.get(body["field"]), "type_name",
+               None) != "date":
+        return "auto_date_field_type"
+    if body.get("time_zone") is not None \
+            and str(body["time_zone"]) not in _UTC_NAMES:
+        return "auto_date_time_zone"
+    return None
 
 
 def _classify_bucket(kind: str, body: dict, mapper_service) -> str:
@@ -660,7 +701,8 @@ class AggEngine:
                  cost_router=False):
         from elasticsearch_tpu.search.caches import LruCache
         self.mapper_service = mapper_service
-        self.store = aggs_ops.AggFieldStore(warmup=warmup)
+        self.store = aggs_ops.AggFieldStore(warmup=warmup,
+                                            scale_of=self._scale_of)
         self.plan_cache = LruCache(max_entries=plan_cache_entries)
         # bool (own fresh router) or a CostRouter INSTANCE — the node
         # passes one shared, disk-backed router so every index's engine
@@ -676,6 +718,18 @@ class AggEngine:
             "mesh_dispatches": 0, "router_host_routed": 0,
             "router_probes": 0, "fallback_reasons": {},
         }
+
+    def _scale_of(self, field: str) -> Optional[int]:
+        """A `scaled_float` field's whole factor, which puts its column
+        on its lattice (`ops/aggs.AggColumn.k_scale`); else None."""
+        mapper = self.mapper_service.get(field)
+        if getattr(mapper, "type_name", None) != "scaled_float":
+            return None
+        try:
+            factor = float(mapper.params.get("scaling_factor", 100))
+        except (TypeError, ValueError):
+            return None
+        return int(factor) if factor.is_integer() and factor > 1 else None
 
     # ---------------------------------------------------------------- plan
     def plan_for(self, aggs_spec: dict) -> AggPlan:
@@ -945,19 +999,23 @@ class AggEngine:
         return mesh
 
     @staticmethod
-    def _check_metric_col(kind: str, col) -> None:
-        if kind in SUM_KINDS and not col.integral_exact:
+    def _check_metric_col(kind: str, col, lattice_ok: bool = True) -> None:
+        # a column on its lattice sums exactly in the 32-bit program
+        # (`_launch_metric` falls back where that cannot run); a caller
+        # with no such program passes `lattice_ok` False
+        if kind in SUM_KINDS and not col.integral_exact \
+                and not (lattice_ok and col.k_scale != 1):
             raise _Fallback("non_integral_sum")
         if kind == "value_count" and col.multi_valued:
             # value_count counts every VALUE (all_values) while the f64
             # column keeps only a doc's first — host business
             raise _Fallback("multi_valued_field")
 
-    def _metric_cols(self, ctx, node, snap):
+    def _metric_cols(self, ctx, node, snap, lattice_ok=True):
         cols = {}
         for m in node.subs:
             col = self.store.column(ctx.reader, m.field, snap=snap)
-            self._check_metric_col(m.kind, col)
+            self._check_metric_col(m.kind, col, lattice_ok)
             cols[m.name] = (m, col)
         return cols
 
@@ -972,20 +1030,23 @@ class AggEngine:
             raise _Fallback("bad_missing_value")
 
     # ------------------------------------------------------------ levels --
-    def _bind_level(self, ctx, node, body, snap, mesh, single=False):
+    def _bind_level(self, ctx, node, body, snap, mesh, single=False,
+                    span=None):
         """One bucket level of a node's chain: its rung `k`, what the
         x64 program takes for it (`x64`, fetched only if that program
         runs: the column's f64 pair is uploaded there) and, where the
         column's 32-bit form gives the host's own ids, what the 32-bit
         program takes (`n32`: kind, arguments, the lane its absent keys
         fold into). `single`: the node has no level under it, and a
-        terms' absent keys stay in the board's last lane."""
+        terms' absent keys stay in the board's last lane. `span`: (least,
+        greatest) keys that a fixed interval's buckets start and end at,
+        in the column's place (the matched rows', for an auto rounding)."""
         reader = ctx.reader
         if node.kind == "terms":
             return dict(self._ords_level(ctx, node.field, body, snap, mesh,
                                          single), meta=None, body=body)
         col = self.store.column(reader, node.field, snap=snap)
-        hparams, meta = self._hist_params(node, body, col)
+        hparams, meta = self._hist_params(node, body, col, span)
         k = meta["n_buckets"]
         lvl = {"kind": "hist", "k": k, "col": col, "miss": False,
                "meta": meta, "body": body, "n32": None}
@@ -1040,9 +1101,19 @@ class AggEngine:
         v >= bound <=> k32 >= that. None where the 32-bit form cannot
         give the host's ids: a column without `k32`, date_nanos'
         division, an interval or offset that is no integer (the host's
-        f64 floor may round at a boundary), magnitudes past 2^52."""
+        f64 floor may round at a boundary), magnitudes past 2^52. A
+        column on its lattice takes its bounds from `_lattice_bounds`."""
         k = meta["n_buckets"]
         offset = meta["offset"]
+        if col.k32 is not None and col.k_scale != 1:
+            longs = AggEngine._lattice_bounds(col, meta)
+            if longs is None:
+                return None
+            table = np.full(k + 1, aggs_ops.I32_MAX, dtype=np.int32)
+            table[0] = -1
+            table[1:] = np.clip(-((col.k_base - longs) // col.k_unit), 0,
+                                col.k_max + 1)
+            return table
         if col.k32 is None or meta["div"] != 1.0 \
                 or not float(offset).is_integer() \
                 or max(abs(col.vmin - offset),
@@ -1063,6 +1134,36 @@ class AggEngine:
         table[0] = -1
         table[1:1 + len(lo)] = np.clip(lo, 0, col.k_max + 1)
         return table
+
+    @staticmethod
+    def _lattice_bounds(col, meta) -> Optional[np.ndarray]:
+        """A histogram level's lower bounds over a column on its lattice,
+        as stored longs: L_j, the least long that the host's own key
+        math, floor((L / k_scale - offset) / interval), puts in bucket
+        base + j or above. That math is monotone in L, so a rounded guess
+        is corrected by steps of one; None where it does not settle (or
+        for a calendar, a division, magnitudes past 2^52)."""
+        if meta.get("cal_bounds") is not None or meta["div"] != 1.0:
+            return None
+        interval, offset = meta["interval"], meta["offset"]
+        base, k, scale = meta["base"], meta["n_buckets"], col.k_scale
+        if (max(abs(col.vmin - offset), abs(col.vmax - offset))
+                + interval) * scale >= _TWO52 \
+                or (abs(base) + k) * interval * scale >= _TWO52:
+            return None
+        want = base + np.arange(k, dtype=np.float64)
+        longs = np.ceil((want * interval + offset) * scale).astype(np.int64)
+
+        def ids(x):
+            return np.floor((x / scale - offset) / interval)
+
+        for _ in range(4):
+            down = ids(longs - 1) >= want
+            up = ids(longs) < want
+            if not (down.any() or up.any()):
+                return longs
+            longs = longs - down + up
+        return None
 
     def _zero_level(self, snap, mesh):
         """The one-lane level of a whole-match metric under the x64
@@ -1138,6 +1239,8 @@ class AggEngine:
                 n_limbs=n_limbs, form=form)
             return _Pending((out,), lambda got: _widen_metric(
                 got[0], parts, bits, n_limbs, mcol, ks, folds))
+        if kind in SUM_KINDS and not mcol.integral_exact:
+            raise _Fallback("non_integral_sum")
         levels, ks, flat = self._chain_x64(
             chain or [self._zero_level(snap, mesh)])
         out = self._launch(
@@ -1172,7 +1275,8 @@ class AggEngine:
             bounds, frm_to = self._range_bounds(body)
             boards["frm_to"] = frm_to
             rparams = self._mparams(body)
-            mcols = self._metric_cols(ctx, node, snap)
+            # a range's metric boards are x64 alone
+            mcols = self._metric_cols(ctx, node, snap, lattice_ok=False)
             keys_d = col.device("vals", mesh)
             kp_d = col.device("present", mesh)
             tail = (bounds, rparams)
@@ -1193,6 +1297,10 @@ class AggEngine:
                 counts=self._read(counts),
                 metrics={n: tuple(self._read(x) for x in b)
                          for n, b in mboards.items()}, col=col)
+
+        elif node.mode == "auto_date_histogram":
+            boards.update(self._run_auto_date(ctx, node, body, mask_box,
+                                              mask_io, mesh))
 
         elif node.mode == "metric":
             col = store.column(reader, node.field, snap=snap)
@@ -1231,6 +1339,44 @@ class AggEngine:
                                    boards.get("metric", (np.zeros(1),))[0]))
             self._record_mesh_leg(mesh, 1 + 4 * len(node.subs), b_len)
         return boards, mesh is not None
+
+    def _run_auto_date(self, ctx, node, body, mask_box, mask_io, mesh):
+        """`auto_date_histogram` (`search/auto_date.py`) in two programs:
+        the matched stamps' count and extrema; the host chooses the
+        rounding from them (`aggs.auto_round`, a counter
+        `aggs.auto_date.rounding.<interval>` a node); then the counts at
+        that rounding, its buckets from the least matched key's on."""
+        snap = mask_box["snap"]
+        col = self.store.column(ctx.reader, node.field, snap=snap)
+        try:
+            target = int(body.get("buckets", 10))
+        except (TypeError, ValueError):
+            raise _Fallback("bad_buckets")
+        if target < 1:
+            raise _Fallback("bad_buckets")
+        cnt, _sum, mn, mx = self._read_pending(self._launch_metric(
+            mask_box, mask_io, [], "extrema", col, {}, mesh))
+        if int(cnt[0]) == 0:
+            return {"auto": None, "col": col}
+        with telemetry.stage("aggs.auto_round"):
+            i, m, _n = AD.choose(mn[0], mx[0], target)
+            _counter("aggs.auto_date.rounding." + AD.interval(i, m)).inc()
+        fixed = AD.FIXED_MS[i]
+        level = ({"fixed_interval": f"1{AD.unit(i)}"} if fixed else
+                 {"calendar_interval": "month" if AD.unit(i) == "M"
+                  else "year"})
+        lnode = _Node(node.name, "date_histogram", kind="date_histogram",
+                      field=node.field)
+        lvl = self._bind_level(ctx, lnode, dict(level, field=node.field),
+                               snap, mesh, single=True,
+                               span=(mn[0], mx[0]))
+        if lvl["kind"] == "empty":
+            return {"auto": None, "col": col}
+        counts = self._read_pending(self._launch_counts(
+            mask_box, mask_io, [lvl], mesh))
+        return {"auto": (i, m, int(AD.round_down(mn[0], i)),
+                         int(AD.round_down(mx[0], i)), lvl["meta"]),
+                "counts": counts, "col": col}
 
     # ------------------------------------------------- composite trees --
     def _run_tree_node(self, ctx, node, spec, rows, mask_box, partial):
@@ -1378,8 +1524,9 @@ class AggEngine:
         self._cal_cache.put(key, entry)
         return entry
 
-    def _hist_params(self, node, body, col):
+    def _hist_params(self, node, body, col, span=None):
         date = node.mode == "date_histogram"
+        fmt = body.get("format")
         if date:
             interval, calendar = A._date_interval(body)
             offset = A._date_offset_ms(body.get("offset"))
@@ -1387,8 +1534,8 @@ class AggEngine:
             div = 1e6 if getattr(mapper, "type_name", None) == "date_nanos" \
                 else 1.0
             missing = None
+            fmt = fmt or A.field_date_format(mapper)
             if calendar:
-                fmt = body.get("format")
                 if col.vmin is None:
                     meta = {"interval": 0.0, "offset": offset, "base": 0.0,
                             "date": True, "n_buckets": 0, "fmt": fmt,
@@ -1424,7 +1571,7 @@ class AggEngine:
             missing = body.get("missing")
         if not (interval > 0) or not math.isfinite(interval):
             raise _Fallback("bad_interval")
-        vmin, vmax = col.vmin, col.vmax
+        vmin, vmax = span if span is not None else (col.vmin, col.vmax)
         if div != 1.0:
             vmin = None if vmin is None else vmin / div
             vmax = None if vmax is None else vmax / div
@@ -1461,8 +1608,7 @@ class AggEngine:
             if kflag and n_buckets else None
         meta = {"interval": interval, "offset": offset, "base": base,
                 "date": date, "n_buckets": n_buckets, "div": div,
-                "miss_lane": miss_lane,
-                "fmt": body.get("format"),
+                "miss_lane": miss_lane, "fmt": fmt,
                 "tz": A._resolve_tz(body.get("time_zone")) if date
                 else None}
         return hparams, meta
@@ -1508,6 +1654,8 @@ class AggEngine:
         if node.mode in ("histogram", "date_histogram"):
             return self._assemble_histo(ctx, node, body, boards,
                                         sub_kinds, sub_bodies, partial)
+        if node.mode == "auto_date_histogram":
+            return self._assemble_auto_date(ctx, node, body, boards, partial)
         if node.mode == "range":
             return self._assemble_range(ctx, node, body, boards,
                                         sub_kinds, sub_bodies, partial)
@@ -1766,8 +1914,7 @@ class AggEngine:
                 continue
             b = {"key": int(key) if date else key, "doc_count": c}
             if date:
-                b["key_as_string"] = A._format_date_key(int(key), fmt, tz) \
-                    if fmt else A._millis_to_iso_tz(int(key), tz)
+                b["key_as_string"] = A.date_key_string(int(key), fmt, tz)
             if metrics:
                 if lane is not None:
                     self._sub_outputs(b, lane, metrics, sub_kinds,
@@ -1777,11 +1924,44 @@ class AggEngine:
                                             sub_bodies, partial)
             buckets.append(b)
         out = {"buckets": buckets}
+        if partial and date and fmt:
+            out["$fmt"] = fmt           # the keys the coordinator makes
         if not date:
             f = body.get("format")
             if f:
                 for b in out["buckets"]:
                     b["key_as_string"] = A._decimal_format(b["key"], f)
+        return out
+
+    def _assemble_auto_date(self, ctx, node, body, boards, partial):
+        """The rounding's buckets from the least matched key's to the
+        greatest's, merged m at a time from the first; a shard's partial
+        is the rounding's non-empty buckets, unmerged
+        (`search/auto_date.py`)."""
+        fmt = body.get("format") or A.field_date_format(
+            self.mapper_service.get(node.field))
+        if boards["auto"] is None:
+            return {"buckets": [], "interval": AD.interval(0, 1)}
+        i, m, first, last, meta = boards["auto"]
+        if partial:
+            m = 1
+        keys = AD.keys_between(first, last, i)
+        cal = meta.get("cal_bounds")
+        lane0 = 0 if cal is None else int(np.searchsorted(
+            np.asarray(cal, dtype=np.float64), first))
+        per = boards["counts"][lane0:lane0 + len(keys)]
+        buckets = []
+        for lo, hi in AD.groups(len(keys), m):
+            c = int(per[lo:hi].sum())
+            if partial and not c:
+                continue
+            key = int(keys[lo])
+            buckets.append({"key_as_string": A.date_key_string(key, fmt),
+                            "key": key, "doc_count": c})
+        A._check_max_buckets(ctx, len(buckets))
+        out = {"buckets": buckets, "interval": AD.interval(i, m)}
+        if partial and fmt:
+            out["$fmt"] = fmt
         return out
 
     # -------------------------------------------------------------- range
@@ -2095,13 +2275,13 @@ class AggEngine:
                 continue
             b = {"key": int(key) if date else key, "doc_count": c}
             if date:
-                b["key_as_string"] = A._format_date_key(int(key), fmt,
-                                                        tz) \
-                    if fmt else A._millis_to_iso_tz(int(key), tz)
+                b["key_as_string"] = A.date_key_string(int(key), fmt, tz)
             P_i = [p * k + lane for p in P] if lane is not None else []
             bucket_fill(b, P_i)
             buckets.append(b)
         out = {"buckets": buckets}
+        if partial and date and fmt:
+            out["$fmt"] = fmt           # the keys the coordinator makes
         if not date:
             f = body.get("format")
             if f:

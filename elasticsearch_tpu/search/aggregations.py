@@ -23,6 +23,7 @@ from elasticsearch_tpu.common.errors import (
     ArrayIndexOutOfBoundsError, IllegalArgumentError, ParsingError,
 )
 from elasticsearch_tpu.index.mapping import parse_date_millis
+from elasticsearch_tpu.search import auto_date as AD
 from elasticsearch_tpu.search.queries import SearchContext, parse_query
 
 # ---------------------------------------------------------------------------
@@ -1264,28 +1265,13 @@ def _compute_bucket(ctx: SearchContext, rows: np.ndarray, kind: str,
                 + offset_ms
         return _histo_buckets(ctx, rows, sub_aggs, keys, present, min_count,
                               None, interval_ms, date=True, recurse=recurse,
-                              fmt=spec.get("format"), tz=tz,
+                              fmt=spec.get("format")
+                              or field_date_format(mapper), tz=tz,
                               calendar=(calendar, offset_ms) if calendar
                               else None)
 
     if kind == "auto_date_histogram":
-        target = int(spec.get("buckets", 10))
-        vals, present = numeric_values(ctx, rows, field)
-        v = vals[present]
-        if len(v) == 0:
-            return {"buckets": [], "interval": "1ms"}
-        span = max(v.max() - v.min(), 1.0)
-        interval_ms = max(span / target, 1.0)
-        # snap to a sane unit
-        for unit in (1, 1000, 60_000, 3_600_000, 86_400_000, 2_592_000_000, 31_536_000_000):
-            if interval_ms <= unit:
-                interval_ms = unit
-                break
-        keys = np.floor(vals / interval_ms) * interval_ms
-        out = _histo_buckets(ctx, rows, sub_aggs, keys, present, 0, None,
-                             interval_ms, date=True, recurse=recurse)
-        out["interval"] = f"{int(interval_ms)}ms"
-        return out
+        return _auto_date_buckets(ctx, rows, spec, field, sub_aggs, recurse)
 
     if kind in ("range", "date_range", "ip_range"):
         ranges = spec.get("ranges", [])
@@ -1798,8 +1784,7 @@ def _range_field_histo(ctx, rows, sub_aggs, spec, field, recurse=None) -> dict:
     for key in sorted(groups):
         brows = np.asarray(sorted(set(groups[key])), dtype=np.int64)
         b = {"key": int(key), "doc_count": int(len(brows)),
-             "key_as_string": _format_date_key(int(key), fmt, tz) if fmt
-             else _millis_to_iso_tz(int(key), tz)}
+             "key_as_string": date_key_string(int(key), fmt, tz)}
         if sub_aggs:
             b.update(recurse(ctx, brows, sub_aggs))
         buckets.append(b)
@@ -1901,8 +1886,7 @@ def _histo_buckets(ctx, rows, sub_aggs, keys, present, min_count,
             continue
         b = {"key": int(key) if date else key, "doc_count": int(len(brows))}
         if date:
-            b["key_as_string"] = _format_date_key(int(key), fmt, tz) if fmt \
-                else _millis_to_iso_tz(int(key), tz)
+            b["key_as_string"] = date_key_string(int(key), fmt, tz)
         if sub_aggs:
             b.update(recurse(ctx, brows, sub_aggs))
         buckets.append(b)
@@ -1996,6 +1980,68 @@ def _format_date_key(millis: int, fmt: str, tz=None) -> str:
     if "SSS" in out:
         out = out.replace("SSS", f"{d.microsecond // 1000:03d}")
     return out
+
+
+def field_date_format(mapper) -> Optional[str]:
+    """The pattern a date field's bucket keys print in where the
+    aggregation names no `format`: the first of the mapping's formats
+    where it is a pattern of its own (`yyyy-MM-dd HH:mm:ss`), as the
+    field's DocValueFormat prints it. None for a named format
+    (`strict_date_optional_time`, `epoch_second`, ...) and the default:
+    those keys print in ISO 8601 here."""
+    if getattr(mapper, "type_name", None) not in ("date", "date_nanos"):
+        return None
+    first = str(mapper.params.get("format", "")).split("||")[0].strip()
+    return first if re.search(r"yy|MM|dd|HH", first) else None
+
+
+def date_key_string(millis: int, fmt: Optional[str], tz=None) -> str:
+    """A date bucket's `key_as_string`: in `fmt` where one is given (the
+    aggregation's, else the field's own pattern), else ISO 8601."""
+    return _format_date_key(millis, fmt, tz) if fmt \
+        else _millis_to_iso_tz(millis, tz)
+
+
+def _auto_date_buckets(ctx, rows, spec, field, sub_aggs, recurse) -> dict:
+    """`auto_date_histogram` by the statement in `search/auto_date.py`.
+    A shard's spec (`auto_date.SHARD_KEY`, set by the partial walker)
+    answers at its own rounding, unmerged and with no empty bucket."""
+    recurse = recurse or compute_aggs
+    target = _parse_int_param(spec, "buckets", 10, "auto_date_histogram")
+    if target < 1:
+        raise IllegalArgumentError(
+            "[buckets] must be greater than 0 in [auto_date_histogram]")
+    mapper = ctx.mapper_service.get(field) if field else None
+    vals, present = numeric_values(ctx, rows, field)
+    if getattr(mapper, "type_name", None) == "date_nanos":
+        vals = vals / 1e6
+    if not present.any():
+        return {"buckets": [], "interval": AD.interval(0, 1)}
+    shard = bool(spec.get(AD.SHARD_KEY))
+    v = vals[present]
+    i, m, _n = AD.choose(v.min(), v.max(), target)
+    if shard:
+        m = 1
+    rounded = AD.round_down(v, i)
+    keys = AD.keys_between(rounded.min(), rounded.max(), i)
+    group = np.searchsorted(keys, rounded) // m
+    order = np.argsort(group, kind="stable")
+    prow = rows[present][order]
+    cut = np.searchsorted(group[order], np.arange(len(keys) // m + 2))
+    fmt = spec.get("format") or field_date_format(mapper)
+    buckets = []
+    for g, (lo, _hi) in enumerate(AD.groups(len(keys), m)):
+        brows = prow[cut[g]:cut[g + 1]]
+        if shard and not len(brows):
+            continue
+        key = int(keys[lo])
+        b = {"key_as_string": date_key_string(key, fmt), "key": key,
+             "doc_count": int(len(brows))}
+        if sub_aggs:
+            b.update(recurse(ctx, brows, sub_aggs))
+        buckets.append(b)
+    _check_max_buckets(ctx, len(buckets))
+    return {"buckets": buckets, "interval": AD.interval(i, m)}
 
 
 def _date_offset_ms(offset) -> float:

@@ -328,7 +328,8 @@ def _iter_range_clauses(query: Optional[dict]):
             yield from _iter_range_clauses(spec.get("filter"))
 
 
-def _to_number(value, mapper_service, field, round_up: bool = False) -> Optional[float]:
+def _to_number(value, mapper_service, field, round_up: bool = False,
+               fmt=None) -> Optional[float]:
     if isinstance(value, bool):
         return None
     if isinstance(value, (int, float)):
@@ -338,10 +339,14 @@ def _to_number(value, mapper_service, field, round_up: bool = False) -> Optional
         type_name = getattr(mapper, "type_name", None)
         if type_name == "date":
             try:
-                from elasticsearch_tpu.index.mapping import parse_date_millis
+                from elasticsearch_tpu.index.mapping import (
+                    parse_date_in_format, parse_date_millis)
                 # mirror RangeQuery's rounding (queries.py _coerce_bound):
                 # upper bounds round UP to unit end so can_match never
                 # skips a shard the real query would hit
+                millis = parse_date_in_format(value, fmt, round_up)
+                if millis is not None:
+                    return float(millis)
                 return float(parse_date_millis(value, round_up=round_up))
             except Exception:
                 return None
@@ -367,9 +372,10 @@ def can_match(reader, mapper_service, body: dict) -> bool:
             continue
         fmin, fmax = stats
         gte = _to_number(bounds.get("gte", bounds.get("gt")), mapper_service, field,
-                         round_up="gte" not in bounds and "gt" in bounds)
+                         round_up="gte" not in bounds and "gt" in bounds,
+                         fmt=bounds.get("format"))
         lte = _to_number(bounds.get("lte", bounds.get("lt")), mapper_service, field,
-                         round_up="lte" in bounds)
+                         round_up="lte" in bounds, fmt=bounds.get("format"))
         if gte is not None:
             if "gt" in bounds and "gte" not in bounds:
                 if fmax <= gte:

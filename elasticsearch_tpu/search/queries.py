@@ -426,11 +426,20 @@ class RangeQuery(Query):
         self.field = field
         self.gte, self.gt, self.lte, self.lt = gte, gt, lte, lt
         self.boost = boost
+        self.fmt = fmt
         self.relation = relation
 
     def _coerce_bound(self, ctx, value, round_up: bool = False):
-        from elasticsearch_tpu.index.mapping import parse_date_nanos
+        from elasticsearch_tpu.index.mapping import (parse_date_in_format,
+                                                     parse_date_nanos)
         mapper = ctx.mapper_service.get(self.field)
+        if isinstance(mapper, DateFieldMapper) and self.fmt:
+            # the query's own `format` reads its bounds
+            millis = parse_date_in_format(value, self.fmt, round_up)
+            if millis is not None:
+                return float(millis * 1_000_000
+                             if isinstance(mapper, DateNanosFieldMapper)
+                             else millis)
         if isinstance(mapper, DateNanosFieldMapper):
             if isinstance(value, str) and ("||" in value
                                            or value.startswith("now")
@@ -1476,6 +1485,7 @@ def parse_query(body: Optional[dict]) -> Query:
         return RangeQuery(field, gte=v.get("gte", v.get("from")), gt=v.get("gt"),
                           lte=v.get("lte", v.get("to")), lt=v.get("lt"),
                           boost=float(v.get("boost", 1.0)),
+                          fmt=v.get("format"),
                           relation=v.get("relation", "intersects").lower())
     if kind == "exists":
         return ExistsQuery(spec["field"])

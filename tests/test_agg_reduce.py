@@ -382,3 +382,128 @@ def test_scripted_metric_params_and_missing_map_script(ctx):
     with _pytest.raises(IllegalArgumentError, match="map_script"):
         compute_aggs(ctx, ctx.all_rows(),
                      {"m": {"scripted_metric": {"combine_script": "return 1"}}})
+
+
+# ---------------------------------------------------------------------------
+# auto_date_histogram as Elasticsearch 7.x answers it (`search/auto_date.py`)
+# against the plain reference of `nyc-taxis-dash`, and date keys in the
+# field's own format
+# ---------------------------------------------------------------------------
+
+def _taxi_reference():
+    import json
+    import os
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, repo)
+    from benchmark.kinds import taxi_aggs_reference
+    with open(os.path.join(repo, "benchmark", "configs",
+                           "nyc-taxis-dash.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(repo, "benchmark", "traffic",
+                           "taxi-aggs-steady.json")) as f:
+        mix = json.load(f)
+    # four weeks of rides, so dense that every day and hour holds some
+    config["data"]["rides"]["span_days"] = 28
+    return taxi_aggs_reference, config, mix
+
+
+@pytest.fixture(scope="module")
+def rides(tmp_path_factory):
+    reference, config, mix = _taxi_reference()
+    n = 4096
+    corpus = reference.RideCorpus(2 ** 31 + 4310, config, n, mix)
+    rows = corpus.rows([(b, 2048) for b in range(n // 2048)])
+    e = Engine(str(tmp_path_factory.mktemp("rides") / "shard"),
+               MapperService({"properties": {"dropoff_datetime": {
+                   "type": "date", "format": "yyyy-MM-dd HH:mm:ss"}}}))
+    for i, ms in enumerate(rows.dropoff.tolist()):
+        e.index(str(i), {"dropoff_datetime": reference.stamp(ms)})
+    e.refresh()
+    yield (reference, rows, corpus.t0_ms,
+           SearchContext(e.acquire_searcher(), e.mapper_service))
+    e.close()
+
+
+@pytest.mark.parametrize("width,interval", [(1, "3h"), (7, "12h"),
+                                            (21, "7d")])
+def test_auto_date_histogram_windows_against_the_reference(rides, width,
+                                                           interval):
+    """The walker and its partial reduce over three skewed shards both
+    answer a 1-, 7- and 21-day window as the reference does: the
+    rounding table, the merge from the first key, `interval` as "7d",
+    keys in the field's `yyyy-MM-dd HH:mm:ss`."""
+    reference, rows, t0, ctx = rides
+    start = t0 + 3 * reference.DAY_MS
+    want = rows.answer("autohisto_agg", start, width)["aggregations"]
+    assert want["dropoffs_over_time"]["interval"] == interval
+    matched = np.sort(ctx.all_rows()[rows.matching("autohisto_agg", start,
+                                                   width)])
+    spec = {"dropoffs_over_time": {"auto_date_histogram": {
+        "field": "dropoff_datetime", "buckets": 20}}}
+    assert compute_aggs(ctx, matched, spec) == want
+    n = len(matched)
+    # shards split in time: each alone chooses a finer rounding
+    splits = [matched[:n // 6], matched[n // 6:n // 2], matched[n // 2:]]
+    assert _reduce(ctx, splits, spec) == want
+
+
+def test_auto_date_histogram_reduce_of_empty_and_coarser_shards(rides):
+    reference, rows, t0, ctx = rides
+    start = t0 + 2 * reference.DAY_MS
+    matched = np.sort(ctx.all_rows()[rows.matching("autohisto_agg", start,
+                                                   7)])
+    spec = {"a": {"auto_date_histogram": {"field": "dropoff_datetime",
+                                          "buckets": 20}}}
+    empty = compute_partial_aggs(ctx, matched[:0], spec)
+    assert empty["a"]["buckets"] == [] and empty["a"]["interval"] == "1s"
+    assert finalize_aggs(empty, spec) == {"a": {"buckets": [],
+                                                "interval": "1s"}}
+    # a shard of one hour (minutes) merged with one of a week (hours)
+    one_hour = matched[:8]
+    parts = [compute_partial_aggs(ctx, rows_, spec)
+             for rows_ in (one_hour, matched[8:], matched[:0])]
+    assert parts[0]["a"]["interval"] in ("1s", "1m")
+    assert parts[1]["a"]["interval"] == "1h"
+    merged = merge_partial_aggs(merge_partial_aggs(parts[0], parts[1], spec),
+                                parts[2], spec)
+    assert merged["a"]["interval"] == "1h"
+    assert finalize_aggs(merged, spec) == compute_aggs(ctx, matched, spec)
+
+
+def test_date_keys_print_in_the_fields_own_format(rides):
+    from elasticsearch_tpu.search.aggregations import field_date_format
+    reference, rows, t0, ctx = rides
+    mapper = ctx.mapper_service.get("dropoff_datetime")
+    assert field_date_format(mapper) == "yyyy-MM-dd HH:mm:ss"
+    # a named first format keeps ISO 8601 (`http-logs-dash`'s keys)
+    named = MapperService({"properties": {"t": {
+        "type": "date", "format": "strict_date_optional_time||epoch_second"},
+        "u": {"type": "date"}}})
+    assert field_date_format(named.get("t")) is None
+    assert field_date_format(named.get("u")) is None
+    start = t0 + 5 * reference.DAY_MS
+    matched = np.sort(ctx.all_rows()[rows.matching("date_histogram_agg",
+                                                   start, 7)])
+    spec = {"d": {"date_histogram": {"field": "dropoff_datetime",
+                                     "calendar_interval": "day"}}}
+    want = {"d": rows.answer("date_histogram_agg", start, 7)[
+        "aggregations"]["dropoffs_over_time"]}
+    assert compute_aggs(ctx, matched, spec) == want
+    # the shards hold days 1-2 and 5-7: the coordinator makes days 3 and 4
+    window = rows.matching("date_histogram_agg", start, 7)
+    day = (rows.dropoff[window] - start) // reference.DAY_MS
+    gap = np.sort(ctx.all_rows()[window[(day < 2) | (day > 3)]])
+    got = _reduce(ctx, [gap[:len(gap) // 3], gap[len(gap) // 3:]], spec)
+    filled = [b for b in got["d"]["buckets"] if b["doc_count"] == 0]
+    assert [b["key"] for b in filled] == [start + 2 * reference.DAY_MS,
+                                          start + 3 * reference.DAY_MS]
+    assert [b["key_as_string"] for b in filled] == [
+        reference.stamp(start + 2 * reference.DAY_MS),
+        reference.stamp(start + 3 * reference.DAY_MS)]
+    # an aggregation's own format wins over the field's
+    own = {"d": {"date_histogram": {"field": "dropoff_datetime",
+                                    "calendar_interval": "day",
+                                    "format": "dd/MM/yyyy"}}}
+    first = compute_aggs(ctx, matched, own)["d"]["buckets"][0]
+    assert first["key_as_string"] == reference.ddmmyyyy(start)
