@@ -341,6 +341,24 @@ N32_MAX_ROWS = 1 << 30
 ONEHOT_TILE = 2048
 ONEHOT_LANES_A_COLUMN = 8192
 
+# Lanes of a one-hot board up to which its min and max are reduced
+# tile by tile inside the product, past which they scatter: for one
+# level of bounds, and for ordinals or a chain of levels. Read in
+# `extrema_form` alone. The extrema alone on the chip at 2^18 rows
+# (`tools/extrema_timing.py`; PERF.md section 6), tiled against
+# scattered, ms: one level of bounds 0.69 / 5.6 at 513 lanes, 2.9 / 7.2
+# at 2,049, 12.3 / 17.9 at 8,193, 24.0 / 32.1 at 16,385, 142.8 / 116.9 at
+# 65,537 (its scatter first counts the bounds for the rows' lanes, which
+# grows with them too); ordinals 0.44 / 4.8 at 513, 1.4 / 3.7 at 2,049,
+# 2.9 / 3.6 at 4,097, 5.5 / 3.6 at 8,193 (their lane is free, so the
+# scatter stays flat); a chain of ordinals 2.2 / 3.7 at 4,096, 4.3 / 3.7
+# at 8,192, one of bounds and ordinals 4.6 / 4.0 at 8,192. Each limit is
+# the widest width read at which the tiled route won; 2^19 rows read
+# twice as long on both routes, so the row bucket drops out.
+EXTREMA_TILED_BOUNDS = 16385
+EXTREMA_TILED_LANES = 4097
+EXTREMA_FORMS = ("reduced", "tiled", "scattered")
+
 N32_KERNELS = frozenset({"aggs.n32_counts", "aggs.n32_metric"})
 
 
@@ -374,6 +392,28 @@ def board_form(lanes: int, kind: str, cols: int = 1) -> str:
     if kind == "ords" and lanes > ONEHOT_LANES_A_COLUMN * cols:
         return "scatter"
     return "onehot"
+
+
+def extrema_form(levels, lanes, form: str) -> str:
+    """How a 32-bit board's min and max are taken, from its shape alone
+    (`levels` the kinds, `lanes` each level's lanes): 'reduced' (one
+    lane: a masked reduction over every row), 'tiled' (a masked
+    reduction over each tile's rows inside the one-hot product, the
+    tile's lane one-hot [T, total] beside the product's) or 'scattered'
+    (int32 `.at[].min/max` into the rows' lanes). The tile's reduction
+    costs rows x lanes. A scatter serialises its updates, so it costs
+    the same at one lane as at 513, ONCE IT HAS THE LANES: ordinals are
+    theirs, a table has to be searched, which costs rows x bounds too.
+    So one level of bounds is reduced up to EXTREMA_TILED_BOUNDS lanes,
+    ordinals and chains up to EXTREMA_TILED_LANES."""
+    total = math.prod(lanes)
+    if total == 1:
+        return "reduced"
+    top = EXTREMA_TILED_BOUNDS if tuple(levels) == ("bounds",) \
+        else EXTREMA_TILED_LANES
+    if form == "onehot" and total <= top:
+        return "tiled"
+    return "scattered"
 
 
 def _split_n32_levels(levels, level_args):
@@ -422,14 +462,17 @@ def _n32_scatter(lane, total: int, cols):
                       for c in cols])
 
 
-def _n32_onehot(lvls, lanes, cols, tile: int):
+def _n32_onehot(lvls, lanes, cols, tile: int, v=None, extrema=()):
     """[len(cols), prod(lanes)] int32 by products: the widest level m is
     the one-hot side A [T, lanes_m] (for a table the THERMOMETER
     key >= lo, whose board is the difference of neighbouring lanes: one
     comparison an element, not two), the other side W [C, T] is the
     one-hot of the other levels' composite lane times the columns. 0/1
     and limbs of at most 8 bits are exact in bf16, a tile's sums exact in
-    f32 (under 2^24), tiles add in int32."""
+    f32 (under 2^24), tiles add in int32. With `extrema` ('min', 'max')
+    and the value `v`, also each lane's min / max of `v` over the rows
+    `cols[0]` marks, reduced over a tile's rows and carried from tile to
+    tile ('tiled' in `extrema_form`): returns (board, {part: [total]})."""
     import jax
     import jax.numpy as jnp
     m = max(range(len(lanes)), key=lambda j: lanes[j]) if lanes else None
@@ -444,8 +487,11 @@ def _n32_onehot(lvls, lanes, cols, tile: int):
     xs = {"cols": jnp.stack(cols).reshape(len(cols), n_tiles, t)
           .swapaxes(0, 1),
           "rows": [lv[1].reshape(n_tiles, t) for lv in lvls]}
+    if extrema:
+        xs["v"] = v.reshape(n_tiles, t)
 
     def body(acc, x):
+        acc = dict(acc)
         w = x["cols"].astype(jnp.bfloat16)                    # [c, T]
         if n_minor > 1:
             mlane = jnp.zeros(t, jnp.int32)
@@ -456,21 +502,50 @@ def _n32_onehot(lvls, lanes, cols, tile: int):
             oh = (jnp.arange(n_minor, dtype=jnp.int32)[:, None]
                   == mlane[None, :]).astype(jnp.bfloat16)     # [minor, T]
             w = (oh[:, None, :] * w[None, :, :]).reshape(-1, t)
+        hot = None
         if m is None:
             a = jnp.ones((t, 1), jnp.bfloat16)
         elif lvls[m][0] == "ords":
-            a = ((x["rows"][m] + 1)[:, None] == jnp.arange(
-                n_major, dtype=jnp.int32)[None, :]).astype(jnp.bfloat16)
+            hot = (x["rows"][m] + 1)[:, None] == jnp.arange(
+                n_major, dtype=jnp.int32)[None, :]
+            a = hot.astype(jnp.bfloat16)
         else:
-            a = (x["rows"][m][:, None] >= lvls[m][2][None, :]).astype(
-                jnp.bfloat16)
+            hot = x["rows"][m][:, None] >= lvls[m][2][None, :]
+            a = hot.astype(jnp.bfloat16)
         part = jax.lax.dot_general(
             w, a, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # [C, major]
-        return acc + part.astype(jnp.int32), None
+        acc["g"] = acc["g"] + part.astype(jnp.int32)
+        if extrema:
+            if n_minor > 1:
+                lane = jnp.zeros(t, jnp.int32)
+                for (kind, _rows, table), n, rows in zip(lvls, lanes,
+                                                          x["rows"]):
+                    lane = lane * n + _n32_lane(kind, rows, table)
+                hot = lane[:, None] == jnp.arange(
+                    n_minor * n_major, dtype=jnp.int32)[None, :]
+            elif lvls[m][0] == "bounds":
+                # the thermometer's last lane at or under the key
+                hot = hot & ~jnp.concatenate(
+                    [hot[:, 1:], jnp.zeros((t, 1), bool)], axis=1)
+            # the tile's rows with a value, in their lane [T, lanes]
+            hot = hot & (x["cols"][0] != 0)[:, None]
+            vt = x["v"][:, None]
+            if "min" in extrema:
+                acc["min"] = jnp.minimum(acc["min"], jnp.min(
+                    jnp.where(hot, vt, I32_MAX), axis=0))
+            if "max" in extrema:
+                acc["max"] = jnp.maximum(acc["max"], jnp.max(
+                    jnp.where(hot, vt, -1), axis=0))
+        return acc, None
 
-    acc0 = jnp.zeros((n_minor * len(cols), n_major), jnp.int32)
-    g, _ = jax.lax.scan(body, acc0, xs)
+    acc0 = {"g": jnp.zeros((n_minor * len(cols), n_major), jnp.int32)}
+    if "min" in extrema:
+        acc0["min"] = jnp.full(n_minor * n_major, I32_MAX, jnp.int32)
+    if "max" in extrema:
+        acc0["max"] = jnp.full(n_minor * n_major, -1, jnp.int32)
+    acc, _ = jax.lax.scan(body, acc0, xs)
+    g = acc.pop("g")
     if m is not None and lvls[m][0] == "bounds":
         # thermometer: lane b holds the rows AT OR ABOVE bound b
         g = g - jnp.concatenate(
@@ -481,7 +556,7 @@ def _n32_onehot(lvls, lanes, cols, tile: int):
         if m is not None and j < m:
             pre *= lanes[j]
     g = g.reshape(pre, n_minor // pre, len(cols), n_major)
-    return g.transpose(2, 0, 3, 1).reshape(len(cols), -1)
+    return g.transpose(2, 0, 3, 1).reshape(len(cols), -1), acc
 
 
 def _n32_boards(mask, mk32, mmiss, level_args, levels, n_buckets, parts,
@@ -496,21 +571,30 @@ def _n32_boards(mask, mk32, mmiss, level_args, levels, n_buckets, parts,
     cols, v, ok = _n32_columns(mask, mk32, mmiss, bits,
                                n_limbs if want_sum else 0)
     extrema = [p for p in ("min", "max") if mk32 is not None and p in parts]
+    route = extrema_form(levels, lanes, form) if extrema else None
     lane = None
-    if form == "scatter" or extrema:
+    if form == "scatter" or route == "scattered":
         lane = jnp.zeros(mask.shape, jnp.int32)
         for (kind, rows, table), n in zip(lvls, lanes):
             lane = lane * n + _n32_lane(kind, rows, table)
+    tiled = {}
     if form == "scatter":
         board = _n32_scatter(lane, total, cols)
     else:
-        board = _n32_onehot(lvls, lanes, cols, ONEHOT_TILE)
+        board, tiled = _n32_onehot(
+            lvls, lanes, cols, ONEHOT_TILE, v,
+            tuple(extrema) if route == "tiled" else ())
     if mk32 is None:
         return board[0]
-    # an extremum is a scatter whatever the form: the product sums
+    # an empty lane's min is I32_MAX, its max -1, on every route
     out = [board]
     for p in extrema:
-        if p == "min":
+        if p in tiled:
+            out.append(tiled[p])
+        elif route == "reduced":
+            out.append((jnp.min(jnp.where(ok, v, I32_MAX)) if p == "min"
+                        else jnp.max(jnp.where(ok, v, -1))).reshape(1))
+        elif p == "min":
             out.append(jnp.full(total, I32_MAX, jnp.int32).at[lane].min(
                 jnp.where(ok, v, I32_MAX)))
         else:

@@ -1217,7 +1217,8 @@ class AggEngine:
         (count int64, sum f64, min f64, max f64). 32-bit where the chain
         and the field's column have that form and the `missing`
         substitute lies on the column's lattice; there only the boards
-        the metric's kind reads are computed."""
+        the metric's kind reads are computed, and `aggs.extrema.<route>`
+        counts a program with a min or max by `ops/aggs.extrema_form`."""
         snap = mask_box["snap"]
         narrow = mcol.k32 is not None \
             and all(lv["n32"] is not None for lv in chain)
@@ -1232,6 +1233,11 @@ class AggEngine:
                 if bits else 0
             levels, ks, form, flat, folds = self._chain_n32(
                 chain, 1 + n_limbs)
+            if "min" in parts or "max" in parts:
+                route = aggs_ops.extrema_form(
+                    levels, [k + 1 for k in ks], form)
+                for r in aggs_ops.EXTREMA_FORMS:
+                    _counter("aggs.extrema." + r).inc(int(r == route))
             out = self._launch(
                 mask_box, "aggs.n32_metric", mask_io,
                 mcol.device("k32", mesh), np.int32(mmiss), *flat, mesh=mesh,

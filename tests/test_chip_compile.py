@@ -289,6 +289,10 @@ def _dash_panel_programs(sh):
     bits = agg_ops.limb_bits(r)
     limbs = agg_ops.n_limbs_for(1 << 24, bits)
     assert (bits, limbs) == (8, 4)
+    r18 = 1 << 18
+    flag18 = _sds(sh, (r18,), jnp.bool_)
+    bits18 = agg_ops.limb_bits(r18)
+    limbs18 = agg_ops.n_limbs_for(1 << 24, bits18)
 
     def hist(k):
         return {"levels": ("hist",), "n_buckets": (k,)}
@@ -377,6 +381,20 @@ def _dash_panel_programs(sh):
         "n32.ordinals.counts-65536": (
             N32, agg_ops._agg_n32_counts, counts32, (flag, ords),
             ordinals(65536), (jnp.int32, (65537,))),
+        # `taxi-aggs-steady`'s extrema at its row bucket of 2^18: the
+        # distance `stats` over 512 buckets (tiled into the product) and
+        # `auto_date_histogram`'s whole-match min and max (reduced)
+        "n32.taxi.distance-stats-512": (
+            N32, agg_ops._agg_n32_metric, metric32,
+            (flag18, i32(r18), i32(), i32(r18), i32(513)),
+            dict(bounds(512, 1 + limbs18), parts=("sum", "min", "max"),
+                 limb_bits=bits18, n_limbs=limbs18),
+            (jnp.int32, (3 + limbs18, 513))),
+        "n32.taxi.autohisto-extrema": (
+            N32, agg_ops._agg_n32_metric, metric32, (flag18, i32(r18), i32()),
+            {"levels": (), "n_buckets": (), "form": "onehot",
+             "parts": ("min", "max"), "limb_bits": 0, "n_limbs": 0},
+            (jnp.int32, (3, 1))),
     }
 
 
@@ -389,13 +407,15 @@ def _dash_panel_programs(sh):
     "n32.by-hour.counts-2048", "n32.bytes-by-hour.sum-2048",
     "n32.bytes-by-hour.total.sum", "n32.status-in-range.counts-8",
     "n32.status-in-range.counts-16", "n32.status-by-hour.counts-2048x8",
-    "n32.stats-2048", "n32.ordinals.counts-65536"])
+    "n32.stats-2048", "n32.ordinals.counts-65536",
+    "n32.taxi.distance-stats-512", "n32.taxi.autohisto-extrema"])
 def test_dash_panel_program_compiles_at_the_cells_row_bucket(one_chip,
                                                               program):
     """The programs of the cell `dash-aggs-steady` at its row bucket
     (2^20) and rungs, for the described chip: the x64 scatter programs
     (int64 counts and f64 sums by `.at[].add` over a million rows) and,
-    beside them, the 32-bit ones, none of whose boards is 64 bits wide."""
+    beside them, the 32-bit ones, none of whose boards is 64 bits wide;
+    and `taxi-aggs-steady`'s two extrema programs, at its 2^18."""
     arithmetic, fn, static_names, args, statics, (dtype, shape) = \
         _dash_panel_programs(one_chip)[program]
     if arithmetic == X64:

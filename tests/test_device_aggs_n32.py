@@ -16,7 +16,8 @@ walker over their own corpora:
   * a column the 32-bit form cannot hold (fractions, a span past 2^31
     units) takes the x64 program and gives the same answer;
   * the mesh twins on the conftest's virtual devices;
-  * both ways to fill a board (`board_form`) agree with numpy.
+  * both ways to fill a board (`board_form`), and each route to a
+    board's min and max (`extrema_form`), agree with numpy.
 
 The four panels of `http-logs-dash` at 32,768 rows are in
 `test_dash_aggs_http_logs.py`, over that file's corpus.
@@ -305,11 +306,58 @@ def test_board_form_follows_the_shape():
     assert aggs_ops.board_form(65537, "ords", cols=4) == "scatter"
 
 
-@pytest.mark.parametrize("levels,ks", [
+def test_extrema_form_follows_the_shape():
+    form = aggs_ops.extrema_form
+    bounds, top = aggs_ops.EXTREMA_TILED_BOUNDS, aggs_ops.EXTREMA_TILED_LANES
+    # one lane: a reduction whatever the form
+    assert form((), (), "onehot") == "reduced"
+    assert form((), (), "scatter") == "reduced"
+    # the taxi cell's stats under 512 buckets, and up to the crossover
+    assert form(("bounds",), (513,), "onehot") == "tiled"
+    assert form(("bounds",), (bounds,), "onehot") == "tiled"
+    assert form(("bounds",), (bounds + 1,), "onehot") == "scattered"
+    # ordinals' lanes are free to a scatter, so they keep it sooner; a
+    # chain is held to the same limit on the product of its lanes
+    assert form(("ords",), (513,), "onehot") == "tiled"
+    assert form(("ords",), (top,), "onehot") == "tiled"
+    assert form(("ords",), (top + 1,), "onehot") == "scattered"
+    assert form(("ords",), (8193,), "onehot") == "scattered"
+    assert form(("bounds", "ords"), (512, 8), "onehot") == "tiled"
+    assert form(("bounds", "ords"), (1024, 8), "onehot") == "scattered"
+    assert form(("ords", "ords"), (1024, 8), "onehot") == "scattered"
+    # the parity test's "three-narrow" and "three-levels"
+    assert form(("ords", "bounds", "ords"), (5, 17, 9), "onehot") == "tiled"
+    assert form(("ords", "bounds", "ords"), (9, 33, 17),
+                "onehot") == "scattered"
+    # a board that is itself scattered
+    assert form(("bounds",), (513,), "scatter") == "scattered"
+    assert form(("ords",), (513,), "scatter") == "scattered"
+    assert 513 < top < bounds < 65537
+    assert set(aggs_ops.EXTREMA_FORMS) == {"reduced", "tiled", "scattered"}
+
+
+# a one-hot board's extrema are tiled at every shape but "three-levels",
+# whose 5,049 lanes pass EXTREMA_TILED_LANES and scatter
+LEVEL_SHAPES = [
     ((), ()), (("ords",), (8,)), (("bounds",), (64,)),
     (("bounds", "ords"), (64, 8)), (("ords", "bounds", "ords"), (8, 32, 16)),
-], ids=["whole", "ords", "bounds", "bounds-ords", "three-levels"])
-def test_both_forms_fill_the_same_board_as_numpy(levels, ks):
+    (("ords", "bounds", "ords"), (4, 16, 8)),
+]
+LEVEL_IDS = ["whole", "ords", "bounds", "bounds-ords", "three-levels",
+             "three-narrow"]
+PARTS = [("sum",), ("sum", "min", "max"), ("min", "max")]
+
+
+@pytest.mark.parametrize("levels,ks,parts", [
+    pytest.param(levels, ks, parts, id=name if parts == ("sum",)
+                 else name + "-" + "-".join(parts))
+    for parts in PARTS for (levels, ks), name in zip(LEVEL_SHAPES,
+                                                     LEVEL_IDS)])
+def test_both_forms_fill_the_same_board_as_numpy(levels, ks, parts):
+    """Counts, the sum's limbs and each lane's min / max, on both ways to
+    fill a board and on whichever extrema route each picks, against
+    numpy. The values take 0 and I32_MAX - 1, some rows take the
+    `missing` substitute, and some lanes hold no row (I32_MAX / -1)."""
     rng = np.random.default_rng(36)
     r = 4096
     mask = rng.random(r) < 0.7
@@ -332,11 +380,32 @@ def test_both_forms_fill_the_same_board_as_numpy(levels, ks):
     flat = np.zeros(r, dtype=np.int64)
     for lane, k in zip(lanes, ks):
         flat = flat * (k + 1) + lane
-    v = rng.integers(-1, 1 << 24, r).astype(np.int32)
-    ok = mask & (v >= 0)
-    bits, n = 8, 3
+    extrema = "min" in parts
+    if extrema:
+        # the largest and least values a column can hold, and rows with
+        # no value that take the substitute
+        v = rng.integers(-1, aggs_ops.I32_MAX, r).astype(np.int32)
+        v[rng.random(r) < 0.02] = 0
+        v[rng.random(r) < 0.02] = aggs_ops.I32_MAX - 1
+        miss = 7
+        if total > 1:
+            mask[flat == total - 1] = False     # a lane no row reaches
+    else:
+        v = rng.integers(-1, 1 << 24, r).astype(np.int32)
+        miss = -1
+    v_eff = np.where(v >= 0, v, miss)
+    ok = mask & (v_eff >= 0)
+    bits = 8
+    n = aggs_ops.n_limbs_for(int(v_eff.max()), bits) if "sum" in parts \
+        else 0
     want_sum = np.zeros(total, dtype=np.int64)
-    np.add.at(want_sum, flat[ok], v[ok].astype(np.int64))
+    np.add.at(want_sum, flat[ok], v_eff[ok].astype(np.int64))
+    want_min = np.full(total, aggs_ops.I32_MAX, dtype=np.int64)
+    np.minimum.at(want_min, flat[ok], v_eff[ok])
+    want_max = np.full(total, -1, dtype=np.int64)
+    np.maximum.at(want_max, flat[ok], v_eff[ok])
+    if extrema and total > 1:
+        assert (want_min == aggs_ops.I32_MAX).any()    # an empty lane
     for form in ("onehot", "scatter"):
         counts = np.asarray(aggs_ops._agg_n32_counts(
             mask, *args, levels=levels, n_buckets=ks, form=form))
@@ -344,14 +413,45 @@ def test_both_forms_fill_the_same_board_as_numpy(levels, ks):
         assert np.array_equal(counts, np.bincount(flat[mask],
                                                   minlength=total))
         board = np.asarray(aggs_ops._agg_n32_metric(
-            mask, v, np.int32(-1), *args, levels=levels, n_buckets=ks,
-            parts=("sum",), limb_bits=bits, n_limbs=n,
+            mask, v, np.int32(miss), *args, levels=levels, n_buckets=ks,
+            parts=parts, limb_bits=bits if n else 0, n_limbs=n,
             form=form)).astype(np.int64)
-        assert board.shape == (1 + n, total)
+        assert board.shape == (1 + n + 2 * extrema, total)
         assert np.array_equal(board[0], np.bincount(flat[ok],
                                                     minlength=total))
-        assert np.array_equal(
-            sum(board[1 + j] << (bits * j) for j in range(n)), want_sum)
+        if n:
+            assert np.array_equal(
+                sum(board[1 + j] << (bits * j) for j in range(n)),
+                want_sum)
+        if extrema:
+            assert np.array_equal(board[1 + n], want_min), \
+                aggs_ops.extrema_form(levels, [k + 1 for k in ks], form)
+            assert np.array_equal(board[2 + n], want_max), \
+                aggs_ops.extrema_form(levels, [k + 1 for k in ks], form)
+
+
+def test_each_extrema_program_counts_its_route(ctx, engine):
+    """`aggs.extrema.<route>` adds one a 32-bit metric program with a min
+    or max, under the route `extrema_form` picks: a top-level `stats` is
+    reduced, one under a few terms buckets tiled; a `sum` counts none."""
+    names = ["aggs.extrema." + r for r in aggs_ops.EXTREMA_FORMS]
+
+    def read():
+        return [metrics.counter(n).value for n in names]
+
+    spec = {"all": {"stats": {"field": "neg"}},
+            "lo": {"min": {"field": "big"}},
+            "t": {"terms": {"field": "cat"},
+                  "aggs": {"hi": {"max": {"field": "neg"}},
+                           "s": {"sum": {"field": "big"}}}}}
+    before = read()
+    _got, narrow, x64 = _answer(ctx, engine, spec)
+    got = [b - a for a, b in zip(before, read())]
+    assert x64 == 0 and narrow >= 3
+    assert got == [2, 1, 0], dict(zip(names, got))
+    before = read()
+    _answer(ctx, engine, {"s": {"sum": {"field": "big"}}})
+    assert read() == before
 
 
 # ---------------------------------------------------------------------------
